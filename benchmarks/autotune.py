@@ -464,6 +464,11 @@ def _model_and_fingerprint(width):
 
 
 def run_sweep(args, smoke: bool) -> int:
+    # sweeps in this process, then verifies in a child: CPU-only until
+    # the verify step runs in-process (one process for each chip)
+    from benchmarks import cpu_only_children_env
+    child_env = cpu_only_children_env("benchmarks.autotune sweep")
+
     from deeplearning4j_tpu.optimize import autotune
     from deeplearning4j_tpu.parallel.aot_cache import ArtifactStore
 
@@ -586,13 +591,12 @@ def run_sweep(args, smoke: bool) -> int:
 
     # gate 5: node B — a fresh process serves from node A's artifact
     # with zero live compiles and bitwise-identical answers
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     out = subprocess.run(
         [sys.executable, "-m", "benchmarks.autotune", "--verify-node",
          "--store", store_dir, "--width", str(width),
          "--seed", str(args.seed)],
-        cwd=_ROOT, env=env, capture_output=True, text=True, timeout=600)
+        cwd=_ROOT, env=child_env, capture_output=True, text=True,
+        timeout=600)
     if out.returncode != 0:
         failures.append(f"verify-node exited {out.returncode}:\n"
                         f"{out.stdout[-2000:]}\n{out.stderr[-2000:]}")

@@ -309,7 +309,8 @@ def run_ab(args, smoke: bool) -> int:
 
 # ---- cluster chaos smoke --------------------------------------------------
 
-def _start_nn_node(node_id, shards, reg_dir, store_dir, key, log_path):
+def _start_nn_node(node_id, shards, reg_dir, store_dir, key, log_path,
+                   env):
     cmd = [sys.executable, "-m", "deeplearning4j_tpu", "serve",
            "--neighbors-index", key, "--artifact-store", store_dir,
            "--neighbors-shards", ",".join(str(s) for s in shards),
@@ -317,7 +318,7 @@ def _start_nn_node(node_id, shards, reg_dir, store_dir, key, log_path):
            "--ui-port", "0", "--join", reg_dir, "--node-id", node_id,
            "--drain-timeout", "20"]
     log = open(log_path, "w")
-    return subprocess.Popen(cmd, cwd=_ROOT, stdout=log,
+    return subprocess.Popen(cmd, cwd=_ROOT, env=env, stdout=log,
                             stderr=subprocess.STDOUT), log
 
 
@@ -347,6 +348,9 @@ def run_cluster(args, smoke: bool = True) -> int:
     import tempfile
     from concurrent.futures import ThreadPoolExecutor
 
+    from benchmarks import cpu_only_children_env
+    child_env = cpu_only_children_env("benchmarks.neighbors cluster smoke")
+
     from deeplearning4j_tpu.parallel.aot_cache import ArtifactStore
     from deeplearning4j_tpu.parallel.node import NodeRegistry
     from deeplearning4j_tpu.retrieval.cluster import NeighborsDispatcher
@@ -375,13 +379,13 @@ def run_cluster(args, smoke: bool = True) -> int:
     handles = []
     failures = []
     pa, log = _start_nn_node("a", [0, 1], reg_dir, store_dir,
-                             "nnbench", logs["a"])
+                             "nnbench", logs["a"], child_env)
     handles.append(log)
     pb = None
     try:
         _wait_nn_node(registry, "a", pa.pid)
         pb, log = _start_nn_node("b", [2, 3], reg_dir, store_dir,
-                                 "nnbench", logs["b"])
+                                 "nnbench", logs["b"], child_env)
         handles.append(log)
         rec_b = _wait_nn_node(registry, "b", pb.pid)
 
@@ -435,7 +439,7 @@ def run_cluster(args, smoke: bool = True) -> int:
         # the shared store
         t_join = time.time()
         pa2, log = _start_nn_node("a", [0, 1], reg_dir, store_dir,
-                                  "nnbench", logs["a"] + ".2")
+                                  "nnbench", logs["a"] + ".2", child_env)
         handles.append(log)
         rec_a2 = _wait_nn_node(registry, "a", pa2.pid)
         rejoin_s = time.time() - t_join

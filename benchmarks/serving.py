@@ -648,10 +648,11 @@ def run_fleet(args, smoke: bool) -> int:
 
 # ---- cluster chaos soak: node kill / rejoin through the remote tier ------
 
-def _start_node(model_zip, node_id, reg_dir, store_dir, log_path,
+def _start_node(model_zip, node_id, reg_dir, store_dir, log_path, env,
                 slo_ms=1000.0):
     """Spawn one worker node subprocess (the real CLI path: ``serve
-    --join``). Output goes to a log file — tail printed on failure."""
+    --join``) under ``env``. Output goes to a log file — tail printed on
+    failure."""
     cmd = [sys.executable, "-m", "deeplearning4j_tpu", "serve",
            "--model", model_zip, "--inference-mode", "batched",
            "--batch-limit", "16", "--warmup-shape", str(FEATURES),
@@ -660,7 +661,7 @@ def _start_node(model_zip, node_id, reg_dir, store_dir, log_path,
            "--node-id", node_id, "--slo-ms", str(slo_ms),
            "--drain-timeout", "20"]
     log = open(log_path, "w")
-    proc = subprocess.Popen(cmd, cwd=_ROOT, stdout=log,
+    proc = subprocess.Popen(cmd, cwd=_ROOT, env=env, stdout=log,
                             stderr=subprocess.STDOUT)
     return proc, log
 
@@ -709,6 +710,9 @@ def run_cluster(args, smoke: bool) -> int:
     import urllib.request
     from concurrent.futures import ThreadPoolExecutor
 
+    from benchmarks import cpu_only_children_env
+    child_env = cpu_only_children_env("benchmarks.serving cluster soak")
+
     from deeplearning4j_tpu.models.serialization import save_model
     from deeplearning4j_tpu.parallel.aot_cache import ArtifactStore
     from deeplearning4j_tpu.parallel.node import NodeRegistry
@@ -733,7 +737,7 @@ def run_cluster(args, smoke: bool) -> int:
     def start(node_id):
         p, log = _start_node(model_zip, node_id, reg_dir, store_dir,
                              os.path.join(work, f"{node_id}.log"),
-                             slo_ms=args.slo_ms)
+                             child_env, slo_ms=args.slo_ms)
         procs.setdefault(node_id, []).append(p)
         handles.append(log)
         logs[node_id] = os.path.join(work, f"{node_id}.log")

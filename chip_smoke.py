@@ -1,0 +1,415 @@
+"""On-chip smoke: the quickest proof that the system still starts on a TPU.
+
+One process, one chip (``--chips 4``: one process, one four-chip host).
+Drives the main path once through the entry points a user calls, at the
+full width of ResNet-50 64x64 bf16 batch 384 (the BASELINE config), from
+a seed, with no network and no files outside the checkout:
+
+  phase=device   what JAX found; anything but a TPU exits non-zero here,
+                 before any model is built
+  phase=train    ``model.fit(iterator)`` through the device feeder with
+                 k_steps=1 (make_train_step) and k_steps>1
+                 (make_scan_train_step); the fed losses must equal the
+                 synchronous ``prefetch=0`` loop bit for bit
+  phase=serve    the fitted model behind ``ServingEngine``; requests of
+                 1, 3, 32 and 50 rows (the split path) against
+                 ``model.output``
+  phase=kernels  flash attention, the Pallas LSTM recurrence and the
+                 Pallas conv+BN kernels, compiled (interpret=False),
+                 forward and backward, against plain XLA
+
+Each phase prints ``phase=<name> ok|FAIL seconds=<t> ...``; seconds are
+set-up information, not a result. Any FAIL exits non-zero. On success
+the last line of stdout is ``{"ok": true, "device": {...}}``.
+"""
+
+import argparse
+import json
+import sys
+import time
+import traceback
+
+import numpy as np
+
+BATCH = 384
+K1_STEPS = 6                 # k_steps=1 steps (also the prefetch=0 replay)
+K_STEPS, K_DISPATCHES = 4, 3  # scanned dispatches after them
+SERVE_SIZES = (1, 3, 32, 50)
+# max |got - want| / max |want| against the XLA reference
+TOL = {"bfloat16": 4e-2, "float32": 2e-2}
+
+
+# ---- shared helpers ------------------------------------------------------
+
+class _Compiles:
+    """Backend-compile seconds and persistent-cache traffic, from JAX's
+    own monitoring events (cold vs warm cache shows up here)."""
+
+    def __init__(self):
+        import jax.monitoring as mon
+        self.seconds, self.hits, self.writes = 0.0, 0, 0
+        mon.register_event_duration_secs_listener(self._duration)
+        mon.register_event_listener(self._event)
+
+    def _duration(self, event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.seconds += secs
+
+    def _event(self, event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.writes += 1
+
+
+def _rel_err(got, want) -> float:
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+    if got.shape != want.shape:
+        raise AssertionError(f"shape {got.shape} != {want.shape}")
+    if not np.isfinite(got).all():
+        raise AssertionError("non-finite kernel output")
+    return float(np.max(np.abs(got - want)) / (np.max(np.abs(want)) + 1e-30))
+
+
+def _check_grads(name, fn, ref, args, dtype, errs):
+    """Forward and backward of ``fn`` against ``ref`` on ``args``; the
+    worst relative error, or the failure, lands in ``errs[name]``. The
+    cotangent weights every output element differently, so a wrong
+    element anywhere shows in some gradient."""
+    import jax
+    import jax.numpy as jnp
+
+    def fwd_bwd(f):
+        def inner(*a):
+            outs, vjp = jax.vjp(f, *a)
+            cts = jax.tree_util.tree_map(
+                lambda o: jnp.cos(jnp.arange(o.size, dtype=jnp.float32))
+                .reshape(o.shape).astype(o.dtype), outs)
+            return outs, vjp(cts)
+        return jax.jit(inner)
+
+    try:
+        got = jax.tree_util.tree_leaves(fwd_bwd(fn)(*args))
+        want = jax.tree_util.tree_leaves(fwd_bwd(ref)(*args))
+        errs[name] = max(_rel_err(g, w) for g, w in zip(got, want))
+        if errs[name] > TOL[dtype]:
+            raise AssertionError(f"max relative error {errs[name]:.3e} > "
+                                 f"{TOL[dtype]:.0e}")
+    except Exception as e:     # report every kernel, then fail the phase
+        traceback.print_exc()
+        errs[name] = f"FAIL {type(e).__name__}: {str(e)[:200]}"
+
+
+# ---- phases --------------------------------------------------------------
+
+def build_model():
+    from deeplearning4j_tpu.zoo.models import ResNet50
+    return ResNet50(num_classes=200, height=64, width=64, channels=3,
+                    compute_dtype="bfloat16", fused_blocks=True,
+                    fused_impl="xla", s2d_stem=True).init()
+
+
+def _loss_listener(track_batch_shards=False):
+    """Keeps each dispatch's loss on the device (no per-step sync that
+    would serialize the feeder) and, on several chips, records which
+    devices hold shards of the staged global batch."""
+    import jax
+    from deeplearning4j_tpu.optimize.listeners import TrainingListener
+
+    class Losses(TrainingListener):
+        def __init__(self):
+            self.device_losses = []
+            self.batch_shards = []      # per staged batch: {device: rows}
+
+        def iteration_done(self, model, iteration, epoch, loss, etl_ms,
+                           batch_size):
+            self.device_losses.append(loss)
+            if track_batch_shards:
+                self.batch_shards += [
+                    {s.device.id: s.data.shape[0]
+                     for s in a.addressable_shards}
+                    for a in jax.live_arrays()
+                    if a.shape == (BATCH, 64, 64, 3)]
+
+        def losses(self):
+            return np.asarray(
+                [np.asarray(x, np.float32) for x in self.device_losses])
+
+    return Losses()
+
+
+def _fit(model, steps, seed, listener, trainer=None, **kw):
+    """``fit`` (the model's own, or a wrapper's) over ``steps`` synthetic
+    TinyImageNet batches made from ``seed``; returns the loss of every
+    dispatch the listener has seen so far."""
+    from deeplearning4j_tpu.datasets.fetchers import (
+        TinyImageNetDataSetIterator)
+    model.set_listeners(listener)
+    (trainer or model).fit(TinyImageNetDataSetIterator(
+        batch_size=BATCH, subset=BATCH * steps, seed=seed), **kw)
+    return listener.losses()
+
+
+def phase_train(ctx):
+    model = build_model()
+    ctx["model"] = model
+    lst = _loss_listener()
+    fed = _fit(model, K1_STEPS, 7, lst)
+    scanned = _fit(model, K_STEPS * K_DISPATCHES, 8, lst,
+                   k_steps=K_STEPS)[K1_STEPS:]
+    # the synchronous loop the feeder must replay bitwise: same seeds,
+    # fresh model, no DeviceFeeder and so no StagingPool
+    replay = build_model()
+    sync = _fit(replay, K1_STEPS, 7, _loss_listener(), prefetch=0)
+    losses = np.concatenate([fed, scanned])
+    print("train losses k_steps=1 fed     :", fed.tolist())
+    print("train losses k_steps=1 prefetch=0:", sync.tolist())
+    print(f"train losses k_steps={K_STEPS} (last of each dispatch):",
+          scanned.tolist())
+    if len(fed) != K1_STEPS or len(scanned) != K_DISPATCHES:
+        raise AssertionError(f"expected {K1_STEPS}+{K_DISPATCHES} "
+                             f"dispatches, saw {len(fed)}+{len(scanned)}")
+    if not np.isfinite(losses).all():
+        raise AssertionError("non-finite training loss")
+    if not losses[-1] < losses[0]:
+        raise AssertionError(f"loss did not fall: {losses[0]} -> "
+                             f"{losses[-1]}")
+    if not np.array_equal(fed, sync):
+        raise AssertionError("fed losses differ from the prefetch=0 loop: "
+                             "staged batches were corrupted")
+    _assert_params_on_tpu(model)
+    return {"first_loss": float(losses[0]), "last_loss": float(losses[-1]),
+            "fed_equals_sync": True}
+
+
+def _assert_params_on_tpu(model, n_devices=1):
+    import jax
+    for leaf in jax.tree_util.tree_leaves(model.train_state.params):
+        devs = leaf.devices()
+        if len(devs) != n_devices or any(d.platform != "tpu" for d in devs):
+            raise AssertionError(f"param leaf on {devs}, expected "
+                                 f"{n_devices} TPU device(s)")
+
+
+def phase_train_4chips(ctx):
+    """The train phase through ParallelWrapper on four chips: same seed,
+    same global batch, per-step losses printed beside the one-chip run's."""
+    import jax
+    from deeplearning4j_tpu.parallel.wrapper import ParallelWrapper
+    model = build_model()
+    ctx["model"] = model
+    wrapper = ParallelWrapper.builder(model).workers(4).build()
+    lst = _loss_listener(track_batch_shards=True)
+    losses = _fit(model, K1_STEPS, 7, lst, trainer=wrapper)
+    print("train losses 4 chips (ParallelWrapper):", losses.tolist())
+    if len(losses) != K1_STEPS or not np.isfinite(losses).all():
+        raise AssertionError(f"bad losses {losses}")
+    if not losses[-1] < losses[0]:
+        raise AssertionError(f"loss did not fall: {losses[0]} -> "
+                             f"{losses[-1]}")
+    want = {d.id: BATCH // 4 for d in jax.devices()[:4]}
+    if not lst.batch_shards or any(b != want for b in lst.batch_shards):
+        raise AssertionError(f"staged batches held as {lst.batch_shards}, "
+                             f"expected every one as {want}")
+    _assert_params_on_tpu(model, n_devices=4)
+    return {"first_loss": float(losses[0]), "last_loss": float(losses[-1]),
+            "batch_rows_per_device": BATCH // 4}
+
+
+def phase_serve(ctx):
+    from deeplearning4j_tpu.parallel import ServingEngine
+    model = ctx.get("model") or build_model()
+    eng = ServingEngine(model, batch_limit=32, feature_shape=(64, 64, 3))
+    try:
+        rng = np.random.default_rng(0)
+        worst = 0.0
+        for n in SERVE_SIZES:
+            x = rng.normal(size=(n, 64, 64, 3)).astype(np.float32)
+            got = eng.output(x)
+            want = np.asarray(model.output(x))
+            if got.shape != (n, 200) or not np.isfinite(got).all():
+                raise AssertionError(f"request of {n}: shape {got.shape} "
+                                     "or non-finite")
+            if not np.allclose(got, want, rtol=2e-2, atol=1e-3):
+                raise AssertionError(
+                    f"request of {n}: engine and model.output differ by "
+                    f"{np.max(np.abs(got - want)):.3e}")
+            worst = max(worst, float(np.max(np.abs(got - want))))
+        eng.assert_warm()
+    finally:
+        eng.shutdown()
+    return {"requests": len(SERVE_SIZES), "max_abs_diff": worst,
+            "warmup_seconds": round(eng.warmup_seconds, 1)}
+
+
+def phase_kernels(ctx):
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.nn.layers.attention import (
+        scaled_dot_product_attention)
+    from deeplearning4j_tpu.ops.fused_conv import (
+        conv_bn_stats_xla, fused_conv_bn_act)
+    from deeplearning4j_tpu.ops.pallas_kernels import flash_attention
+    from deeplearning4j_tpu.ops.pallas_lstm import lstm_fused
+
+    errs = {}
+    rng = np.random.default_rng(0)
+
+    def arr(shape, dtype, scale=1.0):
+        return jnp.asarray(rng.normal(size=shape) * scale, dtype)
+
+    # flash attention at BERT-base heads, T=1024 (the dispatcher's
+    # crossover): causal, and a ragged key-validity mask
+    n, t, h, dh = 4, 1024, 12, 64
+    q, k, v = (arr((n, t, h, dh), jnp.bfloat16) for _ in range(3))
+    mask = jnp.asarray(np.arange(t)[None, :]
+                       < np.array([1024, 700, 333, 129])[:, None],
+                       jnp.float32)
+    _check_grads(
+        "flash_causal",
+        lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                        interpret=False),
+        lambda q, k, v: scaled_dot_product_attention(q, k, v, causal=True),
+        (q, k, v), "bfloat16", errs)
+    _check_grads(
+        "flash_masked",
+        lambda q, k, v: flash_attention(q, k, v, mask=mask,
+                                        interpret=False),
+        lambda q, k, v: scaled_dot_product_attention(q, k, v, mask=mask),
+        (q, k, v), "bfloat16", errs)
+
+    # Pallas LSTM recurrence at the 2xLSTM-512 geometry of
+    # benchmarks/lstm_crossover.py --quick: batch 256, hidden 512, T=128
+    nb, nh, nt = 256, 512, 128
+    lens = rng.integers(1, nt + 1, nb)
+    lmask = jnp.asarray(np.arange(nt)[:, None] < lens[None, :], jnp.float32)
+
+    def lstm_ref(m):
+        """Plain lax.scan cell in full-precision f32 whatever the input
+        dtype (the kernel carries h/c in f32 too), so the error measured
+        is the kernel's own and not a bf16 reference's."""
+        def run(zx, h0, c0, wh):
+            dt = zx.dtype
+            zx, h0, c0, wh = (a.astype(jnp.float32)
+                              for a in (zx, h0, c0, wh))
+
+            def cell(carry, inp):
+                zx_t, m_t = inp
+                hp, cp = carry
+                z = zx_t + jnp.dot(hp, wh)
+                i, f, o = (jax.nn.sigmoid(z[:, j * nh:(j + 1) * nh])
+                           for j in range(3))
+                c = f * cp + i * jnp.tanh(z[:, 3 * nh:])
+                hy = o * jnp.tanh(c)
+                m_t = m_t[:, None]
+                return (m_t * hy + (1 - m_t) * hp,
+                        m_t * c + (1 - m_t) * cp), m_t * hy + (1 - m_t) * hp
+            ms = jnp.ones((nt, nb), jnp.float32) if m is None else m
+            with jax.default_matmul_precision("highest"):
+                (hT, cT), ys = jax.lax.scan(cell, (h0, c0), (zx, ms))
+            return ys.astype(dt), hT.astype(dt), cT.astype(dt)
+        return run
+
+    for dtype in ("bfloat16", "float32"):
+        dt = jnp.dtype(dtype)
+        args = (arr((nt, nb, 4 * nh), dt, 0.1), arr((nb, nh), dt, 0.1),
+                arr((nb, nh), dt, 0.1), arr((nh, 4 * nh), dt, 0.05))
+        for tag, m in (("nomask", None), ("mask", lmask)):
+            _check_grads(
+                f"lstm_{dtype}_{tag}",
+                lambda zx, h0, c0, wh, m=m: lstm_fused(
+                    zx, h0, c0, wh, m, interpret=False),
+                lstm_ref(m), args, dtype, errs)
+
+    # Pallas conv+BN (the path fused_blocks=True selects by default): one
+    # bottleneck's three convs at the stage-2 and stage-4 shapes of the
+    # 64x64 model, batch 384
+    for stage, f, hw in (("s2", 128, 8), ("s4", 512, 2)):
+        for tag, cin, wshape in (("1x1_reduce", 4 * f, (4 * f, f)),
+                                 ("3x3", f, (3, 3, f, f)),
+                                 ("1x1_expand", f, (f, 4 * f))):
+            fan_in = int(np.prod(wshape[:-1]))
+            args = (arr((BATCH, hw, hw, cin), jnp.bfloat16),
+                    arr(wshape, jnp.bfloat16, fan_in ** -0.5),
+                    1.0 + arr((cin,), jnp.float32, 0.1),
+                    arr((cin,), jnp.float32, 0.1))
+            _check_grads(
+                f"conv_{stage}_{tag}",
+                lambda x, w, s, b: fused_conv_bn_act(
+                    x, w, s, b, True, True, 1, False),
+                lambda x, w, s, b: conv_bn_stats_xla(
+                    x, w, s, b, True, True, 1),
+                args, "bfloat16", errs)
+    for name, e in errs.items():
+        print(f"kernel {name}: "
+              + (e if isinstance(e, str) else f"max_rel_err={e:.3e}"))
+    failed = [n for n, e in errs.items() if isinstance(e, str)]
+    if failed:
+        raise AssertionError(f"{len(failed)} of {len(errs)} kernel checks "
+                             f"failed: {', '.join(failed)}")
+    return {"checks": len(errs), "worst_rel_err": f"{max(errs.values()):.3e}"}
+
+
+# ---- driver --------------------------------------------------------------
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    args = ap.parse_args()
+
+    t0 = time.perf_counter()
+    import importlib.metadata as md
+    import jax
+    import jaxlib
+    import deeplearning4j_tpu  # noqa: F401  (applies the compile-cache rule)
+    compiles = _Compiles()
+    devs = jax.devices()
+    dev = devs[0]
+    print(f"phase=device {'ok' if dev.platform == 'tpu' else 'FAIL'} "
+          f"seconds={time.perf_counter() - t0:.1f} "
+          f"jax={jax.__version__} jaxlib={jaxlib.__version__} "
+          f"libtpu={md.version('libtpu')} platform={dev.platform} "
+          f"device_kind={dev.device_kind!r} count={len(devs)} "
+          f"compile_cache_dir={jax.config.jax_compilation_cache_dir}",
+          flush=True)
+    if dev.platform != "tpu":
+        sys.exit(f"chip_smoke.py needs a TPU; JAX found platform "
+                 f"{dev.platform!r}. Not run.")
+    if len(devs) < args.chips:
+        sys.exit(f"chip_smoke.py --chips {args.chips}: JAX found "
+                 f"{len(devs)} device(s). Not run.")
+
+    phases = ([("train", phase_train), ("serve", phase_serve),
+               ("kernels", phase_kernels)] if args.chips == 1
+              else [("train", phase_train_4chips)])
+    ctx, failed = {}, []
+    for name, fn in phases:
+        t0, c0 = time.perf_counter(), compiles.seconds
+        try:
+            info = fn(ctx)
+            status = "ok"
+        except Exception as e:       # every phase reports; any FAIL exits 1
+            traceback.print_exc()
+            info = {"error": f"{type(e).__name__}: {str(e)[:300]}"}
+            status = "FAIL"
+            failed.append(name)
+        extra = " ".join(f"{k}={v}" for k, v in info.items())
+        print(f"phase={name} {status} "
+              f"seconds={time.perf_counter() - t0:.1f} "
+              f"compile_seconds={compiles.seconds - c0:.1f} {extra}",
+              flush=True)
+
+    from deeplearning4j_tpu.utils import native
+    print(f"compile_seconds_total={compiles.seconds:.1f} "
+          f"cache_hits={compiles.hits} cache_writes={compiles.writes} "
+          f"native_lib_loaded={native.loaded()}", flush=True)
+    if failed:
+        sys.exit(f"chip_smoke.py: FAIL in {', '.join(failed)}")
+    print(json.dumps({"ok": True, "device": {
+        "platform": dev.platform, "kind": dev.device_kind,
+        "count": len(devs)}}))
+
+
+if __name__ == "__main__":
+    main()

@@ -18,38 +18,28 @@ way around: ``NeuralNetConfiguration``, ``MultiLayerConfiguration``,
 ``ParallelWrapper``, ``Evaluation``, ``EarlyStoppingConfiguration``, etc.
 """
 
-def _wire_persistent_compile_cache():
-    """Point JAX's persistent compilation cache at a per-user directory
-    (VERDICT r3 #6: the 25-60 s cold XLA compile of the big embedding /
-    conv steps should be paid once per MACHINE, not per process).
-    Opt-out with DL4J_COMPILE_CACHE=off; override the location by
-    setting the same variable to a path. Never overrides an explicit
-    jax_compilation_cache_dir the user already configured."""
+def _wire_compile_cache():
+    """The one compile-cache rule. Where ``JAX_COMPILATION_CACHE_DIR`` is
+    set, JAX reads it itself and nothing in this package names another
+    directory. Where it is not, XLA's persistent cache goes to one fixed
+    path inside the checkout (git-ignored): a directory built from a
+    temp name, a pid or the time is never found again, so it never
+    hits. JAX's own thresholds stay (entries only for
+    compiles over 1 s), so the CPU test suite does not fill the checkout
+    with thousands of tiny entries."""
     import os
 
-    loc = os.environ.get("DL4J_COMPILE_CACHE", "")
-    if loc.lower() in ("off", "0", "none"):
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         return
-    try:
-        import jax
+    import jax
 
-        if jax.config.jax_compilation_cache_dir:
-            return
-        path = loc or os.path.join(
-            os.path.expanduser("~"), ".cache", "deeplearning4j_tpu",
-            "xla_cache")
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        # cache anything that took meaningful compile time
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          1.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                          0)
-    except Exception:          # pragma: no cover - cache is best-effort
-        pass
+    jax.config.update(
+        "jax_compilation_cache_dir",
+        os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), ".jax_cache"))
 
 
-_wire_persistent_compile_cache()
+_wire_compile_cache()
 
 from deeplearning4j_tpu.nn.config import (
     NeuralNetConfiguration,

@@ -39,11 +39,7 @@ def check_gradients(
     keeps CI fast at equal coverage confidence for randomly-initialized
     nets).
     """
-    # jax >= 0.5 exposes jax.enable_x64; 0.4.x has it in experimental
-    _enable_x64 = getattr(jax, "enable_x64", None)
-    if _enable_x64 is None:
-        from jax.experimental import enable_x64 as _enable_x64
-    with _enable_x64(True):
+    with jax.enable_x64(True):
         params64 = jax.tree_util.tree_map(
             lambda a: jnp.asarray(np.asarray(a), jnp.float64)
             if jnp.issubdtype(jnp.asarray(a).dtype, jnp.floating) else a,

@@ -131,24 +131,21 @@ class LSTM(FeedForwardLayer):
 
         # Helper tier (CudnnLSTMHelper analog): route the recurrence to
         # the fused Pallas kernel where the measured crossover (or an
-        # explicit DL4J_LSTM_IMPL=fused) says it wins; any trace-time
-        # kernel failure falls back silently to the scan below.
+        # explicit DL4J_LSTM_IMPL=fused) says so; a kernel that cannot
+        # take the geometry or fails to compile raises.
         if self._fused_eligible():
             from deeplearning4j_tpu.ops import pallas_lstm
             if pallas_lstm.choose_impl(n, h, t) == "fused":
-                try:
-                    ysT, hT, cT = pallas_lstm.lstm_fused(
-                        zx.transpose(1, 0, 2), h0, c0, params["Wh"],
-                        None if mask is None else mask.transpose(1, 0))
-                    out = ysT.transpose(1, 0, 2)
-                    if mask is not None:
-                        out = out * mask[:, :, None].astype(out.dtype)
-                    new_state = dict(state)
-                    new_state["last_h"] = hT
-                    new_state["last_c"] = cT
-                    return out, new_state
-                except Exception:
-                    pass
+                ysT, hT, cT = pallas_lstm.lstm_fused(
+                    zx.transpose(1, 0, 2), h0, c0, params["Wh"],
+                    None if mask is None else mask.transpose(1, 0))
+                out = ysT.transpose(1, 0, 2)
+                if mask is not None:
+                    out = out * mask[:, :, None].astype(out.dtype)
+                new_state = dict(state)
+                new_state["last_h"] = hT
+                new_state["last_c"] = cT
+                return out, new_state
 
         def step(carry, inp):
             if mask is None:

@@ -30,9 +30,9 @@ flattened (N·H·W, C) activation; the 3×3 runs per-image with the whole
 keep the MXU busy: at 64×64 inputs the spatial planes are tiny and the
 channel counts large, exactly the regime where conv == matmul.
 
-Like the flash-attention helper, everything falls back to plain XLA math
-(`*_reference`) off-TPU, and the Pallas path runs in interpret mode in
-tests so CPU CI exercises the same kernel code.
+The Pallas path compiles on TPU and runs in interpret mode on the CPU
+(``pallas_kernels.pallas_interpret``), so CPU CI exercises the same
+kernel code.
 """
 
 from __future__ import annotations
@@ -44,6 +44,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from deeplearning4j_tpu.ops.pallas_kernels import (
+    SCOPED_VMEM_CAP, pallas_interpret, scoped_vmem_limit)
 
 
 def _round_up(v: int, m: int) -> int:
@@ -136,22 +140,53 @@ def _c3_images_per_program(n: int, h: int, wd: int, cin: int,
     return bi
 
 
+def _c3_vmem_bound(bi: int, h: int, wd: int, cin: int, cout: int,
+                   itemsize: int) -> int:
+    """Upper bound on the scoped VMEM any 3×3 kernel (forward, merged or
+    split backward) asks Mosaic for at ``bi`` images per program: every
+    block double-buffered and every full-size intermediate of the kernel
+    body live at once, each padded to the (sublane, 128-lane) tile.
+
+    Checked against the compiler on a v5e (PR 21): every ResNet-50 3×3
+    at 64×64 and 224×224 inputs, bf16 and f32, compiles with this bound
+    as its limit. It is 2–4× what Mosaic reports using (16–27 MiB where
+    the default 16 MiB limit refused the backward: 7×7×512 in both
+    dtypes, 56×56×64 f32, 112×112×64 bf16) — loose on purpose, the limit
+    is a cap and not an allocation."""
+    def tile(lead: int, rows: int, c: int, size: int) -> int:
+        return (lead * _round_up(rows, 32 // size) * _round_up(c, 128)
+                * size)
+
+    def act(c, size):           # one (bi, h, w, c) activation
+        return tile(bi * h, wd, c, size)
+
+    def padded(c, size):        # its zero-padded (h+2, w+2) copy
+        return tile(bi * (h + 2), wd + 2, c, size)
+
+    f32 = 4
+    blocks = 2 * (act(cout, itemsize) + act(cin, itemsize))   # dy y x dx
+    weights = tile(1, 9 * cout, cin, itemsize) + tile(1, 9 * cin, cout, f32)
+    temps = (3 * act(cout, f32) + padded(cout, itemsize)
+             + 5 * act(cin, f32) + padded(cin, itemsize)
+             + 2 * act(max(cin, cout), itemsize))
+    return 2 * (blocks + weights) + temps
+
+
+def _c3_params(bi, h, wd, cin, cout, itemsize):
+    """Compiler params raising the scoped-VMEM limit to the bound."""
+    return pltpu.CompilerParams(vmem_limit_bytes=scoped_vmem_limit(
+        _c3_vmem_bound(bi, h, wd, cin, cout, itemsize)))
+
+
 def _c3_fits_vmem(h: int, wd: int, cin: int, cout: int,
                   itemsize: int = 2) -> bool:
-    """Whether even a single-image 3×3 program fits the VMEM budget.
+    """Whether a single-image 3×3 program fits VMEM, by the same bound
+    the kernels pass Mosaic as their limit — so what this admits is what
+    the compiler is asked to accept. Beyond it (ImageNet-size planes,
+    e.g. 224×224×64) the op runs the XLA reference math, forward and
+    backward. `itemsize` is the compute dtype's bytes/element."""
+    return _c3_vmem_bound(1, h, wd, cin, cout, itemsize) <= SCOPED_VMEM_CAP
 
-    The 3×3 kernels keep the whole padded (h+2)×(w+2)×Cin input plane
-    plus the h×w×Cout f32 accumulator resident; at ImageNet-size planes
-    (e.g. 224×224×64) that exceeds the ~16 MB of VMEM and the Pallas
-    call fails at compile time. Beyond this budget the op falls back to
-    the XLA reference math (advisor r3 low finding). `itemsize` is the
-    compute dtype's bytes/element — f32 planes cost 2× bf16 (advisor r4
-    low finding)."""
-    plane = (h + 2) * (wd + 2) * cin * itemsize   # padded input plane
-    # accumulator is tiled over cout in bn=min(512,cout) blocks — mirror
-    # _c3_pallas, not the full cout (a 56×56×2048 layer tiles fine)
-    acc = h * wd * min(512, cout) * 4             # f32 matmul accumulator
-    return plane + acc <= 8e6
 
 def _c3_kernel(x_ref, w_ref, s_ref, b_ref, y_ref, st_ref, *,
                relu_in: bool, want_stats: bool, norm_in: bool, h: int,
@@ -184,8 +219,8 @@ def _c3_pallas(x4d, w, scale, shift, relu_in: bool, want_stats: bool,
                out_dtype) -> Tuple[jax.Array, jax.Array]:
     n, h, wd, cin = x4d.shape
     cout = w.shape[3]
-    bi = _c3_images_per_program(n, h, wd, cin,
-                                max(x4d.dtype.itemsize, w.dtype.itemsize))
+    itemsize = max(x4d.dtype.itemsize, w.dtype.itemsize)
+    bi = _c3_images_per_program(n, h, wd, cin, itemsize)
     bn = min(512, cout)
     ni, nn = n // bi, -(-cout // bn)
     kernel = functools.partial(_c3_kernel, relu_in=relu_in,
@@ -208,6 +243,7 @@ def _c3_pallas(x4d, w, scale, shift, relu_in: bool, want_stats: bool,
             jax.ShapeDtypeStruct((n, h, wd, cout), out_dtype),
             jax.ShapeDtypeStruct((ni, 2, cout), jnp.float32),
         ],
+        compiler_params=_c3_params(bi, h, wd, cin, cout, itemsize),
         interpret=interpret,
     )(x4d, w, scale[None, None, None, :], shift[None, None, None, :])
     stats = jnp.sum(st, axis=0) if want_stats else None
@@ -426,8 +462,8 @@ def _c3_bwd_merged_pallas(x, dy, y, w, dst, scale, shift, relu_in,
                           interpret, out_dtype):
     n, h, wd, cin = x.shape
     cout = dy.shape[3]
-    bi = _c3_images_per_program(n, h, wd, cin,
-                                max(x.dtype.itemsize, w.dtype.itemsize))
+    itemsize = max(x.dtype.itemsize, w.dtype.itemsize)
+    bi = _c3_images_per_program(n, h, wd, cin, itemsize)
     ni = n // bi
     wt = w[::-1, ::-1].transpose(0, 1, 3, 2)
     a4 = dst[0][None, None, None, :]
@@ -459,6 +495,7 @@ def _c3_bwd_merged_pallas(x, dy, y, w, dst, scale, shift, relu_in,
             jax.ShapeDtypeStruct((3, 3, cin, cout), jnp.float32),
             jax.ShapeDtypeStruct((ni, 2, cin), jnp.float32),
         ],
+        compiler_params=_c3_params(bi, h, wd, cin, cout, itemsize),
         interpret=interpret,
     )(dy, y, wt, x, a4, b4, s4, sh4)
     st = jnp.sum(st, axis=0)
@@ -469,8 +506,9 @@ def _c3_bwd_pallas(x, dy, y, w, dst, scale, shift, relu_in, norm_in,
                    interpret, out_dtype):
     n, h, wd, cin = x.shape
     cout = dy.shape[3]
-    bi = _c3_images_per_program(n, h, wd, cin,
-                                max(x.dtype.itemsize, w.dtype.itemsize))
+    itemsize = max(x.dtype.itemsize, w.dtype.itemsize)
+    bi = _c3_images_per_program(n, h, wd, cin, itemsize)
+    params = _c3_params(bi, h, wd, cin, cout, itemsize)
     ni = n // bi
     bci = min(512, cin)
     wt = w[::-1, ::-1].transpose(0, 1, 3, 2)       # flip + IO swap
@@ -502,6 +540,7 @@ def _c3_bwd_pallas(x, dy, y, w, dst, scale, shift, relu_in, norm_in,
             jax.ShapeDtypeStruct((n, h, wd, cin), out_dtype),
             jax.ShapeDtypeStruct((ni, 2, cin), jnp.float32),
         ],
+        compiler_params=params,
         interpret=interpret,
     )(dy, y, wt, x, a4, b4, s4, sh4)
     st = jnp.sum(st, axis=0)
@@ -524,6 +563,7 @@ def _c3_bwd_pallas(x, dy, y, w, dst, scale, shift, relu_in, norm_in,
         out_specs=pl.BlockSpec((3, 3, cin, bco),
                                lambda j, k: (0, 0, 0, j)),
         out_shape=jax.ShapeDtypeStruct((3, 3, cin, cout), jnp.float32),
+        compiler_params=params,
         interpret=interpret,
     )(x, dy, y, s4, sh4, a4, b4)
     return dx, dw, st[0], st[1]
@@ -674,7 +714,7 @@ def fused_conv_bn_act(x, w, scale, shift, relu_in: bool = True,
 def _fused_fwd_impl(x, w, scale, shift, relu_in, norm_in, stride,
                     interpret):
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
     if w.ndim == 2:
         if stride != 1:
             x = x[:, ::stride, ::stride, :]
@@ -709,7 +749,7 @@ def _fused_bwd_rule(relu_in, norm_in, stride, interpret, res, cots):
     x, w, scale, shift, y = res
     dy, dst = cots
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
     if dst is None:
         dst = jnp.zeros((2, y.shape[-1]), jnp.float32)
     dst = dst.astype(jnp.float32)

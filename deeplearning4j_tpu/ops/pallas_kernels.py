@@ -13,9 +13,9 @@ wrapper accepts the framework-standard (N, T, H, Dh)). The grid is
 (batch, head, q-block); each program streams the full K/V for its head
 through VMEM in ``block_k`` chunks with an online softmax.
 
-Like the reference's helper SPI, failure is safe: `attention()` silently
-falls back to the plain XLA path when shapes/platform don't fit the
-kernel (ConvolutionLayer.java:173 helperCountFail analog).
+`attention()` chooses between the kernel and the plain XLA path from
+what it can observe in its inputs (backend, sequence length); a kernel
+the compiler refuses raises — there is no silent fallback.
 """
 
 from __future__ import annotations
@@ -98,14 +98,38 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
         lse_ref[0, 0] = jnp.where(valid, m + jnp.log(l_safe), _NEG)
 
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
+def pallas_interpret() -> bool:
+    """Interpret mode for every Pallas entry point in ``ops/``: compiled
+    on "tpu", interpreted on "cpu" (the CPU test suite runs the same
+    kernel code that way), an error anywhere else — a backend that does
+    not call itself "tpu" must not quietly interpret."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels compile for 'tpu' and interpret on 'cpu'; the "
+        f"default backend is {backend!r}")
 
 
-def _dim_sem(n: int):
-    return _CompilerParams(
-        dimension_semantics=("parallel",) * (n - 1) + ("arbitrary",))
+_SCOPED_VMEM_DEFAULT = 16 << 20   # Mosaic's scoped-VMEM limit unless raised
+SCOPED_VMEM_CAP = 100 << 20       # of the 128 MiB a v5e/v6e core has
+
+
+def scoped_vmem_limit(need: int) -> Optional[int]:
+    """``vmem_limit_bytes`` for a kernel that may ask for ``need`` bytes
+    of scoped VMEM: None while Mosaic's default covers it, else the need,
+    capped — past the cap Mosaic's own error surfaces."""
+    if need <= _SCOPED_VMEM_DEFAULT:
+        return None
+    return min(need, SCOPED_VMEM_CAP)
+
+
+def _dim_sem(n: int, vmem_limit_bytes: Optional[int] = None):
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",) * (n - 1) + ("arbitrary",),
+        vmem_limit_bytes=vmem_limit_bytes)
 
 
 def _flash_forward(q, k, v, mask, causal: bool, block_q: int, block_k: int,
@@ -461,8 +485,7 @@ def flash_attention(q, k, v, mask=None, causal: bool = False,
     Drop-in for nn.layers.attention.scaled_dot_product_attention. ``mask``
     is the (N, T_k) key-validity mask. Sequences are padded to the block
     size internally (padding is masked out, query padding sliced off).
-    ``interpret`` defaults to True off-TPU so tests exercise the same
-    kernel on the CPU mesh. ``bwd_impl`` selects the backward
+    ``interpret`` defaults to ``pallas_interpret()``. ``bwd_impl`` selects the backward
     implementation explicitly ("pallas" kernels or the "xla" jnp/scan
     reference); None defers to the ``DL4J_FLASH_BWD`` env override
     (default pallas).
@@ -471,7 +494,7 @@ def flash_attention(q, k, v, mask=None, causal: bool = False,
         raise ValueError(f"bwd_impl must be 'pallas'/'xla'/None, "
                          f"got {bwd_impl!r}")
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
     n, tq, h, dh = q.shape
     tk = k.shape[1]
     block_q = min(block_q, max(tq, 1))
@@ -520,21 +543,16 @@ _FLASH_MIN_SEQ = 1024
 
 def attention(q, k, v, mask=None, causal: bool = False,
               prefer_flash: Optional[bool] = None):
-    """Helper-SPI dispatch (the reflective cuDNN-hook analog): use the
-    Pallas kernel when it applies AND the sequence is long enough to pay
-    for streaming, else the plain XLA lowering (the same dual-tier
-    policy as the reference's cuDNN helper + helperCountFail fallback,
-    ConvolutionLayer.java:173)."""
+    """Helper-SPI dispatch (the reflective cuDNN-hook analog): the
+    Pallas kernel on TPU when the sequence is long enough to pay for
+    streaming (``flash_attention`` pads to its block size, so any length
+    is block-aligned), else the plain XLA lowering. The choice rests on
+    the inputs alone; a kernel the compiler refuses raises."""
     from deeplearning4j_tpu.nn.layers.attention import (
         scaled_dot_product_attention)
     if prefer_flash is None:
         prefer_flash = (jax.default_backend() == "tpu"
                         and max(q.shape[1], k.shape[1]) >= _FLASH_MIN_SEQ)
-    if not prefer_flash:
-        return scaled_dot_product_attention(q, k, v, mask=mask,
-                                            causal=causal)
-    try:
+    if prefer_flash:
         return flash_attention(q, k, v, mask=mask, causal=causal)
-    except Exception:          # helper fallback, never fatal
-        return scaled_dot_product_attention(q, k, v, mask=mask,
-                                            causal=causal)
+    return scaled_dot_product_attention(q, k, v, mask=mask, causal=causal)

@@ -29,9 +29,9 @@ Masking matches the scan cell exactly: masked ticks do not advance
 Dispatch follows the helper-SPI-with-measured-crossover discipline of
 ``pallas_kernels.attention``: ``choose_impl`` routes to the fused
 kernel only where ``benchmarks/lstm_crossover.py`` measurements say it
-wins, falls back to the ``lax.scan`` cell otherwise, and any trace-time
-kernel failure falls back silently (ConvolutionLayer.java:173
-helperCountFail analog).
+wins and the compiled kernel can take the geometry, and to the
+``lax.scan`` cell otherwise. A forced ``DL4J_LSTM_IMPL=fused`` with a
+geometry the compiled kernel cannot take raises with the reason.
 """
 
 from __future__ import annotations
@@ -45,17 +45,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from deeplearning4j_tpu.ops.pallas_kernels import _dim_sem
+from deeplearning4j_tpu.ops.pallas_kernels import (
+    _dim_sem, pallas_interpret, scoped_vmem_limit)
 
 _IMPL_ENV = "DL4J_LSTM_IMPL"  # "fused" | "scan" | "auto" (default)
 
 # Measured crossover thresholds from benchmarks/lstm_crossover.py runs on
 # real hardware: rules of (min_batch, min_hidden, min_seq); the fused
-# kernel is auto-selected when ANY rule is satisfied. EMPTY as of round 6:
-# no TPU chip was attached to the builder session, so auto-dispatch stays
-# on the scan path until the crossover bench is captured on hardware —
-# thresholds here must come from measurements, not guesses (the attention
-# crossover discipline). Opt in explicitly with DL4J_LSTM_IMPL=fused.
+# kernel is auto-selected when ANY rule is satisfied. EMPTY: the
+# crossover bench has not been captured on a chip, so auto-dispatch stays
+# on the scan path — thresholds here must come from measurements, not
+# guesses (the attention crossover discipline). Opt in explicitly with
+# DL4J_LSTM_IMPL=fused.
 _MEASURED_FUSED_WINS: Tuple[Tuple[int, int, int], ...] = ()
 
 # Runtime override installed by the autotune engine (the
@@ -94,16 +95,50 @@ def fused_wins(batch: int, hidden: int, seq: int) -> bool:
                for (b, h, t) in dispatch_rules())
 
 
+def compiled_geometry_error(hidden: int) -> Optional[str]:
+    """Why the COMPILED kernel cannot take this geometry, or None. The
+    kernels slice the (N, 4H) gate slab at lane offsets H, 2H and 3H
+    (``z[:, nh:2 * nh]``); Mosaic needs those lane-aligned. Interpret
+    mode (CPU) takes any H."""
+    if hidden % 128:
+        return (f"hidden size {hidden} is not a multiple of 128: the "
+                "compiled kernel slices the (N, 4H) gate slab at lane "
+                "offsets H, 2H and 3H")
+    return None
+
+
 def choose_impl(batch: int, hidden: int, seq: int,
                 backend: Optional[str] = None) -> str:
-    """Dispatch decision: 'fused' or 'scan'."""
+    """Dispatch decision: 'fused' or 'scan'. An explicit
+    ``DL4J_LSTM_IMPL`` is taken as given (``lstm_fused`` raises where
+    the compiled kernel cannot run it); 'auto' picks the fused kernel
+    only on TPU, where the measured table says it wins, at a geometry
+    the compiled kernel can take."""
     mode = os.environ.get(_IMPL_ENV, "auto")
     if mode in ("fused", "scan"):
         return mode
     backend = backend or jax.default_backend()
-    if backend == "tpu" and fused_wins(batch, hidden, seq):
+    if (backend == "tpu" and fused_wins(batch, hidden, seq)
+            and compiled_geometry_error(hidden) is None):
         return "fused"
     return "scan"
+
+
+def _vmem_limit(block_t: int, n: int, h: int, itemsize: int,
+                bwd: bool) -> Optional[int]:
+    """Scoped-VMEM limit computed from the kernel's buffers: every
+    pipelined block twice (double buffering), the scratch once, and the
+    (N, 4H) f32 gate temporaries. The default limit is too small from
+    about the 2xLSTM-512 geometry on: its f32 backward (N=256, H=512)
+    asks for 22 MiB (chip run, PR 21)."""
+    nh, n4h, h4h = block_t * n * h, block_t * n * 4 * h, 4 * h * h
+    blocks = itemsize * ((4 if bwd else 3) * nh + 2 * n4h + 4 * n * h
+                         + h4h + block_t * n * 128)
+    scratch = 4 * 2 * n * h
+    if bwd:
+        blocks += 4 * h4h          # dWh out, f32
+        scratch += 4 * h4h         # dWh accumulator
+    return scoped_vmem_limit(2 * blocks + scratch + 4 * 4 * n4h)
 
 
 def _fwd_kernel(zx_ref, h0_ref, c0_ref, wh_ref, mask_ref,
@@ -198,7 +233,8 @@ def _fused_forward(zx, h0, c0, wh, mask, block_t: int, interpret: bool):
             pltpu.VMEM((n, h), jnp.float32),
             pltpu.VMEM((n, h), jnp.float32),
         ],
-        compiler_params=_dim_sem(1),
+        compiler_params=_dim_sem(
+            1, _vmem_limit(block_t, n, h, dt.itemsize, bwd=False)),
         interpret=interpret,
     )(zx, h0, c0, wh, mask)
 
@@ -304,7 +340,8 @@ def _fused_backward(dys, dhT, dcT, gates, tcs, cprev, hprev, mask, wh,
             pltpu.VMEM((n, h), jnp.float32),
             pltpu.VMEM((h, g4), jnp.float32),
         ],
-        compiler_params=_dim_sem(1),
+        compiler_params=_dim_sem(
+            1, _vmem_limit(block_t, n, h, dys.dtype.itemsize, bwd=True)),
         interpret=interpret,
     )(dys, dhT, dcT, gates, tcs, cprev, hprev, mask, wh)
 
@@ -349,7 +386,11 @@ def lstm_fused(zx, h0, c0, wh, mask=None, *, block_t: int = _DEF_BLOCK_T,
     whose backward is itself a fused reverse-time kernel.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
+    if not interpret:
+        why = compiled_geometry_error(h0.shape[1])
+        if why is not None:
+            raise ValueError(f"lstm_fused: {why}")
     t = zx.shape[0]
     n = zx.shape[1]
     if mask is None:

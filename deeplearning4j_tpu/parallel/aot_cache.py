@@ -10,10 +10,12 @@ XLA compile per (bucket, target). This module persists both halves:
    per ladder bucket. Loading one skips re-tracing the model's Python
    layer stack — ``export.deserialize(blob).call`` is a thin wrapper
    whose own trace is O(1) in model depth.
-2. **XLA executable cache**: the JAX persistent compilation cache is
-   pointed at ``<cache_dir>/xla`` so the backend compile of each bucket
+2. **XLA executable cache**: the backend compile of each bucket
    (including the blob-wrapper's signature, which is primed at save
-   time) is a disk hit in later processes. Its entries are keyed by the
+   time) lands in JAX's persistent compilation cache, wherever the
+   process keeps it — ``JAX_COMPILATION_CACHE_DIR`` or the package's
+   fixed in-checkout directory (``deeplearning4j_tpu/__init__.py``);
+   this module never redirects it. Its entries are keyed by the
    computation fingerprint + jaxlib version + backend, so a stale entry
    can never be served — it just misses.
 
@@ -41,7 +43,6 @@ Layout on disk::
 
     <cache_dir>/manifest.json          per-precision fingerprints + buckets
     <cache_dir>/bucket_<N>.<precision>.stablehlo   exported modules
-    <cache_dir>/xla/...                JAX persistent compilation cache
 """
 
 from __future__ import annotations
@@ -49,54 +50,16 @@ from __future__ import annotations
 import hashlib
 import json
 import os
-import threading
 from pathlib import Path
 from typing import Any, Dict, Optional
 
 import numpy as np
+from jax import export
 
 from deeplearning4j_tpu.chaos.hook import chaos_site
 
 MANIFEST = "manifest.json"
 FORMAT_VERSION = 2          # 2: per-precision entries + calibration hash
-
-_xla_cache_lock = threading.Lock()
-_xla_cache_dir: Optional[str] = None
-
-
-def enable_xla_cache(path: str) -> bool:
-    """Point the process-wide JAX persistent compilation cache at
-    ``path`` (idempotent; the setting is global — first engine wins and
-    later engines reuse it). Returns False when this jax version has no
-    persistent cache support; the blob half still works."""
-    global _xla_cache_dir
-    import jax
-    with _xla_cache_lock:
-        if _xla_cache_dir is not None:
-            return True
-        try:
-            os.makedirs(path, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", path)
-            # serving sweeps are many small compiles: cache all of them,
-            # not just the >1s ones the training-oriented default keeps
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.0)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                              -1)
-            # a compile that ran before the dir was configured pins the
-            # cache "initialized but disabled" — force re-init so the
-            # new dir takes effect mid-process (e.g. after model load)
-            try:
-                from jax.experimental.compilation_cache import (
-                    compilation_cache as _cc)
-            except ImportError:
-                from jax._src import compilation_cache as _cc
-            if hasattr(_cc, "reset_cache"):
-                _cc.reset_cache()
-        except Exception:
-            return False
-        _xla_cache_dir = path
-        return True
 
 
 def _tree_spec(params) -> list:
@@ -207,7 +170,6 @@ class AOTExecutableCache:
     - ``"mismatch"``  manifest found but the fingerprint diverged —
       ``reason`` names the first differing field; live compile is used
       (and ``save`` rewrites the cache for the new fingerprint)
-    - ``"disabled"``  jax.export unavailable; only the XLA cache half runs
     """
 
     def __init__(self, cache_dir: str):
@@ -219,18 +181,6 @@ class AOTExecutableCache:
         self.misses = 0          # buckets that fell through to live trace
         self.quarantined = 0     # blobs failing their content checksum
         self._chaos_save = chaos_site("store.save")
-        self.xla_cache_enabled = enable_xla_cache(str(self.dir / "xla"))
-        try:
-            from jax import export  # noqa: F401  (jax >= 0.4.34)
-            self._export = export
-        except ImportError:
-            try:
-                from jax.experimental import export  # older spelling
-                self._export = export
-            except ImportError:
-                self._export = None
-                self.state = "disabled"
-                self.reason = "jax.export unavailable"
 
     @staticmethod
     def _precision_of(fp: Dict) -> str:
@@ -247,8 +197,6 @@ class AOTExecutableCache:
         (state/reason record why). Other precisions' entries are
         invisible to the lookup — they can neither satisfy nor
         invalidate it."""
-        if self._export is None:
-            return {}
         path = self.dir / MANIFEST
         if not path.exists():
             self.state = "cold"
@@ -295,8 +243,7 @@ class AOTExecutableCache:
                     # NEVER crash (or serve garbage) on store corruption
                     self._quarantine(blob_path, bucket, "checksum")
                     continue
-                loaded[int(bucket)] = self._export.deserialize(
-                    bytearray(raw))
+                loaded[int(bucket)] = export.deserialize(bytearray(raw))
             except Exception as e:
                 # one bad blob falls through to live compile; the rest
                 # of the table still loads
@@ -337,8 +284,6 @@ class AOTExecutableCache:
         sibling precisions keep theirs (each entry's fingerprint is
         self-contained, so a stale sibling just misses at its own
         load). Returns the number of buckets saved."""
-        if self._export is None:
-            return 0
         import jax
         precision = self._precision_of(fp)
         params, mstate = committed
@@ -348,7 +293,7 @@ class AOTExecutableCache:
             x = np.zeros((int(bucket),) + tuple(example.shape[1:]),
                          example.dtype)
             try:
-                exp = self._export.export(jit_fn)(params, mstate, x)
+                exp = export.export(jit_fn)(params, mstate, x)
                 blob = bytes(exp.serialize())
                 # checksum of the TRUE bytes: corruption between save
                 # and load (torn write, bit rot — or an armed chaos
@@ -390,8 +335,7 @@ class AOTExecutableCache:
         return {"state": self.state, "reason": self.reason,
                 "hits": self.hits, "misses": self.misses,
                 "quarantined": self.quarantined,
-                "dir": str(self.dir),
-                "xla_cache": self.xla_cache_enabled}
+                "dir": str(self.dir)}
 
 
 class ArtifactStore:
@@ -404,7 +348,6 @@ class ArtifactStore:
 
         <root>/objects/<key>/manifest.json
         <root>/objects/<key>/bucket_<N>.<precision>.stablehlo
-        <root>/objects/<key>/xla/...
 
     Concurrency relies on the cache's own discipline: the manifest is
     written atomically and LAST (a reader mid-save just misses), every

@@ -131,26 +131,6 @@ def global_device_value_range(value: float) -> tuple:
     return float(mn), float(mx)  # host-sync-ok: barrier helper: the sync is the point
 
 
-def compat_shard_map(f, mesh: Mesh, in_specs, out_specs,
-                     check_vma: bool = False, axis_names=None):
-    """``jax.shard_map`` across JAX versions. New JAX exposes
-    ``jax.shard_map(..., check_vma=..., axis_names=...)``; 0.4.x has
-    ``jax.experimental.shard_map.shard_map(..., check_rep=..., auto=...)``
-    where ``auto`` is the complement of the manual ``axis_names`` set.
-    An empty/None ``axis_names`` means fully manual in both."""
-    if hasattr(jax, "shard_map"):
-        kw = dict(check_vma=check_vma)
-        if axis_names:
-            kw["axis_names"] = axis_names
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    auto = (frozenset(mesh.axis_names) - frozenset(axis_names)
-            if axis_names else frozenset())
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma, auto=auto)
-
-
 def batch_sharding(mesh: Mesh, axis: str = DATA_AXIS) -> NamedSharding:
     """Leading-dim (batch) sharding for input batches."""
     return NamedSharding(mesh, P(axis))

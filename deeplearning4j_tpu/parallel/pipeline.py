@@ -217,8 +217,7 @@ def pipeline_apply(stage_fn: Callable,
     # standard 3D dp×tp×pp deployment (partial-auto shard_map).
     manual = (frozenset({axis}) if len(mesh.axis_names) > 1
               else frozenset())
-    from deeplearning4j_tpu.parallel.mesh import compat_shard_map
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         lambda p, xm, cm: _pipeline_local(p, xm, cm, fn3, axis, m,
                                           repeats, remat),
         mesh=mesh, in_specs=(pspec, P(), P()), out_specs=P(),

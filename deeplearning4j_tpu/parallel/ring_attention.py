@@ -105,14 +105,13 @@ def _shard_attention(local_fn, q, k, v, mask, mesh: Mesh, axis: str,
     bspec = batch_axis if batch_axis else None
     spec_qkv = P(bspec, axis, None, None)
     spec_mask = P(bspec, axis)
-    from deeplearning4j_tpu.parallel.mesh import compat_shard_map
     if mask is None:
-        shard_fn = compat_shard_map(
+        shard_fn = jax.shard_map(
             lambda q_, k_, v_: local_fn(q_, k_, v_, None),
             mesh=mesh, in_specs=(spec_qkv,) * 3, out_specs=spec_qkv,
             check_vma=False)
         return shard_fn(q, k, v)
-    shard_fn = compat_shard_map(
+    shard_fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(spec_qkv, spec_qkv, spec_qkv, spec_mask),
         out_specs=spec_qkv, check_vma=False)

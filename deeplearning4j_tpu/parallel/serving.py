@@ -24,8 +24,8 @@ coordinated pieces:
    replica and — for the sharded path — replicated over the mesh. No
    per-call reliance on the global trace cache keyed off
    ``model.train_state``: the engine owns an explicit per-bucket
-   executable table (AOT ``jit.lower(...).compile()``, falling back to
-   the jitted call where AOT is unavailable).
+   executable table (AOT ``jit.lower(...).compile()``; a bucket that
+   will not compile raises at warmup).
 3. **Bounded bucket ladder + request splitting.** Batches pad to the
    smallest power-of-two bucket in ``[min_bucket, batch_limit]``;
    oversized requests are split across dispatches at ``output()`` and
@@ -336,8 +336,8 @@ class ServingEngine:
         self._c_aot = reg.counter(
             "dl4j_serving_aot_cache_total",
             "persisted AOT executable cache events: hit = bucket "
-            "loaded from a StableHLO blob, miss = fell through to live "
-            "trace, save = bucket persisted after warmup")
+            "loaded from a StableHLO blob, save = bucket persisted "
+            "after warmup")
         if aot_cache_dir is not None and self._jit is not None \
                 and self.feature_shape is not None:
             from deeplearning4j_tpu.parallel.aot_cache import (
@@ -456,31 +456,22 @@ class ServingEngine:
             params, mstate = self._committed[where]
             x = self._place(np.zeros((bucket,) + self.feature_shape,
                                      self.dtype), where)
-            exe = None
             exp = (self._loaded_exports.get(bucket)
                    if where != MESH else None)
+            # a compile error raises here, at warmup, not on the first
+            # live request: a blob that passed the fingerprint and
+            # checksum checks and still will not compile is a fault, not
+            # a cache miss
             if exp is not None:
                 # persisted-cache path: compile the deserialized
                 # StableHLO wrapper (no model re-trace; the XLA compile
                 # itself is a persistent-cache disk hit, primed at save)
-                try:
-                    exe = jax.jit(exp.call).lower(params, mstate,
-                                                  x).compile()
-                    self.aot_cache.hits += 1
-                    self._c_aot.inc(1.0, session=self.session_id, precision=self._ptag,
-                                    event="hit")
-                except Exception:
-                    self.aot_cache.misses += 1
-                    self._c_aot.inc(1.0, session=self.session_id, precision=self._ptag,
-                                    event="miss")
-            if exe is None:
-                try:
-                    exe = self._jit.lower(params, mstate, x).compile()
-                except Exception:
-                    # AOT unavailable (older jax / exotic shardings):
-                    # the jitted call still caches one executable per
-                    # signature
-                    exe = self._jit
+                exe = jax.jit(exp.call).lower(params, mstate, x).compile()
+                self.aot_cache.hits += 1
+                self._c_aot.inc(1.0, session=self.session_id,
+                                precision=self._ptag, event="hit")
+            else:
+                exe = self._jit.lower(params, mstate, x).compile()
             self._exe[key] = exe
             phase = "warmup" if not self._warmed else "live"
             if self._warmed:

@@ -39,8 +39,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from deeplearning4j_tpu.datasets.dataset import DataSet, DataSetIterator
 from deeplearning4j_tpu.observe.telemetry import has_buffer
 from deeplearning4j_tpu.optimize.solver import TrainState
-from deeplearning4j_tpu.parallel.mesh import (DATA_AXIS, compat_shard_map,
-                                              create_mesh)
+from deeplearning4j_tpu.parallel.mesh import DATA_AXIS, create_mesh
 
 
 class TrainingMode(enum.Enum):
@@ -254,7 +253,7 @@ class ParallelWrapper:
                              for l in leaves),
                             jnp.zeros((), jnp.float32))
                 return jnp.sqrt(sumsq).reshape(1, 1)
-            return compat_shard_map(
+            return jax.shard_map(
                 l2, mesh=mesh, in_specs=(P(),),
                 out_specs=P(DATA_AXIS), check_vma=False)(params)
 
@@ -373,7 +372,7 @@ class ParallelWrapper:
 
         # Everything replicated except the batch: (k, B, ...) sharded on B.
         pspec_batch = P(None, DATA_AXIS)
-        wrapped = compat_shard_map(
+        wrapped = jax.shard_map(
             worker_steps, mesh=mesh,
             in_specs=(P(), pspec_batch, pspec_batch, pspec_batch,
                       pspec_batch, P()),
@@ -517,7 +516,7 @@ class ParallelWrapper:
 
         pspec_batch = P(None, DATA_AXIS)
         stacked = P(DATA_AXIS)          # leading worker dim
-        wrapped = compat_shard_map(
+        wrapped = jax.shard_map(
             round_fn, mesh=mesh,
             in_specs=(P(), stacked, stacked, stacked,
                       pspec_batch, pspec_batch, pspec_batch, pspec_batch,

@@ -138,6 +138,12 @@ def get_lib() -> Optional[ctypes.CDLL]:
     return _lib
 
 
+def loaded() -> bool:
+    """True when the shared library is already loaded in this process;
+    never builds or loads it (chip_smoke.py reports this)."""
+    return _lib is not None
+
+
 def available() -> bool:
     return get_lib() is not None
 

@@ -19,6 +19,16 @@ fi
 # host-sync rule.
 python -m tools.graftlint --baseline tools/graftlint/baseline.json \
   --max-seconds 10
+# no-chip refusal: chip_smoke.py (the on-chip check: ResNet-50 fit(),
+# ServingEngine and the Pallas kernels on one TPU, run through the chip
+# tool before every benchmark) and bench.py have no CPU configuration —
+# where JAX finds no TPU they must exit non-zero before building a
+# model. The suite itself stays on the CPU.
+for script in chip_smoke.py bench.py; do
+  if JAX_PLATFORMS=cpu python "$script" >/dev/null 2>&1; then
+    echo "$script ran without a TPU: it must refuse"; exit 1
+  fi
+done
 # perf tier: compiled-in telemetry WITH in-step histograms (the flight
 # recorder's config) must stay within a 3% step-overhead budget on the
 # CPU path — the observe/ "one fetch per flush interval" claim

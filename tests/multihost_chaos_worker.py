@@ -175,9 +175,8 @@ def main_dp():
 
 def main_3d():
     """Composed dp×tp×pp chaos: a PipelinedTransformerLM trained with a
-    manual jitted SGD step on a 3-axis mesh (GSPMD sequential path —
-    jax 0.4.x cannot lower the partial-auto pipelined schedule, see
-    tests/test_3d_parallel.py), sharded checkpoints every 2 steps, and
+    manual jitted SGD step on a 3-axis mesh (GSPMD sequential path),
+    sharded checkpoints every 2 steps, and
     resume onto whatever layout THIS launch specifies via
     restore_sharded's explicit param_shardings."""
     import numpy as np

@@ -17,18 +17,6 @@ from deeplearning4j_tpu.parallel.pipeline import (
 
 VOCAB, WIDTH, T = 16, 8, 6
 
-# The dp×tp×pp composition needs PARTIAL-AUTO shard_map (manual over
-# 'pipe', GSPMD-auto over 'data'/'model').  jax 0.4.x lowers that to
-# HLO the bundled XLA rejects — axis_index becomes a PartitionId op the
-# SPMD partitioner calls ambiguous, and manual-subgroup shardings trip
-# CHECK failures in spmd_partitioner.cc even for a minimal
-# ppermute+psum body.  jax >= 0.5 (jax.shard_map with axis_names=)
-# fixed the lowering; single-axis (fully-manual) meshes work on both.
-_partial_auto_ok = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="partial-auto shard_map needs jax>=0.5; 0.4.x SPMD "
-           "partitioner cannot lower manual-subgroup collectives")
-
 
 def _mesh(dp, tp, pp):
     devs = np.asarray(jax.devices()[: dp * tp * pp]).reshape(dp, tp, pp)
@@ -48,7 +36,6 @@ def _data(batch, seed=0):
 
 
 class Test3DComposition:
-    @_partial_auto_ok
     def test_pipelined_tp_matches_sequential(self):
         mesh = _mesh(2, 2, 2)
         lm = _lm(mesh, n_layers=4)
@@ -63,7 +50,6 @@ class Test3DComposition:
             ref = float(lm.loss(params, toks, tgts, pipelined=False))
         assert pipelined == pytest.approx(ref, rel=1e-5)
 
-    @_partial_auto_ok
     def test_3d_train_step_moves_params(self):
         mesh = _mesh(2, 2, 2)
         lm = _lm(mesh, n_layers=4)
@@ -86,7 +72,6 @@ class Test3DComposition:
 
 
 class Test3DCheckpointResharding:
-    @_partial_auto_ok
     def test_cross_layout_restore(self, tmp_path):
         """Save on a 2dp×2tp×2pp layout (circular, 2 stages × 2
         repeats), restore onto 1dp×2tp×4pp (4 straight stages) — the

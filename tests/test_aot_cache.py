@@ -18,7 +18,6 @@ import pytest
 from deeplearning4j_tpu.observe.registry import MetricsRegistry
 from deeplearning4j_tpu.parallel.aot_cache import (
     AOTExecutableCache,
-    enable_xla_cache,
     fingerprint,
 )
 from deeplearning4j_tpu.parallel.serving import ServingEngine
@@ -322,16 +321,57 @@ class TestCalibrationDeterminism:
             a["provenance"]
 
 
-class TestXlaCacheConfig:
-    def test_enable_idempotent(self, tmp_path):
-        # process-global, first wins; later calls are True no-ops
-        assert enable_xla_cache(str(tmp_path / "x1")) is True
-        assert enable_xla_cache(str(tmp_path / "x2")) is True
+# child script: report the compile-cache directory in effect after each
+# step that used to move it (package import, AOT store, serving engine)
+_CACHE_DIR_CHILD = """
+import json, sys
+sys.path.insert(0, {root!r})
+import jax
+import deeplearning4j_tpu
+seen = [jax.config.jax_compilation_cache_dir]
+from tests.test_aot_cache import AOTExecutableCache, _engine, _tiny_model
+AOTExecutableCache({other!r} + "/store")
+seen.append(jax.config.jax_compilation_cache_dir)
+eng = _engine(_tiny_model(), {other!r} + "/engine")
+eng.shutdown()
+seen.append(jax.config.jax_compilation_cache_dir)
+print(json.dumps(seen))
+"""
 
-    def test_disabled_without_export(self, tmp_path, monkeypatch):
-        c = AOTExecutableCache(str(tmp_path / "a"))
-        # simulate a jax without usable export support
-        c._export = None
-        c.state = "disabled"
-        assert c.try_load({}) == {}
-        assert c.save(None, (None, None), {}, (1,), None) == 0
+
+# the second "unset" process only has to agree on the path
+_CACHE_DIR_IMPORT_ONLY = """
+import json, sys
+sys.path.insert(0, {root!r})
+import jax
+import deeplearning4j_tpu
+print(json.dumps([jax.config.jax_compilation_cache_dir]))
+"""
+
+
+class TestCompileCacheRule:
+    """One rule (deeplearning4j_tpu/__init__.py): JAX_COMPILATION_CACHE_DIR
+    wins untouched; unset, one fixed path inside the checkout."""
+
+    def _child(self, tmp_path, env_dir, script=_CACHE_DIR_CHILD):
+        env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
+        if env_dir is not None:
+            env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+        proc = subprocess.run(
+            [sys.executable, "-c", script.format(
+                root=_ROOT, other=str(tmp_path / "other"))],
+            cwd=str(tmp_path), capture_output=True, text=True,
+            timeout=300, env=env)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        return json.loads(proc.stdout.strip().splitlines()[-1])
+
+    def test_env_dir_is_never_overridden(self, tmp_path):
+        d = str(tmp_path / "from_env")
+        assert self._child(tmp_path, d) == [d, d, d]
+
+    def test_unset_is_one_fixed_path_in_checkout(self, tmp_path):
+        fixed = os.path.join(_ROOT, ".jax_cache")
+        assert self._child(tmp_path, None) == [fixed] * 3
+        assert self._child(tmp_path, None,
+                           _CACHE_DIR_IMPORT_ONLY) == [fixed]

@@ -10,7 +10,6 @@ import os
 import subprocess
 import sys
 
-import jax
 import pytest
 
 EXAMPLES_DIR = os.path.join(
@@ -29,9 +28,6 @@ def _needs_keras(name: str) -> bool:
 @pytest.mark.slow
 @pytest.mark.parametrize("name", EXAMPLES)
 def test_example_runs(name):
-    if name == "three_d_parallelism.py" and not hasattr(jax, "shard_map"):
-        pytest.skip("partial-auto shard_map needs jax>=0.5 "
-                    "(see tests/test_3d_parallel.py)")
     if _needs_keras(name):
         pytest.importorskip("keras")
     env = dict(os.environ)

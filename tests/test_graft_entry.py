@@ -1,10 +1,11 @@
-"""The driver-visible contract of ``__graft_entry__``.
+"""The driver-visible contract of the root entry points.
 
-The driver imports the module and calls ``dryrun_multichip(8)`` directly —
-no env prep, no ``__main__`` block — in a process where the image's TPU
-PJRT shim is active.  Round 1 failed exactly this invocation (the mesh saw
-1 device), so the regression test here replicates it byte-for-byte in a
-fresh subprocess with the parent's env untouched.
+The driver imports ``__graft_entry__`` and calls ``dryrun_multichip(8)``
+directly — no env prep, no ``__main__`` block.  Round 1 failed exactly this
+invocation (the mesh saw 1 device), so the regression test here replicates
+it byte-for-byte in a fresh subprocess with the parent's env untouched.
+``chip_smoke.py`` and ``bench.py`` measure a chip: where JAX finds none
+they must refuse, not fall back to the CPU.
 """
 
 import os
@@ -30,6 +31,19 @@ def test_dryrun_multichip_errors_clearly_when_mesh_too_small():
     import __graft_entry__ as ge
     with pytest.raises(RuntimeError, match="already"):
         ge.dryrun_multichip(64)
+
+
+@pytest.mark.parametrize("script", ["chip_smoke.py", "bench.py"])
+def test_chip_entry_points_refuse_the_cpu(script):
+    """No accelerator: non-zero exit naming the platform found, before
+    any model is built (seconds, not minutes), and no result line."""
+    proc = subprocess.run(
+        [sys.executable, script], cwd=REPO, capture_output=True, text=True,
+        timeout=120, env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert proc.returncode != 0
+    assert "platform 'cpu'" in proc.stderr
+    assert "phase=train" not in proc.stdout
+    assert '"ok"' not in proc.stdout and '"metric"' not in proc.stdout
 
 
 @pytest.mark.slow

@@ -233,6 +233,23 @@ class TestDispatch:
         assert pallas_lstm.choose_impl(256, 512, 128,
                                        backend="cpu") == "scan"
 
+    def test_compiled_kernel_refuses_unaligned_hidden(self, monkeypatch):
+        """H=200 (TextGenerationLSTM's default) cannot be lane-sliced by
+        the compiled kernel: auto never routes it there, and a forced
+        fused call raises with the reason instead of falling back."""
+        monkeypatch.delenv(pallas_lstm._IMPL_ENV, raising=False)
+        monkeypatch.setattr(pallas_lstm, "_MEASURED_FUSED_WINS",
+                            ((1, 1, 1),))
+        assert pallas_lstm.choose_impl(64, 200, 32,
+                                       backend="tpu") == "scan"
+        assert pallas_lstm.choose_impl(64, 256, 32,
+                                       backend="tpu") == "fused"
+        z = jnp.zeros((4, 8, 800), jnp.float32)
+        hc = jnp.zeros((8, 200), jnp.float32)
+        wh = jnp.zeros((200, 800), jnp.float32)
+        with pytest.raises(ValueError, match="multiple of 128"):
+            pallas_lstm.lstm_fused(z, hc, hc, wh, interpret=False)
+
     def test_env_override_wins(self, monkeypatch):
         monkeypatch.setenv(pallas_lstm._IMPL_ENV, "fused")
         assert pallas_lstm.choose_impl(1, 1, 1, backend="cpu") == "fused"
