@@ -30,13 +30,19 @@ Observability: ``dl4j_feed_depth`` (staged batches at last hand-off)
 and ``dl4j_etl_stall_ms`` (cumulative ms the step loop actually waited
 for data) ride the process registry; the tracer gets ``etl`` spans for
 host-side batch production, ``host_to_device`` spans for the staging
-issue (wire), and ``feed_stall`` spans whenever the queue ran dry — so
-overlap (or its absence) is visible in the Perfetto timeline.
+issue (wire), ``resident`` spans from that issue until the batch is on
+the device (see ``_ResidentWatcher``), and ``feed_stall`` spans whenever
+the queue ran dry — so overlap (or its absence) is visible in the
+Perfetto timeline. ``host_to_device``, ``resident`` and the consumer's
+``dispatch`` span of one batch share its ``seq``.
 """
 
 from __future__ import annotations
 
+import queue
+import threading
 import time
+import weakref
 from collections import deque
 from typing import Any, Callable, Iterable, List, NamedTuple, Optional
 
@@ -135,6 +141,7 @@ class FeedItem(NamedTuple):
     queue_wait_ms: float    # time the consumer stalled for this item
     nbytes: int
     raw: Any = None
+    seq: int = -1           # the feeder's count of staged items (k > 0)
 
     def as_dataset(self) -> DataSet:
         return DataSet(self.features, self.labels, self.features_mask,
@@ -171,6 +178,57 @@ class StagingPool:
         ring.append(ring.pop(0))
         np.copyto(buf, a)
         return buf
+
+
+class _ResidentWatcher:
+    """Records the ``resident`` span (cat ``data``) of every staged item:
+    from the reading its ``host_to_device`` span starts at until
+    ``jax.block_until_ready`` returns on the staged arrays, which is when
+    the batch is on the device and a step can use it. ``device_put``
+    returns long before (the runtime lays the batch out on its own host
+    threads), so this is the hand-off that ``host_to_device`` cannot see.
+
+    One daemon thread per feeder, made only by an enabled tracer, ended
+    by ``DeviceFeeder.close()`` or with the feeder itself. Items are
+    waited for in the order they were staged, so one that became resident
+    out of order reads late, never early. An item's arrays are let go as
+    soon as they are ready, which is before the step that uses them can
+    run: the watcher keeps no batch alive. A transfer that failed leaves
+    no span; the step that uses it raises on the loop's thread."""
+
+    def __init__(self, tracer):
+        self._tracer = tracer
+        self._jobs: queue.SimpleQueue = queue.SimpleQueue()
+        self._thread = threading.Thread(target=self._run, daemon=True,
+                                        name="dl4j-feed-resident")
+        self._thread.start()
+
+    def watch(self, seq, start, nbytes, k, arrays):
+        self._jobs.put((seq, start, nbytes, k, arrays))
+
+    def _run(self):
+        while True:
+            job = self._jobs.get()
+            if job is None:
+                return
+            seq, start, nbytes, k = job[:4]
+            try:
+                jax.block_until_ready(job[4])
+                end = time.perf_counter()
+            except RuntimeError:    # raised again where the batch is used
+                end = None
+            del job                 # the arrays go before the next wait
+            if end is not None:
+                self._tracer.add_span("resident", start, end, cat="data",
+                                      seq=seq, bytes=nbytes, k=k)
+
+    def stop(self):
+        """End the thread once it has recorded what it was handed."""
+        self._jobs.put(None)
+
+    def close(self):
+        self.stop()
+        self._thread.join()
 
 
 class DeviceFeeder:
@@ -260,6 +318,8 @@ class DeviceFeeder:
         self.stall_ms = 0.0
         self.max_depth_seen = 0
         self._staged_bytes = 0
+        self._seq = 0                   # staged items over the feeder's life
+        self._watcher: Optional[_ResidentWatcher] = None
 
     # ---- host-side production -------------------------------------------
     def _normalize(self, batch: DataSet) -> DataSet:
@@ -356,12 +416,29 @@ class DeviceFeeder:
             if self._pool is not None:
                 a = self._pool.stage(a)
             staged.append(self.put(a))
-        self.tracer.add_span("host_to_device", start, time.perf_counter(),
-                             cat="data", wire=True, k=item.k,
-                             bytes=nbytes)
+        seq = self._seq
+        self._seq += 1
+        if self.tracer.enabled:
+            self.tracer.add_span("host_to_device", start,
+                                 time.perf_counter(), cat="data", wire=True,
+                                 k=item.k, bytes=nbytes, seq=seq)
+            if self._watcher is None:
+                self._watcher = _ResidentWatcher(self.tracer)
+                # a feeder dropped without close() still ends its thread
+                weakref.finalize(self, self._watcher.stop)
+            self._watcher.watch(seq, start, nbytes, item.k, tuple(staged))
         self._staged_bytes += nbytes
         return FeedItem(staged[0], staged[1], staged[2], staged[3],
-                        item.k, item.n_examples, 0.0, nbytes)
+                        item.k, item.n_examples, 0.0, nbytes, seq=seq)
+
+    def close(self):
+        """End the ``resident`` watcher once its spans are recorded.
+        ``fit()`` calls this after the last epoch and not between epochs:
+        waiting there would move the epoch boundary that a traced run is
+        made to show. No-op when tracing is off."""
+        if self._watcher is not None:
+            self._watcher.close()
+            self._watcher = None
 
     # ---- the prefetch loop ----------------------------------------------
     def __iter__(self):

@@ -16,20 +16,30 @@ from __future__ import annotations
 
 import queue
 import threading
+import time
 from typing import Iterator, Optional
 
 from deeplearning4j_tpu.datasets.dataset import DataSet, DataSetIterator
+from deeplearning4j_tpu.observe.tracer import NULL_TRACER
 
 
 class AsyncDataSetIterator(DataSetIterator):
     """Background-thread prefetch with a bounded queue (reference:
-    AsyncDataSetIterator, default queue size 8)."""
+    AsyncDataSetIterator, default queue size 8).
+
+    With an enabled ``tracer`` (``fit()`` hands over the model's) the
+    worker records one ``produce`` span (cat ``data``) around each
+    ``next()`` of the base, ``index`` counting from 0 in each pass. The
+    ``index=0`` span also holds whatever the base does when a pass starts
+    (``ArrayDataSetIterator(shuffle=True)`` copies the whole set)."""
 
     _SENTINEL = object()
 
-    def __init__(self, base: DataSetIterator, queue_size: int = 8):
+    def __init__(self, base: DataSetIterator, queue_size: int = 8,
+                 tracer=None):
         self.base = base
         self.queue_size = queue_size
+        self.tracer = tracer if tracer is not None else NULL_TRACER
         self._worker: Optional[threading.Thread] = None
         self._stop: Optional[threading.Event] = None
         self._q: Optional[queue.Queue] = None
@@ -42,9 +52,20 @@ class AsyncDataSetIterator(DataSetIterator):
         stop = threading.Event()
         error = []
 
+        tracer = self.tracer
+
+        def produced():
+            """The base's batches, each ``next()`` under a span."""
+            start = time.perf_counter()
+            for index, batch in enumerate(self.base):
+                tracer.add_span("produce", start, time.perf_counter(),
+                                cat="data", index=index)
+                yield batch
+                start = time.perf_counter()
+
         def worker():
             try:
-                for batch in self.base:
+                for batch in (produced() if tracer.enabled else self.base):
                     while not stop.is_set():
                         try:
                             q.put(batch, timeout=0.05)

@@ -332,27 +332,34 @@ class BaseModel:
             # prefetch thread unless the iterator opted out (shield) or
             # already is one
             source = AsyncDataSetIterator(iterator)
+        if isinstance(source, AsyncDataSetIterator):
+            source.tracer = tracer      # its worker's ``produce`` spans
         feeder = (DeviceFeeder(source, depth=depth, byte_budget=byte_budget,
                                k_steps=k, tracer=tracer)
                   if feed else None)
-        for epoch in range(epochs):
-            for lst in self.listeners:
-                lst.on_epoch_start(self, self.epoch_count)
-            if feeder is not None:
-                self._fit_epoch_fed(feeder, tracer)
-            else:
-                it_start = time.perf_counter()
-                for batch in iterator:
-                    now = time.perf_counter()
-                    etl_ms = (now - it_start) * 1000.0
-                    tracer.add_span("etl", it_start, now, cat="data")
-                    self._fit_batch(batch, etl_ms=etl_ms)
+        try:
+            for epoch in range(epochs):
+                for lst in self.listeners:
+                    lst.on_epoch_start(self, self.epoch_count)
+                if feeder is not None:
+                    self._fit_epoch_fed(feeder, tracer)
+                else:
                     it_start = time.perf_counter()
-            if isinstance(source, DataSetIterator):
-                source.reset()
-            for lst in self.listeners:
-                lst.on_epoch_end(self, self.epoch_count)
-            self.epoch_count += 1
+                    for batch in iterator:
+                        now = time.perf_counter()
+                        etl_ms = (now - it_start) * 1000.0
+                        tracer.add_span("etl", it_start, now, cat="data")
+                        self._fit_batch(batch, etl_ms=etl_ms)
+                        it_start = time.perf_counter()
+                if isinstance(source, DataSetIterator):
+                    source.reset()
+                for lst in self.listeners:
+                    lst.on_epoch_end(self, self.epoch_count)
+                self.epoch_count += 1
+        finally:
+            if feeder is not None:
+                # a traced fit's last ``resident`` spans; no-op untraced
+                feeder.close()
         # tail flush so the last (< flush_interval) rows aren't stranded
         # on device when training ends
         if self._telemetry is not None:
@@ -416,7 +423,7 @@ class BaseModel:
                 self.train_state)
         if self.recompile_watchdog is not None:
             self.recompile_watchdog.observe("train_step", *args)
-        with tracer.span("dispatch", cat="step"):
+        with tracer.span("dispatch", cat="step", seq=item.seq):
             self.train_state, loss = self._train_step(
                 self.train_state, *args, step_key)
         it = self._post_step()
@@ -441,7 +448,7 @@ class BaseModel:
                 self.train_state)
         if self.recompile_watchdog is not None:
             self.recompile_watchdog.observe("scan_train_step", *args)
-        with tracer.span("dispatch", cat="step", k=item.k):
+        with tracer.span("dispatch", cat="step", k=item.k, seq=item.seq):
             self.train_state, losses = self._scan_step(
                 self.train_state, *args, group_key)
         it = self._post_step(item.k)

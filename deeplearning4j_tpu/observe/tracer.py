@@ -3,10 +3,11 @@
 Records named wall-clock spans around the training loop's phases (etl,
 host→device transfer, dispatch, telemetry flush, eval, checkpoint) and
 writes the Chrome Trace Event Format — load the file at
-https://ui.perfetto.dev or chrome://tracing. When
-``use_jax_profiler=True`` each span also opens a
-``jax.profiler.TraceAnnotation`` so the host spans line up against
-device lanes in a jax.profiler capture.
+https://ui.perfetto.dev or chrome://tracing. ``ts`` counts microseconds
+from ``t0``, a ``time.perf_counter`` reading that the export carries as
+``otherData["t0_perf_counter_s"]``: drop one
+``jax.profiler.TraceAnnotation`` holding a ``perf_counter_ns`` into a
+jax.profiler capture and the host spans line up against its device lanes.
 
 Disabled tracers are free: ``span()`` short-circuits before touching the
 clock, so the default NULL_TRACER can stay wired into every fit loop.
@@ -24,14 +25,12 @@ from typing import List, Optional
 
 class SpanTracer:
     def __init__(self, enabled: bool = True,
-                 use_jax_profiler: bool = False,
                  max_events: int = 200_000):
         self.enabled = enabled
-        self.use_jax_profiler = use_jax_profiler
         self.max_events = max_events
         self._events: List[dict] = []
         self._lock = threading.Lock()
-        self._t0 = time.perf_counter()
+        self.t0 = time.perf_counter()       # the zero every ``ts`` counts from
         self._dropped = 0
 
     # ---- recording ------------------------------------------------------
@@ -40,22 +39,11 @@ class SpanTracer:
         if not self.enabled:
             yield
             return
-        ann = None
-        if self.use_jax_profiler:
-            try:
-                import jax.profiler
-                ann = jax.profiler.TraceAnnotation(name)
-                ann.__enter__()
-            except Exception:
-                ann = None
         start = time.perf_counter()
         try:
             yield
         finally:
-            end = time.perf_counter()
-            if ann is not None:
-                ann.__exit__(None, None, None)
-            self.add_span(name, start, end, cat=cat, **args)
+            self.add_span(name, start, time.perf_counter(), cat=cat, **args)
 
     def add_span(self, name: str, start_s: float, end_s: float,
                  cat: str = "train", **args):
@@ -65,7 +53,7 @@ class SpanTracer:
             return
         ev = {
             "name": name, "cat": cat, "ph": "X",
-            "ts": (start_s - self._t0) * 1e6,       # µs, trace-relative
+            "ts": (start_s - self.t0) * 1e6,        # µs, trace-relative
             "dur": max(0.0, (end_s - start_s) * 1e6),
             "pid": os.getpid(), "tid": threading.get_ident(),
         }
@@ -83,7 +71,7 @@ class SpanTracer:
             return
         now = time.perf_counter()
         ev = {"name": name, "cat": cat, "ph": "i", "s": "t",
-              "ts": (now - self._t0) * 1e6,
+              "ts": (now - self.t0) * 1e6,
               "pid": os.getpid(), "tid": threading.get_ident()}
         if args:
             ev["args"] = args
@@ -109,7 +97,8 @@ class SpanTracer:
     def to_chrome_trace(self) -> dict:
         return {"traceEvents": self.events, "displayTimeUnit": "ms",
                 "otherData": {"tracer": "deeplearning4j_tpu.observe",
-                              "dropped_events": self._dropped}}
+                              "dropped_events": self._dropped,
+                              "t0_perf_counter_s": self.t0}}
 
     def save(self, path: str) -> str:
         with open(path, "w") as f:

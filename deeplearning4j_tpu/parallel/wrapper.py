@@ -38,6 +38,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu.datasets.dataset import DataSet, DataSetIterator
 from deeplearning4j_tpu.observe.telemetry import has_buffer
+from deeplearning4j_tpu.observe.tracer import get_tracer
 from deeplearning4j_tpu.optimize.solver import TrainState
 from deeplearning4j_tpu.parallel.mesh import DATA_AXIS, create_mesh
 
@@ -133,6 +134,7 @@ class ParallelWrapper:
                 f" {mode.name}")
         self._step = None
         self._elastic = None        # ASYNC_ELASTIC per-worker state
+        self._feeder = None         # the running fit's DeviceFeeder
         if model.train_state is None:
             model.init()
 
@@ -571,6 +573,11 @@ class ParallelWrapper:
             if rec is not None:
                 rec.record_crash(m, exc=e)
             raise
+        finally:
+            feeder, self._feeder = self._feeder, None
+            if feeder is not None:
+                # a traced fit's last ``resident`` spans; no-op untraced
+                feeder.close()
 
     def _arm_telemetry(self):
         """Extend an attached TelemetryCollector with the per-device row
@@ -728,7 +735,6 @@ class ParallelWrapper:
         from deeplearning4j_tpu.datasets.feeder import DeviceFeeder
         from deeplearning4j_tpu.datasets.iterators import (
             AsyncDataSetIterator)
-        from deeplearning4j_tpu.observe.tracer import get_tracer
         if not getattr(iterator, "async_supported", True):
             return None, iterator
         source = iterator
@@ -736,6 +742,8 @@ class ParallelWrapper:
                 and not isinstance(iterator, AsyncDataSetIterator)):
             source = AsyncDataSetIterator(iterator)
         tracer = get_tracer(self.model)
+        if isinstance(source, AsyncDataSetIterator):
+            source.tracer = tracer      # its worker's ``produce`` spans
         if self.mode in (TrainingMode.AVERAGING,
                          TrainingMode.ASYNC_ELASTIC):
             feeder = DeviceFeeder(
@@ -750,6 +758,7 @@ class ParallelWrapper:
             feeder = DeviceFeeder(source, prepare=self._sync_prepare,
                                   pad_ragged=False, put=self._put_batch,
                                   tracer=tracer, session_id="parallel")
+        self._feeder = feeder
         return feeder, source
 
     def collective_census(self, batch: DataSet):
@@ -815,8 +824,9 @@ class ParallelWrapper:
         feats, labels, fmask, lmask = self._stage_batch(batch)
         if m._telemetry is not None:
             m.train_state = m._telemetry.ensure_buffer(m.train_state)
-        m.train_state, loss = self._step(m.train_state, feats, labels,
-                                         fmask, lmask, key)
+        with get_tracer(m).span("dispatch", cat="step"):
+            m.train_state, loss = self._step(m.train_state, feats, labels,
+                                             fmask, lmask, key)
         self._guarded_wait(loss)
         # _post_step: host iteration mirror + telemetry flush
         # opportunity + flight-recorder poll — no per-batch
@@ -833,9 +843,10 @@ class ParallelWrapper:
         m._rng, key = jax.random.split(m._rng)
         if m._telemetry is not None:
             m.train_state = m._telemetry.ensure_buffer(m.train_state)
-        m.train_state, loss = self._step(
-            m.train_state, item.features, item.labels, item.features_mask,
-            item.labels_mask, key)
+        with get_tracer(m).span("dispatch", cat="step", seq=item.seq):
+            m.train_state, loss = self._step(
+                m.train_state, item.features, item.labels,
+                item.features_mask, item.labels_mask, key)
         self._guarded_wait(loss)
         it = m._post_step()
         for lst in m.listeners:
@@ -954,9 +965,11 @@ class ParallelWrapper:
         m._rng, key = jax.random.split(m._rng)
         if m._telemetry is not None:
             m.train_state = m._telemetry.ensure_buffer(m.train_state)
-        m.train_state, loss = self._step(
-            m.train_state, item.features, item.labels, item.features_mask,
-            item.labels_mask, key)
+        with get_tracer(m).span("dispatch", cat="step", k=item.k,
+                                seq=item.seq):
+            m.train_state, loss = self._step(
+                m.train_state, item.features, item.labels,
+                item.features_mask, item.labels_mask, key)
         self._guarded_wait(loss)
         it = m._post_step(item.k)
         for lst in m.listeners:

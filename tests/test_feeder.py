@@ -78,6 +78,11 @@ def _assert_params_equal(pa, pb):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def _watcher_threads():
+    return {t for t in threading.enumerate()
+            if t.name == "dl4j-feed-resident"}
+
+
 class _Recording(ListDataSetIterator):
     """ListDataSetIterator that counts reset() calls."""
 
@@ -258,6 +263,53 @@ class TestDeviceFeeder:
         assert all(e["args"]["wire"] for e in wire)
         assert all(e["args"]["bytes"] > 0 for e in wire)
 
+    def test_resident_span_per_staged_item(self):
+        """Every staged item: one ``resident`` span, from the reading its
+        ``host_to_device`` span starts at, under the same ``seq``; the
+        count runs on over the feeder's passes."""
+        before = _watcher_threads()
+        tracer = SpanTracer()
+        feeder = DeviceFeeder(ListDataSetIterator(_batches(5)),
+                              tracer=tracer, registry=MetricsRegistry())
+        seqs = [item.seq for _ in range(2) for item in feeder]
+        assert len(_watcher_threads() - before) == 1
+        feeder.close()
+        assert seqs == list(range(10))
+        wire = {e["args"]["seq"]: e for e in tracer.events
+                if e["name"] == "host_to_device"}
+        resident = [e for e in tracer.events if e["name"] == "resident"]
+        assert [e["args"]["seq"] for e in resident] == seqs
+        for e in resident:
+            assert e["cat"] == "data" and e["args"]["k"] == 1
+            assert e["ts"] == wire[e["args"]["seq"]]["ts"]
+            assert e["dur"] >= wire[e["args"]["seq"]]["dur"]
+            assert e["args"]["bytes"] == wire[e["args"]["seq"]]["args"]["bytes"]
+            assert e["tid"] != threading.get_ident()
+        assert _watcher_threads() <= before
+
+    def test_dropped_feeder_ends_its_watcher(self):
+        before = _watcher_threads()
+        feeder = DeviceFeeder(ListDataSetIterator(_batches(2)),
+                              tracer=SpanTracer(), registry=MetricsRegistry())
+        list(feeder)
+        (watcher,) = _watcher_threads() - before
+        del feeder
+        watcher.join(timeout=5.0)
+        assert not watcher.is_alive()
+
+    def test_no_watcher_without_tracer(self):
+        before = set(threading.enumerate())
+        feeder = DeviceFeeder(ListDataSetIterator(_batches(3)),
+                              registry=MetricsRegistry())
+        it = iter(feeder)
+        first = next(it)
+        assert set(threading.enumerate()) == before
+        assert [first.seq] + [item.seq for item in it] == [0, 1, 2]
+        feeder.close()                  # nothing to end: a no-op
+        marker = object()
+        assert [i.seq for i in DeviceFeeder(
+            [marker], registry=MetricsRegistry())] == [-1]
+
     def test_staging_pool_rotates_and_copies(self):
         pool = StagingPool(2)
         a = np.arange(6, dtype=np.float32).reshape(2, 3)
@@ -360,6 +412,64 @@ class TestFitIntegration:
         m_ref.fit(ListDataSetIterator(batches), epochs=2, prefetch=0)
         _assert_params_equal(_params(m_fed), _params(m_ref))
         assert float(m_fed.score()) == float(m_ref.score())
+
+    @pytest.mark.parametrize("k_steps, untraced", [
+        (1, {"prefetch": 0}), (3, {"k_steps": 3})])
+    def test_traced_fit_spans_complete_and_bitwise(self, k_steps, untraced):
+        """With the tracer on: the fed fit still agrees bit for bit with
+        the unfed one (the scanned one with its untraced self), every
+        dispatch has its batch's ``resident`` span in the tracer when
+        ``fit()`` returns, and the watcher is gone."""
+        batches = _batches(6, tail=5)
+        m_fed, m_ref = _tiny_model(), _tiny_model()
+        tracer = SpanTracer()
+        m_fed.set_tracer(tracer)
+        before = _watcher_threads()
+        m_fed.fit(ListDataSetIterator(batches), epochs=2, k_steps=k_steps)
+        events = tracer.events          # read at once: nothing comes later
+        assert _watcher_threads() <= before
+        m_ref.fit(ListDataSetIterator(batches), epochs=2, **untraced)
+        _assert_params_equal(_params(m_fed), _params(m_ref))
+        by_name = {n: [e["args"]["seq"] for e in events if e["name"] == n]
+                   for n in ("host_to_device", "resident", "dispatch")}
+        assert by_name["dispatch"] == sorted(by_name["dispatch"])
+        assert len(by_name["dispatch"]) == (14 if k_steps == 1 else 6)
+        assert (by_name["resident"] == by_name["host_to_device"]
+                == by_name["dispatch"])
+
+    def test_fit_hands_its_tracer_to_the_iterator_it_was_given(self):
+        tracer = SpanTracer()
+        model = _tiny_model()
+        model.set_tracer(tracer)
+        handed = AsyncDataSetIterator(ListDataSetIterator(_batches(3)))
+        model.fit(handed, epochs=2)
+        assert handed.tracer is tracer
+        assert [e["args"]["index"] for e in tracer.events
+                if e["name"] == "produce"] == [0, 1, 2] * 2
+
+    def test_untraced_fit_starts_no_watcher(self, monkeypatch):
+        from deeplearning4j_tpu.datasets import feeder as feeder_mod
+        made = []
+        monkeypatch.setattr(
+            feeder_mod, "_ResidentWatcher",
+            lambda tracer: made.append(tracer) or pytest.fail("watcher"))
+        _tiny_model().fit(ListDataSetIterator(_batches(3)), epochs=2)
+        assert made == []
+
+    def test_failed_traced_fit_leaves_no_watcher(self):
+        from deeplearning4j_tpu.optimize.listeners import TrainingListener
+
+        class Boom(TrainingListener):
+            def iteration_done(self, *a, **k):
+                raise RuntimeError("listener")
+
+        m = _tiny_model()
+        m.set_tracer(SpanTracer())
+        m.set_listeners(Boom())
+        before = _watcher_threads()
+        with pytest.raises(RuntimeError, match="listener"):
+            m.fit(ListDataSetIterator(_batches(3)), epochs=1)
+        assert _watcher_threads() <= before
 
     def test_fused_ksteps_bitwise_equals_per_batch(self):
         """fit(k_steps=3) over the raw ragged stream must replay the

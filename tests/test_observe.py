@@ -244,6 +244,24 @@ class TestTracer:
         loaded = json.loads(Path(path).read_text())
         assert len(loaded["traceEvents"]) == 3
 
+    def test_export_names_its_zero(self):
+        """``ts`` counts from ``t0`` on ``perf_counter``; the export says
+        so, which is what lays a saved trace beside a profile."""
+        import time
+        before = time.perf_counter()
+        tr = SpanTracer()
+        assert before <= tr.t0 <= time.perf_counter()
+        start = time.perf_counter()
+        tr.add_span("etl", start, start + 0.5, cat="data")
+        doc = tr.to_chrome_trace()
+        assert doc["otherData"]["t0_perf_counter_s"] == tr.t0
+        (ev,) = doc["traceEvents"]
+        assert tr.t0 + ev["ts"] / 1e6 == pytest.approx(start, abs=1e-6)
+
+    def test_profiler_annotations_are_gone(self):
+        with pytest.raises(TypeError):
+            SpanTracer(**{"use_jax" + "_profiler": True})
+
     def test_disabled_tracer_records_nothing(self):
         tr = SpanTracer(enabled=False)
         with tr.span("x"):
