@@ -38,9 +38,12 @@ class DataSet:
     def _arrays(self):
         return (self.features, self.labels, self.features_mask, self.labels_mask)
 
+    def _permutation(self, seed: int) -> np.ndarray:
+        """The order ``shuffle(seed)`` puts the examples in."""
+        return np.random.default_rng(seed).permutation(self.num_examples())
+
     def shuffle(self, seed: int = 0) -> "DataSet":
-        rng = np.random.default_rng(seed)
-        perm = rng.permutation(self.num_examples())
+        perm = self._permutation(seed)
         def idx(a):
             # host-sync-ok: host-side shuffle of numpy arrays pre-transfer
             return None if a is None else np.asarray(a)[perm]  # host-sync-ok: host shuffle
@@ -112,7 +115,14 @@ class ListDataSetIterator(DataSetIterator):
 
 class ArrayDataSetIterator(DataSetIterator):
     """Batches a single large DataSet (analog of creating an iterator from
-    arrays; supports shuffling each epoch)."""
+    arrays; supports shuffling each epoch).
+
+    With ``shuffle`` a pass draws the permutation ``DataSet.shuffle(seed +
+    epoch)`` draws and gathers each batch from the set as it is asked for,
+    so the batches are those of the shuffled set without a copy of the
+    whole set: a pass starts in one batch's time, and under
+    ``AsyncDataSetIterator`` the gathers run on the prefetch thread beside
+    the steps. Without it the batches are views of the set."""
 
     def __init__(self, data: DataSet, batch_size: int, shuffle: bool = False,
                  seed: int = 0, drop_last: bool = False):
@@ -124,19 +134,19 @@ class ArrayDataSetIterator(DataSetIterator):
         self._drop_last = drop_last
 
     def __iter__(self):
-        d = self._data
+        n = self._data.num_examples()
+        # to the host once a pass: a jax.Array set would cross per batch
+        arrays = [None if a is None else np.asarray(a)  # host-sync-ok: host batching pre-transfer
+                  for a in self._data._arrays()]
+        perm = None
         if self._shuffle:
-            d = d.shuffle(self._seed + self._epoch)
+            perm = self._data._permutation(self._seed + self._epoch)
             self._epoch += 1
-        n = d.num_examples()
         end = n - (n % self._bs) if self._drop_last else n
         for lo in range(0, end, self._bs):
-            hi = min(lo + self._bs, n)
-            def cut(a):
-                # host-sync-ok: host-side batch slicing before transfer
-                return None if a is None else np.asarray(a)[lo:hi]  # host-sync-ok: host slice
-            yield DataSet(cut(d.features), cut(d.labels),
-                          cut(d.features_mask), cut(d.labels_mask))
+            rows = (slice(lo, lo + self._bs) if perm is None
+                    else perm[lo:lo + self._bs])
+            yield DataSet(*(None if a is None else a[rows] for a in arrays))
 
     @property
     def batch_size(self):

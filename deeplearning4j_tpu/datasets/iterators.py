@@ -31,7 +31,8 @@ class AsyncDataSetIterator(DataSetIterator):
     worker records one ``produce`` span (cat ``data``) around each
     ``next()`` of the base, ``index`` counting from 0 in each pass. The
     ``index=0`` span also holds whatever the base does when a pass starts
-    (``ArrayDataSetIterator(shuffle=True)`` copies the whole set)."""
+    (``ArrayDataSetIterator(shuffle=True)`` draws its permutation; every
+    span of it, the first too, is one batch's gather)."""
 
     _SENTINEL = object()
 

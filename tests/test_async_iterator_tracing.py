@@ -45,7 +45,7 @@ def test_produce_spans_count_batches_and_restart_each_pass():
 
 def test_pass_start_lands_in_the_first_span():
     """Whatever the base does when a pass starts (here: a slow start, as a
-    reshuffle of the whole set is) is inside the ``index=0`` span."""
+    reader that opens its files is) is inside the ``index=0`` span."""
     class SlowStart(DataSetIterator):
         def __iter__(self):
             time.sleep(0.05)

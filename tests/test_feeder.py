@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from deeplearning4j_tpu.datasets.dataset import (
+    ArrayDataSetIterator,
     DataSet,
     DataSetIterator,
     ListDataSetIterator,
@@ -412,6 +413,29 @@ class TestFitIntegration:
         m_ref.fit(ListDataSetIterator(batches), epochs=2, prefetch=0)
         _assert_params_equal(_params(m_fed), _params(m_ref))
         assert float(m_fed.score()) == float(m_ref.score())
+
+    def test_shuffling_iterator_equals_slices_of_the_shuffled_set(self):
+        """``fit(ArrayDataSetIterator(shuffle=True), epochs=2)`` (prefetch
+        thread, per-batch gather, feeder) sees the losses of the same
+        model fed the slices of ``data.shuffle(seed + epoch)`` from a
+        list, epoch by epoch, ragged tail included."""
+        from deeplearning4j_tpu.optimize.listeners import (
+            CollectScoresIterationListener)
+        data = DataSet.merge(_batches(4, batch=8, tail=5))
+        seed = 5
+        fed, fed_scores = _tiny_model(), CollectScoresIterationListener()
+        fed.set_listeners(fed_scores)
+        fed.fit(ArrayDataSetIterator(data, 8, shuffle=True, seed=seed),
+                epochs=2)
+        ref, ref_scores = _tiny_model(), CollectScoresIterationListener()
+        ref.set_listeners(ref_scores)
+        for epoch in range(2):
+            whole = data.shuffle(seed + epoch)
+            ref.fit(ListDataSetIterator(
+                [DataSet(whole.features[lo:lo + 8], whole.labels[lo:lo + 8])
+                 for lo in range(0, 37, 8)]), epochs=1)
+        assert len(fed_scores.scores) == 10
+        assert fed_scores.scores == ref_scores.scores
 
     @pytest.mark.parametrize("k_steps, untraced", [
         (1, {"prefetch": 0}), (3, {"k_steps": 3})])
