@@ -66,6 +66,12 @@ class BaseModel:
         # device sync; the mirror is re-adopted from the device once per
         # fit() call and advanced locally afterwards
         self._host_iteration: Optional[int] = None
+        # the train step whose named scopes were read (observe/scopes.py),
+        # their table, and the fit() call that last handed it to a tracer
+        self._scoped_step = None
+        self._step_scopes = None
+        self._scopes_traced_in = None
+        self._fit_calls = 0
 
     # ---- to be provided by subclasses -----------------------------------
     def init(self, seed: Optional[int] = None):
@@ -170,6 +176,39 @@ class BaseModel:
         return (None if self._telemetry is None
                 else self._telemetry.spec_for(self))
 
+    def _trace_step_scopes(self, tracer, step, *args):
+        """Under an enabled tracer, for models whose layers declare named
+        scopes: which scope each operation of the compiled step belongs to
+        (observe/scopes.py; computed once per built step), handed to the
+        tracer as a zero-length ``step_scopes`` span once per ``fit()``
+        call."""
+        if self._scoped_step is not step:
+            self._scoped_step = step
+            from deeplearning4j_tpu.observe.scopes import compiled_scopes
+            declared = any(getattr(layer, "named_scopes", ())
+                           for layer in getattr(self, "layers", ()))
+            self._step_scopes = (compiled_scopes(step, *args) if declared
+                                 else None)
+            self._scopes_traced_in = None
+        if self._step_scopes and self._scopes_traced_in != self._fit_calls:
+            self._scopes_traced_in = self._fit_calls
+            now = time.perf_counter()
+            tracer.add_span("step_scopes", now, now, cat="step",
+                            table=self._step_scopes)
+
+    def _publish_routing_gauges(self):
+        """Expert layers leave their step's routing counters in the model
+        state (``moe_routing``); publish the last step's as gauges. One
+        small fetch, at a telemetry flush and at the end of a ``fit()``
+        call, never between steps."""
+        state = self.train_state.model_state
+        rows = {name: s["moe_routing"] for name, s in state.items()
+                if isinstance(s, dict) and "moe_routing" in s}
+        if rows:
+            from deeplearning4j_tpu.observe.telemetry import (
+                publish_routing)
+            publish_routing(jax.device_get(rows))  # host-sync-ok: once per fit() call / telemetry flush
+
     def _advance_iteration(self, steps: int = 1) -> int:
         """Host-tracked iteration count after a dispatched step. Syncs
         with the device scalar only when the mirror is stale (once per
@@ -198,6 +237,7 @@ class BaseModel:
             else:
                 tel.on_step(self.train_state, steps)
             if flushed:
+                self._publish_routing_gauges()
                 rec = self._recorder()
                 if rec is not None:
                     rec.poll(self)
@@ -283,6 +323,7 @@ class BaseModel:
         # code may have swapped train_state (checkpoint load, transfer
         # learning) since the last fit
         self._host_iteration = None
+        self._fit_calls += 1
         from deeplearning4j_tpu.observe.tracer import get_tracer
         tracer = get_tracer(self)
         if isinstance(data, (DataSet, MultiDataSet)):
@@ -360,6 +401,7 @@ class BaseModel:
             if feeder is not None:
                 # a traced fit's last ``resident`` spans; no-op untraced
                 feeder.close()
+        self._publish_routing_gauges()
         # tail flush so the last (< flush_interval) rows aren't stranded
         # on device when training ends
         if self._telemetry is not None:
@@ -387,6 +429,10 @@ class BaseModel:
         if self.recompile_watchdog is not None:
             self.recompile_watchdog.observe(
                 "train_step", features, labels, fmask, lmask)
+        if tracer.enabled:
+            self._trace_step_scopes(tracer, self._train_step,
+                                    self.train_state, features, labels,
+                                    fmask, lmask, step_key)
         with tracer.span("dispatch", cat="step"):
             self.train_state, loss = self._train_step(
                 self.train_state, features, labels, fmask, lmask, step_key)
@@ -423,6 +469,9 @@ class BaseModel:
                 self.train_state)
         if self.recompile_watchdog is not None:
             self.recompile_watchdog.observe("train_step", *args)
+        if tracer.enabled:
+            self._trace_step_scopes(tracer, self._train_step,
+                                    self.train_state, *args, step_key)
         with tracer.span("dispatch", cat="step", seq=item.seq):
             self.train_state, loss = self._train_step(
                 self.train_state, *args, step_key)

@@ -236,3 +236,102 @@ class TransformerEncoderBlock(FeedForwardLayer):
         if ctx.mask is not None:
             y = y * ctx.mask[:, :, None].astype(y.dtype)
         return y, state
+
+
+def rotary_embedding(x, positions, rotary_dim: int, theta: float):
+    """Rotate-half rotary position embedding on the first ``rotary_dim``
+    of the head's dimensions of ``x`` (N, T, H, Dh); the rest pass. The
+    pair (i, i + rotary_dim/2) turns by ``positions * theta^(-2i /
+    rotary_dim)``; angles in float32."""
+    half = rotary_dim // 2
+    inv_freq = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32)
+                                * 2.0 / rotary_dim))
+    angle = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    cos = jnp.cos(angle)[None, :, None, :]                 # (1, T, 1, half)
+    sin = jnp.sin(angle)[None, :, None, :]
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:rotary_dim].astype(jnp.float32)
+    turned = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    return jnp.concatenate([turned.astype(x.dtype), x[..., rotary_dim:]], -1)
+
+
+@register_serializable
+@dataclasses.dataclass(frozen=True)
+class GatedAttention(FeedForwardLayer):
+    """Causal grouped-query softmax attention with per-head q/k RMSNorm,
+    rotary embedding on a part of the head and a sigmoid output gate,
+    bias-free: ``W_q`` gives each query head a query and a gate of
+    ``head_dim`` each; ``q <- RMSNorm(q)``, ``k <- RMSNorm(k)`` over the
+    head (zero-centred weights); rotary on the first
+    ``partial_rotary_factor`` of the head; ``n_heads / n_kv_heads`` query
+    heads share a key/value head; ``W_o(attn * sigmoid(gate))``. The head
+    size is its own field, not ``n_out / n_heads``. ``n_out`` is the model
+    width. Attention itself goes through ``ops.pallas_kernels.attention``
+    (the flash kernel from 1,024 positions on a TPU, plain XLA else) with
+    the key/value heads repeated for it."""
+    n_heads: int = 16
+    n_kv_heads: int = 2
+    head_dim: int = 256
+    partial_rotary_factor: float = 0.25
+    rope_theta: float = 1e7
+    eps: float = 1e-6
+    init_std: float = 0.02
+
+    def __post_init__(self):
+        if self.n_heads % self.n_kv_heads:
+            raise ValueError(
+                f"n_heads={self.n_heads} is not a multiple of "
+                f"n_kv_heads={self.n_kv_heads}")
+
+    def output_type(self, input_type: InputType) -> InputType:
+        t = (input_type.timesteps
+             if isinstance(input_type, RecurrentType) else None)
+        return RecurrentType(self.n_out, t)
+
+    def initialize(self, key, input_type):
+        n_in = self.resolved_n_in(input_type)
+        h, hk, dh = self.n_heads, self.n_kv_heads, self.head_dim
+        dt = self.param_dtype()
+        kq, kk, kv, ko = jax.random.split(key, 4)
+
+        def normal(k, shape):
+            return self.init_std * jax.random.normal(k, shape, dt)
+
+        return {
+            # per head: [query | gate]
+            "W_q": normal(kq, (n_in, h * 2 * dh)),
+            "W_k": normal(kk, (n_in, hk * dh)),
+            "W_v": normal(kv, (n_in, hk * dh)),
+            "W_o": normal(ko, (h * dh, self.n_out)),
+            "q_norm": jnp.zeros((dh,), dt),
+            "k_norm": jnp.zeros((dh,), dt),
+        }
+
+    def apply(self, params, state, x, ctx: LayerContext):
+        from deeplearning4j_tpu.nn.layers.normalization import rms_norm
+        from deeplearning4j_tpu.ops.pallas_kernels import attention as _attn
+        n, t, _ = x.shape
+        h, hk, dh = self.n_heads, self.n_kv_heads, self.head_dim
+        with jax.named_scope("attn.gated"):
+            qg = jnp.einsum("ntf,fe->nte", x, params["W_q"]).reshape(
+                n, t, h, 2 * dh)
+            q, gate = qg[..., :dh], qg[..., dh:]
+            k = jnp.einsum("ntf,fe->nte", x, params["W_k"]).reshape(
+                n, t, hk, dh)
+            v = jnp.einsum("ntf,fe->nte", x, params["W_v"]).reshape(
+                n, t, hk, dh)
+            q = rms_norm(q, params["q_norm"], self.eps)
+            k = rms_norm(k, params["k_norm"], self.eps)
+            rot = int(dh * self.partial_rotary_factor)
+            pos = jnp.arange(t)
+            q = rotary_embedding(q, pos, rot, self.rope_theta)
+            k = rotary_embedding(k, pos, rot, self.rope_theta)
+            rep = h // hk
+            if rep > 1:
+                k = jnp.repeat(k, rep, axis=2)
+                v = jnp.repeat(v, rep, axis=2)
+            o = _attn(q, k, v, mask=ctx.mask, causal=True)
+            o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
+            y = jnp.einsum("nte,eo->nto", o.reshape(n, t, h * dh),
+                           params["W_o"])
+        return y, state

@@ -9,7 +9,7 @@ only; backward is ``jax.grad``.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -352,13 +352,20 @@ class AutoEncoder(FeedForwardLayer):
 @register_serializable
 @dataclasses.dataclass(frozen=True)
 class MixtureOfExperts(FeedForwardLayer):
-    """Sparse MoE FFN (no reference analog — SURVEY §2.11 row 7 lists
-    expert parallelism as ABSENT there; designed fresh per §7.2 stage 7).
-    Top-k routed expert FFNs over the feature dim; expert weights are
-    stacked (E, ...) so ``parallel.moe.set_default_mesh`` shards them over
-    the ``expert`` mesh axis and GSPMD inserts the dispatch all-to-alls.
-    The load-balancing + router-z losses are surfaced through layer state
-    (``moe_aux_loss``) and added to the training loss by the models."""
+    """Sparse MoE FFN by dense dispatch with capacity (no reference
+    analog — SURVEY §2.11 row 7 lists expert parallelism as ABSENT there;
+    designed fresh per §7.2 stage 7). Top-k routed expert FFNs (biases,
+    one activation) over the feature dim; expert weights are stacked
+    (E, ...) so ``parallel.moe.set_default_mesh`` shards them over the
+    ``expert`` mesh axis and GSPMD inserts the dispatch all-to-alls. The
+    load-balancing + router-z losses are surfaced through layer state
+    (``moe_aux_loss``) and added to the training loss by the models.
+
+    **This layer drops tokens**: an expert takes ``capacity_factor * top_k
+    * tokens / experts`` of them and what overflows gets weight 0 (Switch
+    semantics), and its (tokens, experts, capacity) tensors suit small
+    expert counts. ``HeldExpertsMoE`` below is the path that drops none
+    and the one a held-experts (expert-parallel) deployment uses."""
 
     num_experts: int = 4
     hidden: int = 0              # d_ff; 0 → 4 * n_out
@@ -404,3 +411,98 @@ class MixtureOfExperts(FeedForwardLayer):
         aux = (self.aux_weight * out.aux_loss
                + self.z_weight * out.router_z_loss)
         return out.y, {"moe_aux_loss": aux}
+
+
+@register_serializable
+@dataclasses.dataclass(frozen=True)
+class HeldExpertsMoE(FeedForwardLayer):
+    """A chip's share of a mixture of gated (SwiGLU) experts, with no
+    token dropped (``parallel.moe.held_experts_ffn``), plus the shared
+    expert every chip computes alike:
+
+        y = sum_{i in top-k(x), i held here} p_i E_i(x)
+            + sigmoid(x . w_s) * E_shared(x)
+
+    ``E(x) = W_down(silu(W_gate x) * W_up x)``, no biases; ``p`` the
+    float32 softmax over ALL ``num_experts``, its top ``top_k``
+    renormalised to sum 1 (``norm_topk``). ``held_experts`` names the
+    experts whose weights live here (default: all of them); the router
+    keeps ``num_experts`` outputs whatever is held, and what the absent
+    experts would add is left out. ``shared_hidden`` 0 leaves the shared
+    expert out (a share that is not the one to count it).
+
+    The layer's state carries the step's routing counters
+    (``parallel.moe.ROUTING_COUNTERS``: assignments that landed on held
+    experts, the largest and the mean load of a held expert, dropped
+    assignments, always 0), float32[4] under ``moe_routing``."""
+    num_experts: int = 8
+    held_experts: Tuple[int, ...] = ()
+    hidden: int = 0              # a routed expert's width
+    shared_hidden: int = 0       # the shared expert's; 0: none
+    top_k: int = 2
+    norm_topk: bool = True
+    init_std: float = 0.02
+
+    @property
+    def held(self) -> Tuple[int, ...]:
+        return (tuple(self.held_experts) if self.held_experts
+                else tuple(range(self.num_experts)))
+
+    def __post_init__(self):
+        held = self.held
+        if len(set(held)) != len(held) or not all(
+                0 <= i < self.num_experts for i in held):
+            raise ValueError(
+                f"held_experts={held} must be distinct ids below "
+                f"num_experts={self.num_experts}")
+
+    def output_type(self, input_type: InputType) -> InputType:
+        if isinstance(input_type, RecurrentType):
+            return RecurrentType(self.n_out, input_type.timesteps)
+        return FeedForwardType(self.n_out)
+
+    def initialize(self, key, input_type):
+        n_in = self.resolved_n_in(input_type)
+        dt = self.param_dtype()
+        g, f, fs = len(self.held), self.hidden, self.shared_hidden
+        ks = jax.random.split(key, 8)
+
+        def normal(k, shape):
+            return self.init_std * jax.random.normal(k, shape, dt)
+
+        params = {
+            "router": normal(ks[0], (n_in, self.num_experts)),
+            "w_gate": normal(ks[1], (g, n_in, f)),
+            "w_up": normal(ks[2], (g, n_in, f)),
+            "w_down": normal(ks[3], (g, f, self.n_out)),
+        }
+        if fs:
+            params.update(
+                shared_gate=normal(ks[4], (n_in, fs)),
+                shared_up=normal(ks[5], (n_in, fs)),
+                shared_down=normal(ks[6], (fs, self.n_out)),
+                shared_w=normal(ks[7], (n_in,)))
+        return params
+
+    def init_state(self, input_type):
+        return {"moe_routing": jnp.zeros((4,), jnp.float32)}
+
+    def apply(self, params, state, x, ctx):
+        from deeplearning4j_tpu.parallel.moe import held_experts_ffn
+        xt = x.reshape(-1, x.shape[-1])
+        y, counters = held_experts_ffn(
+            xt, params["router"], params["w_gate"], params["w_up"],
+            params["w_down"], self.held, top_k=self.top_k,
+            norm_topk=self.norm_topk)
+        if self.shared_hidden:
+            with jax.named_scope("moe.shared"):
+                h = (jax.nn.silu(xt @ params["shared_gate"])
+                     * (xt @ params["shared_up"]))
+                gate = jax.nn.sigmoid(jnp.einsum(
+                    "td,d->t", xt, params["shared_w"],
+                    preferred_element_type=jnp.promote_types(
+                        jnp.float32, xt.dtype)))
+                y = y + (h @ params["shared_down"]) * gate[:, None].astype(
+                    y.dtype)
+        return (y.reshape(x.shape[:-1] + (self.n_out,)),
+                {"moe_routing": counters})

@@ -138,3 +138,35 @@ class LayerNormalization(Layer):
         y = (xf - mean) * jax.lax.rsqrt(var + self.eps)
         y = y.astype(x.dtype)
         return y * params["gamma"] + params["beta"], state
+
+
+def rms_norm(x, weight, eps: float = 1e-6, zero_centered: bool = True):
+    """``x * rsqrt(mean(x^2) + eps) * (1 + w)`` over the last axis
+    (``* w`` when not ``zero_centered``), statistics in float32; the
+    result has ``x``'s type."""
+    xf = x.astype(jnp.promote_types(jnp.float32, x.dtype))
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
+    w = weight.astype(y.dtype)
+    return (y * (1.0 + w if zero_centered else w)).astype(x.dtype)
+
+
+@register_serializable
+@dataclasses.dataclass(frozen=True)
+class RMSNorm(Layer):
+    """Root-mean-square normalization over the feature axis, no mean and
+    no bias. ``zero_centered`` stores the scale as its distance from one
+    (weight initialised to 0, applied as ``1 + w``), the form the
+    Qwen3-Next family trains; otherwise the weight starts at 1."""
+    eps: float = 1e-6
+    zero_centered: bool = True
+
+    def output_type(self, input_type: InputType) -> InputType:
+        return input_type
+
+    def initialize(self, key, input_type):
+        nf = input_type.shape()[-1]
+        fill = 0.0 if self.zero_centered else 1.0
+        return {"w": jnp.full((nf,), fill, self.param_dtype())}
+
+    def apply(self, params, state, x, ctx):
+        return rms_norm(x, params["w"], self.eps, self.zero_centered), state

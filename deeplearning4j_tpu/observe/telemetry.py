@@ -623,3 +623,30 @@ class TelemetryCollector:
 
     def last_replica_record(self) -> Optional[dict]:
         return self.replica_history[-1] if self.replica_history else None
+
+
+# ---- routing counters of expert layers ------------------------------------
+
+ROUTING_GAUGES = (
+    ("dl4j_moe_assignments_held",
+     "token-to-expert assignments of the last step that landed on experts "
+     "this chip holds"),
+    ("dl4j_moe_expert_load_max",
+     "largest number of assignments one held expert took in the last step"),
+    ("dl4j_moe_expert_load_mean",
+     "mean number of assignments a held expert took in the last step"),
+    ("dl4j_moe_dropped_assignments",
+     "assignments to held experts that were not computed in the last step "
+     "(the held-experts path drops none: always 0)"),
+)
+
+
+def publish_routing(rows: Dict[str, Any],
+                    registry: Optional[MetricsRegistry] = None) -> None:
+    """``rows``: layer name -> the four counters a ``HeldExpertsMoE``
+    leaves in its state (``parallel.moe.ROUTING_COUNTERS``' order), on the
+    host. Each becomes a gauge with a ``layer`` label."""
+    reg = registry if registry is not None else default_registry()
+    for layer, row in rows.items():
+        for (name, help_text), value in zip(ROUTING_GAUGES, np.asarray(row)):
+            reg.gauge(name, help_text).set(float(value), layer=layer)

@@ -475,9 +475,28 @@ def _pad_len(t: int, block: int) -> int:
     return (-t) % block
 
 
+def _default_blocks(head_dim: int):
+    """The tile sizes for a head of ``head_dim``: the tuned 1024 x 1024
+    while the dK/dV kernel's float32 working set fits Mosaic's scoped
+    VMEM, else ``block_q`` halved until it does. That working set is two
+    (block_q, block_k) score tiles and about 5.5 (block, head) tiles a
+    side, whatever the inputs' type (the TPU compiler, 16 heads, T =
+    8,192: 18.5 MB at 1024 x 1024 x 256 and 19.8 MB at 512 x 1024 x 512
+    are refused; 1024 x 1024 x 128, 512 x 1024 x 256 and 256 x 1024 x 512
+    compile)."""
+    block_q, block_k = _DEF_BLOCK_Q, _DEF_BLOCK_K
+
+    def working_set(bq):
+        return 4 * (2 * bq * block_k + 5.5 * (bq + block_k) * head_dim)
+
+    while block_q > 128 and working_set(block_q) > _SCOPED_VMEM_DEFAULT:
+        block_q //= 2
+    return block_q, block_k
+
+
 def flash_attention(q, k, v, mask=None, causal: bool = False,
-                    block_q: int = _DEF_BLOCK_Q,
-                    block_k: int = _DEF_BLOCK_K,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
                     bwd_impl: Optional[str] = None):
     """Blockwise (flash) attention on (N, T, H, Dh) tensors.
@@ -485,7 +504,8 @@ def flash_attention(q, k, v, mask=None, causal: bool = False,
     Drop-in for nn.layers.attention.scaled_dot_product_attention. ``mask``
     is the (N, T_k) key-validity mask. Sequences are padded to the block
     size internally (padding is masked out, query padding sliced off).
-    ``interpret`` defaults to ``pallas_interpret()``. ``bwd_impl`` selects the backward
+    ``block_q`` / ``block_k`` default to ``_default_blocks`` of the head
+    size. ``interpret`` defaults to ``pallas_interpret()``. ``bwd_impl`` selects the backward
     implementation explicitly ("pallas" kernels or the "xla" jnp/scan
     reference); None defers to the ``DL4J_FLASH_BWD`` env override
     (default pallas).
@@ -497,8 +517,9 @@ def flash_attention(q, k, v, mask=None, causal: bool = False,
         interpret = pallas_interpret()
     n, tq, h, dh = q.shape
     tk = k.shape[1]
-    block_q = min(block_q, max(tq, 1))
-    block_k = min(block_k, max(tk, 1))
+    auto_q, auto_k = _default_blocks(dh)
+    block_q = min(block_q or auto_q, max(tq, 1))
+    block_k = min(block_k or auto_k, max(tk, 1))
     if not interpret:
         # Mosaic constraints: q blocks land in the sublane dim (multiple
         # of 8); the mask's dynamic k-slice is in the lane dim (multiple

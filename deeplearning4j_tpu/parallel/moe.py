@@ -1,14 +1,28 @@
 """Expert parallelism: mixture-of-experts FFN with top-k routing.
 
 ABSENT in the reference (SURVEY §2.11 row 7); designed fresh per SURVEY
-§7.2 stage 7. GShard/Switch-style dense dispatch: routing builds
-(tokens, experts, capacity) dispatch/combine tensors so the whole layer is
-three einsums + the expert FFN — fully static shapes, MXU-friendly, no
-gather/scatter. Expert parallelism is expressed the XLA-native way: the
-expert-stacked weights and the (E, C, d) expert-batch tensor carry
-sharding constraints on the ``expert`` mesh axis, and GSPMD inserts the
-all-to-all dispatch/return collectives over ICI — no hand-written
-communication (the reference's Aeron mesh analog is the compiler).
+§7.2 stage 7. Two paths, for two kinds of deployment:
+
+- ``moe_ffn`` (layer ``MixtureOfExperts``): GShard/Switch-style dense
+  dispatch. Routing builds (tokens, experts, capacity) dispatch/combine
+  tensors so the whole layer is three einsums + the expert FFN — fully
+  static shapes, MXU-friendly, no gather/scatter. **This path drops
+  tokens**: what overflows an expert's capacity gets combine weight 0.
+  Expert parallelism is expressed the XLA-native way: the expert-stacked
+  weights and the (E, C, d) expert-batch tensor carry sharding
+  constraints on the ``expert`` mesh axis, and GSPMD inserts the
+  all-to-all dispatch/return collectives over ICI — no hand-written
+  communication (the reference's Aeron mesh analog is the compiler). Its
+  tensors grow with tokens x experts x capacity, so it is for small
+  expert counts.
+- ``held_experts_ffn`` (layer ``HeldExpertsMoE``): **drops no token**
+  whatever the imbalance. The router scores all of the model's experts,
+  the chip is told which of them it holds, the assignments that land on
+  held experts are sorted by expert and go through grouped matrix
+  products (``lax.ragged_dot``), gated (SwiGLU) experts without biases.
+  What the absent experts would add is left out: this is the chip's share
+  of an expert-parallel deployment, computed without the exchange, and
+  the layer a held-experts deployment uses.
 """
 
 from __future__ import annotations
@@ -153,3 +167,82 @@ def moe_ffn(x: jnp.ndarray,
     y = jnp.einsum("tec,ecd->td", combine, expert_out)
     return MoEOutput(y.reshape(orig_shape[:-1] + (y.shape[-1],)),
                      aux.astype(jnp.float32), z.astype(jnp.float32))
+
+
+# ---- held experts: no token dropped ---------------------------------------
+
+# the routing counters a held-experts layer leaves in its state, in order
+ROUTING_COUNTERS = ("assignments_held", "load_max", "load_mean", "dropped")
+
+
+def route_top_k_probs(x, router_w, top_k: int, norm_topk: bool = True):
+    """Softmax over ALL experts in float32, the ``top_k`` largest and, with
+    ``norm_topk``, their weights renormalised to sum 1. ``x`` (T, d),
+    ``router_w`` (d, E). Returns ``(expert ids (T, k) int32, weights
+    (T, k) float32)``."""
+    logits = jnp.einsum("td,de->te", x, router_w.astype(x.dtype),
+                        preferred_element_type=jnp.promote_types(
+                            jnp.float32, x.dtype))
+    probs = jax.nn.softmax(logits, -1)
+    weights, ids = jax.lax.top_k(probs, top_k)
+    if norm_topk:
+        weights = weights / jnp.sum(weights, -1, keepdims=True)
+    return ids.astype(jnp.int32), weights
+
+
+def held_experts_ffn(x, router_w, w_gate, w_up, w_down, held, *,
+                     top_k: int, norm_topk: bool = True):
+    """The held experts' part of a top-k mixture of gated experts,
+    ``sum_{i in top-k(x), i held} p_i W_down_i (silu(W_gate_i x) *
+    W_up_i x)``, with no token dropped.
+
+    x: (T, d); router_w: (d, E) over all E experts; w_gate, w_up:
+    (G, d, f) and w_down: (G, f, d) for the G experts held here, whose
+    ids among the E are ``held`` (a static tuple, in the weights' order).
+    Returns ``(y (T, d) in x's type, counters float32[4])``, the counters
+    in ``ROUTING_COUNTERS``' order.
+
+    The T * k assignments are sorted by held expert (those of absent
+    experts last); the rows of the first ``T * min(k, G)`` of them, the
+    most that can land here (a token picks an expert once), are gathered,
+    three grouped products over ``group_sizes`` compute the experts, and a
+    weighted scatter-add in float32 takes the results back to the tokens.
+    The buffer's length does not depend on the routing, so neither does
+    the step's time: gather and scatter cost by the row, the grouped
+    products only by the rows inside groups."""
+    t, d = x.shape
+    e = router_w.shape[-1]
+    g = len(held)
+    acc = jnp.promote_types(jnp.float32, x.dtype)
+    with jax.named_scope("moe.route"):
+        ids, weights = route_top_k_probs(x, router_w, top_k, norm_topk)
+        local = jnp.full((e,), g, jnp.int32).at[jnp.asarray(held)].set(
+            jnp.arange(g, dtype=jnp.int32))
+        where = local[ids].reshape(-1)                  # (T*k,) in [0, G]
+        sizes = jnp.bincount(where, length=g + 1)[:g].astype(jnp.int32)
+        n_held = jnp.sum(sizes)
+    rows = t * min(top_k, g)
+    with jax.named_scope("moe.dispatch"):
+        sel = jnp.argsort(where, stable=True)[:rows]
+        token = sel // top_k
+        # rows past the last group belong to no expert: the grouped
+        # product leaves them unwritten (on the TPU: whatever the memory
+        # held, NaN included), so they go in as zeros and every product's
+        # result is zeroed there before it is used: a zero cotangent
+        # times a NaN is a NaN
+        live = (jnp.arange(rows) < n_held)[:, None]
+        xs = jnp.where(live, jnp.take(x, token, axis=0), 0)
+    with jax.named_scope("moe.experts"):
+        w_gate, w_up, w_down = (w.astype(x.dtype)
+                                for w in (w_gate, w_up, w_down))
+        up = jnp.where(live, jax.lax.ragged_dot(xs, w_up, sizes), 0)
+        gate = jnp.where(live, jax.lax.ragged_dot(xs, w_gate, sizes), 0)
+        ys = jnp.where(live, jax.lax.ragged_dot(jax.nn.silu(gate) * up,
+                                                w_down, sizes), 0)
+    with jax.named_scope("moe.combine"):
+        p = weights.reshape(-1)[sel].astype(acc)
+        y = jnp.zeros((t, d), acc).at[token].add(ys.astype(acc) * p[:, None])
+    counters = jnp.stack([
+        n_held, jnp.max(sizes), n_held / g,
+        n_held - jnp.minimum(n_held, rows)]).astype(jnp.float32)
+    return y.astype(x.dtype), counters

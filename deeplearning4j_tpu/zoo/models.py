@@ -433,6 +433,98 @@ class TextGenerationLSTM(ZooModel):
 
 
 @dataclasses.dataclass
+class Qwen3Next(ZooModel):
+    """Hybrid linear-attention mixture-of-experts causal language model of
+    the Qwen3-Next family (published config:
+    huggingface.co/Qwen/Qwen3-Next-80B-A3B-Instruct, ``config.json``; the
+    field names below are its keys). ``num_hidden_layers`` blocks, every
+    ``full_attention_interval``-th a gated softmax attention and the others
+    Gated DeltaNets, each followed by a mixture of gated experts with one
+    shared expert; RMSNorm (zero-centred), an untied head, next-token loss.
+
+    ``held_experts`` (default: all) names the routed experts whose weights
+    this chip holds of ``num_experts``: the router keeps ``num_experts``
+    outputs and the rest of the mixture is left out (an expert-parallel
+    deployment's share, ``nn.layers.feedforward.HeldExpertsMoE``).
+    ``vocab_size`` may be a slice of the published vocabulary: embedding,
+    head and loss are over the slice. Features are integer token ids
+    (N, seq_len), labels ``nn.layers.decoder.next_token_labels(ids)``.
+    Not in the model: the multi-token-prediction module, a router auxiliary
+    loss, dropout."""
+    vocab_size: int = 151936
+    hidden_size: int = 2048
+    num_hidden_layers: int = 48
+    full_attention_interval: int = 4
+    num_attention_heads: int = 16
+    num_key_value_heads: int = 2
+    head_dim: int = 256
+    partial_rotary_factor: float = 0.25
+    rope_theta: float = 1e7
+    linear_num_key_heads: int = 16
+    linear_num_value_heads: int = 32
+    linear_key_head_dim: int = 128
+    linear_value_head_dim: int = 128
+    linear_conv_kernel_dim: int = 4
+    num_experts: int = 512
+    held_experts: Tuple[int, ...] = ()
+    num_experts_per_tok: int = 10
+    moe_intermediate_size: int = 512
+    shared_expert_intermediate_size: int = 512
+    norm_topk_prob: bool = True
+    rms_norm_eps: float = 1e-6
+    initializer_range: float = 0.02
+    seq_len: int = 8192
+    chunk_size: int = 64
+    recompute: bool = True
+    compute_dtype: str = "bfloat16"
+    updater: Optional[Updater] = None
+    seed: int = 123
+
+    def conf(self):
+        from deeplearning4j_tpu.nn.layers.decoder import (
+            GATED_ATTENTION, GATED_DELTANET, CausalLMOutputLayer,
+            HybridDecoderBlock, TokenEmbedding)
+        std = self.initializer_range
+        b = (NeuralNetConfiguration.Builder()
+             .seed(self.seed)
+             .updater(self.updater or Adam(1e-4))
+             .compute_dtype(self.compute_dtype)
+             .list()
+             .layer(TokenEmbedding(name="embed", vocab_size=self.vocab_size,
+                                   n_out=self.hidden_size, init_std=std)))
+        for l in range(self.num_hidden_layers):
+            full = (l + 1) % self.full_attention_interval == 0
+            b = b.layer(HybridDecoderBlock(
+                name=f"block{l}", n_out=self.hidden_size,
+                mixer=GATED_ATTENTION if full else GATED_DELTANET,
+                n_heads=self.num_attention_heads,
+                n_kv_heads=self.num_key_value_heads, head_dim=self.head_dim,
+                partial_rotary_factor=self.partial_rotary_factor,
+                rope_theta=self.rope_theta,
+                n_key_heads=self.linear_num_key_heads,
+                n_value_heads=self.linear_num_value_heads,
+                key_head_dim=self.linear_key_head_dim,
+                value_head_dim=self.linear_value_head_dim,
+                conv_kernel=self.linear_conv_kernel_dim,
+                chunk_size=self.chunk_size,
+                num_experts=self.num_experts,
+                held_experts=tuple(self.held_experts),
+                expert_hidden=self.moe_intermediate_size,
+                shared_hidden=self.shared_expert_intermediate_size,
+                top_k=self.num_experts_per_tok,
+                norm_topk=self.norm_topk_prob, eps=self.rms_norm_eps,
+                init_std=std, recompute=self.recompute))
+        return (b.layer(CausalLMOutputLayer(
+                    name="lm_head", n_out=self.vocab_size,
+                    eps=self.rms_norm_eps, init_std=std))
+                .set_input_type(InputType.recurrent(1, self.seq_len))
+                .build())
+
+    def init(self) -> MultiLayerNetwork:
+        return MultiLayerNetwork(self.conf()).init()
+
+
+@dataclasses.dataclass
 class AlexNet(ZooModel):
     """reference: model/AlexNet.java (single-stream variant)."""
     num_classes: int = 1000

@@ -290,3 +290,14 @@ class TestFlashPallasBackward:
         assert np.isfinite(np.asarray(dk)).all()
         np.testing.assert_allclose(np.asarray(dk)[0, 4:], 0.0, atol=1e-6)
         np.testing.assert_allclose(np.asarray(dv)[0, 4:], 0.0, atol=1e-6)
+
+
+@pytest.mark.parametrize("head_dim,blocks", [
+    (64, (1024, 1024)), (128, (1024, 1024)), (256, (512, 1024)),
+    (512, (256, 1024))])
+def test_default_blocks_shrink_with_the_head(head_dim, blocks):
+    """The tuned tiles up to a head of 128; from 256 the query tile halves
+    until the dK/dV kernel's working set fits the scoped VMEM (what the
+    TPU compiler accepts and refuses: tests/test_tpu_compile.py)."""
+    from deeplearning4j_tpu.ops.pallas_kernels import _default_blocks
+    assert _default_blocks(head_dim) == blocks

@@ -1,0 +1,58 @@
+"""Kernels of the main path compiled at their real widths for a TPU v5e
+that is described, not attached: what interpret mode cannot show (tiles
+the compiler refuses for the fast memory they need). Nothing runs, so
+nothing here says a word about results or times.
+
+The topology is described inside a fixture, after a test of this file has
+started: only the worker that is given this file loads the TPU's library.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_compile_cache():
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip
+    from jax.experimental.compilation_cache import compilation_cache
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("heads,head_dim", [(12, 64), (16, 256), (16, 512)])
+def test_flash_attention_compiles_with_its_default_tiles(
+        one_chip, no_compile_cache, heads, head_dim):
+    """Forward and both backward kernels, causal, 8,192 positions,
+    bfloat16: BERT-like heads of 64, the gated attention's 16 heads of 256
+    (1024 x 1024 tiles are refused there: 18.5 MB of scoped VMEM), and 512."""
+    from deeplearning4j_tpu.ops.pallas_kernels import flash_attention
+    x = jax.ShapeDtypeStruct((1, 8192, heads, head_dim), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def total(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True,
+                                       interpret=False).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(total, argnums=(0, 1, 2))).lower(
+        x, x, x).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
