@@ -17,6 +17,16 @@ a seed, with no network and no files outside the checkout:
   phase=kernels  flash attention, the Pallas LSTM recurrence and the
                  Pallas conv+BN kernels, compiled (interpret=False),
                  forward and backward, against plain XLA
+  phase=feed_race  the input pipeline alone, with no train step, so that
+                 the prefetch thread gathers flat out into buffers it
+                 uses again: 200 shuffled batches of the ResNet cell's
+                 1280 rows through ``DeviceFeeder`` (``--chips 4``: 40 of
+                 5120 rows through ``ParallelWrapper``'s feeder and its
+                 ``put``), every staged device batch against an
+                 independent gather from a copy of the set on the device;
+                 and when the runtime lets go of a host array it is given
+
+``--phases a,b`` runs only the phases named.
 
 Each phase prints ``phase=<name> ok|FAIL seconds=<t> ...``; seconds are
 set-up information, not a result. Any FAIL exits non-zero. On success
@@ -35,6 +45,9 @@ BATCH = 384
 K1_STEPS = 6                 # k_steps=1 steps (also the prefetch=0 replay)
 K_STEPS, K_DISPATCHES = 4, 3  # scanned dispatches after them
 SERVE_SIZES = (1, 3, 32, 50)
+# the feed race: (rows a batch, batches) by chips, over the ResNet cells' set
+RACE = {1: (1280, 200), 4: (5120, 40)}
+RACE_SET_ROWS, RACE_SEED, RACE_IN_FLIGHT = 25600, 5, 2
 # max |got - want| / max |want| against the XLA reference
 TOL = {"bfloat16": 4e-2, "float32": 2e-2}
 
@@ -159,7 +172,7 @@ def phase_train(ctx):
     scanned = _fit(model, K_STEPS * K_DISPATCHES, 8, lst,
                    k_steps=K_STEPS)[K1_STEPS:]
     # the synchronous loop the feeder must replay bitwise: same seeds,
-    # fresh model, no DeviceFeeder and so no StagingPool
+    # fresh model, no DeviceFeeder
     replay = build_model()
     sync = _fit(replay, K1_STEPS, 7, _loss_listener(), prefetch=0)
     losses = np.concatenate([fed, scanned])
@@ -351,11 +364,161 @@ def phase_kernels(ctx):
     return {"checks": len(errs), "worst_rel_err": f"{max(errs.values()):.3e}"}
 
 
+def _host_array_hold(put, rows):
+    """Reference counts of one host array of ``rows`` ResNet images round
+    ``put``: before, when ``put`` returns, when the staged array is ready,
+    after the next call into the runtime, after the staged array goes.
+    ``released_before_ready`` says whether the count was ever back at its
+    first reading while the runtime could still be reading the array."""
+    import jax
+    held = [np.ones((rows, 64, 64, 3), np.float32)]
+    # once before the reading: a shape's first ``put`` also builds programs
+    jax.block_until_ready(put(np.zeros_like(held[0])))
+    counts = {"before": sys.getrefcount(held[0])}
+    t0 = time.perf_counter()
+    staged = put(held[0])
+    counts["put_returned"] = sys.getrefcount(held[0])
+    released_at = ready_at = None
+    while ready_at is None:
+        # the count first: a release seen together with readiness is no
+        # early release
+        if released_at is None and (sys.getrefcount(held[0])
+                                    == counts["before"]):
+            released_at = time.perf_counter() - t0
+        if staged.is_ready():
+            ready_at = time.perf_counter() - t0
+    counts["ready"] = sys.getrefcount(held[0])
+    jax.device_put(np.zeros(3, np.float32)).block_until_ready()
+    counts["after_next_call"] = sys.getrefcount(held[0])
+    del staged
+    counts["staged_array_gone"] = sys.getrefcount(held[0])
+    return {"counts": counts, "ready_ms": round(ready_at * 1e3, 1),
+            "released_before_ready": released_at is not None,
+            "released_ms": (None if released_at is None
+                            else round(released_at * 1e3, 1))}
+
+
+def _tiny_wrapper():
+    """A ParallelWrapper over four chips whose model is beside the point:
+    the race check runs its feeder and its ``put``, never its step."""
+    from deeplearning4j_tpu.models.multi_layer_network import (
+        MultiLayerNetwork)
+    from deeplearning4j_tpu.nn.config import NeuralNetConfiguration
+    from deeplearning4j_tpu.nn.inputs import InputType
+    from deeplearning4j_tpu.nn.layers.feedforward import DenseLayer
+    from deeplearning4j_tpu.nn.layers.output import OutputLayer
+    from deeplearning4j_tpu.ops.losses import LossFunction
+    from deeplearning4j_tpu.optimize.updaters import Adam
+    from deeplearning4j_tpu.parallel.wrapper import ParallelWrapper
+    conf = (NeuralNetConfiguration.Builder().seed(1).updater(Adam(1e-2))
+            .list().layer(DenseLayer(n_out=8))
+            .layer(OutputLayer(n_out=200, loss=LossFunction.MCXENT))
+            .set_input_type(InputType.feed_forward(5)).build())
+    wrapper = ParallelWrapper.builder(
+        MultiLayerNetwork(conf).init()).workers(4).build()
+    wrapper._step, wrapper._batch_sh = wrapper._build_sync_step()
+    return wrapper
+
+
+def _tally_class():
+    from deeplearning4j_tpu.datasets.dataset import DataSetIterator
+
+    class Tally(DataSetIterator):
+        """Hands on the batches of ``base`` (a user's wrapper, as far as
+        the pipeline can tell) and notes which of them say that their
+        memory had been used before."""
+
+        def __init__(self, base):
+            self.base, self.reused = base, []
+
+        def __iter__(self):
+            for batch in self.base:
+                self.reused.append(batch.reused_buffers)
+                yield batch
+
+        def reset(self):
+            self.base.reset()
+
+        @property
+        def batch_size(self):
+            return self.base.batch_size
+
+    return Tally
+
+
+def phase_feed_race(ctx):
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec
+    from deeplearning4j_tpu.datasets.dataset import (
+        ArrayDataSetIterator, DataSet)
+    from deeplearning4j_tpu.datasets.feeder import DeviceFeeder
+    from deeplearning4j_tpu.datasets.iterators import AsyncDataSetIterator
+
+    chips = ctx["chips"]
+    rows, batches = RACE[chips]
+    rng = np.random.default_rng(RACE_SEED)
+    data = DataSet(rng.random((RACE_SET_ROWS, 64, 64, 3), dtype=np.float32),
+                   np.eye(200, dtype=np.float32)[
+                       rng.integers(0, 200, RACE_SET_ROWS)])
+    base = _tally_class()(ArrayDataSetIterator(data, rows, shuffle=True,
+                                       seed=RACE_SEED, drop_last=True))
+    if chips == 1:
+        put, whole_sh = jax.device_put, None
+        source = AsyncDataSetIterator(base)     # as fit() wraps it
+        feeder = DeviceFeeder(source)
+    else:
+        wrapper = _tiny_wrapper()
+        put = wrapper._put_batch
+        whole_sh = NamedSharding(wrapper.mesh, PartitionSpec())
+        feeder, source = wrapper._make_feeder(base)
+    hold = _host_array_hold(put, rows)
+    print("host array round put:", json.dumps(hold), flush=True)
+
+    whole = (jax.device_put(data.features, whole_sh),
+             jax.device_put(data.labels, whole_sh))
+    same = jax.jit(lambda f, l, wf, wl, at: jnp.logical_and(
+        jnp.array_equal(f, wf[at]), jnp.array_equal(l, wl[at])))
+    per_pass = RACE_SET_ROWS // rows
+    verdicts = []
+    t0 = time.perf_counter()
+    for epoch in range(batches // per_pass):
+        # the order DataSet.shuffle(seed + epoch) draws, drawn here
+        order = np.random.default_rng(RACE_SEED + epoch).permutation(
+            RACE_SET_ROWS)
+        for i, item in enumerate(feeder):
+            verdicts.append(same(item.features, item.labels, *whole,
+                                 order[i * rows:(i + 1) * rows]))
+            if len(verdicts) > RACE_IN_FLIGHT:
+                # as many unchecked batches on the device as a fit() keeps
+                # staged, no more: the loop waits for transfers only
+                verdicts[-1 - RACE_IN_FLIGHT].block_until_ready()
+        source.reset()
+    feeder.close()
+    ok = np.asarray(jax.device_get(verdicts))
+    seconds = time.perf_counter() - t0
+    wrong = [int(i) for i in np.flatnonzero(~ok)]
+    info = {"batches": len(ok), "rows": rows, "wrong": len(wrong),
+            "reused": sum(base.reused), "gathered": len(base.reused),
+            "pool_buffers": [len(p) for p in base.base._pools[:2]],
+            "mbytes_per_s": round(len(ok) * rows * 64 * 64 * 3 * 4
+                                  / seconds / 1e6, 1),
+            "released_before_ready": hold["released_before_ready"]}
+    if len(ok) != batches:
+        raise AssertionError(f"{len(ok)} batches staged, expected {batches}")
+    if wrong:
+        raise AssertionError(f"staged batches {wrong[:20]} differ from an "
+                             f"independent gather: {info}")
+    return info
+
+
 # ---- driver --------------------------------------------------------------
 
 def main():
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    ap.add_argument("--phases", default="",
+                    help="comma-separated phases to run (default: all)")
     args = ap.parse_args()
 
     t0 = time.perf_counter()
@@ -383,7 +546,15 @@ def main():
     phases = ([("train", phase_train), ("serve", phase_serve),
                ("kernels", phase_kernels)] if args.chips == 1
               else [("train", phase_train_4chips)])
-    ctx, failed = {}, []
+    phases.append(("feed_race", phase_feed_race))
+    if args.phases:
+        asked = args.phases.split(",")
+        unknown = set(asked) - {name for name, _ in phases}
+        if unknown:
+            sys.exit(f"chip_smoke.py --chips {args.chips} has no phase "
+                     f"{sorted(unknown)}; it has {[n for n, _ in phases]}")
+        phases = [(n, fn) for n, fn in phases if n in asked]
+    ctx, failed = {"chips": args.chips}, []
     for name, fn in phases:
         t0, c0 = time.perf_counter(), compiles.seconds
         try:

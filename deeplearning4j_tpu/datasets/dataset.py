@@ -13,6 +13,8 @@ the reference's AsyncDataSetIterator thread.
 from __future__ import annotations
 
 import dataclasses
+import sys
+import threading
 from typing import Iterator, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
@@ -113,6 +115,16 @@ class ListDataSetIterator(DataSetIterator):
         return self._batches[0].num_examples() if self._batches else None
 
 
+def _refcount_of_a_pooled_buffer_nobody_holds() -> int:
+    """What ``sys.getrefcount(pool[i])`` reads while the list ``pool`` is
+    the object's only holder (the list's reference and the call's own)."""
+    pool = [object()]
+    return sys.getrefcount(pool[0])
+
+
+_UNHELD = _refcount_of_a_pooled_buffer_nobody_holds()
+
+
 class ArrayDataSetIterator(DataSetIterator):
     """Batches a single large DataSet (analog of creating an iterator from
     arrays; supports shuffling each epoch).
@@ -122,7 +134,29 @@ class ArrayDataSetIterator(DataSetIterator):
     so the batches are those of the shuffled set without a copy of the
     whole set: a pass starts in one batch's time, and under
     ``AsyncDataSetIterator`` the gathers run on the prefetch thread beside
-    the steps. Without it the batches are views of the set."""
+    the steps. Without it the batches are views of the set.
+
+    A shuffled batch is the caller's own: nothing the iterator does later
+    writes to it while anything refers to it. Its memory is used again
+    once nothing does. Per array of the set the iterator keeps up to
+    ``_POOL_BUFFERS`` batch-shaped buffers and gathers into one that
+    nobody holds: no batch, no view or slice of one, no queue entry, no
+    ``jax.Array`` that adopted it, no transfer the runtime still reads it
+    for. CPython's reference count of the buffer sees all of these (every
+    view's ``.base`` is the buffer; the runtime keeps the array it was
+    given until its transfer is done). Where every kept buffer is held it
+    allocates, so a consumer that keeps its batches gets fresh arrays, and
+    one that drops them gets memory whose pages have been touched before:
+    for a 63 MB batch the first touch costs ten times the gather. Each
+    gathered batch says which it got in ``reused_buffers``
+    (``AsyncDataSetIterator`` puts it on the ``produce`` span). The short
+    last batch of a pass without ``drop_last`` is a fresh array."""
+
+    # what one pass can have in flight: ``AsyncDataSetIterator``'s queue
+    # of 8, the feeder's 2 staged, one in the producer's hand and one in
+    # the feeder's, and the few whose transfer is done but which the
+    # runtime lets go of only at its next call
+    _POOL_BUFFERS = 16
 
     def __init__(self, data: DataSet, batch_size: int, shuffle: bool = False,
                  seed: int = 0, drop_last: bool = False):
@@ -132,21 +166,65 @@ class ArrayDataSetIterator(DataSetIterator):
         self._seed = seed
         self._epoch = 0
         self._drop_last = drop_last
+        # on the iterator, so that the touched pages outlive a fit() call
+        self._pools: List[List[np.ndarray]] = [[] for _ in data._arrays()]
+        self._pools_lock = threading.Lock()
 
     def __iter__(self):
         n = self._data.num_examples()
         # to the host once a pass: a jax.Array set would cross per batch
         arrays = [None if a is None else np.asarray(a)  # host-sync-ok: host batching pre-transfer
                   for a in self._data._arrays()]
-        perm = None
-        if self._shuffle:
-            perm = self._data._permutation(self._seed + self._epoch)
-            self._epoch += 1
         end = n - (n % self._bs) if self._drop_last else n
+        if not self._shuffle:
+            for lo in range(0, end, self._bs):
+                yield DataSet(*(None if a is None else a[lo:lo + self._bs]
+                                for a in arrays))
+            return
+        perm = self._data._permutation(self._seed + self._epoch)
+        self._epoch += 1
         for lo in range(0, end, self._bs):
-            rows = (slice(lo, lo + self._bs) if perm is None
-                    else perm[lo:lo + self._bs])
-            yield DataSet(*(None if a is None else a[rows] for a in arrays))
+            # no local of this frame may hold a buffer across the yield
+            yield self._gather(arrays, perm[lo:lo + self._bs])
+
+    def _gather(self, arrays, rows: np.ndarray) -> DataSet:
+        """Rows ``rows`` of every array, as ``a[rows]`` gives them."""
+        out, reused = [], True
+        full = len(rows) == self._bs    # the short last batch is not pooled
+        for a, pool in zip(arrays, self._pools):
+            if a is None:
+                out.append(None)
+                continue
+            shape = (len(rows),) + a.shape[1:]
+            buf, touched = (self._unheld_buffer(pool, shape, a.dtype) if full
+                            else (np.empty(shape, a.dtype), False))
+            # "clip": under the default "raise" numpy gathers into a fresh
+            # temporary and copies it to ``out``; a permutation's rows are
+            # in range, so the mode changes no value
+            np.take(a, rows, axis=0, out=buf, mode="clip")
+            out.append(buf)
+            reused = reused and touched
+        batch = DataSet(*out)
+        batch.reused_buffers = reused
+        return batch
+
+    def _unheld_buffer(self, pool: List[np.ndarray], shape, dtype
+                       ) -> Tuple[np.ndarray, bool]:
+        """A buffer of ``pool`` that only ``pool`` refers to, and True; or,
+        where each is held, a new one (kept while the pool has room) and
+        False. The lock makes reading the count and taking the buffer one
+        step among this iterator's threads; nothing else can take a
+        reference to an object that only the pool refers to."""
+        with self._pools_lock:
+            if pool and (pool[0].shape != shape or pool[0].dtype != dtype):
+                pool.clear()            # the set's arrays were replaced
+            for i in range(len(pool)):  # by index: a loop variable holds
+                if sys.getrefcount(pool[i]) == _UNHELD:
+                    return pool[i], True
+            buf = np.empty(shape, dtype)
+            if len(pool) < self._POOL_BUFFERS:
+                pool.append(buf)
+            return buf, False
 
     @property
     def batch_size(self):

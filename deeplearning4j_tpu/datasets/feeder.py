@@ -11,7 +11,11 @@ PERF_ANALYSIS:
    instead issues ``jax.device_put`` for batches *i+1 / i+2* while the
    (asynchronously dispatched) step *i* still computes, holding up to
    ``depth`` staged batches in a bounded double-buffer (default 2
-   slots, optional byte budget).
+   slots, optional byte budget). The feeder makes no host copy of its
+   own: ``put`` is handed the arrays the source produced, and the
+   runtime keeps each until its transfer is done (which is how a source
+   that uses its buffers again, ``ArrayDataSetIterator``, knows when it
+   may).
 2. **Per-dispatch overhead.** Each dispatch carries a fixed cost;
    ``k_steps > 1`` groups K prefetched batches into ONE stacked device
    array and the fit loop runs ``make_scan_train_step`` over it — the
@@ -156,30 +160,6 @@ class _HostItem(NamedTuple):
     raw: Any = None
 
 
-class StagingPool:
-    """Reusable host staging buffers, the pinned-memory analog: one
-    rotating ring of ``slots`` numpy buffers per (shape, dtype), so
-    steady-state feeding stops allocating fresh host arrays per batch.
-    Only safe when ``put`` COPIES (real accelerators do; the CPU backend
-    zero-copy adopts numpy buffers — reusing one would corrupt staged
-    batches, so the feeder auto-disables the pool there)."""
-
-    def __init__(self, slots: int):
-        self.slots = max(2, int(slots))
-        self._rings = {}
-
-    def stage(self, a: np.ndarray) -> np.ndarray:
-        key = (a.shape, a.dtype.str)
-        ring = self._rings.get(key)
-        if ring is None:
-            ring = [np.empty(a.shape, a.dtype) for _ in range(self.slots)]
-            self._rings[key] = ring
-        buf = ring[0]
-        ring.append(ring.pop(0))
-        np.copyto(buf, a)
-        return buf
-
-
 class _ResidentWatcher:
     """Records the ``resident`` span (cat ``data``) of every staged item:
     from the reading its ``host_to_device`` span starts at until
@@ -258,9 +238,9 @@ class DeviceFeeder:
         per-batch items; "pad" repeats the last batch to a full group —
         the AVERAGING-round contract, where the round is the unit
     put : staging function ``np.ndarray -> jax.Array`` (default
-        ``jax.device_put``; the wrapper passes its sharded staging)
-    reuse_staging : reuse host staging buffers between batches (None =
-        auto: on for non-CPU backends, where ``device_put`` copies)
+        ``jax.device_put``; the wrapper passes its sharded staging). It
+        is handed the very arrays the source produced (the stacked ones
+        of a K-group): the feeder makes no host copy of its own
     """
 
     def __init__(self, source: Iterable, *, depth: Optional[int] = None,
@@ -271,8 +251,7 @@ class DeviceFeeder:
                  = None,
                  group_remainder: str = "split",
                  put: Optional[Callable] = None,
-                 tracer=None, registry=None, session_id: str = "train",
-                 reuse_staging: Optional[bool] = None):
+                 tracer=None, registry=None, session_id: str = "train"):
         if depth is None:
             # direct constructions (fit() resolves its own): measured
             # tuned depth when a process TunedConfig is installed, else
@@ -305,10 +284,6 @@ class DeviceFeeder:
         self._g_stall = reg.gauge(
             "dl4j_etl_stall_ms", "cumulative ms the step loop waited on "
             "the input feeder (0 = ETL fully hidden behind compute)")
-        if reuse_staging is None:
-            reuse_staging = jax.devices()[0].platform != "cpu"
-        self._pool = (StagingPool(self.depth + 2) if reuse_staging
-                      else None)
         # bucket = the normalized example count; seeded from the
         # source's declared batch size so a tiny first pass (ragged
         # FIRST batch) can't lock in an undersized bucket
@@ -413,8 +388,6 @@ class DeviceFeeder:
                 continue
             a = np.asarray(a)  # host-sync-ok: host-side batch staging before transfer
             nbytes += a.nbytes
-            if self._pool is not None:
-                a = self._pool.stage(a)
             staged.append(self.put(a))
         seq = self._seq
         self._seq += 1

@@ -10,6 +10,9 @@ prepares the next minibatches while the TPU executes the current step
 (reference: AsyncDataSetIterator wraps fit's iterator at
 MultiLayerNetwork.java:1273). Combined with the jitted step's async
 dispatch, this keeps the device fed without an explicit infeed queue.
+The batches it queues are the base's own objects: it copies nothing, and
+a queued batch is held (``ArrayDataSetIterator`` does not gather into a
+buffer while the queue refers to it).
 """
 
 from __future__ import annotations
@@ -32,7 +35,11 @@ class AsyncDataSetIterator(DataSetIterator):
     ``next()`` of the base, ``index`` counting from 0 in each pass. The
     ``index=0`` span also holds whatever the base does when a pass starts
     (``ArrayDataSetIterator(shuffle=True)`` draws its permutation; every
-    span of it, the first too, is one batch's gather)."""
+    span of it, the first too, is one batch's gather). Where the batch
+    says so (``reused_buffers``, set by ``ArrayDataSetIterator`` on a
+    gathered batch and carried through any wrapper that hands the batch
+    on), the span says in ``reused`` whether the batch went into memory
+    that has been used before or into a fresh allocation."""
 
     _SENTINEL = object()
 
@@ -59,8 +66,11 @@ class AsyncDataSetIterator(DataSetIterator):
             """The base's batches, each ``next()`` under a span."""
             start = time.perf_counter()
             for index, batch in enumerate(self.base):
-                tracer.add_span("produce", start, time.perf_counter(),
-                                cat="data", index=index)
+                end = time.perf_counter()
+                reused = getattr(batch, "reused_buffers", None)
+                said = {} if reused is None else {"reused": reused}
+                tracer.add_span("produce", start, end, cat="data",
+                                index=index, **said)
                 yield batch
                 start = time.perf_counter()
 

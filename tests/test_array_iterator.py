@@ -1,7 +1,12 @@
 """``ArrayDataSetIterator``: a shuffled pass hands out the batches of
 ``DataSet.shuffle(seed + epoch)`` bit for bit without ever holding a
-shuffled copy of the set; an unshuffled pass hands out views."""
+shuffled copy of the set, gathered into memory it has used before and
+that nobody holds any more; an unshuffled pass hands out views."""
 
+import collections
+import sys
+import threading
+import time
 import tracemalloc
 
 import jax.numpy as jnp
@@ -9,6 +14,7 @@ import numpy as np
 import pytest
 
 from deeplearning4j_tpu.datasets.dataset import ArrayDataSetIterator, DataSet
+from deeplearning4j_tpu.datasets.iterators import AsyncDataSetIterator
 
 
 def _set(n, masks=False, on_device=False, seed=0):
@@ -70,17 +76,159 @@ def test_unshuffled_batches_are_views_of_the_set(n, drop_last):
             assert a.base is whole
 
 
-def test_shuffled_batches_are_the_callers_own():
-    """No output buffer is reused: a batch kept from a pass (the prefetch
-    queue holds eight) is not written by a later one."""
-    data = _set(40)
+def _hold_batch(batch):
+    return batch
+
+
+def _hold_slice(batch):
+    return batch.features[2:5]
+
+
+def _hold_asarray(batch):
+    return np.asarray(batch.labels)
+
+
+def _hold_jax_array(batch):
+    # the CPU backend adopts a numpy array it is given where it can
+    return jnp.asarray(batch.features)
+
+
+_HOLDERS = {"batch": (_hold_batch, lambda w: w),
+            "slice": (_hold_slice, lambda w: w.features[2:5]),
+            "asarray": (_hold_asarray, lambda w: w.labels),
+            "jax_array": (_hold_jax_array, lambda w: w.features)}
+
+
+def _assert_held(held, want):
+    if isinstance(held, DataSet):
+        _assert_same_batches([held], [want])
+    else:
+        np.testing.assert_array_equal(np.asarray(held), want)
+
+
+@pytest.mark.parametrize("holder", sorted(_HOLDERS))
+def test_shuffled_batches_are_the_callers_own(holder):
+    """The iterator gathers into buffers it has used before, and only into
+    one that nothing refers to: whatever a caller keeps of a batch (the
+    batch, a slice of one of its arrays, ``np.asarray`` of one, a
+    ``jax.Array`` made from one) is never written by a later batch, over
+    passes in which everything else is dropped and comes back."""
+    hold, part_of = _HOLDERS[holder]
+    data = _set(88)
+    it = ArrayDataSetIterator(data, 8, shuffle=True, seed=2)
+    kept, reused = [], 0
+    for epoch in range(3):
+        want = _slices(data.shuffle(2 + epoch), 8, False)
+        for i, batch in enumerate(it):
+            assert not np.shares_memory(batch.features, data.features)
+            reused += batch.reused_buffers
+            if i % 3 == 0:
+                kept.append((hold(batch), part_of(want[i])))
+            del batch
+            for held, wanted in kept:
+                _assert_held(held, wanted)
+    assert len(kept) == 12
+    assert reused >= 15                 # of 33: the others' memory came back
+
+
+def test_a_queued_batch_is_held():
+    """``AsyncDataSetIterator``'s queue fills while its consumer dawdles;
+    no entry of it is written before it is taken."""
+    data = _set(160)
+    it = AsyncDataSetIterator(ArrayDataSetIterator(data, 8, shuffle=True),
+                              queue_size=8)
+    for epoch in range(3):
+        want = _slices(data.shuffle(epoch), 8, False)
+        got = []
+        for i, batch in enumerate(it):
+            if i in (0, 9):
+                time.sleep(0.05)        # the worker runs the queue full
+            got.append(DataSet(*(None if a is None else a.copy()
+                                 for a in batch._arrays())))
+        _assert_same_batches(got, want)
+
+
+def test_dropped_batches_come_back():
+    """A consumer that drops each batch as it takes the next is handed two
+    buffers in turn: batch i + 2 lies where batch i lay, and from the
+    third on each says that its memory had been used before."""
+    data = _set(80, masks=True)
     it = ArrayDataSetIterator(data, 8, shuffle=True)
-    kept = list(it)
-    copies = [b.features.copy() for b in kept]
-    list(it)
-    for batch, copy in zip(kept, copies):
-        assert not np.shares_memory(batch.features, data.features)
-        np.testing.assert_array_equal(batch.features, copy)
+    where, reused = [], []
+    for _ in range(2):
+        for batch in it:
+            where.append([a.ctypes.data for a in batch._arrays()])
+            reused.append(batch.reused_buffers)
+    assert len(where) == 20
+    assert where[2:] == where[:-2]
+    assert where[0] != where[1]
+    assert reused == [False, False] + [True] * 18
+    assert [len(pool) for pool in it._pools] == [2, 2, 2, 2]
+
+
+def test_only_gathered_batches_say_where_they_went():
+    data = _set(20)
+    assert [b.reused_buffers for b in
+            ArrayDataSetIterator(data, 8, shuffle=True)] == [
+                False, False, False]    # two held in turn, the short last
+    assert not any(hasattr(b, "reused_buffers")
+                   for b in ArrayDataSetIterator(data, 8))
+
+
+def test_threads_sharing_an_iterator_never_share_a_buffer():
+    """Sixteen threads pass over one iterator at once, switching every 10
+    microseconds: a batch in one thread's hand is never written by another
+    thread's gather (taking a buffer is one step under the pool's lock)."""
+    data = _set(64)
+    it = ArrayDataSetIterator(data, 8, shuffle=True)
+    deadline = time.monotonic() + 1.5
+    torn, passes = [], []
+
+    def consumer():
+        done = 0
+        while time.monotonic() < deadline and not torn:
+            for batch in it:
+                before = batch.features.copy()
+                time.sleep(0)           # let the others gather
+                if not np.array_equal(batch.features, before):
+                    torn.append(before)
+            done += 1
+        passes.append(done)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=consumer) for _ in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert not torn
+    assert len(passes) == 16 and min(passes) >= 1
+    assert len(it._pools[0]) <= it._POOL_BUFFERS
+
+
+def test_pool_stays_under_its_bound():
+    """A consumer that holds its last 20 batches: the iterator keeps no
+    more buffers than its bound, allocates for the rest, and writes to
+    nothing that is held."""
+    data = _set(240)
+    it = ArrayDataSetIterator(data, 8, shuffle=True, seed=4)
+    held = collections.deque(maxlen=20)
+    fresh = 0
+    for epoch in range(3):
+        want = _slices(data.shuffle(4 + epoch), 8, False)
+        for i, batch in enumerate(it):
+            held.append((batch, want[i]))
+            fresh += not batch.reused_buffers
+            del batch
+            assert all(len(pool) <= it._POOL_BUFFERS for pool in it._pools)
+        _assert_same_batches([b for b, _ in held], [w for _, w in held])
+    assert [len(pool) for pool in it._pools[:2]] == [it._POOL_BUFFERS] * 2
+    assert 20 <= fresh < 90             # some of the 90 went into used memory
 
 
 @pytest.mark.parametrize("shuffle", [False, True])

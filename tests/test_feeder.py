@@ -18,7 +18,6 @@ from deeplearning4j_tpu.datasets.dataset import (
 )
 from deeplearning4j_tpu.datasets.feeder import (
     DeviceFeeder,
-    StagingPool,
     ensure_labels_mask,
     ones_labels_mask,
     pad_to_bucket,
@@ -311,23 +310,80 @@ class TestDeviceFeeder:
         assert [i.seq for i in DeviceFeeder(
             [marker], registry=MetricsRegistry())] == [-1]
 
-    def test_staging_pool_rotates_and_copies(self):
-        pool = StagingPool(2)
-        a = np.arange(6, dtype=np.float32).reshape(2, 3)
-        b1 = pool.stage(a)
-        b2 = pool.stage(a + 1)
-        assert b1 is not b2
-        np.testing.assert_array_equal(b1, a)
-        np.testing.assert_array_equal(b2, a + 1)
-        assert pool.stage(a) is b1      # ring wraps
+    @pytest.mark.parametrize("path", ["single", "k_steps", "group_prepare"])
+    def test_put_receives_what_the_source_produced(self, path, monkeypatch):
+        """The feeder makes no host copy of its own: ``put`` is handed the
+        source's arrays themselves, or the ones a K-group was stacked
+        into."""
+        batches = _batches(4)
+        made = []                       # what the host side produced
 
-    def test_staging_pool_disabled_on_cpu(self):
-        """CPU device_put zero-copy adopts numpy buffers — reusing one
-        would corrupt staged batches, so the auto mode disables the
-        pool here (this suite runs on the CPU backend)."""
-        feeder = DeviceFeeder(ListDataSetIterator(_batches(1)),
-                              registry=MetricsRegistry())
-        assert feeder._pool is None
+        def stack(arrays):
+            made.append(real_stack(arrays))
+            return made[-1]
+
+        def group_prepare(group):
+            made.extend([np.concatenate([b.features for b in group]),
+                         np.concatenate([b.labels for b in group])])
+            return (made[-2], made[-1], None, None)
+
+        kwargs = {"single": {},
+                  "k_steps": {"k_steps": 2, "pad_ragged": False},
+                  "group_prepare": {"k_steps": 2, "pad_ragged": False,
+                                    "group_prepare": group_prepare}}[path]
+        if path == "single":
+            made = [a for b in batches for a in (b.features, b.labels)]
+        elif path == "k_steps":
+            real_stack = np.stack
+            monkeypatch.setattr(np, "stack", stack)
+        put = []
+        feeder = DeviceFeeder(
+            ListDataSetIterator(batches), registry=MetricsRegistry(),
+            put=lambda a: put.append(a) or jax.device_put(a), **kwargs)
+        items = list(feeder)
+        assert len(items) == (4 if path == "single" else 2)
+        # features and labels; the stacked groups carry a labels mask too
+        assert len(put) == len(made) == (
+            3 if path == "k_steps" else 2) * len(items)
+        assert all(got is want for got, want in zip(put, made))
+
+    @pytest.mark.parametrize("consumer_ms", [0, 8], ids=["fast", "slow"])
+    def test_reused_buffers_under_a_put_that_copies_late(self, consumer_ms):
+        """``ArrayDataSetIterator(shuffle=True)`` -> ``AsyncDataSetIterator``
+        -> ``DeviceFeeder`` with a ``put`` that reads its array late, on a
+        thread, as an accelerator's runtime does, keeping the array it was
+        given until then: three epochs deliver the batches of
+        ``DataSet.shuffle`` bit for bit."""
+        from concurrent.futures import ThreadPoolExecutor
+        data = DataSet.merge(_batches(12, batch=8))
+        seed = 3
+        source = AsyncDataSetIterator(
+            ArrayDataSetIterator(data, 8, shuffle=True, seed=seed),
+            queue_size=4)
+        reused = []
+
+        def late_copy(a):
+            time.sleep(0.004)
+            return a.copy()
+
+        with ThreadPoolExecutor(max_workers=3) as runtime:
+            feeder = DeviceFeeder(source, registry=MetricsRegistry(),
+                                  put=lambda a: runtime.submit(late_copy, a))
+            for epoch in range(3):
+                whole = data.shuffle(seed + epoch)
+                n = 0
+                for n, item in enumerate(feeder, 1):
+                    time.sleep(consumer_ms / 1e3)
+                    lo = 8 * (n - 1)
+                    np.testing.assert_array_equal(
+                        item.features.result(), whole.features[lo:lo + 8])
+                    np.testing.assert_array_equal(
+                        item.labels.result(), whole.labels[lo:lo + 8])
+                assert n == 12
+                source.reset()
+        pools = source.base._pools[:2]
+        assert all(2 <= len(pool) <= source.base._POOL_BUFFERS
+                   for pool in pools)
 
     def test_rejects_bad_config(self):
         src = ListDataSetIterator(_batches(1))

@@ -66,3 +66,19 @@ def test_dryrun_multichip_driver_invocation():
     assert "ring-attention + Ulysses a2a + MoE train step OK" in proc.stdout
     assert "circular pipeline" in proc.stdout
     assert "Megatron-paired transformer train step OK" in proc.stdout
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_feed_race_phase_at_a_tiny_size(chips, monkeypatch):
+    """The smoke's race check, rehearsed here on virtual devices with the
+    cell's image shape and a set of 320 rows: every staged batch equals an
+    independent gather, through ``DeviceFeeder`` and through
+    ``ParallelWrapper``'s feeder, and the phase reports what it counted."""
+    import chip_smoke
+    monkeypatch.setattr(chip_smoke, "RACE", {1: (16, 40), 4: (64, 10)})
+    monkeypatch.setattr(chip_smoke, "RACE_SET_ROWS", 320)
+    info = chip_smoke.phase_feed_race({"chips": chips})
+    rows, batches = chip_smoke.RACE[chips]
+    assert info["wrong"] == 0 and info["rows"] == rows
+    assert info["batches"] == info["gathered"] == batches
+    assert 0 < info["reused"] < batches
