@@ -36,14 +36,13 @@ def main():
     from deeplearning4j_tpu.optimize.updaters import Nesterovs
     from deeplearning4j_tpu.zoo.models import ResNet50
 
-    # FusedBottleneckBlock(impl="xla") with Gram-matrix BN statistics for
-    # the expanding projections (ops/fused_conv.py conv_bn_stats_xla) and
-    # the space-to-depth stem: the configuration PERF_ANALYSIS r4/r5
-    # arrived at, batch 384 its sweet spot.
+    # Fused bottleneck blocks and the space-to-depth stem: the
+    # configuration PERF_ANALYSIS r4/r5 arrived at, batch 384 its sweet
+    # spot.
     model = ResNet50(num_classes=200, height=64, width=64, channels=3,
                      compute_dtype="bfloat16",
                      updater=Nesterovs(1e-2, 0.9), fused_blocks=True,
-                     fused_impl="xla", s2d_stem=True).init()
+                     s2d_stem=True).init()
 
     # K optimizer steps per dispatch (lax.scan in optimize/solver.py:
     # make_scan_train_step). Batches are staged device-side once

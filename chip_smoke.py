@@ -14,9 +14,10 @@ a seed, with no network and no files outside the checkout:
   phase=serve    the fitted model behind ``ServingEngine``; requests of
                  1, 3, 32 and 50 rows (the split path) against
                  ``model.output``
-  phase=kernels  flash attention, the Pallas LSTM recurrence and the
-                 Pallas conv+BN kernels, compiled (interpret=False),
-                 forward and backward, against plain XLA
+  phase=kernels  flash attention and the Pallas LSTM recurrence,
+                 compiled (interpret=False), and the fused block's conv
+                 + BN statistics (Gram and direct), forward and
+                 backward, against plain XLA
   phase=feed_race  the input pipeline alone, with no train step, so that
                  the prefetch thread gathers flat out into buffers it
                  uses again: 200 shuffled batches of the ResNet cell's
@@ -120,7 +121,7 @@ def build_model():
     from deeplearning4j_tpu.zoo.models import ResNet50
     return ResNet50(num_classes=200, height=64, width=64, channels=3,
                     compute_dtype="bfloat16", fused_blocks=True,
-                    fused_impl="xla", s2d_stem=True).init()
+                    s2d_stem=True).init()
 
 
 def _loss_listener(track_batch_shards=False):
@@ -262,7 +263,7 @@ def phase_kernels(ctx):
     from deeplearning4j_tpu.nn.layers.attention import (
         scaled_dot_product_attention)
     from deeplearning4j_tpu.ops.fused_conv import (
-        conv_bn_stats_xla, fused_conv_bn_act)
+        _conv_reference, conv_bn_stats_xla)
     from deeplearning4j_tpu.ops.pallas_kernels import flash_attention
     from deeplearning4j_tpu.ops.pallas_lstm import lstm_fused
 
@@ -335,9 +336,19 @@ def phase_kernels(ctx):
                     zx, h0, c0, wh, m, interpret=False),
                 lstm_ref(m), args, dtype, errs)
 
-    # Pallas conv+BN (the path fused_blocks=True selects by default): one
-    # bottleneck's three convs at the stage-2 and stage-4 shapes of the
-    # 64x64 model, batch 384
+    # The fused block's conv + BN statistics against the plain reference:
+    # one bottleneck's three convs at the stage-2 and stage-4 shapes of
+    # the 64x64 model, batch 384. The stage-2 expand (128 -> 512) takes
+    # the Gram statistics, the stage-4 expand (512 -> 2048) the direct
+    # reduction.
+    def conv_ref(x, w, s, b):
+        """The reference on float32 copies of the bfloat16 operands: its
+        own error stays out of the comparison, and a bfloat16 3x3 with
+        float32 accumulation has no transpose rule."""
+        y, sums = _conv_reference(x.astype(jnp.float32),
+                                  w.astype(jnp.float32), s, b, True, True, 1)
+        return y.astype(x.dtype), sums
+
     for stage, f, hw in (("s2", 128, 8), ("s4", 512, 2)):
         for tag, cin, wshape in (("1x1_reduce", 4 * f, (4 * f, f)),
                                  ("3x3", f, (3, 3, f, f)),
@@ -347,13 +358,8 @@ def phase_kernels(ctx):
                     arr(wshape, jnp.bfloat16, fan_in ** -0.5),
                     1.0 + arr((cin,), jnp.float32, 0.1),
                     arr((cin,), jnp.float32, 0.1))
-            _check_grads(
-                f"conv_{stage}_{tag}",
-                lambda x, w, s, b: fused_conv_bn_act(
-                    x, w, s, b, True, True, 1, False),
-                lambda x, w, s, b: conv_bn_stats_xla(
-                    x, w, s, b, True, True, 1),
-                args, "bfloat16", errs)
+            _check_grads(f"conv_{stage}_{tag}", conv_bn_stats_xla, conv_ref,
+                         args, "bfloat16", errs)
     for name, e in errs.items():
         print(f"kernel {name}: "
               + (e if isinstance(e, str) else f"max_rel_err={e:.3e}"))

@@ -2,10 +2,12 @@
 
 One layer = the whole bottleneck residual unit
 (1×1 conv → BN → ReLU → 3×3 conv → BN → ReLU → 1×1 conv → BN →
-(+shortcut) → ReLU), executed through the Pallas fused conv+BN kernels
-(ops/fused_conv.py) so that BN batch statistics ride the conv output
-pass and normalize+ReLU ride the consumer conv's input pass — no extra
-HBM round trips per BatchNorm.
+(+shortcut) → ReLU). Each conv returns its output together with the
+per-channel sums its BatchNorm needs (ops/fused_conv.py
+``conv_bn_stats_xla``: plain XLA convs, Gram-matrix statistics for the
+expanding projections, so the 4f-channel activations are never re-read
+for a statistics pass), and each BatchNorm's normalize+ReLU is applied
+as a per-channel scale+shift on the consumer conv's input.
 
 This is the block-granular analog of the reference's per-layer cuDNN
 helper tier (CudnnConvolutionHelper.java:62, SURVEY §2.4): the zoo's
@@ -14,8 +16,8 @@ IDENTICAL to the unfused conv/BN/activation composition (equivalence
 tested in tests/test_fused_conv.py / tests/test_fused_block.py).
 
 Eval mode uses running stats — pure elementwise normalize that XLA
-fuses fine — through the same fused kernels with the running-stat
-scale/shift in the prologue.
+fuses fine — through the same convs with the running-stat scale/shift
+on their inputs.
 """
 
 from __future__ import annotations
@@ -29,7 +31,7 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.nn.inputs import ConvolutionalType, InputType
 from deeplearning4j_tpu.nn.layers.base import Layer
 from deeplearning4j_tpu.ops.fused_conv import (
-    fused_conv_bn_act,
+    conv_bn_stats_xla,
     stats_to_scale_shift,
 )
 from deeplearning4j_tpu.ops.initializers import WeightInit
@@ -46,17 +48,6 @@ class FusedBottleneckBlock(Layer):
     downsample: bool = False
     eps: float = 1e-5
     decay: float = 0.9
-    # "pallas": the custom-kernel tier; "xla": plain-XLA convs with
-    # Gram-matrix BN statistics for the expanding projections
-    # (ops/fused_conv.py conv_bn_stats_xla) — no custom calls, no
-    # layout copies, stats still never re-read the 4f activations
-    impl: str = "pallas"
-
-    def __post_init__(self):
-        if self.impl not in ("pallas", "xla"):
-            raise ValueError(
-                f"FusedBottleneckBlock impl must be 'pallas' or 'xla', "
-                f"got {self.impl!r}")
 
     # ---- shape ----------------------------------------------------------
     def _out_hw(self, it: ConvolutionalType) -> Tuple[int, int]:
@@ -110,7 +101,7 @@ class FusedBottleneckBlock(Layer):
 
         def bn_form(name, stats, count):
             """(scale, shift) for the normalize folded into the NEXT
-            kernel's prologue; updates running stats in train mode."""
+            conv's input; updates running stats in train mode."""
             gamma = params[f"{name}_gamma"].astype(f32)
             beta = params[f"{name}_beta"].astype(f32)
             if train and stats is not None:
@@ -130,42 +121,31 @@ class FusedBottleneckBlock(Layer):
 
         ones = jnp.ones((x.shape[-1],), f32)
         zeros = jnp.zeros((x.shape[-1],), f32)
-        if self.impl == "xla":
-            from deeplearning4j_tpu.ops.fused_conv import conv_bn_stats_xla
-            conv = conv_bn_stats_xla
-        else:
-            conv = fused_conv_bn_act
 
-        y1, st1 = conv(x, params["W1"], ones, zeros,
-                       False, False, self.stride)
+        y1, st1 = conv_bn_stats_xla(x, params["W1"], ones, zeros,
+                                    False, False, self.stride)
         m1 = y1.size // y1.shape[-1]
         s1, b1 = bn_form("bn1", st1, m1)
 
-        y2, st2 = conv(y1, params["W2"], s1, b1, True, True, 1)
+        y2, st2 = conv_bn_stats_xla(y1, params["W2"], s1, b1,
+                                    True, True, 1)
         m2 = y2.size // y2.shape[-1]
         s2, b2 = bn_form("bn2", st2, m2)
 
-        y3, st3 = conv(y2, params["W3"], s2, b2, True, True, 1)
+        y3, st3 = conv_bn_stats_xla(y2, params["W3"], s2, b2,
+                                    True, True, 1)
         m3 = y3.size // y3.shape[-1]
         s3, b3 = bn_form("bn3", st3, m3)
 
-        # Tail normalize+add+ReLU. Pallas impl: on 2-D (M, C) views in
-        # the compute dtype — 4-D/f32 tails made XLA pick the conv
-        # activation layout and relayout-copy + upcast around every
-        # Pallas kernel. XLA impl: stay 4-D — there the reshape itself
-        # is the relayout.
-        f4 = y3.shape[-1]
-        out_shape = y3.shape
-        flat = self.impl != "xla"
-        v = (lambda a: a.reshape(-1, f4)) if flat else (lambda a: a)
-        main = v(y3) * s3.astype(y3.dtype) + b3.astype(y3.dtype)
+        # Tail normalize+add+ReLU, 4-D in the compute dtype: a reshape
+        # to (M, C) would itself be a relayout.
+        main = y3 * s3.astype(y3.dtype) + b3.astype(y3.dtype)
         if self.downsample:
-            yds, stds = conv(x, params["Wds"], ones, zeros,
-                             False, False, self.stride)
+            yds, stds = conv_bn_stats_xla(x, params["Wds"], ones, zeros,
+                                          False, False, self.stride)
             sds, bds = bn_form("bnds", stds, yds.size // yds.shape[-1])
-            shortcut = v(yds) * sds.astype(y3.dtype) \
-                + bds.astype(y3.dtype)
+            shortcut = yds * sds.astype(y3.dtype) + bds.astype(y3.dtype)
         else:
-            shortcut = v(x)
+            shortcut = x
         out = jnp.maximum(main + shortcut, 0.0).astype(x.dtype)
-        return out.reshape(out_shape), new_state
+        return out, new_state
