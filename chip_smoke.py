@@ -17,7 +17,9 @@ a seed, with no network and no files outside the checkout:
   phase=kernels  flash attention and the Pallas LSTM recurrence,
                  compiled (interpret=False), and the fused block's conv
                  + BN statistics (Gram and direct), forward and
-                 backward, against plain XLA
+                 backward, against plain XLA; the held experts' loop over
+                 blocks of rows at routings that fill one block and
+                 several
   phase=feed_race  the input pipeline alone, with no train step, so that
                  the prefetch thread gathers flat out into buffers it
                  uses again: 200 shuffled batches of the ResNet cell's
@@ -360,6 +362,60 @@ def phase_kernels(ctx):
                     arr((cin,), jnp.float32, 0.1))
             _check_grads(f"conv_{stage}_{tag}", conv_bn_stats_xla, conv_ref,
                          args, "bfloat16", errs)
+    # The held experts' loop over blocks of rows at the Qwen3-Next cell's
+    # shapes (8,192 tokens, top-10 of 512 with 32 held: blocks of 8,192
+    # rows) under routers that fill one block and several, against every
+    # held expert applied to every token in float32 and masked by the
+    # same routing. On the TPU the grouped products leave the rows past a
+    # block's last group unwritten.
+    from deeplearning4j_tpu.parallel.moe import (dispatch_block,
+                                                 held_experts_ffn)
+    t, d, f, e, g, k = 8192, 2048, 512, 512, 32, 10
+    held = tuple(range(g))
+
+    def experts_ref(x, router, w_gate, w_up, w_down):
+        # the routing written out, not the program's: softmax over all
+        # experts, the k largest kept and renormalised
+        probs = jax.nn.softmax(jnp.dot(
+            x, router, preferred_element_type=jnp.float32), -1)
+        p = jnp.where(probs >= jnp.sort(probs, -1)[:, -k, None], probs, 0.0)
+        p = p / jnp.sum(p, -1, keepdims=True)
+        x32 = x.astype(jnp.float32)
+
+        def one(y, expert):
+            j, wg, wu, wd = expert
+            pj = p[:, j]
+            h = jax.nn.silu(x32 @ wg.astype(jnp.float32)) * (
+                x32 @ wu.astype(jnp.float32))
+            return y + pj[:, None] * (h @ wd.astype(jnp.float32)), None
+        with jax.default_matmul_precision("highest"):
+            y, _ = jax.lax.scan(one, jnp.zeros((t, d), jnp.float32),
+                                (jnp.arange(g), w_gate, w_up, w_down))
+        return y.astype(x.dtype)
+
+    x = arr((t, d), jnp.bfloat16).at[:, 0].set(1.0)
+    weights = (arr((g, d, f), jnp.bfloat16, 0.02),
+               arr((g, d, f), jnp.bfloat16, 0.02),
+               arr((g, f, d), jnp.bfloat16, 0.02))
+    blocks_ran = {}
+    for pushed in (0, 4, 10):
+        # 2.5 on the logits of ``pushed`` held experts: most tokens pick
+        # most of them (a push of 8 makes every token pick all, and the
+        # gradient of the renormalised weights then cancels to nothing
+        # but bfloat16's rounding, with the one-buffer program too)
+        router = arr((d, e), jnp.bfloat16, 0.02).at[0, :pushed].add(2.5)
+        name = f"held_experts_pushed_{pushed}"
+        _check_grads(
+            name, lambda *a: held_experts_ffn(*a, held, top_k=k)[0],
+            experts_ref, (x, router) + weights, "bfloat16", errs)
+        landed = int(jax.jit(lambda *a: held_experts_ffn(
+            *a, held, top_k=k)[1][0])(x, router, *weights))
+        blocks_ran[name] = -(-landed // dispatch_block(t, k, g, e))
+        if (blocks_ran[name] > 1) != (pushed > 0) \
+                and not isinstance(errs[name], str):
+            errs[name] = (f"FAIL {landed} held assignments ran "
+                          f"{blocks_ran[name]} blocks")
+    print(f"held_experts blocks ran: {blocks_ran}")
     for name, e in errs.items():
         print(f"kernel {name}: "
               + (e if isinstance(e, str) else f"max_rel_err={e:.3e}"))

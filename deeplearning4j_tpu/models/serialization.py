@@ -173,6 +173,21 @@ def _migrate_qkv_layout(model, params):
     return new
 
 
+def _upgrade_layer_states(model, flat: Dict[str, np.ndarray]):
+    """Saved state arrays by path, each layer's own passed through its
+    ``upgrade_state``: a layer whose state changed form since the file
+    was written says there how the old form reads now."""
+    out = dict(flat)
+    for name, layer in _named_layers(model).items():
+        prefix = f"{name}/"
+        saved = {k[len(prefix):]: v for k, v in flat.items()
+                 if k.startswith(prefix)}
+        if saved:
+            out.update({prefix + k: v
+                        for k, v in layer.upgrade_state(saved).items()})
+    return out
+
+
 def _migrate_qkv_opt_state(model, opt_state):
     """Apply the same which-major → head-major repack to optimizer-state
     leaves that mirror an attention param (Adam mu/nu etc.): each leaf's
@@ -229,8 +244,9 @@ def _restore(path: str, expected_class: str, loader, load_updater: bool):
         params = _unflatten_like(model.train_state.params, _read_tree(zf, "params"))
         if migrate:
             params = _migrate_qkv_layout(model, params)
-        state = _unflatten_like(model.train_state.model_state,
-                                _read_tree(zf, "state"))
+        state = _unflatten_like(
+            model.train_state.model_state,
+            _upgrade_layer_states(model, _read_tree(zf, "state")))
         opt_state = model.train_state.opt_state
         if load_updater and meta.get("has_updater"):
             opt_state = _unflatten_like(opt_state, _read_tree(zf, "updater"))

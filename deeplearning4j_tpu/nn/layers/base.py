@@ -79,6 +79,12 @@ class Layer:
               ctx: LayerContext) -> Tuple[jnp.ndarray, State]:
         raise NotImplementedError
 
+    def upgrade_state(self, saved: Dict[str, Any]) -> Dict[str, Any]:
+        """``saved``: this layer's state arrays as a checkpoint holds them,
+        by path inside the layer. A layer whose state changed form since
+        older checkpoints were written returns them in today's form."""
+        return saved
+
     # ---- helpers --------------------------------------------------------
     @property
     def has_params(self) -> bool:

@@ -160,6 +160,9 @@ class HybridDecoderBlock(FeedForwardLayer):
     def init_state(self, input_type):
         return self._parts()[1].init_state(input_type)
 
+    def upgrade_state(self, saved):
+        return self._parts()[1].upgrade_state(saved)
+
     def _apply(self, params, state, x, ctx: LayerContext):
         mixer, moe, norm = self._parts()
         h, _ = norm.apply(params["norm1"], {}, x, ctx)

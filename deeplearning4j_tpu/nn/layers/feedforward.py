@@ -434,7 +434,9 @@ class HeldExpertsMoE(FeedForwardLayer):
     The layer's state carries the step's routing counters
     (``parallel.moe.ROUTING_COUNTERS``: assignments that landed on held
     experts, the largest and the mean load of a held expert, dropped
-    assignments, always 0), float32[4] under ``moe_routing``."""
+    assignments, always 0) and, fifth, the blocks the step's dispatch loop
+    ran (``ceil(assignments_held / parallel.moe.dispatch_block(...))``):
+    float32[5] under ``moe_routing``."""
     num_experts: int = 8
     held_experts: Tuple[int, ...] = ()
     hidden: int = 0              # a routed expert's width
@@ -485,15 +487,23 @@ class HeldExpertsMoE(FeedForwardLayer):
         return params
 
     def init_state(self, input_type):
-        return {"moe_routing": jnp.zeros((4,), jnp.float32)}
+        return {"moe_routing": jnp.zeros((5,), jnp.float32)}
+
+    def upgrade_state(self, saved):
+        # a row saved with the four counters reads 0 blocks until a step
+        row = saved["moe_routing"]
+        return {**saved, "moe_routing": jnp.pad(row, (0, max(0, 5 - row.size)))}
 
     def apply(self, params, state, x, ctx):
-        from deeplearning4j_tpu.parallel.moe import held_experts_ffn
+        from deeplearning4j_tpu.parallel.moe import (dispatch_block,
+                                                     held_experts_ffn)
         xt = x.reshape(-1, x.shape[-1])
         y, counters = held_experts_ffn(
             xt, params["router"], params["w_gate"], params["w_up"],
             params["w_down"], self.held, top_k=self.top_k,
             norm_topk=self.norm_topk)
+        blocks = jnp.ceil(counters[0] / dispatch_block(
+            xt.shape[0], self.top_k, len(self.held), self.num_experts))
         if self.shared_hidden:
             with jax.named_scope("moe.shared"):
                 h = (jax.nn.silu(xt @ params["shared_gate"])
@@ -505,4 +515,4 @@ class HeldExpertsMoE(FeedForwardLayer):
                 y = y + (h @ params["shared_down"]) * gate[:, None].astype(
                     y.dtype)
         return (y.reshape(x.shape[:-1] + (self.n_out,)),
-                {"moe_routing": counters})
+                {"moe_routing": jnp.append(counters, blocks)})

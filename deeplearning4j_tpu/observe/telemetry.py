@@ -638,14 +638,18 @@ ROUTING_GAUGES = (
     ("dl4j_moe_dropped_assignments",
      "assignments to held experts that were not computed in the last step "
      "(the held-experts path drops none: always 0)"),
+    ("dl4j_moe_dispatch_blocks",
+     "blocks of rows the held experts' dispatch loop ran in the last step: "
+     "the held assignments over the block, rounded up"),
 )
 
 
 def publish_routing(rows: Dict[str, Any],
                     registry: Optional[MetricsRegistry] = None) -> None:
-    """``rows``: layer name -> the four counters a ``HeldExpertsMoE``
-    leaves in its state (``parallel.moe.ROUTING_COUNTERS``' order), on the
-    host. Each becomes a gauge with a ``layer`` label."""
+    """``rows``: layer name -> the row a ``HeldExpertsMoE`` leaves in its
+    state (``parallel.moe.ROUTING_COUNTERS``' order, then the dispatch
+    loop's blocks), on the host. Each value becomes a gauge with a
+    ``layer`` label."""
     reg = registry if registry is not None else default_registry()
     for layer, row in rows.items():
         for (name, help_text), value in zip(ROUTING_GAUGES, np.asarray(row)):

@@ -18,11 +18,13 @@ ABSENT in the reference (SURVEY §2.11 row 7); designed fresh per SURVEY
 - ``held_experts_ffn`` (layer ``HeldExpertsMoE``): **drops no token**
   whatever the imbalance. The router scores all of the model's experts,
   the chip is told which of them it holds, the assignments that land on
-  held experts are sorted by expert and go through grouped matrix
-  products (``lax.ragged_dot``), gated (SwiGLU) experts without biases.
-  What the absent experts would add is left out: this is the chip's share
-  of an expert-parallel deployment, computed without the exchange, and
-  the layer a held-experts deployment uses.
+  held experts are sorted by expert and go, a block of rows at a time
+  and as many blocks as the step's routing filled, through grouped
+  matrix products (``lax.ragged_dot``), gated (SwiGLU) experts without
+  biases: its time follows the load that landed here, not the most that
+  could. What the absent experts would add is left out: this is the
+  chip's share of an expert-parallel deployment, computed without the
+  exchange, and the layer a held-experts deployment uses.
 """
 
 from __future__ import annotations
@@ -190,6 +192,178 @@ def route_top_k_probs(x, router_w, top_k: int, norm_topk: bool = True):
     return ids.astype(jnp.int32), weights
 
 
+# the shortest block of the held experts' loop: toy shapes run one block
+_MIN_DISPATCH_BLOCK = 256
+
+
+def dispatch_block(tokens: int, top_k: int, held: int, experts: int) -> int:
+    """Rows a pass of ``held_experts_ffn``'s loop takes for these shapes:
+    the load of an even router, ``tokens * top_k * held / experts``,
+    rounded up to a power of two (at least ``_MIN_DISPATCH_BLOCK``), and at
+    most the ``tokens * min(top_k, held)`` rows that can land here."""
+    even = -(-tokens * top_k * held // experts)
+    return min(tokens * min(top_k, held),
+               max(_MIN_DISPATCH_BLOCK, 1 << (even - 1).bit_length()))
+
+
+def _block_rows(sel, ends, i, block: int, top_k: int):
+    """Block ``i`` of the sorted assignments, rows ``[i * block, (i + 1) *
+    block)``: their indices among the T * k, their tokens, the block's own
+    group sizes and which of its rows hold an assignment."""
+    start = i * block
+    idx = jax.lax.dynamic_slice(sel, (start,), (block,))
+    inside = jnp.clip(ends - start, 0, block)
+    sizes = jnp.diff(inside, prepend=0)
+    live = (jnp.arange(block) < inside[-1])[:, None]
+    return idx, idx // top_k, sizes, live
+
+
+def _gated(up, gate):
+    return jax.nn.silu(gate) * up
+
+
+def _block_hidden(xs, w_gate, w_up, sizes, live):
+    """One block's gathered rows up to their experts' ``up`` and ``gate``.
+    Rows past the last group belong to no expert: a grouped product leaves
+    them unwritten (on the TPU: whatever the memory held, NaN included),
+    so they go in as zeros and every product's result is zeroed there
+    before it is used: a zero cotangent times a NaN is a NaN."""
+    xs = jnp.where(live, xs, 0)
+    up = jnp.where(live, jax.lax.ragged_dot(xs, w_up, sizes), 0)
+    gate = jnp.where(live, jax.lax.ragged_dot(xs, w_gate, sizes), 0)
+    return xs, up, gate
+
+
+# a grouped product's weight gradient: (rows, a), (rows, b) -> (G, a, b)
+_ROWS_CONTRACTED = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(([0], [0]), ([], [])),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+
+
+def _tokens_rows(rows, slots, n_held, most, scale=None):
+    """``sum_j rows[slots[j]]`` for every token, in float32, over the first
+    ``most`` of a token's slots: ``slots`` (k, T) are the places of a
+    token's assignments among the sorted rows, its held ones first, so
+    ``most`` passes reach every held assignment of every token. A slot at
+    or past ``n_held`` is an absent expert's and adds nothing. ``scale``
+    (k, T), where given, weights each row."""
+    def one(j, total):
+        at = slots[j]
+        w = (at < n_held).astype(total.dtype)
+        if scale is not None:
+            w = w * scale[j]
+        return total + jnp.take(rows, at, axis=0, mode="clip").astype(
+            total.dtype) * w[:, None]
+    return jax.lax.fori_loop(
+        0, most, one, jnp.zeros((slots.shape[1], rows.shape[1]), jnp.float32))
+
+
+def _row_buffers(n_blocks, rows: int, widths, dtype):
+    """Zeroed ``(rows, width)`` buffers for the loop's passes to write
+    their blocks into. The zero is read from ``n_blocks`` (never
+    negative): XLA copies the broadcast of a constant without its name,
+    and the writing of these zeros, 0.5 ms for 81,920 x 2,048, would count
+    for no scope."""
+    zero = jnp.minimum(n_blocks, 0).astype(dtype)
+    return [jnp.full((rows, n), zero) for n in widths]
+
+
+def _held_blocks(block: int, top_k: int):
+    """``f(x, weights, w_gate, w_up, w_down, sel, places, ends, n_blocks,
+    most) -> y``: the first ``n_blocks`` blocks of the sorted
+    assignments ``sel`` dispatched and computed, one block a pass of a
+    loop whose trip count is the traced ``n_blocks``, each pass leaving its
+    rows in a buffer as long as ``sel``; then a second loop of ``most``
+    passes (the most held assignments any token has) gathers every
+    token's rows back and sums them, weighted, in float32
+    (``_tokens_rows``): a gather of T rows a pass, where a scatter-add a
+    block cost as much again for its fixed part. Such loops have no
+    reverse rule, so the backward is written here: the same two loops,
+    each pass of the first computing its block's ``up`` and ``gate`` again.
+    The weights' gradients are no sums over the passes either: a pass
+    leaves its rows of both operands in buffers, and one grouped product
+    each after the loop reads the rows the routing filled, as the one
+    long buffer's backward did."""
+
+    def forward(x, weights, w_gate, w_up, w_down, sel, places, ends,
+                n_blocks, most):
+        def body(i, rows):
+            _, token, sizes, live = _block_rows(sel, ends, i, block, top_k)
+            with jax.named_scope("moe.experts"):
+                _, up, gate = _block_hidden(
+                    jnp.take(x, token, axis=0), w_gate, w_up, sizes, live)
+                ys = jnp.where(live, jax.lax.ragged_dot(
+                    _gated(up, gate), w_down, sizes), 0)
+                return jax.lax.dynamic_update_slice(rows, ys, (i * block, 0))
+
+        with jax.named_scope("moe.dispatch"):
+            rows = jax.lax.fori_loop(0, n_blocks, body, _row_buffers(
+                n_blocks, sel.size, x.shape[1:], x.dtype)[0])
+        with jax.named_scope("moe.combine"):
+            # a token's held places first, each with its weight
+            slots, p = jax.lax.sort((places, weights.T), dimension=0,
+                                    num_keys=1)
+            return _tokens_rows(rows, slots, ends[-1], most, p).astype(x.dtype)
+
+    def forward_kept(*args):
+        return forward(*args), args
+
+    def backward(kept, dy):
+        (x, weights, w_gate, w_up, w_down, sel, places, ends, n_blocks,
+         most) = kept
+        p_flat = weights.reshape(-1)
+        with jax.named_scope("moe.experts"):
+            to_in = [jnp.swapaxes(w, 1, 2) for w in (w_gate, w_up, w_down)]
+
+        def body(i, carried):
+            dp_flat, rows = carried
+            idx, token, sizes, live = _block_rows(sel, ends, i, block, top_k)
+            with jax.named_scope("moe.combine"):
+                dout = jnp.take(dy, token, axis=0)
+                dys = jnp.where(
+                    live, dout * p_flat[idx][:, None], 0).astype(x.dtype)
+            with jax.named_scope("moe.experts"):
+                xs, up, gate = _block_hidden(
+                    jnp.take(x, token, axis=0), w_gate, w_up, sizes, live)
+                h, pull = jax.vjp(_gated, up, gate)
+                ys = jnp.where(live, jax.lax.ragged_dot(h, w_down, sizes), 0)
+                d_up, d_gate = pull(jnp.where(
+                    live, jax.lax.ragged_dot(dys, to_in[2], sizes), 0))
+                dxs = jnp.where(
+                    live, jax.lax.ragged_dot(d_up, to_in[1], sizes)
+                    + jax.lax.ragged_dot(d_gate, to_in[0], sizes), 0)
+                rows = [jax.lax.dynamic_update_slice(a, b, (i * block, 0))
+                        for a, b in zip(rows, (xs, h, d_up, d_gate, dys, dxs))]
+            with jax.named_scope("moe.combine"):
+                # rows that hold no assignment add zeros
+                return dp_flat.at[idx].add(jnp.sum(
+                    dout.astype(dp_flat.dtype) * ys, -1)), rows
+
+        with jax.named_scope("moe.dispatch"):
+            dp_flat, (xs, h, d_up, d_gate, dys, dxs) = jax.lax.fori_loop(
+                0, n_blocks, body, (jnp.zeros_like(p_flat), _row_buffers(
+                    n_blocks, sel.size, (x.shape[1], *3 * w_up.shape[2:],
+                                         *2 * x.shape[1:]), x.dtype)))
+        with jax.named_scope("moe.combine"):
+            dx = _tokens_rows(dxs, jnp.sort(places, axis=0), ends[-1],
+                              most).astype(x.dtype)
+        with jax.named_scope("moe.experts"):
+            sizes = jnp.diff(ends, prepend=0)
+            dws = [jax.lax.ragged_dot_general(a, b, sizes, _ROWS_CONTRACTED)
+                   for a, b in ((xs, d_gate), (xs, d_up), (h, dys))]
+            # the barrier ties the three products to ``dx``, which the
+            # layer below waits for: left to the scheduler they run with
+            # the optimizer's update at the step's end, and every layer's
+            # row buffers live until then
+            dx, *dws = jax.lax.optimization_barrier((dx, *dws))
+        return (dx, dp_flat.reshape(weights.shape), *dws,
+                None, None, None, None, None)
+
+    f = jax.custom_vjp(forward)
+    f.defvjp(forward_kept, backward)
+    return f
+
+
 def held_experts_ffn(x, router_w, w_gate, w_up, w_down, held, *,
                      top_k: int, norm_topk: bool = True):
     """The held experts' part of a top-k mixture of gated experts,
@@ -203,46 +377,46 @@ def held_experts_ffn(x, router_w, w_gate, w_up, w_down, held, *,
     in ``ROUTING_COUNTERS``' order.
 
     The T * k assignments are sorted by held expert (those of absent
-    experts last); the rows of the first ``T * min(k, G)`` of them, the
-    most that can land here (a token picks an expert once), are gathered,
-    three grouped products over ``group_sizes`` compute the experts, and a
-    weighted scatter-add in float32 takes the results back to the tokens.
-    The buffer's length does not depend on the routing, so neither does
-    the step's time: gather and scatter cost by the row, the grouped
-    products only by the rows inside groups."""
+    experts last). The held ones go through a loop, ``dispatch_block``
+    rows a pass and as many passes as the step's routing filled,
+    ``ceil(assignments_held / block)``: a pass gathers its rows and three
+    grouped products over the block's own group sizes compute the
+    experts. A second loop takes the results back to the tokens, weighted
+    and summed in float32: a pass gathers one row for every token, and
+    there are as many passes as the token with the most held assignments
+    has of them. So the step's time follows the load in steps of a block,
+    up to the ``T * min(k, G)`` rows that can land here (a token picks an
+    expert once), and nothing is dropped at any load."""
     t, d = x.shape
     e = router_w.shape[-1]
     g = len(held)
-    acc = jnp.promote_types(jnp.float32, x.dtype)
+    rows = t * min(top_k, g)
+    block = dispatch_block(t, top_k, g, e)
     with jax.named_scope("moe.route"):
         ids, weights = route_top_k_probs(x, router_w, top_k, norm_topk)
-        local = jnp.full((e,), g, jnp.int32).at[jnp.asarray(held)].set(
-            jnp.arange(g, dtype=jnp.int32))
-        where = local[ids].reshape(-1)                  # (T*k,) in [0, G]
-        sizes = jnp.bincount(where, length=g + 1)[:g].astype(jnp.int32)
-        n_held = jnp.sum(sizes)
-    rows = t * min(top_k, g)
+        # which held expert an assignment is, G where it is an absent
+        # one's: by comparison, a table lookup of T * k integers takes the
+        # TPU longer than a grouped product
+        hit = ids[..., None] == jnp.asarray(held, jnp.int32)
+        where = jnp.where(hit.any(-1), hit.argmax(-1), g).reshape(-1)
+        sizes = jnp.sum(hit, (0, 1), dtype=jnp.int32)
+        ends = jnp.cumsum(sizes)
+        n_held = ends[-1]
+        n_blocks = (n_held + block - 1) // block
     with jax.named_scope("moe.dispatch"):
-        sel = jnp.argsort(where, stable=True)[:rows]
-        token = sel // top_k
-        # rows past the last group belong to no expert: the grouped
-        # product leaves them unwritten (on the TPU: whatever the memory
-        # held, NaN included), so they go in as zeros and every product's
-        # result is zeroed there before it is used: a zero cotangent
-        # times a NaN is a NaN
-        live = (jnp.arange(rows) < n_held)[:, None]
-        xs = jnp.where(live, jnp.take(x, token, axis=0), 0)
+        # whole blocks: the rows that pad the last one hold no assignment
+        order = jnp.argsort(where, stable=True)
+        sel = jnp.pad(order[:rows], (0, -rows % block))
+        # (k, T): each token's places in that order; a held assignment's
+        # place lies before every absent expert's
+        places = jnp.argsort(order).reshape(t, top_k).T
+        most = jnp.max(jnp.sum(places < n_held, axis=0))
     with jax.named_scope("moe.experts"):
         w_gate, w_up, w_down = (w.astype(x.dtype)
                                 for w in (w_gate, w_up, w_down))
-        up = jnp.where(live, jax.lax.ragged_dot(xs, w_up, sizes), 0)
-        gate = jnp.where(live, jax.lax.ragged_dot(xs, w_gate, sizes), 0)
-        ys = jnp.where(live, jax.lax.ragged_dot(jax.nn.silu(gate) * up,
-                                                w_down, sizes), 0)
-    with jax.named_scope("moe.combine"):
-        p = weights.reshape(-1)[sel].astype(acc)
-        y = jnp.zeros((t, d), acc).at[token].add(ys.astype(acc) * p[:, None])
+    y = _held_blocks(block, top_k)(
+        x, weights, w_gate, w_up, w_down, sel, places, ends, n_blocks, most)
     counters = jnp.stack([
         n_held, jnp.max(sizes), n_held / g,
         n_held - jnp.minimum(n_held, rows)]).astype(jnp.float32)
-    return y.astype(x.dtype), counters
+    return y, counters

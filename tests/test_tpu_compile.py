@@ -56,3 +56,37 @@ def test_flash_attention_compiles_with_its_default_tiles(
     compiled = jax.jit(jax.grad(total, argnums=(0, 1, 2))).lower(
         x, x, x).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def test_held_experts_block_loop_compiles_at_the_cells_shapes(
+        one_chip, no_compile_cache):
+    """Forward and backward of ``held_experts_ffn`` under ``jax.checkpoint``
+    at 8,192 tokens, top-10 of 512 with 32 held, bfloat16: grouped
+    products inside loops with a traced trip count, every operation of
+    which the TPU's compiler leaves under a ``moe.*`` scope, and so the
+    zeroing of the 81,920-row buffers before them, in no more memory than
+    the 1.64 GB the one 81,920-row buffer took."""
+    from deeplearning4j_tpu.observe.scopes import scopes_in_hlo
+    from deeplearning4j_tpu.parallel.moe import held_experts_ffn
+    t, d, e, g, f, k = 8192, 2048, 512, 32, 512, 10
+    shapes = [jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+              for s in ((t, d), (d, e), (g, d, f), (g, d, f), (g, f, d))]
+
+    def total(*a):
+        y, _ = jax.checkpoint(lambda *b: held_experts_ffn(
+            *b, tuple(range(g)), top_k=k))(*a)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(total, argnums=range(5))).lower(
+        *shapes).compile()
+    text = compiled.as_text()
+    table = scopes_in_hlo(text)
+    loops = {n: op for n, op in table.items() if "/while" in op}
+    assert any("transpose(" not in op for op in loops.values())
+    assert any("transpose(" in op for op in loops.values())
+    assert not [op for op in loops.values() if "moe." not in op]
+    unnamed = [line.split(" = ")[0].strip() for line in text.splitlines()
+               if " = bf16[81920," in line and " broadcast(" in line
+               and "moe." not in line]
+    assert not unnamed
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.64e9
