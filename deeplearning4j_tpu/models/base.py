@@ -186,7 +186,7 @@ class BaseModel:
             self._scoped_step = step
             from deeplearning4j_tpu.observe.scopes import compiled_scopes
             declared = any(getattr(layer, "named_scopes", ())
-                           for layer in getattr(self, "layers", ()))
+                           for layer in self._constraint_layers())
             self._step_scopes = (compiled_scopes(step, *args) if declared
                                  else None)
             self._scopes_traced_in = None

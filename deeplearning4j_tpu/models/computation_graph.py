@@ -116,6 +116,8 @@ class ComputationGraph(BaseModel):
                 ctx = LayerContext(train=train, rng=key, mask=mask)
                 lp = cast_params(params.get(name, {}), g.compute_dtype)
                 lp = node.layer.apply_weight_noise(lp, ctx, key)
+                if node.layer.extra_inputs:
+                    x = (x, *xs[1:])
                 is_output = name in self.conf.network_outputs
                 if is_output and stop_before_loss and hasattr(
                         node.layer, "compute_loss"):
@@ -129,6 +131,11 @@ class ComputationGraph(BaseModel):
                     y, s = node.layer.apply(lp, model_state.get(name, {}),
                                             x, ctx)
                 new_state[name] = s
+                extras = node.layer.extra_output_types(it)
+                if extras:
+                    y, *emitted = y
+                    for extra, value in zip(extras, emitted):
+                        acts[f"{name}:{extra}"] = value
                 if self._tp_plan is not None:
                     y = self._tp_plan.constrain(name, y)
                 acts[name] = y

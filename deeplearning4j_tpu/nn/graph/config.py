@@ -33,12 +33,21 @@ from deeplearning4j_tpu.utils.serde import register_serializable
 @register_serializable
 @dataclasses.dataclass(frozen=True)
 class NodeDef:
-    """One DAG node: exactly one of ``layer`` / ``vertex`` is set."""
+    """One DAG node: exactly one of ``layer`` / ``vertex`` is set. An
+    input is a node's or a network input's name, or ``node:extra`` for
+    what a layer node emits beside its output
+    (``Layer.extra_output_types``); a layer node lists one input and then
+    its layer's ``extra_inputs``."""
     name: str
     inputs: Tuple[str, ...]
     layer: Optional[Layer] = None
     vertex: Optional[GraphVertex] = None
     preprocessor: Optional[Preprocessor] = None  # applied to single input
+
+
+def source_node(source: str) -> str:
+    """The node an input names: ``a`` of ``a`` and of ``a:extra``."""
+    return source.split(":", 1)[0]
 
 
 class GraphBuilder:
@@ -59,10 +68,12 @@ class GraphBuilder:
 
     def add_layer(self, name: str, layer: Layer, *inputs: str,
                   preprocessor: Optional[Preprocessor] = None) -> "GraphBuilder":
-        if len(inputs) != 1:
+        extras = tuple(layer.extra_inputs)
+        if len(inputs) != 1 + len(extras):
             raise ValueError(
-                f"layer node '{name}' needs exactly 1 input; wire multi-input"
-                " through a MergeVertex/ElementWiseVertex first")
+                f"layer node '{name}' needs exactly {1 + len(extras)} "
+                f"input(s) (its own and {extras}), got {inputs}; wire "
+                "multi-input through a MergeVertex/ElementWiseVertex first")
         layer = dataclasses.replace(layer, name=name)
         self._nodes.append(NodeDef(name, tuple(inputs), layer=layer,
                                    preprocessor=preprocessor))
@@ -138,7 +149,15 @@ class ComputationGraphConfiguration:
                 raise ValueError(f"node name collides with input: {inp}")
         for n in self.nodes:
             for src in n.inputs:
-                if src not in by_name and src not in self.network_inputs:
+                if ":" in src:
+                    node, extra = src.split(":", 1)
+                    emitter = by_name.get(node)
+                    if emitter is None or emitter.layer is None or extra \
+                            not in emitter.layer.extra_output_types(None):
+                        raise ValueError(
+                            f"node '{n.name}' reads '{src}', which no layer"
+                            " node emits")
+                elif src not in by_name and src not in self.network_inputs:
                     raise ValueError(f"node '{n.name}' references unknown"
                                      f" input '{src}'")
         for out in self.network_outputs:
@@ -155,10 +174,11 @@ class ComputationGraphConfiguration:
         indeg: Dict[str, int] = {n.name: 0 for n in self.nodes}
         consumers: Dict[str, List[str]] = {}
         for n in self.nodes:
-            for src in n.inputs:
+            sources = [source_node(s) for s in n.inputs]
+            for src in sources:
                 if src in indeg or src in self.network_inputs:
                     consumers.setdefault(src, []).append(n.name)
-            indeg[n.name] = sum(1 for s in n.inputs
+            indeg[n.name] = sum(1 for s in sources
                                 if s not in self.network_inputs)
         queue = [n.name for n in self.nodes if indeg[n.name] == 0]
         order: List[str] = []
@@ -200,7 +220,9 @@ class ComputationGraphConfiguration:
                 node = dataclasses.replace(node, layer=layer, preprocessor=pp)
                 new_nodes[name] = node
                 types[name] = layer.output_type(it)
-                node_in_types = [it]
+                for extra, et in layer.extra_output_types(it).items():
+                    types[f"{name}:{extra}"] = et
+                node_in_types = [it] + in_types[1:]
             else:
                 types[name] = node.vertex.output_type(*in_types)
                 node_in_types = in_types

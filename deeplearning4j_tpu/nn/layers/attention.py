@@ -17,6 +17,7 @@ TPU-first choices:
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional
 
 import jax
@@ -34,11 +35,13 @@ from deeplearning4j_tpu.ops.initializers import WeightInit
 from deeplearning4j_tpu.utils.serde import register_serializable
 
 
-def scaled_dot_product_attention(q, k, v, mask=None, causal=False):
+def scaled_dot_product_attention(q, k, v, mask=None, causal=False,
+                                 window: Optional[int] = None):
     """Plain attention on (N, T, H, Dh) tensors; softmax in f32.
 
     ``mask``: (N, T_k) key validity mask. The single-chip reference path
-    that parallel/ring_attention.py must match exactly.
+    that parallel/ring_attention.py must match exactly. ``window`` (causal
+    only): a query sees itself and the ``window - 1`` positions before it.
 
     Internal score order is (N, Tq, Tk, H) — HEAD TRAILING — so both
     contractions keep (h, dh) as the packed-QKV tensor's trailing dims
@@ -56,11 +59,16 @@ def scaled_dot_product_attention(q, k, v, mask=None, causal=False):
     # fully-padded sequence would poison the whole batch's gradients
     neg = jnp.asarray(jnp.finfo(sdt).min / 2, sdt)
     valid = None
+    if window is not None and not causal:
+        raise ValueError(f"window={window} needs causal=True")
     if causal:
         tq, tk = s.shape[1], s.shape[2]
         qpos = jnp.arange(tq)[:, None, None]
         kpos = jnp.arange(tk)[None, :, None]
-        s = jnp.where((kpos <= qpos)[None], s, neg)
+        seen = kpos <= qpos
+        if window is not None:
+            seen = seen & (kpos > qpos - window)
+        s = jnp.where(seen[None], s, neg)
     if mask is not None:
         valid = mask[:, None, :, None].astype(bool)
         s = jnp.where(valid, s, neg)
@@ -335,3 +343,136 @@ class GatedAttention(FeedForwardLayer):
             y = jnp.einsum("nte,eo->nto", o.reshape(n, t, h * dh),
                            params["W_o"])
         return y, state
+
+
+def differential_lambda_init(layer_index: int) -> float:
+    """``0.8 - 0.6 exp(-0.3 l)`` for the layer of depth ``l`` in the whole
+    model (arXiv:2410.05258, section 3.1)."""
+    return 0.8 - 0.6 * math.exp(-0.3 * layer_index)
+
+
+@register_serializable
+@dataclasses.dataclass(frozen=True)
+class DifferentialAttention(FeedForwardLayer):
+    """Causal differential attention with grouped keys and values: two
+    softmax maps a pair of adjacent heads, their difference read out.
+
+    ``q = h W_q + b`` (``n_heads`` of ``head_dim``), ``k``, ``v``
+    (``n_kv_heads``). Adjacent heads pair: ``q1, q2 = q[2i], q[2i+1]``,
+    ``k1, k2 = k[2j], k[2j+1]``, ``v = [v[2j] | v[2j+1]]`` (twice the head
+    size); query pair ``i`` reads key/value pair ``i // (n_heads /
+    n_kv_heads)``. ``A1 = softmax(q1 k1^T / sqrt(head_dim))``, ``A2``
+    likewise; ``lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init``
+    with ``lambda_init = differential_lambda_init(layer_index)``; ``o =
+    RMSNorm(A1 v - lambda A2 v) * w * (1 - lambda_init)`` over the pair's
+    ``2 * head_dim``; ``concat(o) W_o + b_o``. Softmax, lambda and the
+    norm's statistics are float32.
+
+    ``window`` narrows the causal mask to a query's own position and the
+    ``window - 1`` before it. With ``cross`` the layer has no key or value
+    projection: ``mix`` is handed the ``k`` and ``v`` (N, T, ``n_kv_heads
+    * head_dim``) another layer projected, as they are. Both maps run as
+    one call of ``ops.pallas_kernels.attention`` over ``n_heads`` heads
+    (the ``q1`` pairs, then the ``q2`` pairs) with a value of twice the
+    head size: the flash kernel from 1,024 positions on a TPU, plain XLA
+    else. ``n_out`` is the model width."""
+    n_heads: int = 40
+    n_kv_heads: int = 20
+    head_dim: int = 64
+    window: Optional[int] = None
+    cross: bool = False
+    layer_index: int = 0
+    eps: float = 1e-5
+    init_std: float = 0.02
+
+    named_scopes = ("attn.window", "attn.full", "attn.cross")
+
+    def __post_init__(self):
+        if self.n_heads % 2 or self.n_kv_heads % 2 \
+                or self.n_heads % self.n_kv_heads:
+            raise ValueError(
+                f"n_heads={self.n_heads} and n_kv_heads={self.n_kv_heads}: "
+                "both even (heads pair) and the first a multiple of the "
+                "second")
+
+    @property
+    def scope(self) -> str:
+        if self.cross:
+            return "attn.cross"
+        return "attn.full" if self.window is None else "attn.window"
+
+    @property
+    def extra_inputs(self):
+        return ("k", "v") if self.cross else ()
+
+    def output_type(self, input_type: InputType) -> InputType:
+        t = (input_type.timesteps
+             if isinstance(input_type, RecurrentType) else None)
+        return RecurrentType(self.n_out, t)
+
+    def initialize(self, key, input_type):
+        n_in = self.resolved_n_in(input_type)
+        h, hk, dh = self.n_heads, self.n_kv_heads, self.head_dim
+        dt = self.param_dtype()
+        kq, ko, kl = jax.random.split(key, 3)
+        cols = h * dh if self.cross else (h + 2 * hk) * dh
+        lam = 0.1 * jax.random.normal(kl, (4, dh), dt)   # the paper's
+        return {
+            # columns [q | k | v], each head-major (cross: q alone)
+            "W_qkv": self.init_std * jax.random.normal(kq, (n_in, cols), dt),
+            "b_qkv": jnp.zeros((cols,), dt),
+            "W_o": self.init_std * jax.random.normal(
+                ko, (h * dh, self.n_out), dt),
+            "b_o": jnp.zeros((self.n_out,), dt),
+            "lambda_q1": lam[0], "lambda_k1": lam[1],
+            "lambda_q2": lam[2], "lambda_k2": lam[3],
+            "subln": jnp.ones((2 * dh,), dt),
+        }
+
+    def mix(self, params, x, *kv, mask=None):
+        """``(mixed (N, T, n_out), (k, v))``: ``k`` and ``v`` (N, T,
+        ``n_kv_heads * head_dim``) as projected here or, ``cross``, as
+        handed in after ``x``."""
+        from deeplearning4j_tpu.nn.layers.normalization import rms_norm
+        from deeplearning4j_tpu.ops.pallas_kernels import attention as _attn
+        n, t, _ = x.shape
+        h, hk, dh = self.n_heads, self.n_kv_heads, self.head_dim
+        f32 = jnp.promote_types(jnp.float32, x.dtype)
+        with jax.named_scope(self.scope):
+            qkv = jnp.einsum("ntf,fe->nte", x, params["W_qkv"]) \
+                + params["b_qkv"]
+            if self.cross:
+                q, (k, v) = qkv, kv
+            else:
+                q = qkv[..., :h * dh]
+                k = qkv[..., h * dh:(h + hk) * dh]
+                v = qkv[..., (h + hk) * dh:]
+            q = q.reshape(n, t, h // 2, 2, dh)
+            kp = k.reshape(n, t, hk // 2, 2, dh)
+            vp = v.reshape(n, t, hk // 2, 2 * dh)
+            rep = h // hk
+            if rep > 1:
+                kp = jnp.repeat(kp, rep, axis=2)
+                vp = jnp.repeat(vp, rep, axis=2)
+            # one call: the q1 maps as heads 0 .. h/2 - 1, the q2 maps after
+            o = _attn(jnp.concatenate([q[:, :, :, 0], q[:, :, :, 1]], 2),
+                      jnp.concatenate([kp[:, :, :, 0], kp[:, :, :, 1]], 2),
+                      jnp.concatenate([vp, vp], 2),
+                      mask=mask, causal=True, window=self.window,
+                      scope=self.scope)
+            lam0 = differential_lambda_init(self.layer_index)
+            lq1, lk1, lq2, lk2 = (params[name].astype(f32) for name in (
+                "lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"))
+            lam = (jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2))
+                   + lam0)
+            o = (o[:, :, :h // 2].astype(f32)
+                 - lam * o[:, :, h // 2:].astype(f32))
+            o = rms_norm(o, params["subln"], self.eps, zero_centered=False)
+            o = (o * (1.0 - lam0)).astype(x.dtype)
+            y = jnp.einsum("nte,eo->nto", o.reshape(n, t, h * dh),
+                           params["W_o"]) + params["b_o"]
+        return y, (k, v)
+
+    def apply(self, params, state, x, ctx: LayerContext):
+        x = x if self.cross else (x,)
+        return self.mix(params, *x, mask=ctx.mask)[0], state

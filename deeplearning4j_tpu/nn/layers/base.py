@@ -79,6 +79,22 @@ class Layer:
               ctx: LayerContext) -> Tuple[jnp.ndarray, State]:
         raise NotImplementedError
 
+    # ---- more than one input or output (ComputationGraph nodes) ---------
+    @property
+    def extra_inputs(self) -> Tuple[str, ...]:
+        """Names of the inputs this layer takes after its first. A layer
+        that declares some is applied to the tuple ``(x, *extras)``: a
+        graph node lists that many further sources, in this order."""
+        return ()
+
+    def extra_output_types(self, input_type: InputType
+                           ) -> Dict[str, InputType]:
+        """Name -> type of what this layer emits beside its output, in
+        order. A layer that declares some returns the tuple ``(y,
+        *extras)`` from ``apply``; a graph node ``b`` reads the extra
+        ``name`` of node ``a`` as the source ``a:name``."""
+        return {}
+
     def upgrade_state(self, saved: Dict[str, Any]) -> Dict[str, Any]:
         """``saved``: this layer's state arrays as a checkpoint holds them,
         by path inside the layer. A layer whose state changed form since

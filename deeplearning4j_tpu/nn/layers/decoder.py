@@ -1,5 +1,5 @@
 """Decoder-only language-model layers: token embedding, the hybrid
-decoder block, and the head with its next-token loss.
+decoder blocks, and the head with its next-token loss.
 
 The block is the unit of the hybrid linear-attention mixture-of-experts
 family (Qwen3-Next): pre-norm residuals round a token mixer and an
@@ -13,7 +13,23 @@ expert layer is a ``HeldExpertsMoE``. A model is a ``MultiLayerNetwork``
 of ``TokenEmbedding``, blocks, ``CausalLMOutputLayer``: ordinary
 serialisable layers, trained by ``fit()``.
 
-``recompute`` wraps the block's ``apply`` in ``jax.checkpoint`` while
+``StateSpaceHybridBlock`` is the unit of the decoder-hybrid-decoder
+family (Phi-4-mini-flash; arXiv:2507.06607): pre-LayerNorm residuals round
+a token mixer and a dense gated MLP,
+
+    h = x + Mixer(LayerNorm(x)),   y = h + MLP(LayerNorm(h))
+
+with no positional encoding anywhere. The mixer is a ``MambaMixer``, a
+``DifferentialAttention`` (causal, over a window or the whole sequence), a
+``GatedMemoryUnit`` on the memory an earlier Mamba block emitted, or a
+differential cross-attention on the keys and values an earlier attention
+block projected. What one block emits and another reads are edges of a
+``ComputationGraph`` (``extra_output_types`` / ``extra_inputs``): a model
+is a graph of ``TokenEmbedding``, blocks and ``CausalLMOutputLayer``, in
+which the head may read the embedding's table (``tied``) in place of a
+matrix of its own.
+
+``recompute`` wraps a block's ``apply`` in ``jax.checkpoint`` while
 training, so the step ``fit()`` builds keeps only each block's input for
 the backward pass and recomputes the block's internals there.
 """
@@ -21,13 +37,16 @@ the backward pass and recomputes the block's internals there.
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn.inputs import InputType, RecurrentType
-from deeplearning4j_tpu.nn.layers.attention import GatedAttention
+from deeplearning4j_tpu.nn.layers.attention import (
+    DifferentialAttention,
+    GatedAttention,
+)
 from deeplearning4j_tpu.nn.layers.base import (
     FeedForwardLayer,
     Layer,
@@ -35,11 +54,23 @@ from deeplearning4j_tpu.nn.layers.base import (
 )
 from deeplearning4j_tpu.nn.layers.feedforward import HeldExpertsMoE
 from deeplearning4j_tpu.nn.layers.linear_attention import GatedDeltaNet
-from deeplearning4j_tpu.nn.layers.normalization import RMSNorm, rms_norm
+from deeplearning4j_tpu.nn.layers.normalization import (
+    LayerNormalization,
+    RMSNorm,
+    rms_norm,
+)
+from deeplearning4j_tpu.nn.layers.state_space import (
+    GatedMemoryUnit,
+    MambaMixer,
+)
 from deeplearning4j_tpu.utils.serde import register_serializable
 
 GATED_DELTANET = "gated_deltanet"
 GATED_ATTENTION = "gated_attention"
+MAMBA = "mamba"
+ATTENTION = "attention"
+GATED_MEMORY = "gated_memory"
+CROSS_ATTENTION = "cross_attention"
 
 # labels below zero are positions without a next token (a row's last)
 IGNORE_LABEL = -1
@@ -49,15 +80,23 @@ IGNORE_LABEL = -1
 @dataclasses.dataclass(frozen=True)
 class TokenEmbedding(Layer):
     """Integer token ids (N, T) -> (N, T, n_out); the table starts
-    normal(0, ``init_std``)."""
+    normal(0, ``init_std``). With ``emit_table`` the layer also emits the
+    table itself (``table``, (vocab_size, n_out)) for a head that is tied
+    to it: one leaf of the parameter tree with two uses, whose gradients
+    add."""
     vocab_size: int = 0
     n_out: int = 0
     init_std: float = 0.02
+    emit_table: bool = False
 
     def output_type(self, input_type: InputType) -> InputType:
         t = (input_type.timesteps
              if isinstance(input_type, RecurrentType) else None)
         return RecurrentType(self.n_out, t)
+
+    def extra_output_types(self, input_type):
+        return ({"table": InputType.feed_forward(self.n_out)}
+                if self.emit_table else {})
 
     def initialize(self, key, input_type):
         return {"W": self.init_std * jax.random.normal(
@@ -67,7 +106,8 @@ class TokenEmbedding(Layer):
         idx = x.astype(jnp.int32)
         if idx.ndim == 3 and idx.shape[-1] == 1:
             idx = idx[..., 0]
-        return jnp.take(params["W"], idx, axis=0), state
+        y = jnp.take(params["W"], idx, axis=0)
+        return ((y, params["W"]) if self.emit_table else y), state
 
 
 @register_serializable
@@ -181,9 +221,140 @@ class HybridDecoderBlock(FeedForwardLayer):
 
 @register_serializable
 @dataclasses.dataclass(frozen=True)
+class StateSpaceHybridBlock(FeedForwardLayer):
+    """One block of the decoder-hybrid-decoder family (module docstring).
+    ``mixer`` picks the token mixer; the fields after it are the parts'
+    own (``MambaMixer``, ``DifferentialAttention``, ``GatedMemoryUnit``),
+    kept flat so that the block serialises as one layer. ``layer_index``
+    is the block's depth in the whole published model (differential
+    attention's ``lambda_init`` reads it), whatever part of that model is
+    built. ``n_out`` is the model width and equals the input's.
+
+    With ``emit`` a ``mamba`` block also emits ``memory`` (its scan's
+    result before the gate) and an ``attention`` block ``k`` and ``v`` (its
+    projected keys and values); a ``gated_memory`` block takes ``memory``
+    and a ``cross_attention`` block ``k`` and ``v`` as further inputs."""
+    mixer: str = MAMBA
+    emit: bool = False
+    layer_index: int = 0
+    # differential attention
+    n_heads: int = 40
+    n_kv_heads: int = 20
+    head_dim: int = 64
+    window: Optional[int] = None
+    # mamba and the gated memory unit
+    d_inner: int = 0
+    d_state: int = 16
+    d_conv: int = 4
+    dt_rank: int = 0
+    # gated MLP
+    mlp_hidden: int = 0
+    eps: float = 1e-5
+    init_std: float = 0.02
+    recompute: bool = False
+
+    named_scopes = (MambaMixer.named_scopes
+                    + DifferentialAttention.named_scopes
+                    + GatedMemoryUnit.named_scopes + ("mlp.glu",))
+
+    def __post_init__(self):
+        if self.mixer not in (MAMBA, ATTENTION, GATED_MEMORY,
+                              CROSS_ATTENTION):
+            raise ValueError(
+                f"mixer={self.mixer!r}: one of {MAMBA!r}, {ATTENTION!r}, "
+                f"{GATED_MEMORY!r}, {CROSS_ATTENTION!r}")
+        if self.emit and self.mixer not in (MAMBA, ATTENTION):
+            raise ValueError(f"a {self.mixer!r} block emits nothing")
+
+    def output_type(self, input_type: InputType) -> InputType:
+        t = (input_type.timesteps
+             if isinstance(input_type, RecurrentType) else None)
+        return RecurrentType(self.n_out, t)
+
+    @property
+    def extra_inputs(self):
+        return {GATED_MEMORY: ("memory",),
+                CROSS_ATTENTION: ("k", "v")}.get(self.mixer, ())
+
+    def extra_output_types(self, input_type):
+        if not self.emit:
+            return {}
+        t = (input_type.timesteps
+             if isinstance(input_type, RecurrentType) else None)
+        if self.mixer == MAMBA:
+            return {"memory": RecurrentType(self.d_inner, t)}
+        kv = RecurrentType(self.n_kv_heads * self.head_dim, t)
+        return {"k": kv, "v": kv}
+
+    def _mixer(self):
+        common = dict(n_in=self.n_out, n_out=self.n_out, dtype=self.dtype,
+                      init_std=self.init_std)
+        if self.mixer == MAMBA:
+            return MambaMixer(
+                d_inner=self.d_inner, d_state=self.d_state,
+                d_conv=self.d_conv, dt_rank=self.dt_rank, **common)
+        if self.mixer == GATED_MEMORY:
+            return GatedMemoryUnit(d_memory=self.d_inner, **common)
+        return DifferentialAttention(
+            n_heads=self.n_heads, n_kv_heads=self.n_kv_heads,
+            head_dim=self.head_dim, window=self.window,
+            cross=self.mixer == CROSS_ATTENTION,
+            layer_index=self.layer_index, eps=self.eps, **common)
+
+    def initialize(self, key, input_type):
+        width = self.resolved_n_in(input_type)
+        if self.n_out and width != self.n_out:
+            raise ValueError(
+                f"StateSpaceHybridBlock needs n_in == n_out (residuals); "
+                f"got {width} vs {self.n_out}")
+        norm = LayerNormalization(eps=self.eps, dtype=self.dtype)
+        km, k1, k2 = jax.random.split(key, 3)
+        rt = RecurrentType(width, None)
+        dt = self.param_dtype()
+        return {"norm1": norm.initialize(None, rt),
+                "mixer": self._mixer().initialize(km, rt),
+                "norm2": norm.initialize(None, rt),
+                # columns [gate | up]
+                "mlp": {"W1": self.init_std * jax.random.normal(
+                            k1, (width, 2 * self.mlp_hidden), dt),
+                        "W2": self.init_std * jax.random.normal(
+                            k2, (self.mlp_hidden, width), dt)}}
+
+    def _apply(self, params, x, ctx: LayerContext):
+        x, *extras = x if isinstance(x, tuple) else (x,)
+        norm = LayerNormalization(eps=self.eps, dtype=self.dtype)
+        h, _ = norm.apply(params["norm1"], {}, x, ctx)
+        m, emitted = self._mixer().mix(params["mixer"], h, *extras,
+                                       mask=ctx.mask)
+        x = x + m
+        h, _ = norm.apply(params["norm2"], {}, x, ctx)
+        with jax.named_scope("mlp.glu"):
+            f32 = jnp.promote_types(jnp.float32, h.dtype)
+            gu = jnp.einsum("ntf,fe->nte", h, params["mlp"]["W1"])
+            g, u = gu[..., :self.mlp_hidden], gu[..., self.mlp_hidden:]
+            act = (u.astype(f32) * jax.nn.silu(g.astype(f32))).astype(h.dtype)
+            f = jnp.einsum("nte,ef->ntf", act, params["mlp"]["W2"])
+        y = x + f
+        return (y,) + tuple(emitted) if self.emit else y
+
+    def apply(self, params, state, x, ctx: LayerContext):
+        if self.recompute and ctx.train:
+            return jax.checkpoint(
+                lambda p, a: self._apply(p, a, ctx))(params, x), state
+        return self._apply(params, x, ctx), state
+
+
+@register_serializable
+@dataclasses.dataclass(frozen=True)
 class CausalLMOutputLayer(FeedForwardLayer):
-    """Final RMSNorm, an untied head over the (possibly sliced)
-    vocabulary, and the next-token cross-entropy in float32.
+    """A final norm, a head over the (possibly sliced) vocabulary, and the
+    next-token cross-entropy in float32.
+
+    ``norm`` is ``"rms"`` (zero-centred RMSNorm) or ``"layer"`` (LayerNorm
+    with weight and bias). The head is a matrix of its own or, with
+    ``tied``, the embedding's table: the layer then takes ``table``
+    (n_out, n_in) as a further input (``TokenEmbedding.emit_table``) and
+    holds the norm alone.
 
     ``apply`` returns the logits (N, T, n_out), float32. ``compute_loss``
     takes integer labels (N, T): ``labels[n, t]`` is the id that follows
@@ -193,26 +364,51 @@ class CausalLMOutputLayer(FeedForwardLayer):
     eps: float = 1e-6
     init_std: float = 0.02
     has_bias: bool = False
+    norm: str = "rms"
+    tied: bool = False
 
     named_scopes = ("lm.head_loss",)
+
+    def __post_init__(self):
+        if self.norm not in ("rms", "layer"):
+            raise ValueError(f"norm={self.norm!r}: 'rms' or 'layer'")
 
     def output_type(self, input_type: InputType) -> InputType:
         t = (input_type.timesteps
              if isinstance(input_type, RecurrentType) else None)
         return RecurrentType(self.n_out, t)
 
+    @property
+    def extra_inputs(self):
+        return ("table",) if self.tied else ()
+
     def initialize(self, key, input_type):
         n_in = self.resolved_n_in(input_type)
         dt = self.param_dtype()
-        return {"norm": {"w": jnp.zeros((n_in,), dt)},
-                "W": self.init_std * jax.random.normal(
-                    key, (n_in, self.n_out), dt)}
+        if self.norm == "layer":
+            params = {"norm": LayerNormalization(
+                eps=self.eps, dtype=self.dtype).initialize(
+                    None, RecurrentType(n_in, None))}
+        else:
+            params = {"norm": {"w": jnp.zeros((n_in,), dt)}}
+        if not self.tied:
+            params["W"] = self.init_std * jax.random.normal(
+                key, (n_in, self.n_out), dt)
+        return params
 
     def _logits(self, params, x):
-        h = rms_norm(x, params["norm"]["w"], self.eps)
-        return jnp.einsum(
-            "nth,hv->ntv", h, params["W"].astype(h.dtype),
-            preferred_element_type=jnp.promote_types(jnp.float32, h.dtype))
+        x, *table = x if isinstance(x, tuple) else (x,)
+        if self.norm == "layer":
+            h, _ = LayerNormalization(eps=self.eps).apply(
+                params["norm"], {}, x, None)
+        else:
+            h = rms_norm(x, params["norm"]["w"], self.eps)
+        acc = jnp.promote_types(jnp.float32, h.dtype)
+        if self.tied:
+            return jnp.einsum("nth,vh->ntv", h, table[0].astype(h.dtype),
+                              preferred_element_type=acc)
+        return jnp.einsum("nth,hv->ntv", h, params["W"].astype(h.dtype),
+                          preferred_element_type=acc)
 
     def apply(self, params, state, x, ctx):
         return self._logits(params, x), state

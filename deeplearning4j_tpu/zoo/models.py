@@ -532,6 +532,125 @@ class Qwen3Next(ZooModel):
 
 
 @dataclasses.dataclass
+class Phi4MiniFlash(ZooModel):
+    """Decoder-hybrid-decoder causal language model of the Phi-4-mini-flash
+    family (published config: huggingface.co/microsoft/
+    Phi-4-mini-flash-reasoning, ``config.json``; arXiv:2507.06607; the
+    field names below are its keys, the ``mamba_*`` ones the family's
+    Mamba-1 sizes). Every block is ``x + Mixer(LayerNorm(x))`` and ``x +
+    MLP(LayerNorm(x))`` with a dense gated MLP and no positional encoding;
+    the mixer by the block's index ``l`` of ``num_hidden_layers`` (half =
+    ``num_hidden_layers // 2``):
+
+    - ``l`` < half, even: Mamba; odd: differential attention over a causal
+      window of ``sliding_window``;
+    - ``l`` = half: Mamba that also emits its memory (the scan's result
+      before the gate); ``l`` = half + 1: differential attention over the
+      whole sequence, which also emits its keys and values;
+    - above, even: a gated memory unit on that memory; odd: differential
+      cross-attention, its own queries on those keys and values.
+
+    The model is a ``ComputationGraph``: what block half and block half +
+    1 emit reach their readers as edges (``block16:memory``,
+    ``block17:k``), and the head reads the embedding's table
+    (``embed:table``, ``tie_word_embeddings``): one matrix, one leaf.
+
+    ``layer_indices`` (default: all) names the published blocks that are
+    built, each with its own index (a chip's pipeline stage; differential
+    attention's ``lambda_init`` reads the index). ``vocab_size`` may be a
+    slice of the published vocabulary: embedding, head and loss are over
+    the slice. Features are integer token ids (N, seq_len), labels
+    ``nn.layers.decoder.next_token_labels(ids)``."""
+    vocab_size: int = 200064
+    hidden_size: int = 2560
+    intermediate_size: int = 10240
+    num_hidden_layers: int = 32
+    num_attention_heads: int = 40
+    num_key_value_heads: int = 20
+    sliding_window: int = 512
+    mb_per_layer: int = 2
+    layer_norm_eps: float = 1e-5
+    tie_word_embeddings: bool = True
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 0          # 0: ceil(hidden_size / 16)
+    layer_indices: Tuple[int, ...] = ()
+    initializer_range: float = 0.02
+    seq_len: int = 8192
+    recompute: bool = True
+    compute_dtype: str = "bfloat16"
+    updater: Optional[Updater] = None
+    seed: int = 123
+
+    def mixer_of(self, l: int):
+        """``(mixer, emit, window)`` of published block ``l``."""
+        from deeplearning4j_tpu.nn.layers.decoder import (
+            ATTENTION, CROSS_ATTENTION, GATED_MEMORY, MAMBA)
+        half = self.num_hidden_layers // 2
+        state_space = l % self.mb_per_layer == 0
+        if l < half:
+            return ((MAMBA, False, None) if state_space
+                    else (ATTENTION, False, self.sliding_window))
+        if l < half + 2:
+            return (MAMBA if state_space else ATTENTION), True, None
+        return (GATED_MEMORY if state_space else CROSS_ATTENTION), False, \
+            None
+
+    def conf(self):
+        from deeplearning4j_tpu.nn.layers.decoder import (
+            CausalLMOutputLayer, StateSpaceHybridBlock, TokenEmbedding)
+        std = self.initializer_range
+        width = self.hidden_size
+        tied = self.tie_word_embeddings
+        g = (NeuralNetConfiguration.Builder()
+             .seed(self.seed)
+             .updater(self.updater or Adam(1e-4))
+             .compute_dtype(self.compute_dtype)
+             .graph_builder()
+             .add_inputs("ids")
+             .set_input_types(InputType.recurrent(1, self.seq_len)))
+        g.add_layer("embed", TokenEmbedding(
+            vocab_size=self.vocab_size, n_out=width, init_std=std,
+            emit_table=tied), "ids")
+        last = "embed"
+        emitted = {}                    # extra input -> the source it names
+        for l in (self.layer_indices
+                  or tuple(range(self.num_hidden_layers))):
+            mixer, emit, window = self.mixer_of(l)
+            block = StateSpaceHybridBlock(
+                n_out=width, mixer=mixer, emit=emit, layer_index=l,
+                n_heads=self.num_attention_heads,
+                n_kv_heads=self.num_key_value_heads,
+                head_dim=width // self.num_attention_heads, window=window,
+                d_inner=self.mamba_expand * width,
+                d_state=self.mamba_d_state, d_conv=self.mamba_d_conv,
+                dt_rank=self.mamba_dt_rank or -(-width // 16),
+                mlp_hidden=self.intermediate_size,
+                eps=self.layer_norm_eps, init_std=std,
+                recompute=self.recompute)
+            missing = [e for e in block.extra_inputs if e not in emitted]
+            if missing:
+                raise ValueError(
+                    f"block {l} ({mixer}) reads {missing}, which no block "
+                    f"of layer_indices={self.layer_indices} before it "
+                    "emits")
+            g.add_layer(f"block{l}", block, last,
+                        *(emitted[e] for e in block.extra_inputs))
+            last = f"block{l}"
+            for extra in block.extra_output_types(None):
+                emitted[extra] = f"{last}:{extra}"
+        g.add_layer("lm_head", CausalLMOutputLayer(
+            n_out=self.vocab_size, eps=self.layer_norm_eps, init_std=std,
+            norm="layer", tied=tied),
+            last, *(("embed:table",) if tied else ()))
+        return g.set_outputs("lm_head").build()
+
+    def init(self) -> ComputationGraph:
+        return ComputationGraph(self.conf()).init()
+
+
+@dataclasses.dataclass
 class AlexNet(ZooModel):
     """reference: model/AlexNet.java (single-stream variant)."""
     num_classes: int = 1000

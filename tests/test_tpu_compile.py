@@ -90,3 +90,45 @@ def test_held_experts_block_loop_compiles_at_the_cells_shapes(
                and "moe." not in line]
     assert not unnamed
     assert compiled.memory_analysis().temp_size_in_bytes < 1.64e9
+
+
+@pytest.mark.parametrize("window", [512, None])
+def test_differential_attention_kernels_compile_at_the_published_widths(
+        one_chip, no_compile_cache, window):
+    """Forward and both backward kernels as ``DifferentialAttention`` calls
+    them at Phi-4-mini-flash's widths: both maps as 40 heads of 64 with a
+    value of 128, 8,192 positions, bfloat16; under the window of 512 the
+    tiles are 512 x 512 and the inner grid axis two key blocks long."""
+    from deeplearning4j_tpu.ops.pallas_kernels import flash_attention
+    qk = jax.ShapeDtypeStruct((1, 8192, 40, 64), jnp.bfloat16,
+                              sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, 8192, 40, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def total(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True, window=window,
+                                       interpret=False).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(total, argnums=(0, 1, 2))).lower(
+        qk, qk, v).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def test_selective_scan_keeps_no_whole_state_tensor_at_the_cells_shapes(
+        one_chip, no_compile_cache):
+    """Forward and backward of ``selective_scan_chunked`` at 8,192 tokens
+    of 5,120 channels x 16 states: the temporaries stay far under the
+    2.7 GB a (T, D, S) float32 tensor would take."""
+    from deeplearning4j_tpu.nn.layers.state_space import (
+        selective_scan_chunked)
+    f32 = jnp.float32
+    shapes = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in (
+        ((1, 8192, 5120), jnp.bfloat16), ((1, 8192, 5120), f32),
+        ((5120, 16), f32), ((1, 8192, 16), f32), ((1, 8192, 16), f32))]
+
+    def total(*a):
+        return jnp.sum(selective_scan_chunked(*a, chunk_size=64))
+
+    compiled = jax.jit(jax.grad(total, argnums=range(5))).lower(
+        *shapes).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
