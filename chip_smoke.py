@@ -19,7 +19,8 @@ a seed, with no network and no files outside the checkout:
                  + BN statistics (Gram and direct), forward and
                  backward, against plain XLA; the held experts' loop over
                  blocks of rows at routings that fill one block and
-                 several
+                 several; the gated delta rule's kernels at the
+                 Qwen3-Next cell's shapes against the plain chunked form
   phase=feed_race  the input pipeline alone, with no train step, so that
                  the prefetch thread gathers flat out into buffers it
                  uses again: 200 shuffled batches of the ResNet cell's
@@ -416,6 +417,31 @@ def phase_kernels(ctx):
             errs[name] = (f"FAIL {landed} held assignments ran "
                           f"{blocks_ran[name]} blocks")
     print(f"held_experts blocks ran: {blocks_ran}")
+
+    # the gated delta rule at the Qwen3-Next cell's shapes: one sequence of
+    # 8,192 tokens, 32 value heads on 16 key heads of 128, chunks of 64.
+    # ``gated_delta_rule`` takes the Pallas kernels here (it is handed a
+    # TPU and heads of 128); the plain chunked form, its oracle, is XLA's
+    from deeplearning4j_tpu.nn.layers.linear_attention import (
+        chunk_gated_delta_rule, l2_normalize)
+    from deeplearning4j_tpu.ops.pallas_delta_rule import (
+        gated_delta_rule, kernels_take)
+    t, hk, hv, dh = 8192, 16, 32, 128
+    q = (l2_normalize(arr((1, t, hk, dh), jnp.float32))
+         / np.sqrt(dh)).astype(jnp.bfloat16)
+    k = l2_normalize(arr((1, t, hk, dh), jnp.float32)).astype(jnp.bfloat16)
+    v = arr((1, t, hv, dh), jnp.bfloat16)
+    log_decay = -jnp.asarray(rng.uniform(1e-3, 0.3, (1, t, hv)), jnp.float32)
+    beta = jnp.asarray(rng.uniform(0.05, 0.95, (1, t, hv)), jnp.float32)
+    _check_grads(
+        "gated_delta_rule",
+        lambda *a: gated_delta_rule(*a, chunk_size=64),
+        lambda q, k, *rest: chunk_gated_delta_rule(
+            jnp.repeat(q, hv // hk, 2), jnp.repeat(k, hv // hk, 2), *rest,
+            chunk_size=64),
+        (q, k, v, log_decay, beta), "bfloat16", errs)
+    if not kernels_take(q, v, 64):
+        errs["gated_delta_rule"] = "FAIL the kernels did not take the call"
     for name, e in errs.items():
         print(f"kernel {name}: "
               + (e if isinstance(e, str) else f"max_rel_err={e:.3e}"))

@@ -171,7 +171,7 @@ class HybridDecoderBlock(FeedForwardLayer):
                 rope_theta=self.rope_theta, **common)
         else:
             mixer = GatedDeltaNet(
-                n_key_heads=self.n_key_heads,
+                name=self.name, n_key_heads=self.n_key_heads,
                 n_value_heads=self.n_value_heads,
                 key_head_dim=self.key_head_dim,
                 value_head_dim=self.value_head_dim,

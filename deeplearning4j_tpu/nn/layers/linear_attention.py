@@ -16,12 +16,16 @@ inside a chunk are brought into WY form (one unit-lower-triangular inverse
 per chunk, taken by repeated squaring since the strict triangle is
 nilpotent), and only the state at the chunk borders is carried by a
 ``lax.scan`` over chunks, so nearly all the work is matrix products.
-Gradients are autodiff's through both.
+Gradients are autodiff's through both: they are the plain forms, and the
+oracles of ``ops/pallas_delta_rule.py``, which runs the chunked rule as
+Pallas TPU kernels with a backward written by hand. ``GatedDeltaNet``
+calls that module's ``gated_delta_rule``, which picks the kernels or
+``chunk_gated_delta_rule`` from the backend and the shapes it is handed.
 
-Types in the chunked form: gates, decay sums and the carried state are
-float32; matrix products take their operands in the compute type
-(bfloat16 under the bf16 policy, as the published kernels do) and
-accumulate in float32.
+Types in the chunked form, plain or kernels: gates, decay sums and the
+carried state are float32; matrix products take their operands in the
+compute type (bfloat16 under the bf16 policy, as the published kernels
+do) and accumulate in float32.
 """
 
 from __future__ import annotations
@@ -227,6 +231,8 @@ class GatedDeltaNet(FeedForwardLayer):
         }
 
     def apply(self, params, state, x, ctx: LayerContext):
+        from deeplearning4j_tpu.ops.pallas_delta_rule import (
+            gated_delta_rule)
         n, t, _ = x.shape
         kd, vd = self._dims
         hk, hv = self.n_key_heads, self.n_value_heads
@@ -248,13 +254,9 @@ class GatedDeltaNet(FeedForwardLayer):
             q = l2_normalize(q, self.eps) / jnp.sqrt(
                 jnp.asarray(self.key_head_dim, f32))
             k = l2_normalize(k, self.eps)
-            rep = hv // hk
-            if rep > 1:
-                q = jnp.repeat(q, rep, axis=2)
-                k = jnp.repeat(k, rep, axis=2)
-            o, _ = chunk_gated_delta_rule(
+            o, _ = gated_delta_rule(
                 q.astype(x.dtype), k.astype(x.dtype), v, g, beta,
-                chunk_size=self.chunk_size)
+                chunk_size=self.chunk_size, layer=self.name or "gdn")
             z = z.reshape(n, t, hv, self.value_head_dim)
             o = rms_norm(o, params["norm_w"], self.eps,
                          zero_centered=False)

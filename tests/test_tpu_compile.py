@@ -132,3 +132,98 @@ def test_selective_scan_keeps_no_whole_state_tensor_at_the_cells_shapes(
     compiled = jax.jit(jax.grad(total, argnums=range(5))).lower(
         *shapes).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
+def _delta_rule_shapes(one_chip, t=8192, hk=16, hv=32, d=128):
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    return (s((1, t, hk, d), jnp.bfloat16), s((1, t, hk, d), jnp.bfloat16),
+            s((1, t, hv, d), jnp.bfloat16), s((1, t, hv), jnp.float32),
+            s((1, t, hv), jnp.float32))
+
+
+def test_gated_delta_rule_kernels_compile_at_the_cells_shapes(
+        one_chip, no_compile_cache):
+    """Forward (with and without the border states) and backward of the
+    gated delta rule's kernels at 8,192 tokens, 32 value and 16 key heads
+    of 128, chunks of 64, bfloat16, inside the scoped VMEM they ask for
+    (Mosaic's default covers it); what is left of the plain form's batched
+    64 x 64 products and of its ``lax.scan`` is nothing: no ``[., 128, 64,
+    64]`` temporary, no ``while``. Everything between the inputs and the
+    gradients is traced under the caller's scope."""
+    import re
+    from deeplearning4j_tpu.observe.scopes import scopes_in_hlo
+    from deeplearning4j_tpu.ops.pallas_delta_rule import (
+        _vmem_need, gated_delta_rule_kernels)
+    from deeplearning4j_tpu.ops.pallas_kernels import scoped_vmem_limit
+
+    def rule(*a):
+        with jax.named_scope("gdn.scan"):
+            return gated_delta_rule_kernels(*a, chunk_size=64,
+                                            interpret=False)[0]
+
+    def both(q, k, v, g, beta, do):
+        o, vjp = jax.vjp(jax.checkpoint(rule), q, k, v, g, beta)
+        return o, vjp(do)
+
+    do = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.float32,
+                              sharding=one_chip)
+    compiled = jax.jit(both).lower(*_delta_rule_shapes(one_chip),
+                                   do).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    assert not re.search(r"\[\d+,128,64,64\]", text)
+    assert " while(" not in text
+    traced = [op for op in scopes_in_hlo(text).values()
+              if op.startswith("jit(both)/")]
+    assert traced and not [op for op in traced if "gdn.scan" not in op]
+    for backward in (False, True):
+        assert scoped_vmem_limit(_vmem_need(2, 128, 128, 64,
+                                            backward)) is None
+    # the border states are the one residual: 268 MB in float32
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.8e9
+
+
+def test_a_gated_deltanet_layer_keeps_its_kernels_under_gdn_scan(
+        one_chip, no_compile_cache, monkeypatch):
+    """A ``GatedDeltaNet`` at the cell's widths under ``jax.checkpoint``,
+    as the decoder block runs it, traced for a TPU (the test stands in for
+    ``jax.default_backend``): the forward, the recomputed forward and the
+    backward kernel each carry ``gdn.scan`` in their ``op_name``, by the
+    join ``yardstick/scopes.py`` makes, so the per-scope readers find
+    them; no loop is left in the layer; and the gauge says 128 chunks."""
+    from deeplearning4j_tpu.nn.inputs import RecurrentType
+    from deeplearning4j_tpu.nn.layers.base import LayerContext
+    from deeplearning4j_tpu.nn.layers.linear_attention import GatedDeltaNet
+    from deeplearning4j_tpu.observe.registry import default_registry
+    from deeplearning4j_tpu.observe.scopes import scopes_in_hlo
+    from deeplearning4j_tpu.ops.pallas_delta_rule import GDN_KERNEL_GAUGE
+    from yardstick.scopes import in_scope
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    t, width = 8192, 2048
+    layer = GatedDeltaNet(name="block0", n_in=width, n_out=width,
+                          dtype="bfloat16")
+    params = jax.eval_shape(
+        lambda key: layer.initialize(key, RecurrentType(width, t)),
+        jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), params)
+    x = jax.ShapeDtypeStruct((1, t, width), jnp.bfloat16, sharding=one_chip)
+
+    def total(p, a):
+        y, _ = jax.checkpoint(lambda p, a: layer.apply(
+            p, {}, a, LayerContext(train=True)))(p, a)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(total, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    table = scopes_in_hlo(text)
+    kernels = {name: op for name, op in table.items()
+               if name.startswith("gdn_delta_rule_")}   # the custom calls
+    assert len(kernels) == 3
+    assert all(in_scope(op, ("gdn.scan",)) for op in kernels.values())
+    assert sum("gdn_delta_rule_bwd" in op and "transpose(" in op
+               for op in kernels.values()) == 1
+    assert sum("rematted_computation" in op for op in kernels.values()) == 1
+    assert not [op for op in table.values() if "/while" in op]
+    assert default_registry().gauge(*GDN_KERNEL_GAUGE).get(
+        layer="block0") == 128
