@@ -80,22 +80,24 @@ def run(t, h=16, dh=64, n=1, causal=True, dtype=jnp.bfloat16,
     from deeplearning4j_tpu.nn.layers.attention import (
         scaled_dot_product_attention)
     from deeplearning4j_tpu.ops.pallas_kernels import flash_attention
+    from deeplearning4j_tpu.ops.visibility import Causal, Visibility
+    vis = Causal() if causal else Visibility()
 
     rng = np.random.default_rng(0)
     mk = lambda: jnp.asarray(rng.normal(0, 1, (n, t, h, dh)), dtype)
     q, k, v = mk(), mk(), mk()
 
     def loss_flash(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal=causal)
+        return jnp.sum(flash_attention(q, k, v, visibility=vis)
                        .astype(jnp.float32) ** 2)
 
     def loss_xla(q, k, v):
         return jnp.sum(scaled_dot_product_attention(q, k, v,
-                                                    causal=causal)
+                                                    visibility=vis)
                        .astype(jnp.float32) ** 2)
 
     g_xla = jax.grad(loss_xla, argnums=(0, 1, 2))
-    fwd_flash = functools.partial(flash_attention, causal=causal)
+    fwd_flash = functools.partial(flash_attention, visibility=vis)
 
     res = {"t": t, "fwd_flash_ms": bench_fwd(fwd_flash, q, k, v, iters=iters)}
 

@@ -269,6 +269,7 @@ def phase_kernels(ctx):
         _conv_reference, conv_bn_stats_xla)
     from deeplearning4j_tpu.ops.pallas_kernels import flash_attention
     from deeplearning4j_tpu.ops.pallas_lstm import lstm_fused
+    from deeplearning4j_tpu.ops.visibility import Causal
 
     errs = {}
     rng = np.random.default_rng(0)
@@ -285,9 +286,10 @@ def phase_kernels(ctx):
                        jnp.float32)
     _check_grads(
         "flash_causal",
-        lambda q, k, v: flash_attention(q, k, v, causal=True,
+        lambda q, k, v: flash_attention(q, k, v, visibility=Causal(),
                                         interpret=False),
-        lambda q, k, v: scaled_dot_product_attention(q, k, v, causal=True),
+        lambda q, k, v: scaled_dot_product_attention(q, k, v,
+                                                     visibility=Causal()),
         (q, k, v), "bfloat16", errs)
     _check_grads(
         "flash_masked",

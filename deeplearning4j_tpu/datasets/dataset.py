@@ -13,6 +13,7 @@ the reference's AsyncDataSetIterator thread.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import sys
 import threading
 from typing import Iterator, List, Optional, Sequence, Tuple, Union
@@ -77,13 +78,80 @@ class MultiDataSet:
         return int(self.features[0].shape[0])
 
 
+class DataSetPreProcessor:
+    """What an iterator does to each minibatch before it hands it out
+    (the reference's ``DataSetPreProcessor``, set with
+    ``iterator.setPreProcessor``): normalisation, augmentation, or the
+    noise a diffusion trainer draws afresh for every batch."""
+
+    def pre_process(self, batch: DataSet, epoch: int = 0,
+                    index: int = 0) -> DataSet:
+        """The batch to hand out for ``batch``, the ``index``-th of the
+        iterator's ``epoch``-th pass since the pre-processor was set:
+        ``batch`` itself, changed in place as the reference's is, or
+        another."""
+        raise NotImplementedError
+
+
+def _through_pre_processor(iter_method):
+    """``__iter__`` whose batches pass through the iterator's
+    pre-processor where one is set; the method as it is where none is."""
+
+    @functools.wraps(iter_method)
+    def __iter__(self):
+        batches = iter_method(self)
+        if self._pre_processor is None:
+            return batches
+        return self._pre_processed(batches)
+    return __iter__
+
+
 class DataSetIterator:
     """Iterator protocol: iterable over DataSet minibatches with reset().
     Matches the reference's interface surface (batch(), totalOutcomes(),
-    resetSupported(), asyncSupported()) where meaningful in Python."""
+    resetSupported(), asyncSupported(), setPreProcessor()) where
+    meaningful in Python.
+
+    ``set_pre_processor`` works on every iterator: a subclass's own
+    ``__iter__`` is wrapped when the class is made, so the pre-processor
+    runs where the batch is produced (under ``fit()``, on the prefetch
+    thread and inside its ``produce`` span). An iterator that only hands
+    on another's batches from another thread gives the pre-processor to
+    that one (``AsyncDataSetIterator``)."""
+
+    _pre_processor: Optional[DataSetPreProcessor] = None
+    _pre_processed_passes = 0
+
+    def __init_subclass__(cls, **kwargs):
+        super().__init_subclass__(**kwargs)
+        own = cls.__dict__.get("__iter__")
+        if own is not None:
+            cls.__iter__ = _through_pre_processor(own)
 
     def __iter__(self) -> Iterator[DataSet]:
         raise NotImplementedError
+
+    def set_pre_processor(self, pre_processor: Optional[DataSetPreProcessor]):
+        self._pre_processor = pre_processor
+        self._pre_processed_passes = 0
+
+    @property
+    def pre_processor(self) -> Optional[DataSetPreProcessor]:
+        return self._pre_processor
+
+    def _pre_processed(self, batches):
+        epoch = self._pre_processed_passes
+        self._pre_processed_passes += 1
+        index = 0
+        for batch in batches:
+            # rebound before the yield: nothing here holds the produced
+            # batch (``ArrayDataSetIterator`` uses its memory again). What
+            # a batch says of its memory (``reused_buffers``) goes on only
+            # with the batch itself, changed in place: another batch is
+            # the pre-processor's own allocation
+            batch = self._pre_processor.pre_process(batch, epoch, index)
+            yield batch
+            index += 1
 
     def reset(self):
         pass

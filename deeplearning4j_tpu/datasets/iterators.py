@@ -23,7 +23,7 @@ import time
 from typing import Iterator, Optional
 
 from deeplearning4j_tpu.datasets.dataset import DataSet, DataSetIterator
-from deeplearning4j_tpu.observe.tracer import NULL_TRACER
+from deeplearning4j_tpu.observe.tracer import NULL_TRACER, set_thread_tracer
 
 
 class AsyncDataSetIterator(DataSetIterator):
@@ -39,7 +39,13 @@ class AsyncDataSetIterator(DataSetIterator):
     says so (``reused_buffers``, set by ``ArrayDataSetIterator`` on a
     gathered batch and carried through any wrapper that hands the batch
     on), the span says in ``reused`` whether the batch went into memory
-    that has been used before or into a fresh allocation."""
+    that has been used before or into a fresh allocation.
+
+    A pre-processor set here is the base's (``set_pre_processor`` hands it
+    on): it runs on the worker, where the batch is produced, inside the
+    ``produce`` span; the worker names its tracer as its thread's
+    (``observe.tracer.thread_tracer``) for a pre-processor that records a
+    span of its own."""
 
     _SENTINEL = object()
 
@@ -75,6 +81,7 @@ class AsyncDataSetIterator(DataSetIterator):
                 start = time.perf_counter()
 
         def worker():
+            set_thread_tracer(tracer)
             try:
                 for batch in (produced() if tracer.enabled else self.base):
                     while not stop.is_set():
@@ -135,6 +142,13 @@ class AsyncDataSetIterator(DataSetIterator):
         if t is None or not t.is_alive():
             return
         self._reap(t, stop, q)
+
+    def set_pre_processor(self, pre_processor):
+        self.base.set_pre_processor(pre_processor)
+
+    @property
+    def pre_processor(self):
+        return self.base.pre_processor
 
     def reset(self):
         # stop → drain → JOIN, and only then reset the base: resetting

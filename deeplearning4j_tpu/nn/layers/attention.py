@@ -32,16 +32,23 @@ from deeplearning4j_tpu.nn.layers.base import (
 from deeplearning4j_tpu.nn.layers.normalization import LayerNormalization
 from deeplearning4j_tpu.ops.activations import Activation
 from deeplearning4j_tpu.ops.initializers import WeightInit
+from deeplearning4j_tpu.ops.visibility import (
+    BlockDiffusion,
+    Causal,
+    Visibility,
+)
 from deeplearning4j_tpu.utils.serde import register_serializable
 
 
-def scaled_dot_product_attention(q, k, v, mask=None, causal=False,
-                                 window: Optional[int] = None):
+def scaled_dot_product_attention(q, k, v, mask=None,
+                                 visibility: Visibility = Visibility()):
     """Plain attention on (N, T, H, Dh) tensors; softmax in f32.
 
     ``mask``: (N, T_k) key validity mask. The single-chip reference path
-    that parallel/ring_attention.py must match exactly. ``window`` (causal
-    only): a query sees itself and the ``window - 1`` positions before it.
+    that parallel/ring_attention.py must match exactly. ``visibility``
+    (``ops/visibility.py``: ``Causal()``, ``Causal(window)``,
+    ``BlockDiffusion(T, B)``) says which keys a query sees; the whole
+    (T_q, T_k) map is materialised here whatever it hides.
 
     Internal score order is (N, Tq, Tk, H) — HEAD TRAILING — so both
     contractions keep (h, dh) as the packed-QKV tensor's trailing dims
@@ -59,15 +66,10 @@ def scaled_dot_product_attention(q, k, v, mask=None, causal=False,
     # fully-padded sequence would poison the whole batch's gradients
     neg = jnp.asarray(jnp.finfo(sdt).min / 2, sdt)
     valid = None
-    if window is not None and not causal:
-        raise ValueError(f"window={window} needs causal=True")
-    if causal:
+    if visibility != Visibility():      # something is hidden
         tq, tk = s.shape[1], s.shape[2]
-        qpos = jnp.arange(tq)[:, None, None]
-        kpos = jnp.arange(tk)[None, :, None]
-        seen = kpos <= qpos
-        if window is not None:
-            seen = seen & (kpos > qpos - window)
+        seen = visibility.visible(jnp.arange(tq)[:, None, None],
+                                  jnp.arange(tk)[None, :, None])
         s = jnp.where(seen[None], s, neg)
     if mask is not None:
         valid = mask[:, None, :, None].astype(bool)
@@ -134,7 +136,8 @@ class SelfAttentionLayer(FeedForwardLayer):
         # helper-SPI dispatch: Pallas flash kernel on TPU, plain XLA
         # lowering elsewhere (ops/pallas_kernels.py)
         from deeplearning4j_tpu.ops.pallas_kernels import attention as _attn
-        o = _attn(q, k, v, mask=ctx.mask, causal=self.causal)
+        o = _attn(q, k, v, mask=ctx.mask,
+                  visibility=Causal() if self.causal else Visibility())
         n, t = o.shape[0], o.shape[1]
         y = o.reshape(n, t, self.n_out)
         y = jnp.einsum("nte,eo->nto", y, params["Wo"])
@@ -266,17 +269,31 @@ def rotary_embedding(x, positions, rotary_dim: int, theta: float):
 @register_serializable
 @dataclasses.dataclass(frozen=True)
 class GatedAttention(FeedForwardLayer):
-    """Causal grouped-query softmax attention with per-head q/k RMSNorm,
-    rotary embedding on a part of the head and a sigmoid output gate,
-    bias-free: ``W_q`` gives each query head a query and a gate of
-    ``head_dim`` each; ``q <- RMSNorm(q)``, ``k <- RMSNorm(k)`` over the
-    head (zero-centred weights); rotary on the first
-    ``partial_rotary_factor`` of the head; ``n_heads / n_kv_heads`` query
-    heads share a key/value head; ``W_o(attn * sigmoid(gate))``. The head
-    size is its own field, not ``n_out / n_heads``. ``n_out`` is the model
-    width. Attention itself goes through ``ops.pallas_kernels.attention``
-    (the flash kernel from 1,024 positions on a TPU, plain XLA else) with
-    the key/value heads repeated for it."""
+    """Grouped-query softmax attention with per-head q/k RMSNorm and a
+    rotary embedding, bias-free; the sigmoid output gate, the share of the
+    head that is rotated and which keys a query sees are fields. ``q <-
+    RMSNorm(q)``, ``k <- RMSNorm(k)`` over the head (zero-centred
+    weights); rotary on the first ``partial_rotary_factor`` of the head (1:
+    the whole head); ``n_heads / n_kv_heads`` query heads share a
+    key/value head. With ``output_gate`` (the default: Qwen3-Next's gated
+    attention, and what a configuration saved before the field was there
+    loads as) ``W_q`` gives each query head a query and a gate of
+    ``head_dim`` each and the layer is ``W_o(attn * sigmoid(gate))``;
+    without, ``W_q`` holds the queries alone and the layer is
+    ``W_o(attn)``.
+
+    ``block_length`` 0 is causal attention at positions ``0 .. T - 1``.
+    ``block_length`` B > 0 is a masked diffusion over blocks of B,
+    trained: the input is ``[noisy | clean]``, two copies of a sequence of
+    ``T / 2`` positions, slot ``s`` at rotary position ``s mod T / 2``,
+    under ``ops.visibility.BlockDiffusion(T / 2, B)``.
+
+    The head size is its own field, not ``n_out / n_heads``. ``n_out`` is
+    the model width. Attention itself goes through
+    ``ops.pallas_kernels.attention`` (the flash kernel from 1,024
+    positions on a TPU, plain XLA else) with the key/value heads repeated
+    for it, under the named scope ``attn.gated`` or, with blocks,
+    ``attn.block_diffusion``."""
     n_heads: int = 16
     n_kv_heads: int = 2
     head_dim: int = 256
@@ -284,12 +301,23 @@ class GatedAttention(FeedForwardLayer):
     rope_theta: float = 1e7
     eps: float = 1e-6
     init_std: float = 0.02
+    output_gate: bool = True
+    block_length: int = 0
+
+    named_scopes = ("attn.gated", "attn.block_diffusion")
 
     def __post_init__(self):
         if self.n_heads % self.n_kv_heads:
             raise ValueError(
                 f"n_heads={self.n_heads} is not a multiple of "
                 f"n_kv_heads={self.n_kv_heads}")
+        if self.block_length < 0:
+            raise ValueError(f"block_length={self.block_length}: 0 "
+                             "(causal) or a block's length")
+
+    @property
+    def scope(self) -> str:
+        return "attn.block_diffusion" if self.block_length else "attn.gated"
 
     def output_type(self, input_type: InputType) -> InputType:
         t = (input_type.timesteps
@@ -306,8 +334,9 @@ class GatedAttention(FeedForwardLayer):
             return self.init_std * jax.random.normal(k, shape, dt)
 
         return {
-            # per head: [query | gate]
-            "W_q": normal(kq, (n_in, h * 2 * dh)),
+            # per head: [query | gate], or the query alone
+            "W_q": normal(kq, (n_in, h * (2 if self.output_gate else 1)
+                               * dh)),
             "W_k": normal(kk, (n_in, hk * dh)),
             "W_v": normal(kv, (n_in, hk * dh)),
             "W_o": normal(ko, (h * dh, self.n_out)),
@@ -320,10 +349,17 @@ class GatedAttention(FeedForwardLayer):
         from deeplearning4j_tpu.ops.pallas_kernels import attention as _attn
         n, t, _ = x.shape
         h, hk, dh = self.n_heads, self.n_kv_heads, self.head_dim
-        with jax.named_scope("attn.gated"):
-            qg = jnp.einsum("ntf,fe->nte", x, params["W_q"]).reshape(
-                n, t, h, 2 * dh)
-            q, gate = qg[..., :dh], qg[..., dh:]
+        if self.block_length and t % 2:
+            raise ValueError(
+                f"block_length={self.block_length} takes [noisy | clean], "
+                f"an even number of positions; got {t}")
+        with jax.named_scope(self.scope):
+            q = jnp.einsum("ntf,fe->nte", x, params["W_q"])
+            if self.output_gate:
+                qg = q.reshape(n, t, h, 2 * dh)
+                q, gate = qg[..., :dh], qg[..., dh:]
+            else:
+                q = q.reshape(n, t, h, dh)
             k = jnp.einsum("ntf,fe->nte", x, params["W_k"]).reshape(
                 n, t, hk, dh)
             v = jnp.einsum("ntf,fe->nte", x, params["W_v"]).reshape(
@@ -332,14 +368,21 @@ class GatedAttention(FeedForwardLayer):
             k = rms_norm(k, params["k_norm"], self.eps)
             rot = int(dh * self.partial_rotary_factor)
             pos = jnp.arange(t)
+            vis = Causal()
+            if self.block_length:
+                pos = pos % (t // 2)
+                vis = BlockDiffusion(t // 2, self.block_length)
             q = rotary_embedding(q, pos, rot, self.rope_theta)
             k = rotary_embedding(k, pos, rot, self.rope_theta)
             rep = h // hk
             if rep > 1:
                 k = jnp.repeat(k, rep, axis=2)
                 v = jnp.repeat(v, rep, axis=2)
-            o = _attn(q, k, v, mask=ctx.mask, causal=True)
-            o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
+            o = _attn(q, k, v, mask=ctx.mask, visibility=vis,
+                      scope=self.scope)
+            if self.output_gate:
+                o = o * jax.nn.sigmoid(
+                    gate.astype(jnp.float32)).astype(o.dtype)
             y = jnp.einsum("nte,eo->nto", o.reshape(n, t, h * dh),
                            params["W_o"])
         return y, state
@@ -458,7 +501,7 @@ class DifferentialAttention(FeedForwardLayer):
             o = _attn(jnp.concatenate([q[:, :, :, 0], q[:, :, :, 1]], 2),
                       jnp.concatenate([kp[:, :, :, 0], kp[:, :, :, 1]], 2),
                       jnp.concatenate([vp, vp], 2),
-                      mask=mask, causal=True, window=self.window,
+                      mask=mask, visibility=Causal(self.window),
                       scope=self.scope)
             lam0 = differential_lambda_init(self.layer_index)
             lq1, lk1, lq2, lk2 = (params[name].astype(f32) for name in (

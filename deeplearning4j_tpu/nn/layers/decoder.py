@@ -1,17 +1,21 @@
 """Decoder-only language-model layers: token embedding, the hybrid
-decoder blocks, and the head with its next-token loss.
+decoder blocks, and the head with its losses (next-token; masked
+diffusion over blocks).
 
-The block is the unit of the hybrid linear-attention mixture-of-experts
-family (Qwen3-Next): pre-norm residuals round a token mixer and an
-expert layer,
+The block is the unit of the mixture-of-experts decoder families
+(Qwen3-Next's hybrid of linear and softmax attention; SDAR's block
+diffusion over a Qwen3-MoE decoder): pre-norm residuals round a token
+mixer and an expert layer,
 
     h = x + Mixer(RMSNorm(x)),   y = h + MoE(RMSNorm(h))
 
 where the mixer is a ``GatedDeltaNet`` (linear attention, a matrix state
-per head) or a ``GatedAttention`` (causal softmax attention) and the
-expert layer is a ``HeldExpertsMoE``. A model is a ``MultiLayerNetwork``
-of ``TokenEmbedding``, blocks, ``CausalLMOutputLayer``: ordinary
-serialisable layers, trained by ``fit()``.
+per head) or a ``GatedAttention`` (softmax attention: causal with an
+output gate, or ungated over ``[noisy | clean]`` under the
+block-diffusion visibility) and the expert layer is a ``HeldExpertsMoE``.
+A model is a ``MultiLayerNetwork`` of ``TokenEmbedding``, blocks,
+``CausalLMOutputLayer``: ordinary serialisable layers, trained by
+``fit()``.
 
 ``StateSpaceHybridBlock`` is the unit of the decoder-hybrid-decoder
 family (Phi-4-mini-flash; arXiv:2507.06607): pre-LayerNorm residuals round
@@ -67,6 +71,7 @@ from deeplearning4j_tpu.utils.serde import register_serializable
 
 GATED_DELTANET = "gated_deltanet"
 GATED_ATTENTION = "gated_attention"
+BLOCK_DIFFUSION_ATTENTION = "block_diffusion_attention"
 MAMBA = "mamba"
 ATTENTION = "attention"
 GATED_MEMORY = "gated_memory"
@@ -119,12 +124,14 @@ class HybridDecoderBlock(FeedForwardLayer):
     serialises as one layer. ``n_out`` is the model width and equals the
     input's."""
     mixer: str = GATED_DELTANET
-    # gated attention
+    # gated attention, and block-diffusion attention (no output gate;
+    # ``block_length`` is its alone)
     n_heads: int = 16
     n_kv_heads: int = 2
     head_dim: int = 256
     partial_rotary_factor: float = 0.25
     rope_theta: float = 1e7
+    block_length: int = 4
     # gated deltanet
     n_key_heads: int = 16
     n_value_heads: int = 32
@@ -139,20 +146,23 @@ class HybridDecoderBlock(FeedForwardLayer):
     shared_hidden: int = 0
     top_k: int = 2
     norm_topk: bool = True
+    router_aux_loss_coef: float = 0.0
     eps: float = 1e-6
     init_std: float = 0.02
     recompute: bool = False
 
     # the ``jax.named_scope`` names this block's parts put into a step
-    named_scopes = ("gdn.proj", "gdn.conv", "gdn.scan", "gdn.out",
-                    "attn.gated", "moe.route", "moe.dispatch", "moe.experts",
-                    "moe.shared", "moe.combine")
+    named_scopes = (("gdn.proj", "gdn.conv", "gdn.scan", "gdn.out")
+                    + GatedAttention.named_scopes
+                    + ("moe.route", "moe.dispatch", "moe.experts",
+                       "moe.shared", "moe.combine"))
 
     def __post_init__(self):
-        if self.mixer not in (GATED_DELTANET, GATED_ATTENTION):
+        kinds = (GATED_DELTANET, GATED_ATTENTION, BLOCK_DIFFUSION_ATTENTION)
+        if self.mixer not in kinds:
             raise ValueError(
-                f"mixer={self.mixer!r}: {GATED_DELTANET!r} or "
-                f"{GATED_ATTENTION!r}")
+                f"mixer={self.mixer!r}: one of "
+                + ", ".join(repr(k) for k in kinds))
 
     def output_type(self, input_type: InputType) -> InputType:
         t = (input_type.timesteps
@@ -163,12 +173,15 @@ class HybridDecoderBlock(FeedForwardLayer):
         w = self.n_out
         common = dict(n_in=w, n_out=w, dtype=self.dtype,
                       init_std=self.init_std)
-        if self.mixer == GATED_ATTENTION:
+        if self.mixer != GATED_DELTANET:
+            diffusion = self.mixer == BLOCK_DIFFUSION_ATTENTION
             mixer = GatedAttention(
                 n_heads=self.n_heads, n_kv_heads=self.n_kv_heads,
                 head_dim=self.head_dim, eps=self.eps,
                 partial_rotary_factor=self.partial_rotary_factor,
-                rope_theta=self.rope_theta, **common)
+                rope_theta=self.rope_theta, output_gate=not diffusion,
+                block_length=self.block_length if diffusion else 0,
+                **common)
         else:
             mixer = GatedDeltaNet(
                 name=self.name, n_key_heads=self.n_key_heads,
@@ -180,7 +193,8 @@ class HybridDecoderBlock(FeedForwardLayer):
         moe = HeldExpertsMoE(
             num_experts=self.num_experts, held_experts=self.held_experts,
             hidden=self.expert_hidden, shared_hidden=self.shared_hidden,
-            top_k=self.top_k, norm_topk=self.norm_topk, **common)
+            top_k=self.top_k, norm_topk=self.norm_topk,
+            aux_loss_coef=self.router_aux_loss_coef, **common)
         return mixer, moe, RMSNorm(eps=self.eps, dtype=self.dtype)
 
     def initialize(self, key, input_type):
@@ -347,8 +361,9 @@ class StateSpaceHybridBlock(FeedForwardLayer):
 @register_serializable
 @dataclasses.dataclass(frozen=True)
 class CausalLMOutputLayer(FeedForwardLayer):
-    """A final norm, a head over the (possibly sliced) vocabulary, and the
-    next-token cross-entropy in float32.
+    """A final norm, a head over the (possibly sliced) vocabulary, and a
+    cross-entropy in float32: next-token, or masked diffusion's, by the
+    layout of the labels.
 
     ``norm`` is ``"rms"`` (zero-centred RMSNorm) or ``"layer"`` (LayerNorm
     with weight and bias). The head is a matrix of its own or, with
@@ -356,11 +371,25 @@ class CausalLMOutputLayer(FeedForwardLayer):
     (n_out, n_in) as a further input (``TokenEmbedding.emit_table``) and
     holds the norm alone.
 
-    ``apply`` returns the logits (N, T, n_out), float32. ``compute_loss``
-    takes integer labels (N, T): ``labels[n, t]`` is the id that follows
-    position t, and a label below zero (``IGNORE_LABEL``: a row's last
-    position) is left out of the mean. A mask (N,) or (N, T), as the
-    feeder attaches, weights rows or positions."""
+    ``apply`` returns the logits (N, T, n_out), float32. Which loss
+    ``compute_loss`` computes, the labels' layout says:
+
+    - integer labels (N, T) or (N, T, 1): **next-token**. ``labels[n, t]``
+      is the id that follows position t, and a label below zero
+      (``IGNORE_LABEL``: a row's last position) is left out of the mean,
+      which is over the positions that count. A mask (N,) or (N, T), as
+      the feeder attaches, weights rows or positions.
+    - float labels (N, T, 2) (``datasets.diffusion.BlockDiffusionNoiser``
+      makes them): **masked diffusion**, for an input of ``2 T``
+      positions ``[noisy | clean]``. ``labels[n, t, 0]`` is the id that
+      position t of the noisy half hides (``IGNORE_LABEL`` where it hides
+      none) and ``labels[n, t, 1]`` the weight of its loss, ``1 / t_n``
+      for the row's noise level (0 where nothing is hidden). Norm and
+      head run over the first T positions only, the position's own
+      logits predict its own token (no shift), and the weighted sum is
+      divided by the positions, ``N T`` (under a mask: those it keeps),
+      not by the weights: ``1 / (N T) sum_n sum_{t masked} (1 / t_n)
+      (logsumexp(logits[n, t]) - logits[n, t, x0[n, t]])``."""
     eps: float = 1e-6
     init_std: float = 0.02
     has_bias: bool = False
@@ -414,6 +443,8 @@ class CausalLMOutputLayer(FeedForwardLayer):
         return self._logits(params, x), state
 
     def compute_loss(self, params, state, x, labels, ctx):
+        if labels.ndim == 3 and labels.shape[-1] == 2:
+            return self._diffusion_loss(params, x, labels, ctx)
         with jax.named_scope("lm.head_loss"):
             logits = self._logits(params, x)
             labels = labels.astype(jnp.int32)
@@ -423,10 +454,37 @@ class CausalLMOutputLayer(FeedForwardLayer):
             if ctx.mask is not None:
                 m = ctx.mask.astype(logits.dtype)
                 weight = weight * (m[:, None] if m.ndim == 1 else m)
-            picked = jnp.take_along_axis(
-                logits, jnp.maximum(labels, 0)[..., None], -1)[..., 0]
-            per = jax.nn.logsumexp(logits, -1) - picked
+            per = _token_losses(logits, labels)
             return jnp.sum(per * weight) / jnp.maximum(jnp.sum(weight), 1.0)
+
+    def _diffusion_loss(self, params, x, labels, ctx):
+        """The masked-diffusion loss of the class docstring."""
+        with jax.named_scope("lm.head_loss"):
+            x, *table = x if isinstance(x, tuple) else (x,)
+            t = labels.shape[1]
+            if x.shape[1] != 2 * t:
+                raise ValueError(
+                    f"labels (N, {t}, 2) go with 2 * {t} positions [noisy "
+                    f"| clean]; the head was handed {x.shape[1]}")
+            logits = self._logits(params, (x[:, :t], *table))
+            ids = labels[..., 0].astype(jnp.int32)
+            weight = jnp.where(ids >= 0, labels[..., 1], 0.0).astype(
+                logits.dtype)
+            kept = jnp.ones(ids.shape, logits.dtype)
+            if ctx.mask is not None:
+                m = ctx.mask.astype(logits.dtype)
+                kept = kept * (m[:, None] if m.ndim == 1 else m)
+            per = _token_losses(logits, ids)
+            return jnp.sum(per * weight * kept) / jnp.maximum(
+                jnp.sum(kept), 1.0)
+
+
+def _token_losses(logits, ids):
+    """``logsumexp(logits) - logits[ids]`` position by position (ids
+    below zero read id 0: their positions carry no weight)."""
+    picked = jnp.take_along_axis(
+        logits, jnp.maximum(ids, 0)[..., None], -1)[..., 0]
+    return jax.nn.logsumexp(logits, -1) - picked
 
 
 def next_token_labels(ids):

@@ -436,7 +436,13 @@ class HeldExpertsMoE(FeedForwardLayer):
     experts, the largest and the mean load of a held expert, dropped
     assignments, always 0) and, fifth, the blocks the step's dispatch loop
     ran (``ceil(assignments_held / parallel.moe.dispatch_block(...))``):
-    float32[5] under ``moe_routing``."""
+    float32[5] under ``moe_routing``.
+
+    ``aux_loss_coef`` above 0 adds that many times the router's
+    load-balancing loss (``parallel.moe.load_balancing_loss``, over all
+    ``num_experts`` outputs, this layer's own tokens) to the training
+    loss, through the state's ``moe_aux_loss`` as ``MixtureOfExperts``
+    does; at 0 the layer computes none."""
     num_experts: int = 8
     held_experts: Tuple[int, ...] = ()
     hidden: int = 0              # a routed expert's width
@@ -444,6 +450,7 @@ class HeldExpertsMoE(FeedForwardLayer):
     top_k: int = 2
     norm_topk: bool = True
     init_std: float = 0.02
+    aux_loss_coef: float = 0.0
 
     @property
     def held(self) -> Tuple[int, ...]:
@@ -487,7 +494,10 @@ class HeldExpertsMoE(FeedForwardLayer):
         return params
 
     def init_state(self, input_type):
-        return {"moe_routing": jnp.zeros((5,), jnp.float32)}
+        state = {"moe_routing": jnp.zeros((5,), jnp.float32)}
+        if self.aux_loss_coef:
+            state["moe_aux_loss"] = jnp.zeros((), jnp.float32)
+        return state
 
     def upgrade_state(self, saved):
         # a row saved with the four counters reads 0 blocks until a step
@@ -498,10 +508,10 @@ class HeldExpertsMoE(FeedForwardLayer):
         from deeplearning4j_tpu.parallel.moe import (dispatch_block,
                                                      held_experts_ffn)
         xt = x.reshape(-1, x.shape[-1])
-        y, counters = held_experts_ffn(
+        y, counters, *aux = held_experts_ffn(
             xt, params["router"], params["w_gate"], params["w_up"],
             params["w_down"], self.held, top_k=self.top_k,
-            norm_topk=self.norm_topk)
+            norm_topk=self.norm_topk, balance=bool(self.aux_loss_coef))
         blocks = jnp.ceil(counters[0] / dispatch_block(
             xt.shape[0], self.top_k, len(self.held), self.num_experts))
         if self.shared_hidden:
@@ -514,5 +524,8 @@ class HeldExpertsMoE(FeedForwardLayer):
                         jnp.float32, xt.dtype)))
                 y = y + (h @ params["shared_down"]) * gate[:, None].astype(
                     y.dtype)
-        return (y.reshape(x.shape[:-1] + (self.n_out,)),
-                {"moe_routing": jnp.append(counters, blocks)})
+        y = y.reshape(x.shape[:-1] + (self.n_out,))
+        new_state = {"moe_routing": jnp.append(counters, blocks)}
+        if aux:
+            new_state["moe_aux_loss"] = self.aux_loss_coef * aux[0]
+        return y, new_state

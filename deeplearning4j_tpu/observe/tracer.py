@@ -120,3 +120,19 @@ def get_tracer(model=None) -> SpanTracer:
     """The tracer attached to a model, else the shared no-op."""
     t: Optional[SpanTracer] = getattr(model, "tracer", None)
     return t if t is not None else NULL_TRACER
+
+
+_ON_THIS_THREAD = threading.local()
+
+
+def set_thread_tracer(tracer: SpanTracer) -> None:
+    """Name ``tracer`` as the one of the work this thread does: the
+    prefetch worker of a traced ``fit()`` does, so that what runs inside
+    its ``produce`` span (a ``DataSetPreProcessor``) can record spans of
+    its own without being handed a tracer."""
+    _ON_THIS_THREAD.tracer = tracer
+
+
+def thread_tracer() -> SpanTracer:
+    """The tracer named for this thread, else the shared no-op."""
+    return getattr(_ON_THIS_THREAD, "tracer", NULL_TRACER)

@@ -21,6 +21,7 @@ the compiler refuses raises — there is no silent fallback.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -28,6 +29,15 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from deeplearning4j_tpu.ops.visibility import (  # noqa: F401  (re-exported)
+    BlockDiffusion,
+    Causal,
+    Visibility,
+    _span,
+    _window_kv_blocks,
+    _window_q_blocks,
+)
 
 # Large-finite instead of -inf: -inf scores make softmax VJPs emit NaN for
 # fully-masked rows (matches nn/layers/attention.py's choice).
@@ -37,61 +47,25 @@ _DEF_BLOCK_Q = 1024  # tuned on v5e: 16k-seq causal attn 21.5ms vs 84ms at 128
 _DEF_BLOCK_K = 1024
 
 
-def _window_kv_blocks(qi, bq: int, bk: int, window: int):
-    """First and last key block a causal window lets query block ``qi``
-    see: keys ``qi * bq - (window - 1)`` to ``qi * bq + bq - 1``. Python
-    ints or traced scalars."""
-    first = qi * bq - (window - 1)
-    lo = (max(first, 0) if isinstance(first, int)
-          else jnp.maximum(first, 0)) // bk
-    return lo, (qi * bq + bq - 1) // bk
-
-
-def _window_q_blocks(ki, bq: int, bk: int, window: int, nq: int):
-    """First and last query block that sees key block ``ki`` under a
-    causal window: queries ``ki * bk`` to ``ki * bk + bk - 1 + window -
-    1``, inside the sequence."""
-    lo = (ki * bk) // bq
-    last = (ki * bk + bk + window - 2) // bq
-    return lo, (min(last, nq - 1) if isinstance(last, int)
-                else jnp.minimum(last, nq - 1))
-
-
-def _span(first_last, blocks: int) -> int:
-    """The widest run of blocks ``first_last(i)`` gives over ``blocks``
-    outer blocks: the extent of a windowed kernel's inner grid axis."""
-    widest = 1
-    for i in range(blocks):
-        lo, hi = first_last(i)
-        widest = max(widest, hi - lo + 1)
-    return widest
-
-
-def _kv_step(qi, kj, bq: int, bk: int, causal: bool,
-             window: Optional[int]):
-    """``(key block, whether its tile holds a visible pair)`` of step
-    ``kj`` of the inner axis of a kernel that streams key blocks past
-    query block ``qi``."""
-    if window is not None:
-        lo, hi = _window_kv_blocks(qi, bq, bk, window)
-        return lo + kj, lo + kj <= hi
-    # causal: tiles fully above the diagonal contribute nothing
-    return kj, ((kj * bk <= (qi + 1) * bq - 1) if causal
-                else (kj == kj))  # always-true traced pred
+def _masked(s, vis: Visibility, qi, ki, bq: int, bk: int):
+    """The (bq, bk) score tile with the pairs ``vis`` hides at ``_NEG``."""
+    seen = vis.tile_visible(qi, ki, bq, bk)
+    return s if seen is None else jnp.where(seen, s, _NEG)
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
-                      m_scr, l_scr, acc_scr, *, causal: bool,
-                      scale: float, window: Optional[int] = None):
+                      m_scr, l_scr, acc_scr, *, vis: Visibility,
+                      scale: float):
     """One (q-block, k-block) tile of the online softmax. The k-block
     axis is the innermost SEQUENTIAL grid dim; the running (m, l, acc)
     live in VMEM scratch across its iterations, so K/V stream from HBM
     block by block and VMEM stays O(block) at any sequence length (the
     pre-round-4 kernel kept the whole K/V resident and died at 16k).
-    Under a ``window`` that axis runs over the key blocks the window
-    reaches only (``_window_kv_blocks``): step ``kj`` is key block ``lo +
-    kj``, and steps past the last such block do nothing and fetch
-    nothing (their index is clamped to the block already held)."""
+    That axis runs over the key blocks ``vis`` lets the query block see
+    (``vis.kv_tile``: all of them, or under a window or the
+    block-diffusion layout the runs that hold a visible pair); steps past
+    a query block's last such block do nothing and fetch nothing (their
+    index is clamped to the block already held)."""
     qi = pl.program_id(2)
     kj = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -104,7 +78,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    ki, live = _kv_step(qi, kj, bq, bk, causal, window)
+    ki, live = vis.kv_tile(qi, kj, bq, bk)
 
     @pl.when(live)
     def _tile():
@@ -114,10 +88,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         kvalid = mask_ref[0, 0] > 0.0
         s = jnp.where(kvalid[None, :], s, _NEG)
-        if causal:
-            qpos = qi * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            kpos = ki * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(_visible(qpos, kpos, window), s, _NEG)
+        s = _masked(s, vis, qi, ki, bq, bk)
         m_prev = m_scr[:, :1]                              # (bq, 1)
         l_prev = l_scr[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -141,15 +112,6 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
         o = jnp.where(valid, acc_scr[...] / l_safe, 0.0)
         o_ref[0, 0] = o.astype(o_ref.dtype)
         lse_ref[0, 0] = jnp.where(valid, m + jnp.log(l_safe), _NEG)
-
-
-def _visible(qpos, kpos, window: Optional[int]):
-    """Causal visibility: a query sees itself and what came before, under
-    a ``window`` only the ``window - 1`` positions before itself."""
-    seen = kpos <= qpos
-    if window is not None:
-        seen = seen & (kpos > qpos - window)
-    return seen
 
 
 def pallas_interpret() -> bool:
@@ -186,47 +148,17 @@ def _dim_sem(n: int, vmem_limit_bytes: Optional[int] = None):
         vmem_limit_bytes=vmem_limit_bytes)
 
 
-def _kv_index(bq: int, bk: int, window: Optional[int]):
-    """Key block of grid step ``(qi, kj)`` for the index maps of the
-    kernels whose inner axis runs over key blocks: ``kj`` itself, or under
-    a window the ``kj``-th block the window reaches, clamped to the last
-    (a repeated index fetches nothing)."""
-    if window is None:
-        return lambda qi, kj: kj
-
-    def block(qi, kj):
-        lo, hi = _window_kv_blocks(qi, bq, bk, window)
-        return jnp.minimum(lo + kj, hi)
-    return block
-
-
-def _q_index(bq: int, bk: int, window: Optional[int], nq: int):
-    """The same for the dK/dV kernel, whose inner axis runs over the
-    query blocks that see key block ``ki``."""
-    if window is None:
-        return lambda ki, qj: qj
-
-    def block(ki, qj):
-        lo, hi = _window_q_blocks(ki, bq, bk, window, nq)
-        return jnp.minimum(lo + qj, hi)
-    return block
-
-
-def _flash_forward(q, k, v, mask, causal: bool, block_q: int, block_k: int,
-                   interpret: bool, window: Optional[int] = None):
+def _flash_forward(q, k, v, mask, vis: Visibility, block_q: int,
+                   block_k: int, interpret: bool):
     n, h, tq, dh = q.shape
     tk, dv = k.shape[2], v.shape[3]
     scale = 1.0 / float(dh) ** 0.5  # host-sync-ok: static shape
     nq, nk = tq // block_q, tk // block_k
-    if window is not None:
-        nk = _span(lambda qi: _window_kv_blocks(qi, block_q, block_k,
-                                                window), nq)
-    grid = (n, h, nq, nk)
-    kb = _kv_index(block_q, block_k, window)
+    grid = (n, h, nq, vis.kv_steps(nq, nk, block_q, block_k))
+    kb = functools.partial(vis.kv_fetch, bq=block_q, bk=block_k)
     vm = pl.ANY if interpret else pltpu.VMEM
 
-    kernel = functools.partial(_flash_fwd_kernel, causal=causal,
-                               scale=scale, window=window)
+    kernel = functools.partial(_flash_fwd_kernel, vis=vis, scale=scale)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -271,31 +203,28 @@ def _flash_forward(q, k, v, mask, causal: bool, block_q: int, block_k: int,
     return out, lse[..., 0]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
-def _flash_attention(q, k, v, mask, causal, block_q, block_k, interpret,
-                     bwd_impl, window):
-    out, _ = _flash_forward(q, k, v, mask, causal, block_q, block_k,
-                            interpret, window)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_attention(q, k, v, mask, vis, block_q, block_k, interpret,
+                     bwd_impl):
+    out, _ = _flash_forward(q, k, v, mask, vis, block_q, block_k, interpret)
     return out
 
 
-def _flash_fwd_rule(q, k, v, mask, causal, block_q, block_k, interpret,
-                    bwd_impl, window):
-    out, lse = _flash_forward(q, k, v, mask, causal, block_q, block_k,
-                              interpret, window)
+def _flash_fwd_rule(q, k, v, mask, vis, block_q, block_k, interpret,
+                    bwd_impl):
+    out, lse = _flash_forward(q, k, v, mask, vis, block_q, block_k,
+                              interpret)
     return out, (q, k, v, mask, out, lse)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
                           delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
-                          causal: bool, scale: float,
-                          window: Optional[int] = None,
-                          q_blocks: int = 0):
+                          vis: Visibility, scale: float, q_blocks: int = 0):
     """dK/dV for one key block: the query-block axis is the innermost
     sequential grid dim, accumulating into VMEM scratch — P is recomputed
-    from the saved logsumexp, never materialized in HBM. Under a
-    ``window`` that axis runs over the query blocks that see the key
-    block only (``_window_q_blocks`` of the ``q_blocks`` there are)."""
+    from the saved logsumexp, never materialized in HBM. That axis runs
+    over the query blocks that see the key block (``vis.q_tile``, of the
+    ``q_blocks`` there are)."""
     ki = pl.program_id(2)
     qj = pl.program_id(3)
     nq = pl.num_programs(3)
@@ -307,13 +236,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    if window is not None:
-        lo, hi = _window_q_blocks(ki, bq, bk, window, q_blocks)
-        qi = lo + qj
-        live = qi <= hi
-    else:
-        qi = qj
-        live = ((qi + 1) * bq - 1 >= ki * bk) if causal else (qi == qi)
+    qi, live = vis.q_tile(ki, qj, bq, bk, q_blocks)
 
     @pl.when(live)
     def _tile():
@@ -325,10 +248,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
         delta = delta_ref[0, 0]
         s = jnp.dot(q, kb.T, preferred_element_type=jnp.float32) * scale
         s = jnp.where(mask_ref[0, 0][None, :] > 0.0, s, _NEG)
-        if causal:
-            qpos = qi * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            kpos = ki * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(_visible(qpos, kpos, window), s, _NEG)
+        s = _masked(s, vis, qi, ki, bq, bk)
         p = jnp.exp(s - lse)
         p = jnp.where(lse > (_NEG * 0.5), p, 0.0)          # (bq, bk)
         dv_scr[...] += lax.dot_general(
@@ -347,11 +267,10 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
-                         delta_ref, dq_ref, dq_scr, *, causal: bool,
-                         scale: float, window: Optional[int] = None):
+                         delta_ref, dq_ref, dq_scr, *, vis: Visibility,
+                         scale: float):
     """dQ for one query block: key blocks stream on the sequential grid
-    dim (under a ``window``, those it reaches: the forward kernel's
-    axis), accumulating into VMEM scratch."""
+    dim (the forward kernel's axis), accumulating into VMEM scratch."""
     qi = pl.program_id(2)
     kj = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -362,7 +281,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    ki, live = _kv_step(qi, kj, bq, bk, causal, window)
+    ki, live = vis.kv_tile(qi, kj, bq, bk)
 
     @pl.when(live)
     def _tile():
@@ -374,10 +293,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
         v = v_ref[0, 0].astype(jnp.float32)
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         s = jnp.where(mask_ref[0, 0][None, :] > 0.0, s, _NEG)
-        if causal:
-            qpos = qi * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            kpos = ki * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(_visible(qpos, kpos, window), s, _NEG)
+        s = _masked(s, vis, qi, ki, bq, bk)
         p = jnp.exp(s - lse)
         p = jnp.where(lse > (_NEG * 0.5), p, 0.0)
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
@@ -390,9 +306,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
         dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
 
 
-def _flash_backward_pallas(q, k, v, mask, out, lse, do, causal: bool,
-                           block_q: int, block_k: int, interpret: bool,
-                           window: Optional[int] = None):
+def _flash_backward_pallas(q, k, v, mask, out, lse, do, vis: Visibility,
+                           block_q: int, block_k: int, interpret: bool):
     """Pallas dq/dk/dv (VERDICT r3 #2 — both passes in kernels, like the
     reference's CudnnLSTMHelper accelerating fwd AND bwd). The tiny
     delta = rowsum(dO ⊙ O) precompute stays in XLA (one fused elementwise
@@ -406,17 +321,13 @@ def _flash_backward_pallas(q, k, v, mask, out, lse, do, causal: bool,
     mask3 = mask[:, None, :]                               # (n, 1, tk)
     vm = pl.ANY if interpret else pltpu.VMEM
     nq, nk = tq // block_q, tk // block_k
-    nq_inner, nk_inner = nq, nk
-    if window is not None:
-        nq_inner = _span(lambda ki: _window_q_blocks(
-            ki, block_q, block_k, window, nq), nk)
-        nk_inner = _span(lambda qi: _window_kv_blocks(
-            qi, block_q, block_k, window), nq)
-    qb = _q_index(block_q, block_k, window, nq)
-    kb = _kv_index(block_q, block_k, window)
+    nq_inner = vis.q_steps(nq, nk, block_q, block_k)
+    nk_inner = vis.kv_steps(nq, nk, block_q, block_k)
+    qb = functools.partial(vis.q_fetch, bq=block_q, bk=block_k, nq=nq)
+    kb = functools.partial(vis.kv_fetch, bq=block_q, bk=block_k)
 
-    kernel = functools.partial(_flash_bwd_dkv_kernel, causal=causal,
-                               scale=scale, window=window, q_blocks=nq)
+    kernel = functools.partial(_flash_bwd_dkv_kernel, vis=vis, scale=scale,
+                               q_blocks=nq)
     dk, dv_ = pl.pallas_call(
         kernel,
         grid=(n, h, nk, nq_inner),
@@ -463,8 +374,7 @@ def _flash_backward_pallas(q, k, v, mask, out, lse, do, causal: bool,
         interpret=interpret,
     )(q, k, v, mask3, do, lse4, delta)
 
-    kernel = functools.partial(_flash_bwd_dq_kernel, causal=causal,
-                               scale=scale, window=window)
+    kernel = functools.partial(_flash_bwd_dq_kernel, vis=vis, scale=scale)
     dq = pl.pallas_call(
         kernel,
         grid=(n, h, nq, nk_inner),
@@ -502,8 +412,7 @@ def _flash_backward_pallas(q, k, v, mask, out, lse, do, causal: bool,
     return dq, dk, dv_
 
 
-def _flash_bwd_rule(causal, block_q, block_k, interpret, bwd_impl, window,
-                    res, do):
+def _flash_bwd_rule(vis, block_q, block_k, interpret, bwd_impl, res, do):
     """Flash backward from saved (O, logsumexp) — dq/dk/dv Pallas kernels
     (``_flash_backward_pallas``); P is recomputed from the normalizer
     instead of being saved. ``bwd_impl`` ("pallas"/"xla", the explicit
@@ -519,15 +428,12 @@ def _flash_bwd_rule(causal, block_q, block_k, interpret, bwd_impl, window,
         bwd_impl = os.environ.get("DL4J_FLASH_BWD", "pallas")
     if bwd_impl != "xla":
         dq, dk, dv = _flash_backward_pallas(
-            q, k, v, mask, out, lse, do, causal, block_q, block_k,
-            interpret, window)
+            q, k, v, mask, out, lse, do, vis, block_q, block_k, interpret)
         return dq, dk, dv, jnp.zeros_like(mask)
-    return _flash_bwd_xla(causal, block_q, block_k, interpret, res, do,
-                          window)
+    return _flash_bwd_xla(vis, block_q, block_k, interpret, res, do)
 
 
-def _flash_bwd_xla(causal, block_q, block_k, interpret, res, do,
-                   window: Optional[int] = None):
+def _flash_bwd_xla(vis: Visibility, block_q, block_k, interpret, res, do):
     """jnp/scan blockwise backward: the pre-round-4 VJP, kept as the
     reference implementation the Pallas kernels are tested against.
     Chunked over k blocks with lax.scan so peak memory is
@@ -546,10 +452,10 @@ def _flash_bwd_xla(causal, block_q, block_k, interpret, res, do,
         s = jnp.einsum("nhqd,nhkd->nhqk", qf, ks) * scale
         mk = lax.dynamic_slice_in_dim(mask, kb * block_k, block_k, axis=1)
         s = jnp.where(mk[:, None, None, :] > 0, s, _NEG)
-        if causal:
+        if vis != Visibility():         # something is hidden
             qpos = jnp.arange(tq)[:, None]
             kpos = kb * block_k + jnp.arange(block_k)[None, :]
-            s = jnp.where(_visible(qpos, kpos, window), s, _NEG)
+            s = jnp.where(vis.visible(qpos, kpos), s, _NEG)
         p = jnp.exp(s - lse[..., None])
         # fully-masked rows carry lse == _NEG: exp(s - lse) degenerates to
         # 1 there; their true probabilities (and grads) are zero
@@ -583,7 +489,7 @@ def _pad_len(t: int, block: int) -> int:
     return (-t) % block
 
 
-def _default_blocks(head_dim: int, window: Optional[int] = None):
+def _default_blocks(head_dim: int, vis: Visibility = Visibility()):
     """The tile sizes for a head of ``head_dim``: the tuned 1024 x 1024
     while the dK/dV kernel's float32 working set fits Mosaic's scoped
     VMEM, else ``block_q`` halved until it does. That working set is two
@@ -593,18 +499,21 @@ def _default_blocks(head_dim: int, window: Optional[int] = None):
     are refused; 1024 x 1024 x 128, 512 x 1024 x 256 and 256 x 1024 x 512
     compile).
 
-    Under a causal ``window`` neither side of the tile is wider than the
-    window (in whole lanes of 128): a head of 64 with a window of 512
-    takes 512 x 512. A query block of ``block_q`` rows reaches ``block_q
-    + window - 1`` keys and the kernel visits the whole key blocks that
-    hold them, so a key block wider than the window wastes the skip:
-    1024 x 1024 would visit 2,048 keys a row where 512 are wanted (4
-    times the work), 512 x 512 visits 1,024 (twice), and narrower tiles
-    (256 x 256: 768) pay more grid steps and half-filled matrix units
-    for what they save."""
+    ``vis`` may narrow the tile (``tile_side``). Under a causal window
+    neither side of the tile is wider than the window (in whole lanes of
+    128): a head of 64 with a window of 512 takes 512 x 512. A query
+    block of ``block_q`` rows reaches ``block_q + window - 1`` keys and
+    the kernel visits the whole key blocks that hold them, so a key block
+    wider than the window wastes the skip: 1024 x 1024 would visit 2,048
+    keys a row where 512 are wanted (4 times the work), 512 x 512 visits
+    1,024 (twice), and narrower tiles (256 x 256: 768) pay more grid
+    steps and half-filled matrix units for what they save. The
+    block-diffusion layout keeps the tuned tile: at 8,192 positions a
+    half 1024 x 1024 tiles visit 80 of 256 (31.3%, the pairs being 25.0%);
+    512 x 512 would visit 28.1% in four times the grid steps."""
     block_q, block_k = _DEF_BLOCK_Q, _DEF_BLOCK_K
-    if window is not None:
-        side = min(block_k, max(128, (window + 127) // 128 * 128))
+    side = vis.tile_side(block_k)
+    if side != block_k:
         block_q = block_k = side
 
     def working_set(bq):
@@ -616,21 +525,20 @@ def _default_blocks(head_dim: int, window: Optional[int] = None):
 
 
 def flash_kv_blocks(tq: int, tk: int, block_q: int, block_k: int,
-                    causal: bool, window: Optional[int] = None):
+                    vis: Visibility):
     """``(visited, total)`` key blocks of one head's forward pass over the
-    kernel's own grid: the (query block, key block) tiles it computes, and
-    all there are. A causal kernel skips the tiles above the diagonal; a
-    windowed one also those wholly before the window."""
+    kernel's own grid: the (query block, key block) tiles it computes, by
+    walking the grid as the kernel does, and all there are. A causal
+    kernel skips the tiles above the diagonal; a windowed one also those
+    wholly before the window; the block-diffusion one every tile but a
+    noisy query block's diagonal and the clean key blocks at or before a
+    query block's own."""
     nq, nk = -(-tq // block_q), -(-tk // block_k)
-    if not causal:
-        return nq * nk, nq * nk
-    visited = 0
+    visited, steps = 0, vis.kv_steps(nq, nk, block_q, block_k)
     for qi in range(nq):
-        hi = min(nk - 1, (qi * block_q + block_q - 1) // block_k)
-        lo = 0
-        if window is not None:
-            lo = max(qi * block_q - (window - 1), 0) // block_k
-        visited += hi - lo + 1
+        for kj in range(steps):
+            ki, live = vis.kv_tile(qi, kj, block_q, block_k)
+            visited += bool(live and ki < nk)
     return visited, nq * nk
 
 
@@ -652,25 +560,44 @@ def _publish_kv_blocks(scope: str, visited: int, total: int) -> None:
         reg.gauge(name, help_text).set(value, scope=scope)
 
 
-def flash_attention(q, k, v, mask=None, causal: bool = False,
+def _halves(x, seq_len: int, pad: int, axis: int):
+    """``x``, whose ``axis`` holds ``[noisy | clean]`` of ``seq_len``
+    each, with ``pad`` zeros after each half."""
+    shape = x.shape
+    x = x.reshape(shape[:axis] + (2, seq_len) + shape[axis + 1:])
+    widths = [(0, 0)] * x.ndim
+    widths[axis + 1] = (0, pad)
+    x = jnp.pad(x, widths)
+    return x.reshape(shape[:axis] + (2 * (seq_len + pad),)
+                     + shape[axis + 1:])
+
+
+def flash_attention(q, k, v, mask=None,
+                    visibility: Visibility = Visibility(),
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
                     bwd_impl: Optional[str] = None,
-                    window: Optional[int] = None,
                     scope: Optional[str] = None):
     """Blockwise (flash) attention on (N, T, H, Dh) tensors; ``v`` may
     have a head size of its own (N, T, H, Dv).
 
     Drop-in for nn.layers.attention.scaled_dot_product_attention. ``mask``
-    is the (N, T_k) key-validity mask. Sequences are padded to the block
-    size internally (padding is masked out, query padding sliced off).
-    ``window`` (causal only) lets a query see itself and the ``window -
-    1`` positions before it; key blocks wholly outside it are neither
-    fetched nor computed, and a window of the whole sequence or more is
-    the causal kernel. ``block_q`` / ``block_k`` default to
-    ``_default_blocks`` of the head size and the window. ``interpret``
-    defaults to ``pallas_interpret()``. ``bwd_impl`` selects the backward
+    is the (N, T_k) key-validity mask. Which keys a query sees is one
+    value, ``visibility`` (``ops/visibility.py``): ``Visibility()`` every
+    key, ``Causal()`` itself and what came before, ``Causal(window)`` of
+    those itself and the ``window - 1`` before it, ``BlockDiffusion(T,
+    B)`` the noisy and the clean copy of a sequence in one pass of ``2 T``
+    positions. The tiles that hold no visible pair are not computed and, but for the plain causal
+    kernel's, not fetched; a window of the whole sequence or more is the
+    causal kernel.
+
+    Sequences are padded to the block size internally (padding is masked
+    out, query padding sliced off); under ``BlockDiffusion`` each half is
+    padded. ``block_q`` / ``block_k`` default to ``_default_blocks`` of the
+    head size and the visibility; under ``BlockDiffusion`` they are
+    rounded to multiples of the block. ``interpret`` defaults to
+    ``pallas_interpret()``. ``bwd_impl`` selects the backward
     implementation explicitly ("pallas" kernels or the "xla" jnp/scan
     reference); None defers to the ``DL4J_FLASH_BWD`` env override
     (default pallas). ``scope`` names the caller's ``jax.named_scope``:
@@ -682,27 +609,35 @@ def flash_attention(q, k, v, mask=None, causal: bool = False,
                          f"got {bwd_impl!r}")
     if interpret is None:
         interpret = pallas_interpret()
+    vis = visibility
     n, tq, h, dh = q.shape
     tk = k.shape[1]
-    if window is not None:
-        if not causal or window < 1 or tq != tk:
+    if isinstance(vis, Causal) and vis.window is not None:
+        if vis.window < 1 or tq != tk:
             raise ValueError(
-                f"window={window} needs causal self-attention (causal="
-                f"{causal}, {tq} queries on {tk} keys) and a window >= 1")
-        if window >= tk:
-            window = None
-    auto_q, auto_k = _default_blocks(max(dh, v.shape[-1]), window)
-    block_q = min(block_q or auto_q, max(tq, 1))
-    block_k = min(block_k or auto_k, max(tk, 1))
-    if not interpret:
-        # Mosaic constraints: q blocks land in the sublane dim (multiple
-        # of 8); the mask's dynamic k-slice is in the lane dim (multiple
-        # of 128). Sequences are padded up to the block size below.
-        block_q = max(8, (block_q + 7) // 8 * 8)
-        block_k = max(128, (block_k + 127) // 128 * 128)
-    if scope is not None:
-        _publish_kv_blocks(scope, *flash_kv_blocks(
-            tq, tk, block_q, block_k, causal, window))
+                f"{vis} needs self-attention ({tq} queries on {tk} keys) "
+                "and a window >= 1")
+        if vis.window >= tk:
+            vis = Causal()
+    diffusion = isinstance(vis, BlockDiffusion)
+    if diffusion and not tq == tk == 2 * vis.seq_len:
+        raise ValueError(
+            f"{vis} needs self-attention over 2 * seq_len positions; got "
+            f"{tq} queries on {tk} keys")
+    auto_q, auto_k = _default_blocks(max(dh, v.shape[-1]), vis)
+    half_q, half_k = (tq // 2, tk // 2) if diffusion else (tq, tk)
+    block_q = min(block_q or auto_q, max(half_q, 1))
+    block_k = min(block_k or auto_k, max(half_k, 1))
+    # Mosaic constraints: q blocks land in the sublane dim (multiple of
+    # 8); the mask's dynamic k-slice is in the lane dim (multiple of
+    # 128). Sequences are padded up to the block size below.
+    unit_q, unit_k = (1, 1) if interpret else (8, 128)
+    if diffusion:       # a tile cuts no block of the diffusion
+        unit_q = math.lcm(unit_q, vis.block)
+        unit_k = math.lcm(unit_k, vis.block)
+    if not interpret or diffusion:
+        block_q = max(unit_q, -(-block_q // unit_q) * unit_q)
+        block_k = max(unit_k, -(-block_k // unit_k) * unit_k)
 
     # NTHD -> NHTD
     qt = jnp.swapaxes(q, 1, 2)
@@ -712,17 +647,33 @@ def flash_attention(q, k, v, mask=None, causal: bool = False,
         mask = jnp.ones((n, tk), jnp.float32)
     mask = mask.astype(jnp.float32)
 
-    pq, pk = _pad_len(tq, block_q), _pad_len(tk, block_k)
-    if pq:
-        qt = jnp.pad(qt, ((0, 0), (0, 0), (0, pq), (0, 0)))
-    if pk:
-        kt = jnp.pad(kt, ((0, 0), (0, 0), (0, pk), (0, 0)))
-        vt = jnp.pad(vt, ((0, 0), (0, 0), (0, pk), (0, 0)))
-        mask = jnp.pad(mask, ((0, 0), (0, pk)))
+    if diffusion:
+        # both tiles divide a half: each half is padded to their multiple
+        t = vis.seq_len
+        pad = _pad_len(t, math.lcm(block_q, block_k))
+        if pad:
+            qt, kt, vt = (_halves(a, t, pad, 2) for a in (qt, kt, vt))
+            mask = _halves(mask, t, pad, 1)
+            vis = BlockDiffusion(t + pad, vis.block)
+    else:
+        pq, pk = _pad_len(tq, block_q), _pad_len(tk, block_k)
+        if pq:
+            qt = jnp.pad(qt, ((0, 0), (0, 0), (0, pq), (0, 0)))
+        if pk:
+            kt = jnp.pad(kt, ((0, 0), (0, 0), (0, pk), (0, 0)))
+            vt = jnp.pad(vt, ((0, 0), (0, 0), (0, pk), (0, 0)))
+            mask = jnp.pad(mask, ((0, 0), (0, pk)))
+    if scope is not None:
+        _publish_kv_blocks(scope, *flash_kv_blocks(
+            qt.shape[2], kt.shape[2], block_q, block_k, vis))
 
-    out = _flash_attention(qt, kt, vt, mask, causal, block_q, block_k,
-                           interpret, bwd_impl, window)
-    if pq:
+    out = _flash_attention(qt, kt, vt, mask, vis, block_q, block_k,
+                           interpret, bwd_impl)
+    if diffusion:
+        if pad:
+            out = out.reshape(n, h, 2, t + pad, -1)[:, :, :, :t].reshape(
+                n, h, 2 * t, -1)
+    elif pq:
         out = out[:, :, :tq, :]
     return jnp.swapaxes(out, 1, 2)                          # NHTD -> NTHD
 
@@ -738,23 +689,23 @@ def flash_attention(q, k, v, mask=None, causal: bool = False,
 _FLASH_MIN_SEQ = 1024
 
 
-def attention(q, k, v, mask=None, causal: bool = False,
+def attention(q, k, v, mask=None, visibility: Visibility = Visibility(),
               prefer_flash: Optional[bool] = None,
-              window: Optional[int] = None, scope: Optional[str] = None):
+              scope: Optional[str] = None):
     """Helper-SPI dispatch (the reflective cuDNN-hook analog): the
     Pallas kernel on TPU when the sequence is long enough to pay for
     streaming (``flash_attention`` pads to its block size, so any length
     is block-aligned), else the plain XLA lowering. The choice rests on
-    the inputs alone; a kernel the compiler refuses raises. ``window``
-    (causal only: a query sees itself and the ``window - 1`` positions
-    before it) is honoured by both; ``scope`` is ``flash_attention``'s."""
+    the inputs alone; a kernel the compiler refuses raises. Which keys a
+    query sees (``visibility``, ``flash_attention``'s) is honoured by
+    both; ``scope`` is ``flash_attention``'s."""
     from deeplearning4j_tpu.nn.layers.attention import (
         scaled_dot_product_attention)
     if prefer_flash is None:
         prefer_flash = (jax.default_backend() == "tpu"
                         and max(q.shape[1], k.shape[1]) >= _FLASH_MIN_SEQ)
     if prefer_flash:
-        return flash_attention(q, k, v, mask=mask, causal=causal,
-                               window=window, scope=scope)
-    return scaled_dot_product_attention(q, k, v, mask=mask, causal=causal,
-                                        window=window)
+        return flash_attention(q, k, v, mask=mask, visibility=visibility,
+                               scope=scope)
+    return scaled_dot_product_attention(q, k, v, mask=mask,
+                                        visibility=visibility)

@@ -177,19 +177,45 @@ def moe_ffn(x: jnp.ndarray,
 ROUTING_COUNTERS = ("assignments_held", "load_max", "load_mean", "dropped")
 
 
-def route_top_k_probs(x, router_w, top_k: int, norm_topk: bool = True):
-    """Softmax over ALL experts in float32, the ``top_k`` largest and, with
-    ``norm_topk``, their weights renormalised to sum 1. ``x`` (T, d),
-    ``router_w`` (d, E). Returns ``(expert ids (T, k) int32, weights
-    (T, k) float32)``."""
+def router_probs(x, router_w):
+    """The router's float32 softmax over ALL experts: ``x`` (T, d),
+    ``router_w`` (d, E) -> (T, E)."""
     logits = jnp.einsum("td,de->te", x, router_w.astype(x.dtype),
                         preferred_element_type=jnp.promote_types(
                             jnp.float32, x.dtype))
-    probs = jax.nn.softmax(logits, -1)
+    return jax.nn.softmax(logits, -1)
+
+
+def top_k_weights(probs, top_k: int, norm_topk: bool = True):
+    """The ``top_k`` largest of ``probs`` (T, E) and, with ``norm_topk``,
+    their weights renormalised to sum 1. Returns ``(expert ids (T, k)
+    int32, weights (T, k) float32)``."""
     weights, ids = jax.lax.top_k(probs, top_k)
     if norm_topk:
         weights = weights / jnp.sum(weights, -1, keepdims=True)
     return ids.astype(jnp.int32), weights
+
+
+def route_top_k_probs(x, router_w, top_k: int, norm_topk: bool = True):
+    """``top_k_weights`` of ``router_probs``: softmax over ALL experts in
+    float32, then the ``top_k`` largest."""
+    return top_k_weights(router_probs(x, router_w), top_k, norm_topk)
+
+
+def load_balancing_loss(probs, ids):
+    """The load-balancing auxiliary loss of a top-k router (Switch
+    Transformer, arXiv:2101.03961 eq. 4-6, as the Mixtral / Qwen3-MoE
+    trainers compute it for one layer): ``E * sum_e f_e P_e`` with ``f_e``
+    the assignments expert e received over the tokens (so the ``f_e`` sum
+    to k) and ``P_e`` the mean of its router probability. ``probs`` (T, E),
+    ``ids`` (T, k). It reads k under an even router and ``E`` where every
+    token picks the same k experts with all of its probability; the
+    gradient reaches the router through ``P_e`` alone. All E outputs
+    count, whichever experts are held."""
+    t, e = probs.shape
+    share = jnp.sum(ids[..., None] == jnp.arange(e, dtype=ids.dtype),
+                    (0, 1), dtype=probs.dtype) / t
+    return e * jnp.sum(share * jnp.mean(probs, 0))
 
 
 # the shortest block of the held experts' loop: toy shapes run one block
@@ -365,7 +391,8 @@ def _held_blocks(block: int, top_k: int):
 
 
 def held_experts_ffn(x, router_w, w_gate, w_up, w_down, held, *,
-                     top_k: int, norm_topk: bool = True):
+                     top_k: int, norm_topk: bool = True,
+                     balance: bool = False):
     """The held experts' part of a top-k mixture of gated experts,
     ``sum_{i in top-k(x), i held} p_i W_down_i (silu(W_gate_i x) *
     W_up_i x)``, with no token dropped.
@@ -374,7 +401,8 @@ def held_experts_ffn(x, router_w, w_gate, w_up, w_down, held, *,
     (G, d, f) and w_down: (G, f, d) for the G experts held here, whose
     ids among the E are ``held`` (a static tuple, in the weights' order).
     Returns ``(y (T, d) in x's type, counters float32[4])``, the counters
-    in ``ROUTING_COUNTERS``' order.
+    in ``ROUTING_COUNTERS``' order, and with ``balance`` a third value, the
+    router's ``load_balancing_loss`` over all E outputs.
 
     The T * k assignments are sorted by held expert (those of absent
     experts last). The held ones go through a loop, ``dispatch_block``
@@ -393,7 +421,8 @@ def held_experts_ffn(x, router_w, w_gate, w_up, w_down, held, *,
     rows = t * min(top_k, g)
     block = dispatch_block(t, top_k, g, e)
     with jax.named_scope("moe.route"):
-        ids, weights = route_top_k_probs(x, router_w, top_k, norm_topk)
+        probs = router_probs(x, router_w)
+        ids, weights = top_k_weights(probs, top_k, norm_topk)
         # which held expert an assignment is, G where it is an absent
         # one's: by comparison, a table lookup of T * k integers takes the
         # TPU longer than a grouped product
@@ -419,4 +448,7 @@ def held_experts_ffn(x, router_w, w_gate, w_up, w_down, held, *,
     counters = jnp.stack([
         n_held, jnp.max(sizes), n_held / g,
         n_held - jnp.minimum(n_held, rows)]).astype(jnp.float32)
+    if balance:
+        with jax.named_scope("moe.route"):
+            return y, counters, load_balancing_loss(probs, ids)
     return y, counters

@@ -144,7 +144,9 @@ def _ulysses_local(q, k, v, mask, axis_name: str, causal: bool):
     mg = (None if mask is None
           else lax.all_gather(mask, axis_name, axis=1, tiled=True))
     from deeplearning4j_tpu.ops.pallas_kernels import attention
-    o = attention(qg, kg, vg, mask=mg, causal=causal)
+    from deeplearning4j_tpu.ops.visibility import Causal, Visibility
+    o = attention(qg, kg, vg, mask=mg,
+                  visibility=Causal() if causal else Visibility())
     return a2a(o, split_axis=1, concat_axis=2)  # (N, T/P, H, Dh)
 
 

@@ -532,6 +532,109 @@ class Qwen3Next(ZooModel):
 
 
 @dataclasses.dataclass
+class SDARMoE(ZooModel):
+    """Block-diffusion mixture-of-experts language model of the SDAR
+    family, trained (published config: huggingface.co/JetLM/
+    SDAR-30B-A3B-Chat, ``config.json``, ``model_type: sdar_moe``;
+    arXiv:2510.06303, whose training layout is BD3-LM's, arXiv:2503.09573;
+    the field names below are the config's keys). A Qwen3-MoE decoder:
+    ``num_hidden_layers`` blocks of grouped-query attention (per-head q/k
+    RMSNorm, rotary on the whole head, no biases, no gate) and a mixture
+    of gated experts with no shared expert; RMSNorm (zero-centred), an
+    untied head. It is trained as a masked diffusion over blocks of
+    ``block_length``: autoregressive from block to block, bidirectional
+    and masked inside a block.
+
+    Features are ``[xt | x0]`` (N, 2 ``seq_len``), the noisy and the clean
+    copy of a row of ``seq_len`` ids in one pass under
+    ``ops.visibility.BlockDiffusion``; labels (N, ``seq_len``, 2) carry
+    the hidden ids and their ``1 / t_n`` weights. Both come from
+    ``datasets.diffusion.BlockDiffusionNoiser(mask_token_id)`` set on the
+    iterator of clean rows (``mask_token_id`` default: the last id of the
+    vocabulary held; data ids lie below it).
+
+    ``held_experts`` (default: all) and a sliced ``vocab_size`` are an
+    expert-parallel deployment's share, as ``Qwen3Next``'s.
+    ``router_aux_loss_coef`` above 0 adds that many times each layer's
+    load-balancing loss over all ``num_experts`` router outputs to the
+    training loss (the family's key; its trainers compute the loss once
+    over the layers' tokens together, here each layer adds its own). Not
+    in the model: dropout, generation (a decode step of this family
+    yields a block, not a token)."""
+    vocab_size: int = 151936
+    hidden_size: int = 2048
+    num_hidden_layers: int = 48
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 4
+    head_dim: int = 128
+    rope_theta: float = 1e6
+    num_experts: int = 128
+    held_experts: Tuple[int, ...] = ()
+    num_experts_per_tok: int = 8
+    moe_intermediate_size: int = 768
+    norm_topk_prob: bool = True
+    router_aux_loss_coef: float = 0.0
+    rms_norm_eps: float = 1e-6
+    initializer_range: float = 0.02
+    block_length: int = 4
+    mask_token_id: Optional[int] = None
+    seq_len: int = 8192
+    recompute: bool = True
+    compute_dtype: str = "bfloat16"
+    updater: Optional[Updater] = None
+    seed: int = 123
+
+    @property
+    def mask_id(self) -> int:
+        return (self.vocab_size - 1 if self.mask_token_id is None
+                else self.mask_token_id)
+
+    def noiser(self, seed: Optional[int] = None, eps: float = 1e-3):
+        """The pre-processor that makes this model's batches from clean
+        rows: ``iterator.set_pre_processor(model.noiser())``."""
+        from deeplearning4j_tpu.datasets.diffusion import BlockDiffusionNoiser
+        return BlockDiffusionNoiser(self.mask_id, eps=eps,
+                                    seed=self.seed if seed is None else seed)
+
+    def conf(self):
+        from deeplearning4j_tpu.nn.layers.decoder import (
+            BLOCK_DIFFUSION_ATTENTION, CausalLMOutputLayer,
+            HybridDecoderBlock, TokenEmbedding)
+        std = self.initializer_range
+        b = (NeuralNetConfiguration.Builder()
+             .seed(self.seed)
+             .updater(self.updater or Adam(1e-4))
+             .compute_dtype(self.compute_dtype)
+             .list()
+             .layer(TokenEmbedding(name="embed", vocab_size=self.vocab_size,
+                                   n_out=self.hidden_size, init_std=std)))
+        for l in range(self.num_hidden_layers):
+            b = b.layer(HybridDecoderBlock(
+                name=f"block{l}", n_out=self.hidden_size,
+                mixer=BLOCK_DIFFUSION_ATTENTION,
+                n_heads=self.num_attention_heads,
+                n_kv_heads=self.num_key_value_heads, head_dim=self.head_dim,
+                partial_rotary_factor=1.0, rope_theta=self.rope_theta,
+                block_length=self.block_length,
+                num_experts=self.num_experts,
+                held_experts=tuple(self.held_experts),
+                expert_hidden=self.moe_intermediate_size, shared_hidden=0,
+                top_k=self.num_experts_per_tok,
+                norm_topk=self.norm_topk_prob,
+                router_aux_loss_coef=self.router_aux_loss_coef,
+                eps=self.rms_norm_eps, init_std=std,
+                recompute=self.recompute))
+        return (b.layer(CausalLMOutputLayer(
+                    name="lm_head", n_out=self.vocab_size,
+                    eps=self.rms_norm_eps, init_std=std))
+                .set_input_type(InputType.recurrent(1, 2 * self.seq_len))
+                .build())
+
+    def init(self) -> MultiLayerNetwork:
+        return MultiLayerNetwork(self.conf()).init()
+
+
+@dataclasses.dataclass
 class Phi4MiniFlash(ZooModel):
     """Decoder-hybrid-decoder causal language model of the Phi-4-mini-flash
     family (published config: huggingface.co/microsoft/

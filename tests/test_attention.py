@@ -18,6 +18,7 @@ from deeplearning4j_tpu.nn.layers.attention import (
     TransformerEncoderBlock,
     scaled_dot_product_attention,
 )
+from deeplearning4j_tpu.ops.visibility import Causal, Visibility
 from deeplearning4j_tpu.parallel.ring_attention import ring_self_attention
 
 
@@ -36,7 +37,8 @@ class TestRingAttention:
     @pytest.mark.parametrize("causal", [False, True])
     def test_matches_single_chip(self, causal):
         q, k, v = _qkv()
-        want = scaled_dot_product_attention(q, k, v, causal=causal)
+        want = scaled_dot_product_attention(
+            q, k, v, visibility=Causal() if causal else Visibility())
         got = ring_self_attention(q, k, v, _mesh(), causal=causal)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-6)
@@ -181,7 +183,8 @@ class TestUlyssesAttention:
         from deeplearning4j_tpu.parallel.ring_attention import (
             ulysses_self_attention)
         q, k, v = _qkv()
-        want = scaled_dot_product_attention(q, k, v, causal=causal)
+        want = scaled_dot_product_attention(
+            q, k, v, visibility=Causal() if causal else Visibility())
         got = ulysses_self_attention(q, k, v, self._mesh4(), causal=causal)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-6)
@@ -209,7 +212,8 @@ class TestUlyssesAttention:
 
         def loss_ref(q, k, v):
             return jnp.sum(
-                scaled_dot_product_attention(q, k, v, causal=True) ** 2)
+                scaled_dot_product_attention(q, k, v,
+                                             visibility=Causal()) ** 2)
 
         g_sp = jax.grad(loss_sp, argnums=(0, 1, 2))(q, k, v)
         g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)

@@ -230,3 +230,86 @@ def test_a_model_saved_with_four_counters_still_loads(tmp_path):
     assert found == [[7.0, 3.0, 1.75, 0.0, 0.0]] * 2
     ids = np.random.default_rng(0).integers(0, 64, (2, 48))
     assert np.isfinite(np.asarray(again.output(ids))).all()
+
+
+# ---- the router's load-balancing loss ---------------------------------------
+
+def test_the_load_balancing_loss_by_hand_and_at_its_two_ends():
+    from deeplearning4j_tpu.parallel.moe import (load_balancing_loss,
+                                                 top_k_weights)
+    rng = np.random.default_rng(4)
+    t, e, k = 96, 8, 3
+    probs = jax.nn.softmax(jnp.asarray(rng.normal(size=(t, e)), jnp.float32))
+    ids, _ = top_k_weights(probs, k)
+    received = np.zeros(e)
+    for row in np.asarray(ids):
+        for i in row:
+            received[i] += 1
+    assert received.sum() == t * k
+    want = e * float((received / t) @ np.asarray(probs, np.float64).mean(0))
+    assert float(load_balancing_loss(probs, ids)) == pytest.approx(
+        want, rel=1e-6)
+    # an even router reads k; every token on the same k with all of its
+    # probability reads E
+    even = jnp.full((t, e), 1.0 / e)
+    spread = jnp.asarray((np.arange(t)[:, None] + np.arange(k)) % e)
+    assert float(load_balancing_loss(even, spread)) == pytest.approx(k)
+    same = jnp.zeros((t, e)).at[:, :k].set(1.0 / k)
+    assert float(load_balancing_loss(same, top_k_weights(same, k)[0])) == (
+        pytest.approx(e))
+    # the gradient reaches the router through the mean probability alone
+    grad = jax.grad(lambda p: load_balancing_loss(p, ids))(probs)
+    np.testing.assert_allclose(
+        grad, np.broadcast_to(e * received / t / t, (t, e)), rtol=1e-6)
+
+
+@pytest.mark.parametrize("coef", [0.0, 0.25])
+def test_the_layer_adds_the_loss_to_the_training_loss_only_when_asked(coef):
+    from deeplearning4j_tpu.parallel.moe import (load_balancing_loss,
+                                                 router_probs, top_k_weights)
+    layer = HeldExpertsMoE(n_in=D, n_out=D, num_experts=E, hidden=F,
+                           held_experts=HELD, top_k=K, aux_loss_coef=coef)
+    rt = RecurrentType(D, None)
+    params = layer.initialize(jax.random.PRNGKey(0), rt)
+    x = jnp.asarray(np.random.default_rng(1).normal(size=(2, 64, D)),
+                    jnp.float32)
+    y, state = layer.apply(params, layer.init_state(rt), x,
+                           LayerContext(train=True))
+    plain, _ = dataclasses.replace(layer, aux_loss_coef=0.0).apply(
+        params, {}, x, LayerContext(train=True))
+    assert np.array_equal(np.asarray(y), np.asarray(plain))
+    assert set(state) == set(layer.init_state(rt))
+    if not coef:
+        assert set(state) == {"moe_routing"}
+        return
+    probs = router_probs(x.reshape(-1, D), params["router"])
+    want = coef * load_balancing_loss(probs, top_k_weights(probs, K)[0])
+    assert float(state["moe_aux_loss"]) == pytest.approx(float(want),
+                                                         rel=1e-6)
+    # absent experts count: the loss is over all E router outputs
+    assert float(want) / coef >= K
+
+    # and through a model: the score is the head's loss plus the term
+    from deeplearning4j_tpu.zoo.models import SDARMoE
+    tiny = dict(vocab_size=32, hidden_size=16, num_hidden_layers=2,
+                num_attention_heads=2, num_key_value_heads=1, head_dim=8,
+                num_experts=8, held_experts=(0, 1), num_experts_per_tok=2,
+                moe_intermediate_size=8, seq_len=8, block_length=4,
+                compute_dtype="float32", recompute=False)
+    zoo = SDARMoE(router_aux_loss_coef=coef, **tiny)
+    model, bare = zoo.init(), SDARMoE(**tiny).init()
+    from deeplearning4j_tpu.datasets.dataset import DataSet
+    clean = DataSet(np.random.default_rng(2).integers(0, 31, (3, 8)), None)
+    batch = zoo.noiser(seed=3).pre_process(clean)
+    term = sum(float(s["moe_aux_loss"]) for s in jax.device_get(
+        model._loss(model.train_state.params, model.train_state.model_state,
+                    jnp.asarray(batch.features), jnp.asarray(batch.labels),
+                    None, None, None, jnp.zeros((), jnp.int32))[1]).values()
+               if "moe_aux_loss" in s)
+    assert term >= coef * 2 * 2                 # k a layer at the least
+    assert model.score(batch) == pytest.approx(bare.score(batch) + term,
+                                               rel=1e-5)
+    # the coefficient survives the configuration's JSON
+    from deeplearning4j_tpu.nn.config import MultiLayerConfiguration
+    again = MultiLayerConfiguration.from_json(zoo.conf().to_json())
+    assert [l.router_aux_loss_coef for l in again.layers[1:-1]] == [coef] * 2

@@ -15,6 +15,11 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.nn.layers.attention import (
     scaled_dot_product_attention)
 from deeplearning4j_tpu.ops.pallas_kernels import attention, flash_attention
+from deeplearning4j_tpu.ops.visibility import Causal, Visibility
+
+
+def _vis(causal):
+    return Causal() if causal else Visibility()
 
 
 def _qkv(rng, n=2, t=48, h=4, dh=16):
@@ -27,9 +32,9 @@ def _qkv(rng, n=2, t=48, h=4, dh=16):
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_matches_plain_forward(rng, causal):
     q, k, v = _qkv(rng)
-    ref = scaled_dot_product_attention(q, k, v, causal=causal)
-    out = flash_attention(q, k, v, causal=causal, block_q=16, block_k=16,
-                          interpret=True)
+    ref = scaled_dot_product_attention(q, k, v, visibility=_vis(causal))
+    out = flash_attention(q, k, v, visibility=_vis(causal), block_q=16,
+                          block_k=16, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 
@@ -49,8 +54,8 @@ def test_flash_respects_key_mask(rng):
 def test_flash_unaligned_lengths(rng):
     """T not a multiple of the block size exercises the padding path."""
     q, k, v = _qkv(rng, t=37)
-    ref = scaled_dot_product_attention(q, k, v, causal=True)
-    out = flash_attention(q, k, v, causal=True, block_q=16, block_k=16,
+    ref = scaled_dot_product_attention(q, k, v, visibility=Causal())
+    out = flash_attention(q, k, v, visibility=Causal(), block_q=16, block_k=16,
                           interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
@@ -64,12 +69,13 @@ def test_flash_gradients_match(rng, causal):
     mask = jnp.asarray(mask)
 
     def loss_flash(q, k, v):
-        o = flash_attention(q, k, v, mask=mask, causal=causal, block_q=8,
-                            block_k=8, interpret=True)
+        o = flash_attention(q, k, v, mask=mask, visibility=_vis(causal),
+                            block_q=8, block_k=8, interpret=True)
         return jnp.sum(o * o)
 
     def loss_ref(q, k, v):
-        o = scaled_dot_product_attention(q, k, v, mask=mask, causal=causal)
+        o = scaled_dot_product_attention(q, k, v, mask=mask,
+                                         visibility=_vis(causal))
         return jnp.sum(o * o)
 
     gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
@@ -105,7 +111,7 @@ class TestFlashBlockLayout:
             rng.normal(size=(N, T, H, Dh)).astype(np.float32))
         q, k, v = mk(), mk(), mk()
         # call flash_attention directly: any lowering error raises here
-        o = flash_attention(q, k, v, causal=True)
+        o = flash_attention(q, k, v, visibility=Causal())
         s = jnp.einsum("nthd,nshd->nhts", q, k) / np.sqrt(Dh)
         m = jnp.tril(jnp.ones((T, T), bool))
         s = jnp.where(m[None, None], s, -1e30)
@@ -197,7 +203,8 @@ class TestFlashPallasBackward:
 
         def run():
             def f(q, k, v):
-                o = flash_attention(q, k, v, mask=mask, causal=causal,
+                o = flash_attention(q, k, v, mask=mask,
+                                    visibility=_vis(causal),
                                     block_q=16, block_k=16,
                                     interpret=True)
                 return jnp.sum(o * do)
@@ -232,8 +239,8 @@ class TestFlashPallasBackward:
 
         def run(impl):
             def f(q, k, v):
-                o = flash_attention(q, k, v, causal=causal, block_q=16,
-                                    block_k=16, interpret=True,
+                o = flash_attention(q, k, v, visibility=_vis(causal),
+                                    block_q=16, block_k=16, interpret=True,
                                     bwd_impl=impl)
                 return jnp.sum(o * do)
             return jax.grad(f, argnums=(0, 1, 2))(q, k, v)
@@ -257,13 +264,13 @@ class TestFlashPallasBackward:
         mask = jnp.asarray(mask)
 
         def loss_flash(q, k, v):
-            o = flash_attention(q, k, v, mask=mask, causal=True,
+            o = flash_attention(q, k, v, mask=mask, visibility=Causal(),
                                 block_q=8, block_k=8, interpret=True)
             return jnp.sum(jnp.tanh(o))
 
         def loss_ref(q, k, v):
             o = scaled_dot_product_attention(q, k, v, mask=mask,
-                                             causal=True)
+                                             visibility=Causal())
             return jnp.sum(jnp.tanh(o))
 
         gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
@@ -281,7 +288,7 @@ class TestFlashPallasBackward:
         mask = jnp.asarray(mask)
 
         def f(q, k, v):
-            o = flash_attention(q, k, v, mask=mask, causal=False,
+            o = flash_attention(q, k, v, mask=mask,
                                 block_q=8, block_k=8, interpret=True)
             return jnp.sum(o)
 

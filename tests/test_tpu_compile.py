@@ -46,11 +46,12 @@ def test_flash_attention_compiles_with_its_default_tiles(
     bfloat16: BERT-like heads of 64, the gated attention's 16 heads of 256
     (1024 x 1024 tiles are refused there: 18.5 MB of scoped VMEM), and 512."""
     from deeplearning4j_tpu.ops.pallas_kernels import flash_attention
+    from deeplearning4j_tpu.ops.visibility import Causal
     x = jax.ShapeDtypeStruct((1, 8192, heads, head_dim), jnp.bfloat16,
                              sharding=one_chip)
 
     def total(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal=True,
+        return jnp.sum(flash_attention(q, k, v, visibility=Causal(),
                                        interpret=False).astype(jnp.float32))
 
     compiled = jax.jit(jax.grad(total, argnums=(0, 1, 2))).lower(
@@ -100,17 +101,40 @@ def test_differential_attention_kernels_compile_at_the_published_widths(
     value of 128, 8,192 positions, bfloat16; under the window of 512 the
     tiles are 512 x 512 and the inner grid axis two key blocks long."""
     from deeplearning4j_tpu.ops.pallas_kernels import flash_attention
+    from deeplearning4j_tpu.ops.visibility import Causal
     qk = jax.ShapeDtypeStruct((1, 8192, 40, 64), jnp.bfloat16,
                               sharding=one_chip)
     v = jax.ShapeDtypeStruct((1, 8192, 40, 128), jnp.bfloat16,
                              sharding=one_chip)
 
     def total(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal=True, window=window,
+        return jnp.sum(flash_attention(q, k, v, visibility=Causal(window),
                                        interpret=False).astype(jnp.float32))
 
     compiled = jax.jit(jax.grad(total, argnums=(0, 1, 2))).lower(
         qk, qk, v).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def test_block_diffusion_kernels_compile_at_the_published_widths(
+        one_chip, no_compile_cache):
+    """Forward and both backward kernels under ``BlockDiffusion(8192, 4)``
+    as the SDAR cell calls them: 32 heads of 128 over the 16,384 positions
+    ``[noisy | clean]``, bfloat16, the tuned 1024 x 1024 tiles; the tile
+    mask is built from scalar bounds (Mosaic selects no vector of
+    booleans, which interpret mode does not say)."""
+    from deeplearning4j_tpu.ops.pallas_kernels import flash_attention
+    from deeplearning4j_tpu.ops.visibility import BlockDiffusion
+    x = jax.ShapeDtypeStruct((1, 16384, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def total(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, visibility=BlockDiffusion(8192, 4),
+            interpret=False).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(total, argnums=(0, 1, 2))).lower(
+        x, x, x).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 3
 
 

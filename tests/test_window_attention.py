@@ -53,11 +53,11 @@ WINDOWS = [1, 5, 16, 32, 40, T, 200]
 def test_window_values_in_flash_and_xla_equal_the_explicit_mask(window):
     q, k, v = qkv()
     want = naive(q, k, v, window)
-    got = pk.flash_attention(q, k, v, causal=True, window=window,
+    got = pk.flash_attention(q, k, v, visibility=pk.Causal(window),
                              block_q=BLOCK, block_k=BLOCK, interpret=True)
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(
-        scaled_dot_product_attention(q, k, v, causal=True, window=window),
+        scaled_dot_product_attention(q, k, v, visibility=pk.Causal(window)),
         want, rtol=2e-5, atol=2e-5)
 
 
@@ -71,9 +71,9 @@ def test_window_gradients_in_both_backward_passes_equal_xlas(window,
         return lambda *a: jnp.sum(jnp.sin(attn(*a)))
 
     want = jax.grad(through(lambda *a: scaled_dot_product_attention(
-        *a, causal=True, window=window)), argnums=(0, 1, 2))(q, k, v)
+        *a, visibility=pk.Causal(window))), argnums=(0, 1, 2))(q, k, v)
     got = jax.grad(through(lambda *a: pk.flash_attention(
-        *a, causal=True, window=window, block_q=BLOCK, block_k=BLOCK,
+        *a, visibility=pk.Causal(window), block_q=BLOCK, block_k=BLOCK,
         interpret=True, bwd_impl=bwd_impl)), argnums=(0, 1, 2))(q, k, v)
     for g, r in zip(got, want):
         np.testing.assert_allclose(g, r, rtol=2e-4, atol=2e-5)
@@ -85,11 +85,11 @@ def test_window_with_unequal_tiles(block_q, block_k):
 
     def total(*a):
         return jnp.sum(jnp.sin(pk.flash_attention(
-            *a, causal=True, window=20, block_q=block_q, block_k=block_k,
+            *a, visibility=pk.Causal(20), block_q=block_q, block_k=block_k,
             interpret=True)))
 
     want = jax.value_and_grad(lambda *a: jnp.sum(jnp.sin(
-        scaled_dot_product_attention(*a, causal=True, window=20))),
+        scaled_dot_product_attention(*a, visibility=pk.Causal(20)))),
         argnums=(0, 1, 2))(q, k, v)
     got = jax.value_and_grad(total, argnums=(0, 1, 2))(q, k, v)
     for g, r in zip(jax.tree_util.tree_leaves(got),
@@ -102,22 +102,20 @@ def test_a_window_of_the_whole_sequence_is_the_causal_kernel():
 
     def lowered(window):
         return jax.jit(lambda *a: pk.flash_attention(
-            *a, causal=True, window=window, block_q=BLOCK, block_k=BLOCK,
+            *a, visibility=pk.Causal(window), block_q=BLOCK, block_k=BLOCK,
             interpret=True)).lower(q, k, v).as_text()
 
     assert lowered(T) == lowered(None) == lowered(10 * T)
     assert lowered(T - 1) != lowered(None)
 
 
-def test_a_window_needs_causal_self_attention():
+def test_a_window_needs_self_attention_and_a_position():
     q, k, v = qkv()
-    with pytest.raises(ValueError, match="causal"):
-        pk.flash_attention(q, k, v, causal=False, window=8, interpret=True)
-    with pytest.raises(ValueError, match="causal"):
-        scaled_dot_product_attention(q, k, v, causal=False, window=8)
     with pytest.raises(ValueError, match="window"):
-        pk.flash_attention(q, k[:, :64], v[:, :64], causal=True, window=8,
+        pk.flash_attention(q, k[:, :64], v[:, :64], visibility=pk.Causal(8),
                            interpret=True)
+    with pytest.raises(ValueError, match="window >= 1"):
+        pk.flash_attention(q, k, v, visibility=pk.Causal(0), interpret=True)
 
 
 def brute_blocks(t, bq, bk, window):
@@ -139,7 +137,8 @@ def brute_blocks(t, bq, bk, window):
 def test_the_grid_visits_the_blocks_the_window_reaches_and_no_other(
         t, bq, bk, window):
     visited, total, widest_row, widest_col = brute_blocks(t, bq, bk, window)
-    assert pk.flash_kv_blocks(t, t, bq, bk, True, window) == (visited, total)
+    assert pk.flash_kv_blocks(t, t, bq, bk, pk.Causal(window)) == (
+        visited, total)
     if window is not None:
         # the inner grid axes are as long as the widest run, no longer
         nq, nk = t // bq, t // bk
@@ -150,12 +149,12 @@ def test_the_grid_visits_the_blocks_the_window_reaches_and_no_other(
 
 
 def test_window_512_at_8192_tokens_visits_under_a_quarter_of_causal():
-    bq, bk = pk._default_blocks(128, 512)
+    bq, bk = pk._default_blocks(128, pk.Causal(512))
     assert (bq, bk) == (512, 512)           # no tile wider than the window
-    assert pk._default_blocks(128, None) == (1024, 1024)
-    assert pk._default_blocks(256, None) == (512, 1024)     # as before
-    windowed, _ = pk.flash_kv_blocks(8192, 8192, bq, bk, True, 512)
-    causal, total = pk.flash_kv_blocks(8192, 8192, 1024, 1024, True, None)
+    assert pk._default_blocks(128, pk.Causal()) == (1024, 1024)
+    assert pk._default_blocks(256, pk.Causal()) == (512, 1024)     # as before
+    windowed, _ = pk.flash_kv_blocks(8192, 8192, bq, bk, pk.Causal(512))
+    causal, total = pk.flash_kv_blocks(8192, 8192, 1024, 1024, pk.Causal())
     assert (windowed, causal, total) == (31, 36, 64)
     # in keys visited: 31 tiles of 512 x 512 against 36 of 1024 x 1024
     assert windowed * 512 * 512 / (causal * 1024 * 1024) < 0.22
@@ -164,10 +163,10 @@ def test_window_512_at_8192_tokens_visits_under_a_quarter_of_causal():
 def test_the_gauge_pair_is_set_from_the_grid_when_the_call_is_traced():
     q, k, v = qkv()
     jax.jit(lambda *a: pk.flash_attention(
-        *a, causal=True, window=20, block_q=BLOCK, block_k=BLOCK,
+        *a, visibility=pk.Causal(20), block_q=BLOCK, block_k=BLOCK,
         interpret=True, scope="attn.window")).lower(q, k, v)
     reg = default_registry()
-    want = pk.flash_kv_blocks(T, T, BLOCK, BLOCK, True, 20)
+    want = pk.flash_kv_blocks(T, T, BLOCK, BLOCK, pk.Causal(20))
     for (name, _), value in zip(pk.FLASH_BLOCK_GAUGES, want):
         series = reg.get_metric(name).series()
         assert [v for key, v in series.items()
