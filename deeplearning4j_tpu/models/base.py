@@ -70,6 +70,7 @@ class BaseModel:
         # their table, and the fit() call that last handed it to a tracer
         self._scoped_step = None
         self._step_scopes = None
+        self._step_kernel_calls = None
         self._scopes_traced_in = None
         self._fit_calls = 0
 
@@ -181,20 +182,27 @@ class BaseModel:
         scopes: which scope each operation of the compiled step belongs to
         (observe/scopes.py; computed once per built step), handed to the
         tracer as a zero-length ``step_scopes`` span once per ``fit()``
-        call."""
+        call, and with it the gauge of the step's Pallas kernel launches
+        under each declared scope."""
+        from deeplearning4j_tpu.observe import scopes
         if self._scoped_step is not step:
             self._scoped_step = step
-            from deeplearning4j_tpu.observe.scopes import compiled_scopes
-            declared = any(getattr(layer, "named_scopes", ())
-                           for layer in self._constraint_layers())
-            self._step_scopes = (compiled_scopes(step, *args) if declared
-                                 else None)
+            declared = dict.fromkeys(
+                s for layer in self._constraint_layers()
+                for s in getattr(layer, "named_scopes", ()))
+            self._step_scopes = self._step_kernel_calls = None
+            if declared:
+                text = step.lower(*args).compile().as_text()
+                self._step_scopes = scopes.scopes_in_hlo(text)
+                self._step_kernel_calls = scopes.kernel_calls(
+                    scopes.kernels_in_hlo(text), declared)
             self._scopes_traced_in = None
         if self._step_scopes and self._scopes_traced_in != self._fit_calls:
             self._scopes_traced_in = self._fit_calls
             now = time.perf_counter()
             tracer.add_span("step_scopes", now, now, cat="step",
                             table=self._step_scopes)
+            scopes.publish_kernel_calls(self._step_kernel_calls)
 
     def _publish_routing_gauges(self):
         """Expert layers leave their step's routing counters in the model

@@ -34,8 +34,17 @@ which the head may read the embedding's table (``tied``) in place of a
 matrix of its own.
 
 ``recompute`` wraps a block's ``apply`` in ``jax.checkpoint`` while
-training, so the step ``fit()`` builds keeps only each block's input for
-the backward pass and recomputes the block's internals there.
+training, so the step ``fit()`` builds keeps each block's input for the
+backward pass and recomputes the block's internals there, but for the
+flash-attention kernel's two results where the mixer took that kernel
+(``_recomputed``): the attention's output and its logsumexp are kept too,
+since only a second launch of the forward kernel could give them back.
+That costs a layer ``T x H x Dv`` at the compute type plus ``T x H``
+float32 a row (at 16,384 positions and 32 heads of 128 in bfloat16: 134.2
++ 2.1 MB, for 18.6 ms of kernel), two to four times the block's input.
+The XLA attention path (short sequences, the CPU) names nothing and keeps
+nothing more. A model at its memory limit has ``recompute`` and the batch
+to give, as before.
 """
 
 from __future__ import annotations
@@ -81,6 +90,18 @@ CROSS_ATTENTION = "cross_attention"
 IGNORE_LABEL = -1
 
 
+def _recomputed(apply):
+    """``apply`` under the blocks' ``jax.checkpoint``: its arguments are
+    kept for the backward pass and, where a mixer ran the flash-attention
+    kernel, that kernel's output and logsumexp (module docstring);
+    everything else is computed again there."""
+    from deeplearning4j_tpu.ops.pallas_kernels import (
+        FLASH_LSE_NAME, FLASH_OUT_NAME)
+    return jax.checkpoint(
+        apply, policy=jax.checkpoint_policies.save_only_these_names(
+            FLASH_OUT_NAME, FLASH_LSE_NAME))
+
+
 @register_serializable
 @dataclasses.dataclass(frozen=True)
 class TokenEmbedding(Layer):
@@ -122,7 +143,13 @@ class HybridDecoderBlock(FeedForwardLayer):
     mixer; the fields after it are the parts' own (``GatedAttention``,
     ``GatedDeltaNet``, ``HeldExpertsMoE``), kept flat so that the block
     serialises as one layer. ``n_out`` is the model width and equals the
-    input's."""
+    input's.
+
+    ``recompute``: while training, keep the block's input for the backward
+    pass and compute its internals again there; an attention mixer on the
+    flash kernel keeps that kernel's output and logsumexp besides (``T x H
+    x head_dim`` at the compute type and ``T x H`` float32 a row), so its
+    forward kernel runs once. Off: no ``jax.checkpoint``."""
     mixer: str = GATED_DELTANET
     # gated attention, and block-diffusion attention (no output gate;
     # ``block_length`` is its alone)
@@ -228,7 +255,7 @@ class HybridDecoderBlock(FeedForwardLayer):
 
     def apply(self, params, state, x, ctx: LayerContext):
         if self.recompute and ctx.train:
-            return jax.checkpoint(
+            return _recomputed(
                 lambda p, s, a: self._apply(p, s, a, ctx))(params, state, x)
         return self._apply(params, state, x, ctx)
 
@@ -247,7 +274,11 @@ class StateSpaceHybridBlock(FeedForwardLayer):
     With ``emit`` a ``mamba`` block also emits ``memory`` (its scan's
     result before the gate) and an ``attention`` block ``k`` and ``v`` (its
     projected keys and values); a ``gated_memory`` block takes ``memory``
-    and a ``cross_attention`` block ``k`` and ``v`` as further inputs."""
+    and a ``cross_attention`` block ``k`` and ``v`` as further inputs.
+
+    ``recompute``: as ``HybridDecoderBlock``'s; what an attention block
+    keeps a row is ``T x H x 2 head_dim`` (a differential map's value head
+    is twice the query's) at the compute type and ``T x H`` float32."""
     mixer: str = MAMBA
     emit: bool = False
     layer_index: int = 0
@@ -353,7 +384,7 @@ class StateSpaceHybridBlock(FeedForwardLayer):
 
     def apply(self, params, state, x, ctx: LayerContext):
         if self.recompute and ctx.train:
-            return jax.checkpoint(
+            return _recomputed(
                 lambda p, a: self._apply(p, a, ctx))(params, x), state
         return self._apply(params, x, ctx), state
 

@@ -11,16 +11,32 @@ to run once more (the persistent cache answers where it is on), reads that
 text here and hands instruction -> op_name to the tracer as the ``table``
 of a zero-length ``step_scopes`` span (cat ``step``), once per ``fit()``
 call, for whoever reduces the device trace: the yardstick's per-scope
-readers. Nothing is lowered, compiled or recorded when tracing is off.
+readers. The same text says how many Pallas kernels the step launches
+under each declared scope (a kernel is a custom call to Mosaic's target),
+published with the span as the gauge ``dl4j_step_kernel_calls{scope}``.
+Nothing is lowered, compiled or recorded when tracing is off.
 """
 
 from __future__ import annotations
 
 import re
-from typing import Dict
+from typing import Dict, Iterable
 
 _INSTRUCTION = re.compile(
     r'^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*?op_name="([^"]*)"')
+# a Pallas kernel's launch in a program compiled for a TPU: a custom call
+# to Mosaic whose ``op_name`` ends in the primitive's name. The values it
+# returns (``get-tuple-element``), its constants and copies carry the same
+# ``op_name`` and are no launches; XLA's own Mosaic kernels
+# (``lax.ragged_dot``) are no ``pallas_call``
+_KERNEL_TARGET = 'custom_call_target="tpu_custom_call"'
+_KERNEL_OP = "pallas_call"
+
+STEP_KERNEL_CALLS_GAUGE = (
+    "dl4j_step_kernel_calls",
+    "Pallas kernel launches of the compiled train step under a declared "
+    "named scope, forward, recomputation and backward together (label: the "
+    "scope; 0: the scope's layers took no kernel)")
 
 
 def scopes_in_hlo(text: str) -> Dict[str, str]:
@@ -34,7 +50,27 @@ def scopes_in_hlo(text: str) -> Dict[str, str]:
     return found
 
 
-def compiled_scopes(jitted_step, *args) -> Dict[str, str]:
-    """Compile ``jitted_step`` for ``args`` again and read its
-    instruction -> op_name map."""
-    return scopes_in_hlo(jitted_step.lower(*args).compile().as_text())
+def kernels_in_hlo(text: str) -> Dict[str, str]:
+    """``scopes_in_hlo`` of the Pallas kernels' launches alone."""
+    launches = scopes_in_hlo("\n".join(
+        line for line in text.splitlines() if _KERNEL_TARGET in line))
+    return {name: op_name for name, op_name in launches.items()
+            if op_name.endswith(_KERNEL_OP)}
+
+
+def kernel_calls(kernels: Dict[str, str],
+                 scopes: Iterable[str]) -> Dict[str, int]:
+    """``{scope: launches}`` for each of ``scopes``: the entries of
+    ``kernels`` (instruction -> op_name, ``kernels_in_hlo``'s) whose path
+    holds the scope as a component, bare or wrapped by a transformation
+    (``transpose(jvp(attn.gated))``)."""
+    return {scope: sum(
+        bool(re.search(rf"(^|[/(]){re.escape(scope)}($|[/)])", op_name))
+        for op_name in kernels.values()) for scope in scopes}
+
+
+def publish_kernel_calls(calls: Dict[str, int]) -> None:
+    from deeplearning4j_tpu.observe.registry import default_registry
+    gauge = default_registry().gauge(*STEP_KERNEL_CALLS_GAUGE)
+    for scope, n in calls.items():
+        gauge.set(n, scope=scope)

@@ -27,6 +27,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -45,6 +46,15 @@ _NEG = float(jnp.finfo(jnp.float32).min) / 2.0  # host-sync-ok: finfo constant
 
 _DEF_BLOCK_Q = 1024  # tuned on v5e: 16k-seq causal attn 21.5ms vs 84ms at 128
 _DEF_BLOCK_K = 1024
+
+# ``checkpoint_name`` tags of the forward kernel's two results as the
+# backward's residuals: a ``jax.checkpoint`` whose policy saves these names
+# (the decoder blocks') keeps both and does not launch the forward kernel
+# again to recompute them; anywhere else the tags are the identity. Both
+# come out of one ``pallas_call``, so a policy that names one alone still
+# pays the whole launch for the other.
+FLASH_OUT_NAME = "flash_attention.out"
+FLASH_LSE_NAME = "flash_attention.lse"
 
 
 def _masked(s, vis: Visibility, qi, ki, bq: int, bk: int):
@@ -214,6 +224,11 @@ def _flash_fwd_rule(q, k, v, mask, vis, block_q, block_k, interpret,
                     bwd_impl):
     out, lse = _flash_forward(q, k, v, mask, vis, block_q, block_k,
                               interpret)
+    # the tagged result is the primal too: the block's backward needs the
+    # attention's output itself (the output projection's weight gradient),
+    # and an untagged copy would bring the second launch back
+    out = checkpoint_name(out, FLASH_OUT_NAME)
+    lse = checkpoint_name(lse, FLASH_LSE_NAME)
     return out, (q, k, v, mask, out, lse)
 
 

@@ -251,3 +251,75 @@ def test_a_gated_deltanet_layer_keeps_its_kernels_under_gdn_scan(
     assert not [op for op in table.values() if "/while" in op]
     assert default_registry().gauge(*GDN_KERNEL_GAUGE).get(
         layer="block0") == 128
+
+
+def _recomputing_blocks():
+    """``{case: (block, positions, kernel scope, kernels, recomputed)}``:
+    a recomputing decoder block of each kind at its cell's mixer widths
+    (the expert layer small: it is not what is read here)."""
+    from deeplearning4j_tpu.nn.layers import decoder as d
+    experts = dict(num_experts=16, held_experts=(0, 1), expert_hidden=256,
+                   top_k=2, dtype="bfloat16", recompute=True)
+    return {
+        "sdar": (d.HybridDecoderBlock(
+            name="block0", n_out=2048, mixer=d.BLOCK_DIFFUSION_ATTENTION,
+            n_heads=32, n_kv_heads=4, head_dim=128,
+            partial_rotary_factor=1.0, rope_theta=1e6, block_length=4,
+            **experts), 16384, "attn.block_diffusion", 3, 0),
+        "qwen3-next": (d.HybridDecoderBlock(
+            name="block3", n_out=2048, mixer=d.GATED_ATTENTION, n_heads=16,
+            n_kv_heads=2, head_dim=256, **experts),
+         8192, "attn.gated", 3, 0),
+        "phi4-mini-flash": (d.StateSpaceHybridBlock(
+            name="block17", n_out=2560, mixer=d.ATTENTION, emit=True,
+            layer_index=17, n_heads=40, n_kv_heads=20, head_dim=64,
+            mlp_hidden=10240, dtype="bfloat16", recompute=True),
+         8192, "attn.full", 3, 0),
+        "qwen3-next-deltanet": (d.HybridDecoderBlock(
+            name="block0", n_out=2048, mixer=d.GATED_DELTANET, **experts),
+         8192, "gdn.scan", 3, 1),
+    }
+
+
+@pytest.mark.parametrize("case", [
+    "sdar", "qwen3-next", "phi4-mini-flash", "qwen3-next-deltanet"])
+def test_a_recomputing_block_runs_its_flash_forward_once(
+        one_chip, no_compile_cache, monkeypatch, case):
+    """A decoder block with ``recompute`` through its own
+    ``apply(train=True)`` under ``jax.grad``, traced for a TPU (the test
+    stands in for ``jax.default_backend``): an attention mixer launches
+    three kernels under its scope (forward, dQ, dK/dV; four where the
+    forward kernel runs again for its result and logsumexp) and none in the
+    recomputation; the DeltaNet block, whose residuals no policy names,
+    still three with its forward kernel run again. Counted as the program's
+    gauge counts them."""
+    from deeplearning4j_tpu.nn.inputs import RecurrentType
+    from deeplearning4j_tpu.nn.layers.base import LayerContext
+    from deeplearning4j_tpu.observe.scopes import kernel_calls, kernels_in_hlo
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    block, t, scope, expected, recomputed = _recomputing_blocks()[case]
+    width = block.n_out
+    rt = RecurrentType(width, t)
+
+    def described(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = described(jax.eval_shape(
+        lambda key: block.initialize(key, rt), jax.random.PRNGKey(0)))
+    state = jax.eval_shape(lambda: block.init_state(rt))
+    x = jax.ShapeDtypeStruct((1, t, width), jnp.bfloat16, sharding=one_chip)
+
+    def total(p, a):
+        zeros = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), state)
+        y, _ = block.apply(p, zeros, a, LayerContext(train=True))
+        return sum(jnp.sum(v.astype(jnp.float32) ** 2)
+                   for v in (y if isinstance(y, tuple) else (y,)))
+
+    text = jax.jit(jax.grad(total, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    kernels = kernels_in_hlo(text)
+    assert kernel_calls(kernels, (scope,)) == {scope: expected}
+    assert len(kernels) == expected
+    assert sum("rematted_computation" in op
+               for op in kernels.values()) == recomputed
