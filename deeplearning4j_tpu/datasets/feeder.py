@@ -34,7 +34,7 @@ Observability: ``dl4j_feed_depth`` (staged batches at last hand-off)
 and ``dl4j_etl_stall_ms`` (cumulative ms the step loop actually waited
 for data) ride the process registry; the tracer gets ``etl`` spans for
 host-side batch production, ``host_to_device`` spans for the staging
-issue (wire), ``resident`` spans from that issue until the batch is on
+issue, ``resident`` spans from that issue until the batch is on
 the device (see ``_ResidentWatcher``), and ``feed_stall`` spans whenever
 the queue ran dry — so overlap (or its absence) is visible in the
 Perfetto timeline. ``host_to_device``, ``resident`` and the consumer's
@@ -393,7 +393,7 @@ class DeviceFeeder:
         self._seq += 1
         if self.tracer.enabled:
             self.tracer.add_span("host_to_device", start,
-                                 time.perf_counter(), cat="data", wire=True,
+                                 time.perf_counter(), cat="data",
                                  k=item.k, bytes=nbytes, seq=seq)
             if self._watcher is None:
                 self._watcher = _ResidentWatcher(self.tracer)

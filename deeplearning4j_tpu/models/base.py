@@ -12,6 +12,8 @@ listeners. Here the optimizer step is one donated jitted function
 from __future__ import annotations
 
 import time
+from collections import deque
+from contextlib import contextmanager
 from typing import Any, List, Optional
 
 import jax
@@ -20,6 +22,7 @@ import numpy as np
 
 from deeplearning4j_tpu.datasets.dataset import DataSet, DataSetIterator
 from deeplearning4j_tpu.evaluation.evaluation import Evaluation, RegressionEvaluation
+from deeplearning4j_tpu.observe.tracer import get_tracer
 from deeplearning4j_tpu.optimize.listeners import TrainingListener
 from deeplearning4j_tpu.optimize.solver import TrainState
 
@@ -73,6 +76,11 @@ class BaseModel:
         self._step_kernel_calls = None
         self._scopes_traced_in = None
         self._fit_calls = 0
+        # in a traced fit() call only (_enter_fit_call): when it was
+        # entered, and the dispatched steps' losses with the steps each
+        # stands for, oldest first (_in_flight)
+        self._fit_entered = None
+        self._sent = None
 
     # ---- to be provided by subclasses -----------------------------------
     def init(self, seed: Optional[int] = None):
@@ -208,37 +216,102 @@ class BaseModel:
         """Expert layers leave their step's routing counters in the model
         state (``moe_routing``); publish the last step's as gauges. One
         small fetch, at a telemetry flush and at the end of a ``fit()``
-        call, never between steps."""
+        call, never between steps; it waits for every step in flight
+        (span ``blocked``, ``on="routing"``)."""
         state = self.train_state.model_state
         rows = {name: s["moe_routing"] for name, s in state.items()
                 if isinstance(s, dict) and "moe_routing" in s}
         if rows:
             from deeplearning4j_tpu.observe.telemetry import (
                 publish_routing)
-            publish_routing(jax.device_get(rows))  # host-sync-ok: once per fit() call / telemetry flush
+            with self._blocked("routing"):
+                rows = jax.device_get(rows)  # host-sync-ok: once per fit() call / telemetry flush
+            publish_routing(rows)
+
+    def _enter_fit_call(self):
+        """Start of a ``fit()`` / ``ParallelWrapper.fit()`` call: the
+        device's iteration count is adopted again at the call's first
+        ``_post_step`` (external code may have swapped ``train_state``
+        since the last call: checkpoint load, transfer learning). Returns
+        the model's tracer; under an enabled one the entry is timed (for
+        ``blocked``'s ``since_call_ms``) and the dispatched steps are
+        kept count of (``_in_flight``), from one call into the next."""
+        self._host_iteration = None
+        self._fit_calls += 1
+        tracer = get_tracer(self)
+        if not tracer.enabled:
+            self._fit_entered = self._sent = None
+        else:
+            self._fit_entered = time.perf_counter()
+            if self._sent is None:
+                self._sent = deque()
+        return tracer
+
+    def _in_flight(self, unnoted: int = 0):
+        """In a traced ``fit()`` call, how far the loop runs ahead of the
+        device: the optimizer steps dispatched whose loss is not ready,
+        plus ``unnoted`` dispatched since the last ``_post_step``. One
+        deque of (loss, steps), which ``_post_step`` appends to; the ready
+        ones are popped from the left with ``is_ready()``, which does not
+        block. Scalars only: it keeps no batch and no train state alive.
+        None in an untraced call, which has no deque."""
+        sent = self._sent
+        if sent is None:
+            return None
+        while sent and sent[0][0].is_ready():
+            sent.popleft()
+        return unnoted + sum(steps for _, steps in sent)
+
+    @contextmanager
+    def _blocked(self, on: str, unnoted: int = 0):
+        """Round a read on which the loop's thread waits for the device
+        outside a ``dispatch`` span: in a traced ``fit()`` call the span
+        ``blocked`` (cat ``step``) with what it waits ``on`` and the steps
+        ``in_flight`` as the wait begins. The read waits for all of them,
+        so the count starts from nothing afterwards. ``on="iteration"``
+        also says how long the call had run by then."""
+        if self._sent is None:
+            yield
+            return
+        args = {"on": on, "in_flight": self._in_flight(unnoted)}
+        if on == "iteration":
+            args["since_call_ms"] = (time.perf_counter()
+                                     - self._fit_entered) * 1e3
+        try:
+            with get_tracer(self).span("blocked", cat="step", **args):
+                yield
+        finally:
+            self._sent.clear()
 
     def _advance_iteration(self, steps: int = 1) -> int:
-        """Host-tracked iteration count after a dispatched step. Syncs
-        with the device scalar only when the mirror is stale (once per
-        fit() call), so steady-state listener dispatch costs no
-        device→host round trip."""
+        """Host-tracked iteration count after a dispatched step. The
+        first read of a ``fit()`` call takes the device's scalar, which
+        is **a full wait for the device**: for the step just dispatched
+        and every one before it (span ``blocked``, ``on="iteration"``).
+        After it the mirror advances on the host, so steady-state
+        listener dispatch costs no device→host round trip."""
         if self._host_iteration is None:
-            self._host_iteration = int(self.train_state.iteration)
+            with self._blocked("iteration"):
+                self._host_iteration = int(self.train_state.iteration)
         else:
             self._host_iteration += steps
         return self._host_iteration
 
-    def _post_step(self, steps: int = 1) -> int:
-        """Shared per-dispatch epilogue: advance the iteration mirror,
-        give the telemetry collector its flush opportunity, and let the
-        flight recorder scan whatever that flush decoded (the recorder
-        reads host-side history only — no device interaction)."""
+    def _post_step(self, steps: int = 1, loss=None) -> int:
+        """Shared per-dispatch epilogue: note the dispatched ``steps`` by
+        their ``loss`` (in a traced call, for ``_in_flight``),
+        advance the iteration mirror (the first one of a ``fit()`` call
+        waits for the device, see ``_advance_iteration``), give the
+        telemetry collector its flush opportunity, and let the flight
+        recorder scan whatever that flush decoded (the recorder reads
+        host-side history only — no device interaction)."""
+        if self._sent is not None and loss is not None:
+            self._sent.append((loss, steps))
         it = self._advance_iteration(steps)
         tel = self._telemetry
         if tel is not None:
             flushed = tel.will_flush(steps)
             if flushed:
-                from deeplearning4j_tpu.observe.tracer import get_tracer
                 with get_tracer(self).span("telemetry_flush",
                                            cat="telemetry"):
                     tel.on_step(self.train_state, steps)
@@ -306,6 +379,7 @@ class BaseModel:
                    k_steps: Optional[int] = None,
                    prefetch: Optional[int] = None,
                    byte_budget: Optional[int] = None):
+        tracer = self._enter_fit_call()
         if self.train_state is None:
             self.init()
         else:
@@ -327,13 +401,6 @@ class BaseModel:
                     "MultiDataSet requires a ComputationGraph; wrap "
                     "single-input data in a DataSet for "
                     "MultiLayerNetwork")
-        # re-adopt the device iteration once per fit() call: external
-        # code may have swapped train_state (checkpoint load, transfer
-        # learning) since the last fit
-        self._host_iteration = None
-        self._fit_calls += 1
-        from deeplearning4j_tpu.observe.tracer import get_tracer
-        tracer = get_tracer(self)
         if isinstance(data, (DataSet, MultiDataSet)):
             # single-batch fit: _post_step already flushed if an interval
             # completed; flushing unconditionally here would turn the
@@ -421,7 +488,6 @@ class BaseModel:
         return self
 
     def _fit_batch(self, batch: DataSet, etl_ms: float = 0.0):
-        from deeplearning4j_tpu.observe.tracer import get_tracer
         tracer = get_tracer(self)
         self._rng, step_key = jax.random.split(self._rng)
         with tracer.span("host_to_device", cat="data"):
@@ -441,10 +507,11 @@ class BaseModel:
             self._trace_step_scopes(tracer, self._train_step,
                                     self.train_state, features, labels,
                                     fmask, lmask, step_key)
-        with tracer.span("dispatch", cat="step"):
+        with tracer.span("dispatch", cat="step",
+                         in_flight=self._in_flight()):
             self.train_state, loss = self._train_step(
                 self.train_state, features, labels, fmask, lmask, step_key)
-        it = self._post_step()
+        it = self._post_step(1, loss)
         for lst in self.listeners:
             lst.iteration_done(self, it, self.epoch_count, loss, etl_ms,
                                batch.num_examples())
@@ -480,10 +547,11 @@ class BaseModel:
         if tracer.enabled:
             self._trace_step_scopes(tracer, self._train_step,
                                     self.train_state, *args, step_key)
-        with tracer.span("dispatch", cat="step", seq=item.seq):
+        with tracer.span("dispatch", cat="step", seq=item.seq,
+                         in_flight=self._in_flight()):
             self.train_state, loss = self._train_step(
                 self.train_state, *args, step_key)
-        it = self._post_step()
+        it = self._post_step(1, loss)
         for lst in self.listeners:
             lst.iteration_done(self, it, self.epoch_count, loss,
                                item.queue_wait_ms, item.n_examples)
@@ -505,10 +573,11 @@ class BaseModel:
                 self.train_state)
         if self.recompile_watchdog is not None:
             self.recompile_watchdog.observe("scan_train_step", *args)
-        with tracer.span("dispatch", cat="step", k=item.k, seq=item.seq):
+        with tracer.span("dispatch", cat="step", k=item.k, seq=item.seq,
+                         in_flight=self._in_flight()):
             self.train_state, losses = self._scan_step(
                 self.train_state, *args, group_key)
-        it = self._post_step(item.k)
+        it = self._post_step(item.k, losses)
         loss = losses[-1]
         for lst in self.listeners:
             lst.iteration_done(self, it, self.epoch_count, loss,

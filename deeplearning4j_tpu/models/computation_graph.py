@@ -251,10 +251,11 @@ class ComputationGraph(BaseModel):
         if self.recompile_watchdog is not None:
             self.recompile_watchdog.observe(
                 "train_step", feats, labels, fmasks, lmasks)
-        with tracer.span("dispatch", cat="step"):
+        with tracer.span("dispatch", cat="step",
+                         in_flight=self._in_flight()):
             self.train_state, loss = self._train_step(
                 self.train_state, feats, labels, fmasks, lmasks, step_key)
-        it = self._post_step()
+        it = self._post_step(1, loss)
         for lst in self.listeners:
             lst.iteration_done(self, it, self.epoch_count, loss, etl_ms,
                                n_examples)
@@ -430,11 +431,12 @@ class ComputationGraph(BaseModel):
             if self.recompile_watchdog is not None:
                 self.recompile_watchdog.observe("tbptt_step", cf, cl,
                                                 cfm, clm)
-            with tracer.span("dispatch", cat="step"):
+            with tracer.span("dispatch", cat="step",
+                             in_flight=self._in_flight(n_chunks)):
                 self.train_state, loss, carries = self._tbptt_step(
                     self.train_state, cf, cl, cfm, clm, step_key, carries)
             n_chunks += 1
-        it = self._post_step(n_chunks)
+        it = self._post_step(n_chunks, loss)
         for lst in self.listeners:
             lst.iteration_done(self, it, self.epoch_count, loss, etl_ms,
                                n)

@@ -334,11 +334,12 @@ class MultiLayerNetwork(BaseModel):
             f, l = jnp.asarray(f), jnp.asarray(l)
             if self.recompile_watchdog is not None:
                 self.recompile_watchdog.observe("tbptt_step", f, l, fm, lm)
-            with tracer.span("dispatch", cat="step"):
+            with tracer.span("dispatch", cat="step",
+                             in_flight=self._in_flight(n_chunks)):
                 self.train_state, loss, carries = self._tbptt_step(
                     self.train_state, f, l, fm, lm, step_key, carries)
             n_chunks += 1
-        it = self._post_step(n_chunks)
+        it = self._post_step(n_chunks, loss)
         for lst in self.listeners:
             lst.iteration_done(self, it, self.epoch_count, loss, etl_ms,
                                batch.num_examples())

@@ -11,8 +11,10 @@ pipeline. This package inverts it:
   host every N steps in ONE device fetch — steady-state training
   performs zero extra syncs.
 - ``tracer``: host-side span tracer (ETL, host→device transfer,
-  dispatch, flush, eval, checkpoint) exporting Chrome/Perfetto trace
-  JSON, optionally annotating the jax.profiler timeline.
+  dispatch, the loop's own waits for the device, flush, eval,
+  checkpoint) exporting Chrome/Perfetto trace JSON; the export names
+  the ``perf_counter`` reading its ``ts`` count from, which lays it
+  beside a jax.profiler capture.
 - ``recompile``: watchdog recording each new (shape, dtype) signature a
   compiled step sees — silent retrace storms become a counter.
 - ``registry``: process-wide metrics registry rendered as Prometheus

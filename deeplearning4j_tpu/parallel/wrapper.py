@@ -548,9 +548,7 @@ class ParallelWrapper:
                              TrainingMode.ASYNC_ELASTIC):
             raise ValueError(f"unsupported mode: {self.mode}")
         m = self.model
-        # re-adopt the device iteration once per fit (BaseModel.fit does
-        # the same); listener dispatch then advances a host mirror
-        m._host_iteration = None
+        m._enter_fit_call()
         self._arm_telemetry()
         try:
             if self.mode is TrainingMode.SHARED_GRADIENTS:
@@ -824,14 +822,12 @@ class ParallelWrapper:
         feats, labels, fmask, lmask = self._stage_batch(batch)
         if m._telemetry is not None:
             m.train_state = m._telemetry.ensure_buffer(m.train_state)
-        with get_tracer(m).span("dispatch", cat="step"):
+        with get_tracer(m).span("dispatch", cat="step",
+                                in_flight=m._in_flight()):
             m.train_state, loss = self._step(m.train_state, feats, labels,
                                              fmask, lmask, key)
         self._guarded_wait(loss)
-        # _post_step: host iteration mirror + telemetry flush
-        # opportunity + flight-recorder poll — no per-batch
-        # device sync (the old int(iteration) read was one)
-        it = m._post_step()
+        it = m._post_step(1, loss)
         for lst in m.listeners:
             lst.iteration_done(m, it, m.epoch_count, loss, etl_ms, n_real)
         m._last_loss = loss
@@ -843,12 +839,13 @@ class ParallelWrapper:
         m._rng, key = jax.random.split(m._rng)
         if m._telemetry is not None:
             m.train_state = m._telemetry.ensure_buffer(m.train_state)
-        with get_tracer(m).span("dispatch", cat="step", seq=item.seq):
+        with get_tracer(m).span("dispatch", cat="step", seq=item.seq,
+                                in_flight=m._in_flight()):
             m.train_state, loss = self._step(
                 m.train_state, item.features, item.labels,
                 item.features_mask, item.labels_mask, key)
         self._guarded_wait(loss)
-        it = m._post_step()
+        it = m._post_step(1, loss)
         for lst in m.listeners:
             lst.iteration_done(m, it, m.epoch_count, loss,
                                item.queue_wait_ms, item.n_examples)
@@ -859,7 +856,8 @@ class ParallelWrapper:
         BaseModel's tail flush), then give the recorder a final look."""
         m = self.model
         if m._telemetry is not None:
-            m._telemetry.flush(m.train_state)
+            with get_tracer(m).span("telemetry_flush", cat="telemetry"):
+                m._telemetry.flush(m.train_state)
             rec = m._recorder()
             if rec is not None:
                 rec.poll(m)
@@ -966,12 +964,12 @@ class ParallelWrapper:
         if m._telemetry is not None:
             m.train_state = m._telemetry.ensure_buffer(m.train_state)
         with get_tracer(m).span("dispatch", cat="step", k=item.k,
-                                seq=item.seq):
+                                seq=item.seq, in_flight=m._in_flight()):
             m.train_state, loss = self._step(
                 m.train_state, item.features, item.labels,
                 item.features_mask, item.labels_mask, key)
-        self._guarded_wait(loss)
-        it = m._post_step(item.k)
+        self._guarded_wait(loss, item.k)
+        it = m._post_step(item.k, loss)
         for lst in m.listeners:
             lst.iteration_done(m, it, m.epoch_count, loss,
                                item.queue_wait_ms, item.n_examples)
@@ -992,9 +990,9 @@ class ParallelWrapper:
             m.train_state = m._telemetry.ensure_buffer(m.train_state)
         m.train_state, loss = self._step(m.train_state, feats, labels,
                                          fmask, lmask, key)
-        self._guarded_wait(loss)
+        self._guarded_wait(loss, len(batches))
         # the round advanced the device iteration by k local steps
-        it = m._post_step(len(batches))
+        it = m._post_step(len(batches), loss)
         for lst in m.listeners:
             lst.iteration_done(m, it, m.epoch_count, loss, 0.0, n_real)
         m._last_loss = loss
@@ -1073,7 +1071,7 @@ class ParallelWrapper:
             el["base_params"], feats, labels, fmask, lmask, key,
             jnp.asarray(present), jnp.asarray(ages),
             jnp.float32(1.0 if hard else 0.0))
-        self._guarded_wait(loss)
+        self._guarded_wait(loss, k_real)
 
         # ---- host bookkeeping: ages, counters, divergence guard -------
         age1 = ages + 1.0
@@ -1088,7 +1086,8 @@ class ParallelWrapper:
 
         # ONE small fetch per round (k steps amortize it) — the
         # divergence guard needs the per-worker grad norms on host
-        arr = np.asarray(stats)  # host-sync-ok: per-round (k steps) fetch of the (n,2) stats row for the divergence guard
+        with m._blocked("elastic_stats", k_real):
+            arr = np.asarray(stats)  # host-sync-ok: per-round (k steps) fetch of the (n,2) stats row for the divergence guard
         gnorms = arr[:, 1]
         finite = gnorms[np.isfinite(gnorms)]
         if finite.size < gnorms.size:
@@ -1103,7 +1102,7 @@ class ParallelWrapper:
                               discarded_stale, float(el["ages"].max()),  # host-sync-ok: host np bookkeeping
                               div, hard)
 
-        it = m._post_step(k_real)
+        it = m._post_step(k_real, loss)
         for lst in m.listeners:
             lst.iteration_done(m, it, m.epoch_count, loss, wait_ms,
                                n_real)
@@ -1145,15 +1144,18 @@ class ParallelWrapper:
                 "sync)").set(div, session=s)
 
     # ---- watchdog plumbing ----------------------------------------------
-    def _guarded_wait(self, x):
-        """Block on a dispatched step's output under the collective
-        watchdog's in-flight window, so a peer that died mid-collective
-        turns into a peer_loss exit instead of an infinite hang. No-op
-        without a watchdog — the usual async dispatch pipelining is then
+    def _guarded_wait(self, x, steps: int = 1):
+        """Block on the output of the ``steps`` just dispatched under the
+        collective watchdog's in-flight window, so a peer that died
+        mid-collective turns into a peer_loss exit instead of an infinite
+        hang (span ``blocked``, ``on="collective"``). No-op without a
+        watchdog — the usual async dispatch pipelining is then
         preserved."""
         wd = self._watchdog
         if wd is None:
             return
-        it = getattr(self.model, "_host_iteration", None)
-        with wd.guard(iteration=it if it is not None else 0):
+        m = self.model
+        it = getattr(m, "_host_iteration", None)
+        with wd.guard(iteration=it if it is not None else 0), \
+                m._blocked("collective", steps):
             jax.block_until_ready(x)

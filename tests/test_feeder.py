@@ -260,7 +260,7 @@ class TestDeviceFeeder:
         names = {e["name"] for e in tracer._events}
         assert {"etl", "host_to_device", "feed_stall"} <= names
         wire = [e for e in tracer._events if e["name"] == "host_to_device"]
-        assert all(e["args"]["wire"] for e in wire)
+        assert [e["args"]["seq"] for e in wire] == [0, 1, 2]
         assert all(e["args"]["bytes"] > 0 for e in wire)
 
     def test_resident_span_per_staged_item(self):

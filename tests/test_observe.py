@@ -277,6 +277,160 @@ class TestTracer:
         assert {"etl", "host_to_device", "dispatch"} <= cats
 
 
+def _loop_spans(tr):
+    """(start, end, args) of the fit loop's ``blocked`` and ``dispatch``
+    spans (cat ``step``), each list in the order recorded."""
+    out = {"blocked": [], "dispatch": []}
+    for e in tr.events:
+        if e["name"] in out and e["cat"] == "step":
+            out[e["name"]].append((e["tid"], e["ts"], e["ts"] + e["dur"],
+                                   e.get("args", {})))
+    return out
+
+
+def _assert_disjoint(spans):
+    """No ``blocked`` span overlaps a ``dispatch`` span of its thread."""
+    for tid, b0, b1, _ in spans["blocked"]:
+        for dtid, d0, d1, _ in spans["dispatch"]:
+            assert dtid != tid or b1 <= d0 or d1 <= b0
+
+
+class _Losses:
+    """Listener that keeps every step's loss, on the device."""
+
+    def __init__(self):
+        self.losses = []
+
+    def __getattr__(self, name):        # every other hook: nothing
+        return lambda *a, **k: None
+
+    def iteration_done(self, model, iteration, epoch, loss, etl_ms, n):
+        self.losses.append(loss)
+
+
+class TestLoopWaits:
+    """Where the fit loop waits for the device (``blocked``) and how far
+    ahead of it it runs (``in_flight``), recorded under a tracer only."""
+
+    @pytest.mark.parametrize("fit_args", [
+        {}, {"prefetch": 0}, {"k_steps": 2}], ids=["fed", "unfed", "k2"])
+    def test_one_iteration_wait_a_call_and_in_flight_on_every_dispatch(
+            self, fit_args):
+        m = _tiny_model()
+        tr = SpanTracer()
+        m.set_tracer(tr)
+        for _ in range(2):
+            m.fit(_ListIter(_batches(4)), epochs=2, **fit_args)
+        spans = _loop_spans(tr)
+        waits = [a for *_, a in spans["blocked"]]
+        assert [a["on"] for a in waits] == ["iteration"] * 2
+        assert all(a["since_call_ms"] >= 0 for a in waits)
+        k = fit_args.get("k_steps", 1)
+        assert len(spans["dispatch"]) == 2 * 2 * 4 // k
+        for *_, a in spans["dispatch"]:
+            assert type(a["in_flight"]) is int and a["in_flight"] >= 0
+        assert all(a["in_flight"] % k == 0
+                   for *_, a in spans["dispatch"] + spans["blocked"])
+        _assert_disjoint(spans)
+
+    def test_in_flight_counts_the_steps_whose_loss_is_not_ready(self):
+        class Loss:
+            def __init__(self, ready):
+                self.ready = ready
+
+            def is_ready(self):
+                return self.ready
+
+        m = _tiny_model()
+        tr = SpanTracer()
+        m.set_tracer(tr)
+        m._enter_fit_call()
+        m._host_iteration = 0           # the call's first wait is behind it
+        first, group, last = Loss(False), Loss(False), Loss(False)
+        assert m._in_flight() == 0
+        m._post_step(1, first)
+        m._post_step(4, group)          # a k_steps group counts k
+        assert m._in_flight() == 5 and m._in_flight(2) == 7
+        group.ready = True              # steps complete in order: the
+        assert m._in_flight() == 5      # oldest is still out
+        first.ready = True
+        assert m._in_flight() == 0 and not m._sent
+        m._post_step(1, last)
+        with m._blocked("collective", 3):
+            pass
+        (ev,) = [e for e in tr.events if e["name"] == "blocked"]
+        assert ev["args"] == {"on": "collective", "in_flight": 4}
+        assert not m._sent              # the read waited for all of them
+
+    def test_single_batch_fit_records_them_too(self):
+        m = _tiny_model()
+        tr = SpanTracer()
+        m.set_tracer(tr)
+        m.fit(_batches(1)[0])
+        spans = _loop_spans(tr)
+        assert [a["on"] for *_, a in spans["blocked"]] == ["iteration"]
+        assert [a["in_flight"] for *_, a in spans["dispatch"]] == [0]
+
+    def test_an_expert_models_call_ends_in_one_routing_wait(self):
+        from deeplearning4j_tpu.datasets.dataset import ArrayDataSetIterator
+        from deeplearning4j_tpu.nn.layers.decoder import next_token_labels
+        from deeplearning4j_tpu.zoo.models import Qwen3Next
+        m = Qwen3Next(
+            vocab_size=64, hidden_size=32, num_hidden_layers=2,
+            full_attention_interval=2, num_attention_heads=4,
+            num_key_value_heads=2, head_dim=16, linear_num_key_heads=2,
+            linear_num_value_heads=4, linear_key_head_dim=8,
+            linear_value_head_dim=8, num_experts=16,
+            held_experts=tuple(range(8)), num_experts_per_tok=2,
+            moe_intermediate_size=16, shared_expert_intermediate_size=16,
+            seq_len=48, chunk_size=16, compute_dtype="float32").init()
+        ids = np.random.default_rng(0).integers(0, 64, (4, 48))
+        it = ArrayDataSetIterator(DataSet(ids, next_token_labels(ids)), 2)
+        tr = SpanTracer()
+        m.set_tracer(tr)
+        m.fit(it, epochs=2)
+        spans = _loop_spans(tr)
+        assert [a["on"] for *_, a in spans["blocked"]] == [
+            "iteration", "routing"]
+        assert "since_call_ms" not in spans["blocked"][1][3]
+        assert spans["blocked"][1][1] >= max(
+            end for _, _, end, _ in spans["dispatch"])
+        _assert_disjoint(spans)
+
+    def test_traced_and_untraced_losses_are_equal_bit_for_bit(self):
+        got = []
+        for tracer in (SpanTracer(), None):
+            m = _tiny_model()
+            tap = _Losses()
+            m.set_listeners(tap)
+            m.set_tracer(tracer)
+            m.fit(_ListIter(_batches(4)), epochs=2)
+            got.append(np.asarray(jax.device_get(tap.losses)))
+        assert got[0].shape == (8,) and np.array_equal(got[0], got[1])
+
+    def test_untraced_fit_keeps_no_deque_and_polls_nothing(self, monkeypatch):
+        polled = []
+        array_type = type(jnp.zeros(()))
+        real = array_type.is_ready
+        monkeypatch.setattr(array_type, "is_ready", lambda self: (
+            polled.append(1), real(self))[1])
+        m = _tiny_model()
+        assert jnp.zeros(()).is_ready() in (True, False) and polled
+        del polled[:]
+        m.fit(_ListIter(_batches(3)), epochs=2)
+        m.fit(_batches(1)[0])
+        assert m._sent is None and m._fit_entered is None
+        assert not polled
+        # and a tracer taken off again leaves nothing behind
+        m.set_tracer(SpanTracer())
+        m.fit(_ListIter(_batches(3)), epochs=1)
+        assert m._sent is not None and polled
+        m.set_tracer(None)
+        del polled[:]
+        m.fit(_ListIter(_batches(3)), epochs=1)
+        assert m._sent is None and not polled
+
+
 class TestRecompileWatchdog:
     def test_new_signature_detected(self):
         reg = MetricsRegistry()
