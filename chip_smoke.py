@@ -20,7 +20,9 @@ a seed, with no network and no files outside the checkout:
                  backward, against plain XLA; the held experts' loop over
                  blocks of rows at routings that fill one block and
                  several; the gated delta rule's kernels at the
-                 Qwen3-Next cell's shapes against the plain chunked form
+                 Qwen3-Next cell's shapes and the selective scan's at
+                 the Phi-4-mini-flash cell's, each against its plain
+                 chunked form
   phase=feed_race  the input pipeline alone, with no train step, so that
                  the prefetch thread gathers flat out into buffers it
                  uses again: 200 shuffled batches of the ResNet cell's
@@ -444,6 +446,22 @@ def phase_kernels(ctx):
         (q, k, v, log_decay, beta), "bfloat16", errs)
     if not kernels_take(q, v, 64):
         errs["gated_delta_rule"] = "FAIL the kernels did not take the call"
+    # the selective scan at the Phi-4-mini-flash cell's shapes: one sequence
+    # of 8,192 tokens, 5,120 channels of 16 states, chunks of 64, bfloat16
+    # ``x``. ``selective_scan`` takes the Pallas kernels here; the plain
+    # chunked form, its oracle, is XLA's
+    from deeplearning4j_tpu.nn.layers.state_space import (
+        selective_scan_chunked)
+    from deeplearning4j_tpu.ops import pallas_selective_scan as ssm
+    d, s = 5120, 16
+    scan_args = (arr((1, t, d), jnp.bfloat16),
+                 jnp.asarray(rng.uniform(1e-3, 0.1, (1, t, d)), jnp.float32),
+                 -jnp.asarray(rng.uniform(1.0, 16.0, (d, s)), jnp.float32),
+                 arr((1, t, s), jnp.float32), arr((1, t, s), jnp.float32))
+    _check_grads("selective_scan", ssm.selective_scan,
+                 selective_scan_chunked, scan_args, "float32", errs)
+    if not ssm.kernels_take(scan_args[0], scan_args[2], 64):
+        errs["selective_scan"] = "FAIL the kernels did not take the call"
     for name, e in errs.items():
         print(f"kernel {name}: "
               + (e if isinstance(e, str) else f"max_rel_err={e:.3e}"))

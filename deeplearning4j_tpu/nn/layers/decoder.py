@@ -336,7 +336,7 @@ class StateSpaceHybridBlock(FeedForwardLayer):
                       init_std=self.init_std)
         if self.mixer == MAMBA:
             return MambaMixer(
-                d_inner=self.d_inner, d_state=self.d_state,
+                name=self.name, d_inner=self.d_inner, d_state=self.d_state,
                 d_conv=self.d_conv, dt_rank=self.dt_rank, **common)
         if self.mixer == GATED_MEMORY:
             return GatedMemoryUnit(d_memory=self.d_inner, **common)

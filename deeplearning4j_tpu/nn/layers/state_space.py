@@ -14,12 +14,14 @@ products in it: ``D * S`` multiply-adds a token, one after another.
 ``selective_scan_step`` is one token of that recurrence, the form a decode
 step takes; ``selective_scan_recurrent`` runs it over a sequence as
 written (a ``lax.scan`` over tokens, the oracle of the tests);
-``selective_scan_chunked`` is what a training step runs: the same token
-loop cut into chunks, each chunk under ``jax.checkpoint`` inside a
+``selective_scan_chunked`` is the training scan in plain JAX: the same
+token loop cut into chunks, each chunk under ``jax.checkpoint`` inside a
 ``lax.scan`` over chunks, so that the backward pass keeps the state at
 the chunk borders only (``T / chunk`` states of ``D * S``) and recomputes
 a chunk's states while it differentiates that chunk. Neither pass holds a
 (T, D, S) tensor. Everything here is float32, whatever the type of ``x``.
+``MambaMixer`` calls ``ops.pallas_selective_scan.selective_scan``, which
+runs the same scan as Pallas kernels on a TPU and this form elsewhere.
 
 Inside the loops the state is laid out (N, S, D), channels last, and the
 per-token inputs are rows of (T, N * D) arrays: both fill the TPU's 8 x
@@ -170,6 +172,8 @@ class MambaMixer(FeedForwardLayer):
         """``(mixed (N, T, n_out), (memory (N, T, d_inner),))``: the form
         the three mixers of a ``StateSpaceHybridBlock`` share, ``(params,
         x, *extra inputs, mask) -> (mixed, what the mixer emits)``."""
+        from deeplearning4j_tpu.ops.pallas_selective_scan import (
+            selective_scan)
         d, s, r = self.d_inner, self.d_state, self.dt_rank
         f32 = jnp.promote_types(jnp.float32, x.dtype)
         with jax.named_scope("ssm.proj"):
@@ -188,9 +192,10 @@ class MambaMixer(FeedForwardLayer):
                            params["W_dt"], preferred_element_type=f32)
                 + params["b_dt"].astype(f32))
         with jax.named_scope("ssm.scan"):
-            y = selective_scan_chunked(
+            y = selective_scan(
                 u, step, -jnp.exp(params["A_log"].astype(f32)),
-                dbc[..., r:r + s], dbc[..., r + s:])
+                dbc[..., r:r + s], dbc[..., r + s:],
+                layer=self.name or "ssm")
             y = y + params["D"].astype(f32) * u.astype(f32)
             gated = (y * jax.nn.silu(z.astype(f32))).astype(x.dtype)
         with jax.named_scope("ssm.out"):

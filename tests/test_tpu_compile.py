@@ -253,6 +253,95 @@ def test_a_gated_deltanet_layer_keeps_its_kernels_under_gdn_scan(
         layer="block0") == 128
 
 
+def test_selective_scan_kernels_compile_at_the_cells_shapes(
+        one_chip, no_compile_cache):
+    """Forward (with and without the border states) and backward of the
+    selective scan's kernels at 8,192 tokens of 5,120 channels x 16 states,
+    bfloat16 ``x`` and float32 ``dt``, chunks of 64, inside Mosaic's
+    default scoped VMEM: two launches and no loop in the differentiated
+    program, everything between the inputs and the gradients traced under
+    the caller's scope, and the temporaries (the border states, 42 MB, and
+    the partial sums of ``dB`` and ``dC``) far under the 2.7 GB a (T, D, S)
+    float32 tensor would take."""
+    from deeplearning4j_tpu.observe.scopes import scopes_in_hlo
+    from deeplearning4j_tpu.ops.pallas_kernels import scoped_vmem_limit
+    from deeplearning4j_tpu.ops.pallas_selective_scan import (
+        _vmem_need, selective_scan_kernels)
+    f32 = jnp.float32
+    shapes = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in (
+        ((1, 8192, 5120), jnp.bfloat16), ((1, 8192, 5120), f32),
+        ((5120, 16), f32), ((1, 8192, 16), f32), ((1, 8192, 16), f32))]
+
+    def scan(*a):
+        with jax.named_scope("ssm.scan"):
+            return selective_scan_kernels(*a, chunk_size=64,
+                                          interpret=False)
+
+    def both(x, dt, a, b, c, dy):
+        y, vjp = jax.vjp(scan, x, dt, a, b, c)
+        return y, vjp(dy)
+
+    plain = jax.jit(scan).lower(*shapes).compile()
+    assert plain.as_text().count("tpu_custom_call") == 1
+    compiled = jax.jit(both).lower(*shapes, shapes[1]).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert " while(" not in text
+    traced = [op for op in scopes_in_hlo(text).values()
+              if op.startswith("jit(both)/")]
+    assert traced and not [op for op in traced if "ssm.scan" not in op]
+    for backward in (False, True):
+        assert scoped_vmem_limit(_vmem_need(16, 512, 64, backward)) is None
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
+def test_a_mamba_layer_keeps_its_kernels_under_ssm_scan(
+        one_chip, no_compile_cache, monkeypatch):
+    """A ``MambaMixer`` at the Phi-4-mini-flash cell's widths under
+    ``jax.checkpoint``, as the decoder block runs it, traced for a TPU (the
+    test stands in for ``jax.default_backend``): the forward, the
+    recomputed forward and the backward kernel each carry ``ssm.scan`` in
+    their ``op_name``, by the join ``yardstick/scopes.py`` makes, so the
+    per-scope readers find them; no loop is left in the layer; and the
+    gauge says 128 chunks."""
+    from deeplearning4j_tpu.nn.inputs import RecurrentType
+    from deeplearning4j_tpu.nn.layers.base import LayerContext
+    from deeplearning4j_tpu.nn.layers.state_space import MambaMixer
+    from deeplearning4j_tpu.observe.registry import default_registry
+    from deeplearning4j_tpu.observe.scopes import scopes_in_hlo
+    from deeplearning4j_tpu.ops.pallas_selective_scan import SSM_KERNEL_GAUGE
+    from yardstick.scopes import in_scope
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    t, width = 8192, 2560
+    layer = MambaMixer(name="block0", n_in=width, n_out=width, d_inner=5120,
+                       d_state=16, d_conv=4, dt_rank=160, dtype="bfloat16")
+    params = jax.eval_shape(
+        lambda key: layer.initialize(key, RecurrentType(width, t)),
+        jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), params)
+    x = jax.ShapeDtypeStruct((1, t, width), jnp.bfloat16, sharding=one_chip)
+
+    def total(p, a):
+        y, _ = jax.checkpoint(lambda p, a: layer.apply(
+            p, {}, a, LayerContext(train=True)))(p, a)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(total, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    table = scopes_in_hlo(text)
+    kernels = {name: op for name, op in table.items()
+               if name.startswith("ssm_selective_scan_")}  # the custom calls
+    assert len(kernels) == 3
+    assert all(in_scope(op, ("ssm.scan",)) for op in kernels.values())
+    assert sum("ssm_selective_scan_bwd" in op and "transpose(" in op
+               for op in kernels.values()) == 1
+    assert sum("rematted_computation" in op for op in kernels.values()) == 1
+    assert not [op for op in table.values() if "/while" in op]
+    assert default_registry().gauge(*SSM_KERNEL_GAUGE).get(
+        layer="block0") == 128
+
+
 def _recomputing_blocks():
     """``{case: (block, positions, kernel scope, kernels, recomputed)}``:
     a recomputing decoder block of each kind at its cell's mixer widths
@@ -278,11 +367,17 @@ def _recomputing_blocks():
         "qwen3-next-deltanet": (d.HybridDecoderBlock(
             name="block0", n_out=2048, mixer=d.GATED_DELTANET, **experts),
          8192, "gdn.scan", 3, 1),
+        "phi4-mini-flash-mamba": (d.StateSpaceHybridBlock(
+            name="block0", n_out=2560, mixer=d.MAMBA, layer_index=0,
+            d_inner=5120, d_state=16, d_conv=4, dt_rank=160,
+            mlp_hidden=10240, dtype="bfloat16", recompute=True),
+         8192, "ssm.scan", 3, 1),
     }
 
 
 @pytest.mark.parametrize("case", [
-    "sdar", "qwen3-next", "phi4-mini-flash", "qwen3-next-deltanet"])
+    "sdar", "qwen3-next", "phi4-mini-flash", "qwen3-next-deltanet",
+    "phi4-mini-flash-mamba"])
 def test_a_recomputing_block_runs_its_flash_forward_once(
         one_chip, no_compile_cache, monkeypatch, case):
     """A decoder block with ``recompute`` through its own
@@ -290,8 +385,8 @@ def test_a_recomputing_block_runs_its_flash_forward_once(
     stands in for ``jax.default_backend``): an attention mixer launches
     three kernels under its scope (forward, dQ, dK/dV; four where the
     forward kernel runs again for its result and logsumexp) and none in the
-    recomputation; the DeltaNet block, whose residuals no policy names,
-    still three with its forward kernel run again. Counted as the program's
+    recomputation; the DeltaNet block and the Mamba block, whose residuals
+    no policy names, still three with their forward kernel run again. Counted as the program's
     gauge counts them."""
     from deeplearning4j_tpu.nn.inputs import RecurrentType
     from deeplearning4j_tpu.nn.layers.base import LayerContext
