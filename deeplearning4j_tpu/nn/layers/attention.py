@@ -269,12 +269,15 @@ def rotary_embedding(x, positions, rotary_dim: int, theta: float):
 @register_serializable
 @dataclasses.dataclass(frozen=True)
 class GatedAttention(FeedForwardLayer):
-    """Grouped-query softmax attention with per-head q/k RMSNorm and a
-    rotary embedding, bias-free; the sigmoid output gate, the share of the
-    head that is rotated and which keys a query sees are fields. ``q <-
-    RMSNorm(q)``, ``k <- RMSNorm(k)`` over the head (zero-centred
-    weights); rotary on the first ``partial_rotary_factor`` of the head (1:
-    the whole head); ``n_heads / n_kv_heads`` query heads share a
+    """Grouped-query softmax attention, bias-free; the per-head q/k
+    RMSNorm, the sigmoid output gate, the share of the head that is
+    rotated and which keys a query sees are fields. With ``qk_norm`` (the
+    default, and what a configuration saved before the field was there
+    loads as) ``q <- RMSNorm(q)``, ``k <- RMSNorm(k)`` over the head
+    (zero-centred weights); without, neither norm nor their weights.
+    Rotary on the first ``partial_rotary_factor`` of the head (1: the
+    whole head; 0: no positional encoding at all, as the Nemotron-H
+    family's attention); ``n_heads / n_kv_heads`` query heads share a
     key/value head. With ``output_gate`` (the default: Qwen3-Next's gated
     attention, and what a configuration saved before the field was there
     loads as) ``W_q`` gives each query head a query and a gate of
@@ -292,8 +295,9 @@ class GatedAttention(FeedForwardLayer):
     the model width. Attention itself goes through
     ``ops.pallas_kernels.attention`` (the flash kernel from 1,024
     positions on a TPU, plain XLA else) with the key/value heads repeated
-    for it, under the named scope ``attn.gated`` or, with blocks,
-    ``attn.block_diffusion``."""
+    for it, under the named scope ``attn.gated``, with blocks
+    ``attn.block_diffusion``, and ``attn.causal`` where it is causal and
+    has neither gate nor q/k norm."""
     n_heads: int = 16
     n_kv_heads: int = 2
     head_dim: int = 256
@@ -303,8 +307,9 @@ class GatedAttention(FeedForwardLayer):
     init_std: float = 0.02
     output_gate: bool = True
     block_length: int = 0
+    qk_norm: bool = True
 
-    named_scopes = ("attn.gated", "attn.block_diffusion")
+    named_scopes = ("attn.gated", "attn.block_diffusion", "attn.causal")
 
     def __post_init__(self):
         if self.n_heads % self.n_kv_heads:
@@ -317,7 +322,10 @@ class GatedAttention(FeedForwardLayer):
 
     @property
     def scope(self) -> str:
-        return "attn.block_diffusion" if self.block_length else "attn.gated"
+        if self.block_length:
+            return "attn.block_diffusion"
+        return ("attn.gated" if self.output_gate or self.qk_norm
+                else "attn.causal")
 
     def output_type(self, input_type: InputType) -> InputType:
         t = (input_type.timesteps
@@ -333,16 +341,18 @@ class GatedAttention(FeedForwardLayer):
         def normal(k, shape):
             return self.init_std * jax.random.normal(k, shape, dt)
 
-        return {
+        params = {
             # per head: [query | gate], or the query alone
             "W_q": normal(kq, (n_in, h * (2 if self.output_gate else 1)
                                * dh)),
             "W_k": normal(kk, (n_in, hk * dh)),
             "W_v": normal(kv, (n_in, hk * dh)),
             "W_o": normal(ko, (h * dh, self.n_out)),
-            "q_norm": jnp.zeros((dh,), dt),
-            "k_norm": jnp.zeros((dh,), dt),
         }
+        if self.qk_norm:
+            params.update(q_norm=jnp.zeros((dh,), dt),
+                          k_norm=jnp.zeros((dh,), dt))
+        return params
 
     def apply(self, params, state, x, ctx: LayerContext):
         from deeplearning4j_tpu.nn.layers.normalization import rms_norm
@@ -364,16 +374,18 @@ class GatedAttention(FeedForwardLayer):
                 n, t, hk, dh)
             v = jnp.einsum("ntf,fe->nte", x, params["W_v"]).reshape(
                 n, t, hk, dh)
-            q = rms_norm(q, params["q_norm"], self.eps)
-            k = rms_norm(k, params["k_norm"], self.eps)
+            if self.qk_norm:
+                q = rms_norm(q, params["q_norm"], self.eps)
+                k = rms_norm(k, params["k_norm"], self.eps)
             rot = int(dh * self.partial_rotary_factor)
             pos = jnp.arange(t)
             vis = Causal()
             if self.block_length:
                 pos = pos % (t // 2)
                 vis = BlockDiffusion(t // 2, self.block_length)
-            q = rotary_embedding(q, pos, rot, self.rope_theta)
-            k = rotary_embedding(k, pos, rot, self.rope_theta)
+            if rot:
+                q = rotary_embedding(q, pos, rot, self.rope_theta)
+                k = rotary_embedding(k, pos, rot, self.rope_theta)
             rep = h // hk
             if rep > 1:
                 k = jnp.repeat(k, rep, axis=2)

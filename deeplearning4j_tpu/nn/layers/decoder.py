@@ -33,6 +33,20 @@ is a graph of ``TokenEmbedding``, blocks and ``CausalLMOutputLayer``, in
 which the head may read the embedding's table (``tied``) in place of a
 matrix of its own.
 
+``SingleMixerBlock`` is the unit of the Nemotron-H family
+(arXiv:2504.03624; Nemotron 3 Nano): **one** pre-norm residual a layer,
+
+    y = x + Mixer(RMSNorm(x))
+
+where the mixer is a ``Mamba2Mixer``, a causal ``GatedAttention`` with
+neither gate, q/k norm nor positional encoding, or the expert layer
+itself (``HeldExpertsMoE`` with plain relu^2 experts under a sigmoid
+router with a score-correction bias, the shared expert added ungated). A
+model is a ``MultiLayerNetwork`` of ``TokenEmbedding``, blocks in the
+order of the family's pattern string, ``CausalLMOutputLayer``. It shares
+``_ResidualBlock`` with ``HybridDecoderBlock``: the output type, the
+recomputation and the hand-off of the expert layer's state.
+
 ``recompute`` wraps a block's ``apply`` in ``jax.checkpoint`` while
 training, so the step ``fit()`` builds keeps each block's input for the
 backward pass and recomputes the block's internals there, but for the
@@ -73,7 +87,9 @@ from deeplearning4j_tpu.nn.layers.normalization import (
     rms_norm,
 )
 from deeplearning4j_tpu.nn.layers.state_space import (
+    SSD_CHUNK,
     GatedMemoryUnit,
+    Mamba2Mixer,
     MambaMixer,
 )
 from deeplearning4j_tpu.utils.serde import register_serializable
@@ -81,6 +97,9 @@ from deeplearning4j_tpu.utils.serde import register_serializable
 GATED_DELTANET = "gated_deltanet"
 GATED_ATTENTION = "gated_attention"
 BLOCK_DIFFUSION_ATTENTION = "block_diffusion_attention"
+MAMBA2 = "mamba2"
+CAUSAL_ATTENTION = "causal_attention"
+EXPERTS = "experts"
 MAMBA = "mamba"
 ATTENTION = "attention"
 GATED_MEMORY = "gated_memory"
@@ -136,9 +155,50 @@ class TokenEmbedding(Layer):
         return ((y, params["W"]) if self.emit_table else y), state
 
 
+_MOE_SCOPES = ("moe.route", "moe.dispatch", "moe.experts", "moe.shared",
+               "moe.combine")
+
+
+class _ResidualBlock:
+    """What the decoder blocks of a ``MultiLayerNetwork`` share: the
+    output's type, ``apply`` (``_apply`` under ``_recomputed`` while
+    training with ``recompute``), and the block's state, which is its
+    expert layer's (``_expert_layer``; None: the block has no state)."""
+
+    def output_type(self, input_type: InputType) -> InputType:
+        t = (input_type.timesteps
+             if isinstance(input_type, RecurrentType) else None)
+        return RecurrentType(self.n_out, t)
+
+    def _expert_layer(self) -> Optional[HeldExpertsMoE]:
+        raise NotImplementedError
+
+    def _check_width(self, input_type) -> int:
+        width = self.resolved_n_in(input_type)
+        if self.n_out and width != self.n_out:
+            raise ValueError(
+                f"{type(self).__name__} needs n_in == n_out (residuals); "
+                f"got {width} vs {self.n_out}")
+        return width
+
+    def init_state(self, input_type):
+        moe = self._expert_layer()
+        return {} if moe is None else moe.init_state(input_type)
+
+    def upgrade_state(self, saved):
+        moe = self._expert_layer()
+        return saved if moe is None else moe.upgrade_state(saved)
+
+    def apply(self, params, state, x, ctx: LayerContext):
+        if self.recompute and ctx.train:
+            return _recomputed(
+                lambda p, s, a: self._apply(p, s, a, ctx))(params, state, x)
+        return self._apply(params, state, x, ctx)
+
+
 @register_serializable
 @dataclasses.dataclass(frozen=True)
-class HybridDecoderBlock(FeedForwardLayer):
+class HybridDecoderBlock(_ResidualBlock, FeedForwardLayer):
     """One decoder block (module docstring). ``mixer`` picks the token
     mixer; the fields after it are the parts' own (``GatedAttention``,
     ``GatedDeltaNet``, ``HeldExpertsMoE``), kept flat so that the block
@@ -180,9 +240,7 @@ class HybridDecoderBlock(FeedForwardLayer):
 
     # the ``jax.named_scope`` names this block's parts put into a step
     named_scopes = (("gdn.proj", "gdn.conv", "gdn.scan", "gdn.out")
-                    + GatedAttention.named_scopes
-                    + ("moe.route", "moe.dispatch", "moe.experts",
-                       "moe.shared", "moe.combine"))
+                    + GatedAttention.named_scopes + _MOE_SCOPES)
 
     def __post_init__(self):
         kinds = (GATED_DELTANET, GATED_ATTENTION, BLOCK_DIFFUSION_ATTENTION)
@@ -190,11 +248,6 @@ class HybridDecoderBlock(FeedForwardLayer):
             raise ValueError(
                 f"mixer={self.mixer!r}: one of "
                 + ", ".join(repr(k) for k in kinds))
-
-    def output_type(self, input_type: InputType) -> InputType:
-        t = (input_type.timesteps
-             if isinstance(input_type, RecurrentType) else None)
-        return RecurrentType(self.n_out, t)
 
     def _parts(self):
         w = self.n_out
@@ -224,12 +277,11 @@ class HybridDecoderBlock(FeedForwardLayer):
             aux_loss_coef=self.router_aux_loss_coef, **common)
         return mixer, moe, RMSNorm(eps=self.eps, dtype=self.dtype)
 
+    def _expert_layer(self):
+        return self._parts()[1]
+
     def initialize(self, key, input_type):
-        width = self.resolved_n_in(input_type)
-        if self.n_out and width != self.n_out:
-            raise ValueError(
-                f"HybridDecoderBlock needs n_in == n_out (residuals); got "
-                f"{width} vs {self.n_out}")
+        width = self._check_width(input_type)
         mixer, moe, norm = self._parts()
         km, ke = jax.random.split(key)
         rt = RecurrentType(width, None)
@@ -237,12 +289,6 @@ class HybridDecoderBlock(FeedForwardLayer):
                 "mixer": mixer.initialize(km, rt),
                 "norm2": norm.initialize(None, rt),
                 "moe": moe.initialize(ke, rt)}
-
-    def init_state(self, input_type):
-        return self._parts()[1].init_state(input_type)
-
-    def upgrade_state(self, saved):
-        return self._parts()[1].upgrade_state(saved)
 
     def _apply(self, params, state, x, ctx: LayerContext):
         mixer, moe, norm = self._parts()
@@ -253,11 +299,100 @@ class HybridDecoderBlock(FeedForwardLayer):
         f, new_state = moe.apply(params["moe"], state, h, ctx)
         return x + f, new_state
 
-    def apply(self, params, state, x, ctx: LayerContext):
-        if self.recompute and ctx.train:
-            return _recomputed(
-                lambda p, s, a: self._apply(p, s, a, ctx))(params, state, x)
-        return self._apply(params, state, x, ctx)
+
+@register_serializable
+@dataclasses.dataclass(frozen=True)
+class SingleMixerBlock(_ResidualBlock, FeedForwardLayer):
+    """One layer of the Nemotron-H family (module docstring): a norm, one
+    mixer, a residual. ``mixer`` picks the mixer; the fields after it are
+    the parts' own (``Mamba2Mixer``, ``GatedAttention``,
+    ``HeldExpertsMoE``), kept flat so that the block serialises as one
+    layer. The attention has no gate, no q/k norm and no positional
+    encoding; the experts are plain (``relu2``) under a sigmoid router
+    whose bias the load moves by ``bias_update_rate`` a step, their
+    weights times ``routed_scale``, the shared expert added ungated.
+    ``n_out`` is the model width and equals the input's. An ``experts``
+    block's state is its expert layer's; the others have none.
+
+    ``recompute``: as ``HybridDecoderBlock``'s."""
+    mixer: str = MAMBA2
+    # causal attention
+    n_heads: int = 32
+    n_kv_heads: int = 2
+    head_dim: int = 128
+    # mamba-2
+    mamba_heads: int = 64
+    mamba_head_dim: int = 64
+    n_groups: int = 8
+    d_state: int = 128
+    conv_kernel: int = 4
+    chunk_size: int = SSD_CHUNK
+    dt_min: float = 1e-3
+    dt_max: float = 0.1
+    dt_floor: float = 1e-4
+    # experts
+    num_experts: int = 8
+    held_experts: Tuple[int, ...] = ()
+    expert_hidden: int = 0
+    shared_hidden: int = 0
+    top_k: int = 2
+    norm_topk: bool = True
+    routed_scale: float = 1.0
+    bias_update_rate: float = 0.0
+    router_aux_loss_coef: float = 0.0
+    eps: float = 1e-5
+    init_std: float = 0.02
+    recompute: bool = False
+
+    named_scopes = (Mamba2Mixer.named_scopes + GatedAttention.named_scopes
+                    + _MOE_SCOPES)
+
+    def __post_init__(self):
+        if self.mixer not in (MAMBA2, CAUSAL_ATTENTION, EXPERTS):
+            raise ValueError(
+                f"mixer={self.mixer!r}: one of {MAMBA2!r}, "
+                f"{CAUSAL_ATTENTION!r}, {EXPERTS!r}")
+
+    def _mixer(self):
+        common = dict(n_in=self.n_out, n_out=self.n_out, dtype=self.dtype,
+                      init_std=self.init_std)
+        if self.mixer == MAMBA2:
+            return Mamba2Mixer(
+                name=self.name, n_heads=self.mamba_heads,
+                head_dim=self.mamba_head_dim, n_groups=self.n_groups,
+                d_state=self.d_state, d_conv=self.conv_kernel,
+                chunk_size=self.chunk_size, dt_min=self.dt_min,
+                dt_max=self.dt_max, dt_floor=self.dt_floor, eps=self.eps,
+                **common)
+        if self.mixer == CAUSAL_ATTENTION:
+            return GatedAttention(
+                n_heads=self.n_heads, n_kv_heads=self.n_kv_heads,
+                head_dim=self.head_dim, eps=self.eps,
+                partial_rotary_factor=0.0, output_gate=False,
+                qk_norm=False, **common)
+        return HeldExpertsMoE(
+            num_experts=self.num_experts, held_experts=self.held_experts,
+            hidden=self.expert_hidden, shared_hidden=self.shared_hidden,
+            top_k=self.top_k, norm_topk=self.norm_topk,
+            aux_loss_coef=self.router_aux_loss_coef, expert_form="relu2",
+            router_scoring="sigmoid", routed_scale=self.routed_scale,
+            shared_gate=False, bias_update_rate=self.bias_update_rate,
+            **common)
+
+    def _expert_layer(self):
+        return self._mixer() if self.mixer == EXPERTS else None
+
+    def initialize(self, key, input_type):
+        rt = RecurrentType(self._check_width(input_type), None)
+        return {"norm": RMSNorm(eps=self.eps, dtype=self.dtype).initialize(
+                    None, rt),
+                "mixer": self._mixer().initialize(key, rt)}
+
+    def _apply(self, params, state, x, ctx: LayerContext):
+        h, _ = RMSNorm(eps=self.eps, dtype=self.dtype).apply(
+            params["norm"], {}, x, ctx)
+        m, new_state = self._mixer().apply(params["mixer"], state, h, ctx)
+        return x + m, new_state
 
 
 @register_serializable

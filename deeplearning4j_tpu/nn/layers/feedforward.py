@@ -416,33 +416,56 @@ class MixtureOfExperts(FeedForwardLayer):
 @register_serializable
 @dataclasses.dataclass(frozen=True)
 class HeldExpertsMoE(FeedForwardLayer):
-    """A chip's share of a mixture of gated (SwiGLU) experts, with no
-    token dropped (``parallel.moe.held_experts_ffn``), plus the shared
-    expert every chip computes alike:
+    """A chip's share of a mixture of experts, with no token dropped
+    (``parallel.moe.held_experts_ffn``), plus the shared expert every chip
+    computes alike:
 
-        y = sum_{i in top-k(x), i held here} p_i E_i(x)
-            + sigmoid(x . w_s) * E_shared(x)
+        y = sum_{i in top-k(x), i held here} p_i E_i(x)  +  g(x) E_shared(x)
 
-    ``E(x) = W_down(silu(W_gate x) * W_up x)``, no biases; ``p`` the
-    float32 softmax over ALL ``num_experts``, its top ``top_k``
-    renormalised to sum 1 (``norm_topk``). ``held_experts`` names the
-    experts whose weights live here (default: all of them); the router
-    keeps ``num_experts`` outputs whatever is held, and what the absent
-    experts would add is left out. ``shared_hidden`` 0 leaves the shared
-    expert out (a share that is not the one to count it).
+    No biases. The defaults are the Qwen3-Next / Qwen3-MoE families' (and
+    what a configuration saved before the fields were there loads as);
+    the other values are the Nemotron-H / DeepSeek-V3 families':
+
+    - ``expert_form`` ``"gated"``: ``E(x) = W_down(silu(W_gate x) * W_up
+      x)`` (SwiGLU; three grouped products a block). ``"relu2"``: the
+      plain form ``E(x) = W_down relu(W_up x)^2`` (two; no ``w_gate`` is
+      allocated). The shared expert has the routed experts' form.
+    - ``router_scoring`` ``"softmax"``: ``p`` the float32 softmax over ALL
+      ``num_experts``, its top ``top_k`` renormalised to sum 1
+      (``norm_topk``). ``"sigmoid"``: each expert's own float32 sigmoid
+      score ``s``; the ``top_k`` are the largest of ``s + b``, ``b`` the
+      score-correction bias (``num_experts`` numbers in the layer's state,
+      ``moe_router_bias``, which no gradient reaches), and ``p`` the
+      chosen experts' unbiased ``s`` over their sum.
+    - ``routed_scale`` multiplies ``p`` (``routed_scaling_factor``).
+    - ``shared_gate``: ``g(x) = sigmoid(x . w_s)``; off, the shared expert
+      is added ungated and no ``shared_w`` is allocated.
+    - ``bias_update_rate`` u above 0 (sigmoid scoring): while training,
+      each step moves the bias towards the even load by its own counts,
+      ``b_e <- b_e + u sign(mean(c) - c_e)``, ``c_e`` the assignments
+      output e received over all ``num_experts`` (DeepSeek-V3,
+      arXiv:2412.19437 section 2.1.2); the new bias is the next step's.
+
+    ``held_experts`` names the experts whose weights live here (default:
+    all of them); the router keeps ``num_experts`` outputs whatever is
+    held, and what the absent experts would add is left out.
+    ``shared_hidden`` 0 leaves the shared expert out (a share that is not
+    the one to count it).
 
     The layer's state carries the step's routing counters
     (``parallel.moe.ROUTING_COUNTERS``: assignments that landed on held
     experts, the largest and the mean load of a held expert, dropped
-    assignments, always 0) and, fifth, the blocks the step's dispatch loop
-    ran (``ceil(assignments_held / parallel.moe.dispatch_block(...))``):
-    float32[5] under ``moe_routing``.
+    assignments, always 0), fifth the blocks the step's dispatch loop
+    ran (``ceil(assignments_held / parallel.moe.dispatch_block(...))``)
+    and, under a sigmoid router, sixth the largest ``|b_e|``: float32[5]
+    or [6] under ``moe_routing``.
 
     ``aux_loss_coef`` above 0 adds that many times the router's
     load-balancing loss (``parallel.moe.load_balancing_loss``, over all
-    ``num_experts`` outputs, this layer's own tokens) to the training
-    loss, through the state's ``moe_aux_loss`` as ``MixtureOfExperts``
-    does; at 0 the layer computes none."""
+    ``num_experts`` outputs, this layer's own tokens; a sigmoid router's
+    scores normalised to sum 1 a token) to the training loss, through
+    the state's ``moe_aux_loss`` as ``MixtureOfExperts`` does; at 0 the
+    layer computes none."""
     num_experts: int = 8
     held_experts: Tuple[int, ...] = ()
     hidden: int = 0              # a routed expert's width
@@ -451,11 +474,24 @@ class HeldExpertsMoE(FeedForwardLayer):
     norm_topk: bool = True
     init_std: float = 0.02
     aux_loss_coef: float = 0.0
+    expert_form: str = "gated"
+    router_scoring: str = "softmax"
+    routed_scale: float = 1.0
+    shared_gate: bool = True
+    bias_update_rate: float = 0.0
 
     @property
     def held(self) -> Tuple[int, ...]:
         return (tuple(self.held_experts) if self.held_experts
                 else tuple(range(self.num_experts)))
+
+    @property
+    def _gated(self) -> bool:
+        return self.expert_form == "gated"
+
+    @property
+    def _biased(self) -> bool:
+        return self.router_scoring == "sigmoid"
 
     def __post_init__(self):
         held = self.held
@@ -464,6 +500,15 @@ class HeldExpertsMoE(FeedForwardLayer):
             raise ValueError(
                 f"held_experts={held} must be distinct ids below "
                 f"num_experts={self.num_experts}")
+        if self.expert_form not in ("gated", "relu2"):
+            raise ValueError(
+                f"expert_form={self.expert_form!r}: 'gated' or 'relu2'")
+        if self.router_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"router_scoring={self.router_scoring!r}: "
+                             "'softmax' or 'sigmoid'")
+        if self.bias_update_rate and not self._biased:
+            raise ValueError("bias_update_rate moves a sigmoid router's "
+                             "bias; router_scoring is 'softmax'")
 
     def output_type(self, input_type: InputType) -> InputType:
         if isinstance(input_type, RecurrentType):
@@ -481,51 +526,81 @@ class HeldExpertsMoE(FeedForwardLayer):
 
         params = {
             "router": normal(ks[0], (n_in, self.num_experts)),
-            "w_gate": normal(ks[1], (g, n_in, f)),
             "w_up": normal(ks[2], (g, n_in, f)),
             "w_down": normal(ks[3], (g, f, self.n_out)),
         }
+        if self._gated:
+            params["w_gate"] = normal(ks[1], (g, n_in, f))
         if fs:
-            params.update(
-                shared_gate=normal(ks[4], (n_in, fs)),
-                shared_up=normal(ks[5], (n_in, fs)),
-                shared_down=normal(ks[6], (fs, self.n_out)),
-                shared_w=normal(ks[7], (n_in,)))
+            params.update(shared_up=normal(ks[5], (n_in, fs)),
+                          shared_down=normal(ks[6], (fs, self.n_out)))
+            if self._gated:
+                params["shared_gate"] = normal(ks[4], (n_in, fs))
+            if self.shared_gate:
+                params["shared_w"] = normal(ks[7], (n_in,))
         return params
 
+    @property
+    def _row(self) -> int:
+        """Length of the state's ``moe_routing`` row."""
+        return 6 if self._biased else 5
+
     def init_state(self, input_type):
-        state = {"moe_routing": jnp.zeros((5,), jnp.float32)}
+        state = {"moe_routing": jnp.zeros((self._row,), jnp.float32)}
         if self.aux_loss_coef:
             state["moe_aux_loss"] = jnp.zeros((), jnp.float32)
+        if self._biased:
+            state["moe_router_bias"] = jnp.zeros((self.num_experts,),
+                                                 jnp.float32)
         return state
 
     def upgrade_state(self, saved):
         # a row saved with the four counters reads 0 blocks until a step
         row = saved["moe_routing"]
-        return {**saved, "moe_routing": jnp.pad(row, (0, max(0, 5 - row.size)))}
+        return {**saved, "moe_routing": jnp.pad(
+            row, (0, max(0, self._row - row.size)))}
+
+    def _shared(self, params, xt):
+        from deeplearning4j_tpu.parallel.moe import _gated, _relu2
+        up = xt @ params["shared_up"]
+        h = (_gated(up, xt @ params["shared_gate"]) if self._gated
+             else _relu2(up))
+        y = h @ params["shared_down"]
+        if not self.shared_gate:
+            return y
+        gate = jax.nn.sigmoid(jnp.einsum(
+            "td,d->t", xt, params["shared_w"],
+            preferred_element_type=jnp.promote_types(jnp.float32, xt.dtype)))
+        return y * gate[:, None].astype(y.dtype)
 
     def apply(self, params, state, x, ctx):
         from deeplearning4j_tpu.parallel.moe import (dispatch_block,
                                                      held_experts_ffn)
         xt = x.reshape(-1, x.shape[-1])
-        y, counters, *aux = held_experts_ffn(
-            xt, params["router"], params["w_gate"], params["w_up"],
+        bias = state["moe_router_bias"] if self._biased else None
+        moving = bool(self.bias_update_rate) and ctx.train
+        y, counters, *more = held_experts_ffn(
+            xt, params["router"], params.get("w_gate"), params["w_up"],
             params["w_down"], self.held, top_k=self.top_k,
-            norm_topk=self.norm_topk, balance=bool(self.aux_loss_coef))
-        blocks = jnp.ceil(counters[0] / dispatch_block(
-            xt.shape[0], self.top_k, len(self.held), self.num_experts))
+            norm_topk=self.norm_topk, balance=bool(self.aux_loss_coef),
+            scoring=self.router_scoring, router_bias=bias,
+            routed_scale=self.routed_scale, received=moving)
+        row = [counters, jnp.ceil(counters[0] / dispatch_block(
+            xt.shape[0], self.top_k, len(self.held), self.num_experts))]
         if self.shared_hidden:
             with jax.named_scope("moe.shared"):
-                h = (jax.nn.silu(xt @ params["shared_gate"])
-                     * (xt @ params["shared_up"]))
-                gate = jax.nn.sigmoid(jnp.einsum(
-                    "td,d->t", xt, params["shared_w"],
-                    preferred_element_type=jnp.promote_types(
-                        jnp.float32, xt.dtype)))
-                y = y + (h @ params["shared_down"]) * gate[:, None].astype(
-                    y.dtype)
+                y = y + self._shared(params, xt)
         y = y.reshape(x.shape[:-1] + (self.n_out,))
-        new_state = {"moe_routing": jnp.append(counters, blocks)}
-        if aux:
-            new_state["moe_aux_loss"] = self.aux_loss_coef * aux[0]
+        new_state = {}
+        if self.aux_loss_coef:
+            new_state["moe_aux_loss"] = self.aux_loss_coef * more[0]
+        if self._biased:
+            if moving:
+                with jax.named_scope("moe.route"):
+                    got = jax.lax.stop_gradient(more[-1])
+                    bias = bias + self.bias_update_rate * jnp.sign(
+                        jnp.mean(got) - got)
+            new_state["moe_router_bias"] = bias
+            row.append(jnp.max(jnp.abs(bias)))
+        new_state["moe_routing"] = jnp.hstack(row)
         return y, new_state

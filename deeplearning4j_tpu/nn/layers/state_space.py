@@ -1,8 +1,9 @@
-"""State-space layers: the selective scan (Mamba-1) and its token mixer.
+"""State-space layers: the selective scan (Mamba-1), the state-space
+duality scan (Mamba-2) and their token mixers.
 
-A selective state-space layer keeps, for each of its ``D`` channels, a
-state of ``S`` numbers and moves it by a transition that depends on the
-token:
+**Mamba-1.** A selective state-space layer keeps, for each of its ``D``
+channels, a state of ``S`` numbers and moves it by a transition that
+depends on the token:
 
     s_t[c, n] = exp(dt_t[c] A[c, n]) s_(t-1)[c, n] + dt_t[c] B_t[n] x_t[c]
     y_t[c]    = sum_n C_t[n] s_t[c, n]
@@ -26,6 +27,32 @@ runs the same scan as Pallas kernels on a TPU and this form elsewhere.
 Inside the loops the state is laid out (N, S, D), channels last, and the
 per-token inputs are rows of (T, N * D) arrays: both fill the TPU's 8 x
 128 tiles at any batch size.
+
+**Mamba-2** (state-space duality, arXiv:2405.21060). The channels are
+``H`` heads of ``P``; a head has **one scalar decay** where Mamba-1 has a
+rate for every (channel, state) pair, and a state of ``P x S``; ``B_t``
+and ``C_t`` (``G`` groups of ``S``) are shared by the ``H / G`` heads of a
+group:
+
+    S_t[j] = exp(dt_t[j] A[j]) S_(t-1)[j] + dt_t[j] x_t[j] B_t[g(j)]^T
+    y_t[j] = S_t[j] C_t[g(j)]
+
+With a scalar decay the recurrence over a chunk of ``Q`` tokens is a
+masked matrix product: ``ssd_chunked``, the training form, takes the
+decays' cumulative sums inside the chunk, whose differences give the
+lower-triangular ``L[i, j]`` = decay from token j to token i, computes
+``(L o C B^T) (dt x)`` as two batched matrix products, the state the
+chunk leaves behind ``(decay to the chunk's end * dt x)^T B`` as a third
+and what the state it began with adds to its rows, ``exp(cum) C S``, as a
+fourth; a ``lax.scan`` over the chunks carries the (H, P, S) state, each
+chunk under ``jax.checkpoint``, so the backward pass keeps the border
+states alone. Every exponent is a difference of cumulative sums of
+``dt A`` <= 0 taken forward in time, so none is positive and nothing is
+divided by a decay. ``ssd_step`` is one token (a decode step's form),
+``ssd_recurrent`` the recurrence as written (the tests' oracle). ``dt``,
+``A``, the state and ``y`` are float32; the matrix products take their
+operands in ``x``'s type (bfloat16 under the bf16 policy, as the
+published kernels do) and accumulate in float32.
 """
 
 from __future__ import annotations
@@ -40,6 +67,7 @@ from deeplearning4j_tpu.nn.inputs import InputType, RecurrentType
 from deeplearning4j_tpu.nn.layers.base import FeedForwardLayer, LayerContext
 from deeplearning4j_tpu.nn.layers.linear_attention import (
     causal_depthwise_conv)
+from deeplearning4j_tpu.nn.layers.normalization import rms_norm
 from deeplearning4j_tpu.utils.serde import register_serializable
 
 
@@ -109,6 +137,115 @@ def selective_scan_chunked(x, dt, a, b, c, chunk_size: int = CHUNK):
 
     _, y = jax.lax.scan(chunk, jnp.zeros((n, s, d), f32), chunks)
     return jnp.moveaxis(y.reshape(t + pad, n, d)[:t], 0, 1)
+
+
+# ---- Mamba-2: the state-space duality scan ---------------------------------
+
+# tokens a chunk of the training form (the published ``chunk_size``)
+SSD_CHUNK = 128
+
+SSD_CHUNKS_GAUGE = (
+    "dl4j_ssd_chunks",
+    "chunks one pass of the Mamba-2 scan (ssd_chunked) walks, as the step "
+    "was last traced (label: the layer)")
+
+
+def _per_head(v, heads: int):
+    """``B`` or ``C`` (..., G, S) as each of the ``heads`` reads it: head
+    ``j`` its group ``j // (heads / G)``."""
+    return jnp.repeat(v, heads // v.shape[-2], axis=-2)
+
+
+def ssd_step(state, x_t, dt_t, a, b_t, c_t):
+    """One token. ``state`` (N, H, P, S); ``x_t`` (N, H, P); ``dt_t`` (N,
+    H); ``a`` (H,); ``b_t``, ``c_t`` (N, G, S). Returns the new state and
+    ``y_t`` (N, H, P)."""
+    heads = x_t.shape[-2]
+    b_t, c_t = _per_head(b_t, heads), _per_head(c_t, heads)
+    state = (jnp.exp(dt_t * a)[..., None, None] * state
+             + (dt_t[..., None] * x_t)[..., None] * b_t[..., None, :])
+    return state, jnp.einsum("nhps,nhs->nhp", state, c_t)
+
+
+def _ssd_initial(initial_state, n, h, p, s, dtype):
+    return (jnp.zeros((n, h, p, s), dtype) if initial_state is None
+            else initial_state.astype(dtype))
+
+
+def ssd_recurrent(x, dt, a, b, c, initial_state=None):
+    """The recurrence token by token. ``x`` (N, T, H, P); ``dt`` (N, T,
+    H); ``a`` (H,); ``b``, ``c`` (N, T, G, S). Returns ``(y (N, T, H, P),
+    final state (N, H, P, S))`` in float32 (float64 inputs stay
+    float64)."""
+    f32 = jnp.promote_types(jnp.float32, x.dtype)
+    n, _, h, p = x.shape
+    xs = tuple(jnp.moveaxis(v.astype(f32), 1, 0) for v in (x, dt, b, c))
+
+    def token(state, xs):
+        return ssd_step(state, xs[0], xs[1], a.astype(f32), *xs[2:])
+
+    state, y = jax.lax.scan(
+        token, _ssd_initial(initial_state, n, h, p, b.shape[-1], f32), xs)
+    return jnp.moveaxis(y, 0, 1), state
+
+
+def ssd_chunked(x, dt, a, b, c, chunk_size: int = SSD_CHUNK,
+                initial_state=None):
+    """The same ``(y, final state)`` in chunks of ``chunk_size`` tokens
+    (module docstring); ``chunk_size`` need not divide T (the tail is
+    padded with tokens of step 0, which leave the state alone). The
+    matrix products' operands are of ``x``'s type; ``y`` and the state are
+    float32."""
+    mm = x.dtype
+    f32 = jnp.promote_types(jnp.float32, mm)
+    n, t, h, p = x.shape
+    g, s = b.shape[-2:]
+    r = h // g                                  # heads that share a group
+    q = int(chunk_size)
+    pad = (-t) % q
+
+    def chunks(v):          # (N, T, ...) -> (chunks, N, Q, ...)
+        v = jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
+        return jnp.moveaxis(v.reshape((n, -1, q) + v.shape[2:]), 1, 0)
+
+    def dot(spec, u, v):
+        return jnp.einsum(spec, u.astype(mm), v.astype(mm),
+                          preferred_element_type=f32)
+
+    a = a.astype(f32)
+    lower = jnp.tril(jnp.ones((q, q), bool))
+
+    @jax.checkpoint
+    def chunk(state, xs):
+        x_c, dt_c, b_c, c_c = xs        # (N, Q, H, P), (N, Q, H), (N, Q, G, S)
+        cum = jnp.cumsum(dt_c * a, axis=1)      # <= 0, falling along Q
+        by_head = jnp.moveaxis(cum, 1, 2).reshape(n, g, r, q)
+        # decay from token j to token i >= j; masked before the
+        # exponential, whose argument above the diagonal is > 0
+        decay = jnp.exp(jnp.where(
+            lower, by_head[..., :, None] - by_head[..., None, :], -jnp.inf))
+        step = jnp.moveaxis(dt_c, 1, 2).reshape(n, g, r, 1, q)
+        scores = dot("nigs,njgs->ngij", c_c, b_c)[:, :, None]
+        y = dot("ngrij,njgrp->nigrp", scores * decay * step,
+                x_c.reshape(n, q, g, r, p))
+        # what the state the chunk began with adds to its rows
+        began = dot("nigs,ngrps->nigrp", c_c, state.reshape(n, g, r, p, s))
+        y = y.reshape(n, q, h, p) + jnp.exp(cum)[..., None] * began.reshape(
+            n, q, h, p)
+        # the state it leaves: each token's write decayed to the chunk's end
+        last = cum[:, -1]
+        left = x_c.astype(f32) * (jnp.exp(last[:, None] - cum) * dt_c)[
+            ..., None]
+        wrote = dot("njgrp,njgs->ngrps", left.reshape(n, q, g, r, p), b_c)
+        state = (jnp.exp(last)[..., None, None] * state
+                 + wrote.reshape(n, h, p, s))
+        return state, y
+
+    state, y = jax.lax.scan(
+        chunk, _ssd_initial(initial_state, n, h, p, s, f32),
+        (chunks(x), chunks(dt.astype(f32)), chunks(b), chunks(c)))
+    return jnp.moveaxis(y, 0, 1).reshape(n, t + pad, h, p)[:, :t], state
+
 
 
 @register_serializable
@@ -204,6 +341,127 @@ class MambaMixer(FeedForwardLayer):
 
     def apply(self, params, state, x, ctx: LayerContext):
         return self.mix(params, x)[0], state
+
+
+def grouped_rms_norm(x, w, groups: int, eps: float):
+    """RMSNorm over each of ``groups`` equal runs of the last axis of
+    ``x``, times the plain weight ``w`` (one a channel); statistics in
+    float32, the result in ``x``'s type."""
+    grouped = x.shape[:-1] + (groups, -1)
+    return rms_norm(x.reshape(grouped), w.reshape(groups, -1), eps,
+                    zero_centered=False).reshape(x.shape)
+
+
+@register_serializable
+@dataclasses.dataclass(frozen=True)
+class Mamba2Mixer(FeedForwardLayer):
+    """Mamba-2 token mixer over (N, T, F) (arXiv:2405.21060, as the
+    Nemotron-H family writes it), bias-free but for the convolution's:
+
+    ``[z | xBC | dt] = h W_in`` with widths ``H P`` | ``H P + 2 G S`` |
+    ``H``; ``xBC <- silu(conv(xBC) + b_conv)`` (causal, depthwise,
+    ``d_conv`` taps, over all its channels); ``[x | B | C] = xBC``, ``x``
+    as (H, P), ``B`` and ``C`` as (G, S); ``dt = softplus(dt + dt_bias)``,
+    ``A = -exp(A_log)`` (one a head); the state-space duality scan
+    (``ssd_chunked``, chunks of ``chunk_size``); ``y <- y + D x`` (``D``
+    one a head); ``o = GroupRMSNorm(y * silu(z))``: the gate first, then
+    an RMSNorm over each of the ``G`` groups of ``H P / G`` channels times
+    a plain weight; the result ``o W_out``. ``dt``, ``A``, the state and
+    ``y`` are float32.
+
+    Matrices start normal(0, ``init_std``), the filter uniform in
+    +-1/sqrt(taps) with a zero bias, ``A_log`` = log(1..H), ``D`` = 1, the
+    norm's weight 1, and ``dt_bias`` the inverse softplus of a log-uniform
+    draw in [``dt_min``, ``dt_max``] floored at ``dt_floor``. ``n_out`` is
+    the model width. With a name the layer publishes the chunks a pass
+    walks as the gauge ``dl4j_ssd_chunks`` when the step is traced."""
+    n_heads: int = 64
+    head_dim: int = 64
+    n_groups: int = 8
+    d_state: int = 128
+    d_conv: int = 4
+    chunk_size: int = SSD_CHUNK
+    dt_min: float = 1e-3
+    dt_max: float = 0.1
+    dt_floor: float = 1e-4
+    eps: float = 1e-5
+    init_std: float = 0.02
+
+    named_scopes = ("ssd.proj", "ssd.conv", "ssd.scan", "ssd.out")
+
+    def __post_init__(self):
+        if self.n_heads % self.n_groups:
+            raise ValueError(
+                f"n_heads={self.n_heads} is not a multiple of "
+                f"n_groups={self.n_groups}")
+
+    def output_type(self, input_type: InputType) -> InputType:
+        t = (input_type.timesteps
+             if isinstance(input_type, RecurrentType) else None)
+        return RecurrentType(self.n_out, t)
+
+    @property
+    def _dims(self):
+        """Widths of ``x`` (and ``z``) and of ``B`` (and ``C``)."""
+        return self.n_heads * self.head_dim, self.n_groups * self.d_state
+
+    def initialize(self, key, input_type):
+        n_in = self.resolved_n_in(input_type)
+        d, bc = self._dims
+        h = self.n_heads
+        dt = self.param_dtype()
+        ks = jax.random.split(key, 4)
+        step = jnp.maximum(self.dt_floor, jnp.exp(jax.random.uniform(
+            ks[2], (h,), dt, math.log(self.dt_min), math.log(self.dt_max))))
+        return {
+            "W_in": self.init_std * jax.random.normal(
+                ks[0], (n_in, 2 * d + 2 * bc + h), dt),
+            "conv_w": jax.random.uniform(
+                ks[1], (d + 2 * bc, self.d_conv), dt, -1.0, 1.0)
+            / jnp.sqrt(float(self.d_conv)),
+            "conv_b": jnp.zeros((d + 2 * bc,), dt),
+            # softplus(dt_bias) = step
+            "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+            "A_log": jnp.log(jnp.arange(1, h + 1, dtype=dt)),
+            "D": jnp.ones((h,), dt),
+            "norm_w": jnp.ones((d,), dt),
+            "W_out": self.init_std * jax.random.normal(
+                ks[3], (d, self.n_out), dt),
+        }
+
+    def apply(self, params, state, x, ctx: LayerContext):
+        n, t, _ = x.shape
+        d, bc = self._dims
+        h, p, g = self.n_heads, self.head_dim, self.n_groups
+        f32 = jnp.promote_types(jnp.float32, x.dtype)
+        if self.name:
+            from deeplearning4j_tpu.observe.registry import default_registry
+            default_registry().gauge(*SSD_CHUNKS_GAUGE).set(
+                -(-t // self.chunk_size), layer=self.name)
+        with jax.named_scope("ssd.proj"):
+            zxd = jnp.einsum("ntf,fe->nte", x, params["W_in"])
+            z, xbc = zxd[..., :d], zxd[..., d:2 * d + 2 * bc]
+            step = jax.nn.softplus(zxd[..., 2 * d + 2 * bc:].astype(f32)
+                                   + params["dt_bias"].astype(f32))
+        with jax.named_scope("ssd.conv"):
+            xbc = jax.nn.silu(
+                causal_depthwise_conv(xbc.astype(f32),
+                                      params["conv_w"].astype(f32))
+                + params["conv_b"].astype(f32)).astype(x.dtype)
+        with jax.named_scope("ssd.scan"):
+            u = xbc[..., :d].reshape(n, t, h, p)
+            y, _ = ssd_chunked(
+                u, step, -jnp.exp(params["A_log"].astype(f32)),
+                xbc[..., d:d + bc].reshape(n, t, g, self.d_state),
+                xbc[..., d + bc:].reshape(n, t, g, self.d_state),
+                chunk_size=self.chunk_size)
+            y = y + params["D"].astype(f32)[:, None] * u.astype(f32)
+            gated = y.reshape(n, t, d) * jax.nn.silu(z.astype(f32))
+            o = grouped_rms_norm(gated, params["norm_w"], g,
+                                 self.eps).astype(x.dtype)
+        with jax.named_scope("ssd.out"):
+            out = jnp.einsum("ntd,do->nto", o, params["W_out"])
+        return out, state
 
 
 @register_serializable

@@ -641,6 +641,10 @@ ROUTING_GAUGES = (
     ("dl4j_moe_dispatch_blocks",
      "blocks of rows the held experts' dispatch loop ran in the last step: "
      "the held assignments over the block, rounded up"),
+    ("dl4j_moe_router_bias_absmax",
+     "largest |b_e| of a sigmoid router's score-correction bias after the "
+     "last step: how far the load has moved it (layers whose router has "
+     "none publish no such series)"),
 )
 
 
@@ -648,7 +652,8 @@ def publish_routing(rows: Dict[str, Any],
                     registry: Optional[MetricsRegistry] = None) -> None:
     """``rows``: layer name -> the row a ``HeldExpertsMoE`` leaves in its
     state (``parallel.moe.ROUTING_COUNTERS``' order, then the dispatch
-    loop's blocks), on the host. Each value becomes a gauge with a
+    loop's blocks, then, under a sigmoid router, its bias's largest
+    magnitude), on the host. Each value becomes a gauge with a
     ``layer`` label."""
     reg = registry if registry is not None else default_registry()
     for layer, row in rows.items():

@@ -20,9 +20,10 @@ ABSENT in the reference (SURVEY §2.11 row 7); designed fresh per SURVEY
   the chip is told which of them it holds, the assignments that land on
   held experts are sorted by expert and go, a block of rows at a time
   and as many blocks as the step's routing filled, through grouped
-  matrix products (``lax.ragged_dot``), gated (SwiGLU) experts without
-  biases: its time follows the load that landed here, not the most that
-  could. What the absent experts would add is left out: this is the
+  matrix products (``lax.ragged_dot``), experts without biases that are
+  gated (SwiGLU, three products) or plain with a squared ReLU (two),
+  under a softmax router or a sigmoid one with a score-correction bias:
+  its time follows the load that landed here, not the most that could. What the absent experts would add is left out: this is the
   chip's share of an expert-parallel deployment, computed without the
   exchange, and the layer a held-experts deployment uses.
 """
@@ -30,6 +31,8 @@ ABSENT in the reference (SURVEY §2.11 row 7); designed fresh per SURVEY
 from __future__ import annotations
 
 import dataclasses
+import functools
+import operator
 from typing import Optional
 
 import jax
@@ -177,22 +180,42 @@ def moe_ffn(x: jnp.ndarray,
 ROUTING_COUNTERS = ("assignments_held", "load_max", "load_mean", "dropped")
 
 
-def router_probs(x, router_w):
-    """The router's float32 softmax over ALL experts: ``x`` (T, d),
-    ``router_w`` (d, E) -> (T, E)."""
+def router_probs(x, router_w, scoring: str = "softmax"):
+    """The router's float32 scores over ALL experts: ``x`` (T, d),
+    ``router_w`` (d, E) -> (T, E). ``scoring`` ``"softmax"``: a
+    distribution over the experts; ``"sigmoid"``: each expert's own
+    affinity in (0, 1) (DeepSeek-V3, arXiv:2412.19437)."""
     logits = jnp.einsum("td,de->te", x, router_w.astype(x.dtype),
                         preferred_element_type=jnp.promote_types(
                             jnp.float32, x.dtype))
+    if scoring == "sigmoid":
+        return jax.nn.sigmoid(logits)
+    if scoring != "softmax":
+        raise ValueError(f"scoring={scoring!r}: 'softmax' or 'sigmoid'")
     return jax.nn.softmax(logits, -1)
 
 
-def top_k_weights(probs, top_k: int, norm_topk: bool = True):
+def top_k_weights(probs, top_k: int, norm_topk: bool = True, bias=None,
+                  scale: float = 1.0):
     """The ``top_k`` largest of ``probs`` (T, E) and, with ``norm_topk``,
-    their weights renormalised to sum 1. Returns ``(expert ids (T, k)
+    their weights renormalised to sum 1. With ``bias`` (E,), a sigmoid
+    router's score correction, the choice is of the largest ``probs +
+    bias`` and the weights are the chosen experts' unbiased ``probs``
+    (over their sum + 1e-20): the bias steers the load and reaches no
+    weight, and no gradient reaches it. ``scale`` multiplies the weights
+    (the family's ``routed_scaling_factor``). Returns ``(expert ids (T, k)
     int32, weights (T, k) float32)``."""
-    weights, ids = jax.lax.top_k(probs, top_k)
+    if bias is None:
+        weights, ids = jax.lax.top_k(probs, top_k)
+    else:
+        _, ids = jax.lax.top_k(
+            probs + jax.lax.stop_gradient(bias).astype(probs.dtype), top_k)
+        weights = jnp.take_along_axis(probs, ids, -1)
     if norm_topk:
-        weights = weights / jnp.sum(weights, -1, keepdims=True)
+        total = jnp.sum(weights, -1, keepdims=True)
+        weights = weights / (total if bias is None else total + 1e-20)
+    if scale != 1.0:
+        weights = weights * scale
     return ids.astype(jnp.int32), weights
 
 
@@ -200,6 +223,13 @@ def route_top_k_probs(x, router_w, top_k: int, norm_topk: bool = True):
     """``top_k_weights`` of ``router_probs``: softmax over ALL experts in
     float32, then the ``top_k`` largest."""
     return top_k_weights(router_probs(x, router_w), top_k, norm_topk)
+
+
+def assignments_received(ids, experts: int, dtype=jnp.float32):
+    """How many of the assignments ``ids`` (T, k) each of the ``experts``
+    router outputs received: (E,)."""
+    return jnp.sum(ids[..., None] == jnp.arange(experts, dtype=ids.dtype),
+                   (0, 1), dtype=dtype)
 
 
 def load_balancing_loss(probs, ids):
@@ -213,8 +243,7 @@ def load_balancing_loss(probs, ids):
     gradient reaches the router through ``P_e`` alone. All E outputs
     count, whichever experts are held."""
     t, e = probs.shape
-    share = jnp.sum(ids[..., None] == jnp.arange(e, dtype=ids.dtype),
-                    (0, 1), dtype=probs.dtype) / t
+    share = assignments_received(ids, e, probs.dtype) / t
     return e * jnp.sum(share * jnp.mean(probs, 0))
 
 
@@ -248,16 +277,21 @@ def _gated(up, gate):
     return jax.nn.silu(gate) * up
 
 
-def _block_hidden(xs, w_gate, w_up, sizes, live):
-    """One block's gathered rows up to their experts' ``up`` and ``gate``.
-    Rows past the last group belong to no expert: a grouped product leaves
-    them unwritten (on the TPU: whatever the memory held, NaN included),
-    so they go in as zeros and every product's result is zeroed there
-    before it is used: a zero cotangent times a NaN is a NaN."""
+def _relu2(up):
+    return jnp.square(jax.nn.relu(up))
+
+
+def _block_hidden(xs, w_in, sizes, live):
+    """One block's gathered rows up to their experts' hidden
+    pre-activations, one grouped product for each of ``w_in`` (``up`` and
+    ``gate`` of a gated expert; ``up`` alone of a plain one). Rows past
+    the last group belong to no expert: a grouped product leaves them
+    unwritten (on the TPU: whatever the memory held, NaN included), so
+    they go in as zeros and every product's result is zeroed there before
+    it is used: a zero cotangent times a NaN is a NaN."""
     xs = jnp.where(live, xs, 0)
-    up = jnp.where(live, jax.lax.ragged_dot(xs, w_up, sizes), 0)
-    gate = jnp.where(live, jax.lax.ragged_dot(xs, w_gate, sizes), 0)
-    return xs, up, gate
+    return xs, tuple(jnp.where(live, jax.lax.ragged_dot(xs, w, sizes), 0)
+                     for w in w_in)
 
 
 # a grouped product's weight gradient: (rows, a), (rows, b) -> (G, a, b)
@@ -294,32 +328,34 @@ def _row_buffers(n_blocks, rows: int, widths, dtype):
     return [jnp.full((rows, n), zero) for n in widths]
 
 
-def _held_blocks(block: int, top_k: int):
-    """``f(x, weights, w_gate, w_up, w_down, sel, places, ends, n_blocks,
-    most) -> y``: the first ``n_blocks`` blocks of the sorted
-    assignments ``sel`` dispatched and computed, one block a pass of a
-    loop whose trip count is the traced ``n_blocks``, each pass leaving its
-    rows in a buffer as long as ``sel``; then a second loop of ``most``
-    passes (the most held assignments any token has) gathers every
-    token's rows back and sums them, weighted, in float32
-    (``_tokens_rows``): a gather of T rows a pass, where a scatter-add a
-    block cost as much again for its fixed part. Such loops have no
+def _held_blocks(block: int, top_k: int, act):
+    """``f(x, weights, w_in, w_down, sel, places, ends, n_blocks, most) ->
+    y``: the first ``n_blocks`` blocks of the sorted assignments ``sel``
+    dispatched and computed, one block a pass of a loop whose trip count
+    is the traced ``n_blocks``, each pass leaving its rows in a buffer as
+    long as ``sel``; then a second loop of ``most`` passes (the most held
+    assignments any token has) gathers every token's rows back and sums
+    them, weighted, in float32 (``_tokens_rows``): a gather of T rows a
+    pass, where a scatter-add a block cost as much again for its fixed
+    part. An expert is ``W_down act(x W_in[0], x W_in[1], ...)``: ``w_in``
+    is a tuple of the experts' input-side matrices, ``(w_up, w_gate)``
+    with ``act = _gated`` or ``(w_up,)`` with ``act = _relu2``, and a
+    block costs ``len(w_in) + 1`` grouped products. Such loops have no
     reverse rule, so the backward is written here: the same two loops,
-    each pass of the first computing its block's ``up`` and ``gate`` again.
+    each pass of the first computing its block's pre-activations again.
     The weights' gradients are no sums over the passes either: a pass
     leaves its rows of both operands in buffers, and one grouped product
     each after the loop reads the rows the routing filled, as the one
     long buffer's backward did."""
 
-    def forward(x, weights, w_gate, w_up, w_down, sel, places, ends,
-                n_blocks, most):
+    def forward(x, weights, w_in, w_down, sel, places, ends, n_blocks, most):
         def body(i, rows):
             _, token, sizes, live = _block_rows(sel, ends, i, block, top_k)
             with jax.named_scope("moe.experts"):
-                _, up, gate = _block_hidden(
-                    jnp.take(x, token, axis=0), w_gate, w_up, sizes, live)
+                _, pre = _block_hidden(
+                    jnp.take(x, token, axis=0), w_in, sizes, live)
                 ys = jnp.where(live, jax.lax.ragged_dot(
-                    _gated(up, gate), w_down, sizes), 0)
+                    act(*pre), w_down, sizes), 0)
                 return jax.lax.dynamic_update_slice(rows, ys, (i * block, 0))
 
         with jax.named_scope("moe.dispatch"):
@@ -335,11 +371,11 @@ def _held_blocks(block: int, top_k: int):
         return forward(*args), args
 
     def backward(kept, dy):
-        (x, weights, w_gate, w_up, w_down, sel, places, ends, n_blocks,
-         most) = kept
+        x, weights, w_in, w_down, sel, places, ends, n_blocks, most = kept
         p_flat = weights.reshape(-1)
         with jax.named_scope("moe.experts"):
-            to_in = [jnp.swapaxes(w, 1, 2) for w in (w_gate, w_up, w_down)]
+            to_in = [jnp.swapaxes(w, 1, 2) for w in w_in]
+            down_in = jnp.swapaxes(w_down, 1, 2)
 
         def body(i, carried):
             dp_flat, rows = carried
@@ -349,40 +385,41 @@ def _held_blocks(block: int, top_k: int):
                 dys = jnp.where(
                     live, dout * p_flat[idx][:, None], 0).astype(x.dtype)
             with jax.named_scope("moe.experts"):
-                xs, up, gate = _block_hidden(
-                    jnp.take(x, token, axis=0), w_gate, w_up, sizes, live)
-                h, pull = jax.vjp(_gated, up, gate)
+                xs, pre = _block_hidden(
+                    jnp.take(x, token, axis=0), w_in, sizes, live)
+                h, pull = jax.vjp(act, *pre)
                 ys = jnp.where(live, jax.lax.ragged_dot(h, w_down, sizes), 0)
-                d_up, d_gate = pull(jnp.where(
-                    live, jax.lax.ragged_dot(dys, to_in[2], sizes), 0))
-                dxs = jnp.where(
-                    live, jax.lax.ragged_dot(d_up, to_in[1], sizes)
-                    + jax.lax.ragged_dot(d_gate, to_in[0], sizes), 0)
+                d_pre = pull(jnp.where(
+                    live, jax.lax.ragged_dot(dys, down_in, sizes), 0))
+                dxs = jnp.where(live, functools.reduce(operator.add, (
+                    jax.lax.ragged_dot(d, w, sizes)
+                    for d, w in zip(d_pre, to_in))), 0)
                 rows = [jax.lax.dynamic_update_slice(a, b, (i * block, 0))
-                        for a, b in zip(rows, (xs, h, d_up, d_gate, dys, dxs))]
+                        for a, b in zip(rows, (xs, h, *d_pre, dys, dxs))]
             with jax.named_scope("moe.combine"):
                 # rows that hold no assignment add zeros
                 return dp_flat.at[idx].add(jnp.sum(
                     dout.astype(dp_flat.dtype) * ys, -1)), rows
 
+        d, f = x.shape[1], w_down.shape[1]
         with jax.named_scope("moe.dispatch"):
-            dp_flat, (xs, h, d_up, d_gate, dys, dxs) = jax.lax.fori_loop(
+            dp_flat, (xs, h, *d_pre, dys, dxs) = jax.lax.fori_loop(
                 0, n_blocks, body, (jnp.zeros_like(p_flat), _row_buffers(
-                    n_blocks, sel.size, (x.shape[1], *3 * w_up.shape[2:],
-                                         *2 * x.shape[1:]), x.dtype)))
+                    n_blocks, sel.size, (d, *(f,) * (len(w_in) + 1), d, d),
+                    x.dtype)))
         with jax.named_scope("moe.combine"):
             dx = _tokens_rows(dxs, jnp.sort(places, axis=0), ends[-1],
                               most).astype(x.dtype)
         with jax.named_scope("moe.experts"):
             sizes = jnp.diff(ends, prepend=0)
             dws = [jax.lax.ragged_dot_general(a, b, sizes, _ROWS_CONTRACTED)
-                   for a, b in ((xs, d_gate), (xs, d_up), (h, dys))]
-            # the barrier ties the three products to ``dx``, which the
-            # layer below waits for: left to the scheduler they run with
-            # the optimizer's update at the step's end, and every layer's
-            # row buffers live until then
+                   for a, b in (*((xs, d_w) for d_w in d_pre), (h, dys))]
+            # the barrier ties the products to ``dx``, which the layer
+            # below waits for: left to the scheduler they run with the
+            # optimizer's update at the step's end, and every layer's row
+            # buffers live until then
             dx, *dws = jax.lax.optimization_barrier((dx, *dws))
-        return (dx, dp_flat.reshape(weights.shape), *dws,
+        return (dx, dp_flat.reshape(weights.shape), tuple(dws[:-1]), dws[-1],
                 None, None, None, None, None)
 
     f = jax.custom_vjp(forward)
@@ -392,22 +429,32 @@ def _held_blocks(block: int, top_k: int):
 
 def held_experts_ffn(x, router_w, w_gate, w_up, w_down, held, *,
                      top_k: int, norm_topk: bool = True,
-                     balance: bool = False):
-    """The held experts' part of a top-k mixture of gated experts,
-    ``sum_{i in top-k(x), i held} p_i W_down_i (silu(W_gate_i x) *
-    W_up_i x)``, with no token dropped.
+                     balance: bool = False, scoring: str = "softmax",
+                     router_bias=None, routed_scale: float = 1.0,
+                     received: bool = False):
+    """The held experts' part of a top-k mixture of experts, ``sum_{i in
+    top-k(x), i held} p_i E_i(x)``, with no token dropped. An expert is
+    gated (SwiGLU), ``E(x) = W_down (silu(W_gate x) * W_up x)``, three
+    grouped products a block, or, where ``w_gate`` is None, plain with a
+    squared ReLU, ``E(x) = W_down relu(W_up x)^2``, two.
 
     x: (T, d); router_w: (d, E) over all E experts; w_gate, w_up:
     (G, d, f) and w_down: (G, f, d) for the G experts held here, whose
     ids among the E are ``held`` (a static tuple, in the weights' order).
+    The router (``router_probs``, ``top_k_weights``): ``scoring``
+    ``"softmax"``, or ``"sigmoid"`` with ``router_bias`` (E,) added to the
+    scores for the choice alone; ``routed_scale`` multiplies the weights.
     Returns ``(y (T, d) in x's type, counters float32[4])``, the counters
-    in ``ROUTING_COUNTERS``' order, and with ``balance`` a third value, the
-    router's ``load_balancing_loss`` over all E outputs.
+    in ``ROUTING_COUNTERS``' order; then, with ``balance``, the router's
+    ``load_balancing_loss`` over all E outputs (sigmoid scores normalised
+    to sum 1 a token: DeepSeek-V3's complementary balance loss, eq. 17-20,
+    times k); then, with ``received``, the assignments each of the E
+    outputs received, float32 (E,).
 
     The T * k assignments are sorted by held expert (those of absent
     experts last). The held ones go through a loop, ``dispatch_block``
     rows a pass and as many passes as the step's routing filled,
-    ``ceil(assignments_held / block)``: a pass gathers its rows and three
+    ``ceil(assignments_held / block)``: a pass gathers its rows and the
     grouped products over the block's own group sizes compute the
     experts. A second loop takes the results back to the tokens, weighted
     and summed in float32: a pass gathers one row for every token, and
@@ -421,8 +468,9 @@ def held_experts_ffn(x, router_w, w_gate, w_up, w_down, held, *,
     rows = t * min(top_k, g)
     block = dispatch_block(t, top_k, g, e)
     with jax.named_scope("moe.route"):
-        probs = router_probs(x, router_w)
-        ids, weights = top_k_weights(probs, top_k, norm_topk)
+        probs = router_probs(x, router_w, scoring)
+        ids, weights = top_k_weights(probs, top_k, norm_topk, router_bias,
+                                     routed_scale)
         # which held expert an assignment is, G where it is an absent
         # one's: by comparison, a table lookup of T * k integers takes the
         # TPU longer than a grouped product
@@ -441,14 +489,19 @@ def held_experts_ffn(x, router_w, w_gate, w_up, w_down, held, *,
         places = jnp.argsort(order).reshape(t, top_k).T
         most = jnp.max(jnp.sum(places < n_held, axis=0))
     with jax.named_scope("moe.experts"):
-        w_gate, w_up, w_down = (w.astype(x.dtype)
-                                for w in (w_gate, w_up, w_down))
-    y = _held_blocks(block, top_k)(
-        x, weights, w_gate, w_up, w_down, sel, places, ends, n_blocks, most)
-    counters = jnp.stack([
+        w_in = tuple(w.astype(x.dtype) for w in (
+            (w_up,) if w_gate is None else (w_up, w_gate)))
+        w_down = w_down.astype(x.dtype)
+    y = _held_blocks(block, top_k, _relu2 if w_gate is None else _gated)(
+        x, weights, w_in, w_down, sel, places, ends, n_blocks, most)
+    out = [y, jnp.stack([
         n_held, jnp.max(sizes), n_held / g,
-        n_held - jnp.minimum(n_held, rows)]).astype(jnp.float32)
-    if balance:
-        with jax.named_scope("moe.route"):
-            return y, counters, load_balancing_loss(probs, ids)
-    return y, counters
+        n_held - jnp.minimum(n_held, rows)]).astype(jnp.float32)]
+    with jax.named_scope("moe.route"):
+        if balance:
+            shares = (probs if scoring == "softmax" else
+                      probs / jnp.sum(probs, -1, keepdims=True))
+            out.append(load_balancing_loss(shares, ids))
+        if received:
+            out.append(assignments_received(ids, e))
+    return tuple(out)

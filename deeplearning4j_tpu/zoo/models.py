@@ -635,6 +635,117 @@ class SDARMoE(ZooModel):
 
 
 @dataclasses.dataclass
+class NemotronH(ZooModel):
+    """Hybrid Mamba-2 / attention / mixture-of-experts causal language
+    model of the Nemotron-H family, as Nemotron 3 Nano (published config:
+    huggingface.co/nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16,
+    ``config.json``, ``model_type: nemotron_h``; arXiv:2504.03624; the
+    Mamba-2 layer is arXiv:2405.21060, the router DeepSeek-V3's,
+    arXiv:2412.19437; the field names below are the config's keys). **One
+    mixer a layer**: ``x <- x + Mixer(RMSNorm(x))`` for each character of
+    ``hybrid_override_pattern``, ``M`` a Mamba-2 layer, ``*`` a causal
+    grouped-query attention with no positional encoding, no q/k norm and
+    no gate, ``E`` the expert layer: a sigmoid router whose choice adds a
+    score-correction bias, the chosen experts' unbiased scores
+    renormalised and times ``routed_scaling_factor``, non-gated experts
+    ``W_down relu(W_up x)^2`` and one shared expert of the same form added
+    ungated. RMSNorm (zero-centred), an untied head, next-token loss.
+
+    ``held_experts`` (default: all) and a sliced ``vocab_size`` are an
+    expert-parallel deployment's share, as ``Qwen3Next``'s.
+    ``bias_update_rate`` is the speed at which each step's load moves the
+    routers' bias (the config carries the buffer, not the rate: DeepSeek-
+    V3's 1e-3); ``router_aux_loss_coef`` above 0 adds that many times each
+    expert layer's balance loss on the normalised sigmoid scores
+    (DeepSeek-V3 eq. 17-20) to the training loss. Features are integer
+    token ids (N, seq_len), labels
+    ``nn.layers.decoder.next_token_labels(ids)``. Not in the model:
+    dropout, ``time_step_limit`` (absent from the config: ``dt`` is not
+    clamped), ``rescale_prenorm_residual`` (every matrix starts normal(0,
+    ``initializer_range``)), generation."""
+    vocab_size: int = 131072
+    hidden_size: int = 2688
+    hybrid_override_pattern: str = (
+        "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME")
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 2
+    head_dim: int = 128
+    mamba_num_heads: int = 64
+    mamba_head_dim: int = 64
+    n_groups: int = 8
+    ssm_state_size: int = 128
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    time_step_min: float = 1e-3
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    n_routed_experts: int = 128
+    held_experts: Tuple[int, ...] = ()
+    num_experts_per_tok: int = 6
+    moe_intermediate_size: int = 1856
+    moe_shared_expert_intermediate_size: int = 3712
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 2.5
+    bias_update_rate: float = 1e-3
+    router_aux_loss_coef: float = 0.0
+    layer_norm_epsilon: float = 1e-5
+    initializer_range: float = 0.02
+    seq_len: int = 8192
+    recompute: bool = True
+    compute_dtype: str = "bfloat16"
+    updater: Optional[Updater] = None
+    seed: int = 123
+
+    def conf(self):
+        from deeplearning4j_tpu.nn.layers.decoder import (
+            CAUSAL_ATTENTION, EXPERTS, MAMBA2, CausalLMOutputLayer,
+            SingleMixerBlock, TokenEmbedding)
+        kinds = {"M": MAMBA2, "*": CAUSAL_ATTENTION, "E": EXPERTS}
+        unknown = set(self.hybrid_override_pattern) - set(kinds)
+        if unknown:
+            raise ValueError(
+                f"hybrid_override_pattern holds {sorted(unknown)}; a layer "
+                "is 'M' (Mamba-2), '*' (attention) or 'E' (experts)")
+        std = self.initializer_range
+        b = (NeuralNetConfiguration.Builder()
+             .seed(self.seed)
+             .updater(self.updater or Adam(1e-4))
+             .compute_dtype(self.compute_dtype)
+             .list()
+             .layer(TokenEmbedding(name="embed", vocab_size=self.vocab_size,
+                                   n_out=self.hidden_size, init_std=std)))
+        for l, kind in enumerate(self.hybrid_override_pattern):
+            b = b.layer(SingleMixerBlock(
+                name=f"block{l}", n_out=self.hidden_size, mixer=kinds[kind],
+                n_heads=self.num_attention_heads,
+                n_kv_heads=self.num_key_value_heads, head_dim=self.head_dim,
+                mamba_heads=self.mamba_num_heads,
+                mamba_head_dim=self.mamba_head_dim, n_groups=self.n_groups,
+                d_state=self.ssm_state_size, conv_kernel=self.conv_kernel,
+                chunk_size=self.chunk_size, dt_min=self.time_step_min,
+                dt_max=self.time_step_max, dt_floor=self.time_step_floor,
+                num_experts=self.n_routed_experts,
+                held_experts=tuple(self.held_experts),
+                expert_hidden=self.moe_intermediate_size,
+                shared_hidden=self.moe_shared_expert_intermediate_size,
+                top_k=self.num_experts_per_tok,
+                norm_topk=self.norm_topk_prob,
+                routed_scale=self.routed_scaling_factor,
+                bias_update_rate=self.bias_update_rate,
+                router_aux_loss_coef=self.router_aux_loss_coef,
+                eps=self.layer_norm_epsilon, init_std=std,
+                recompute=self.recompute))
+        return (b.layer(CausalLMOutputLayer(
+                    name="lm_head", n_out=self.vocab_size,
+                    eps=self.layer_norm_epsilon, init_std=std))
+                .set_input_type(InputType.recurrent(1, self.seq_len))
+                .build())
+
+    def init(self) -> MultiLayerNetwork:
+        return MultiLayerNetwork(self.conf()).init()
+
+
+@dataclasses.dataclass
 class Phi4MiniFlash(ZooModel):
     """Decoder-hybrid-decoder causal language model of the Phi-4-mini-flash
     family (published config: huggingface.co/microsoft/
