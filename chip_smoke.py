@@ -20,9 +20,10 @@ a seed, with no network and no files outside the checkout:
                  backward, against plain XLA; the held experts' loop over
                  blocks of rows at routings that fill one block and
                  several; the gated delta rule's kernels at the
-                 Qwen3-Next cell's shapes and the selective scan's at
-                 the Phi-4-mini-flash cell's, each against its plain
-                 chunked form
+                 Qwen3-Next cell's shapes, the selective scan's at the
+                 Phi-4-mini-flash cell's and the Mamba-2 scan's at the
+                 Nemotron 3 Nano cell's, each against its plain chunked
+                 form
   phase=feed_race  the input pipeline alone, with no train step, so that
                  the prefetch thread gathers flat out into buffers it
                  uses again: 200 shuffled batches of the ResNet cell's
@@ -462,6 +463,30 @@ def phase_kernels(ctx):
                  selective_scan_chunked, scan_args, "float32", errs)
     if not ssm.kernels_take(scan_args[0], scan_args[2], 64):
         errs["selective_scan"] = "FAIL the kernels did not take the call"
+    # the Mamba-2 scan with the mixer's skip, gate and grouped norm at the
+    # Nemotron 3 Nano cell's shapes: one sequence of 8,192 tokens, 64 heads
+    # of 64 in 8 groups, 128 states, chunks of 128, bfloat16 ``x``, ``B``,
+    # ``C`` and ``z``. ``ssd_scan`` takes the Pallas kernels here; the
+    # plain forms, its oracle, are XLA's
+    from deeplearning4j_tpu.nn.layers.state_space import (
+        gated_group_norm, ssd_chunked)
+    from deeplearning4j_tpu.ops import pallas_ssd_scan as ssd
+    h, p, g, s = 64, 64, 8, 128
+    ssd_args = (arr((1, t, h, p), jnp.bfloat16),
+                jnp.asarray(rng.uniform(1e-3, 0.1, (1, t, h)), jnp.float32),
+                -jnp.arange(1, h + 1, dtype=jnp.float32),
+                arr((1, t, g, s), jnp.bfloat16, 0.3),
+                arr((1, t, g, s), jnp.bfloat16, 0.3),
+                arr((1, t, h * p), jnp.bfloat16),
+                jnp.asarray(rng.uniform(0.5, 1.5, (h,)), jnp.float32),
+                jnp.asarray(rng.uniform(0.5, 1.5, (h * p,)), jnp.float32))
+    _check_grads(
+        "ssd_scan", lambda *a: ssd.ssd_scan(*a, 1e-5),
+        lambda x, dt, a, b, c, z, skip, w: gated_group_norm(
+            ssd_chunked(x, dt, a, b, c, chunk_size=128)[0], x, z, skip, w,
+            g, 1e-5), ssd_args, "bfloat16", errs)
+    if not ssd.kernels_take(ssd_args[0], ssd_args[3], 128):
+        errs["ssd_scan"] = "FAIL the kernels did not take the call"
     for name, e in errs.items():
         print(f"kernel {name}: "
               + (e if isinstance(e, str) else f"max_rel_err={e:.3e}"))

@@ -52,7 +52,12 @@ divided by a decay. ``ssd_step`` is one token (a decode step's form),
 ``ssd_recurrent`` the recurrence as written (the tests' oracle). ``dt``,
 ``A``, the state and ``y`` are float32; the matrix products take their
 operands in ``x``'s type (bfloat16 under the bf16 policy, as the
-published kernels do) and accumulate in float32.
+published kernels do) and accumulate in float32. ``Mamba2Mixer`` calls
+``ops.pallas_ssd_scan.ssd_scan``, which runs the same chunks as Pallas
+kernels on a TPU (one launch a pass, a group's heads and their state in
+VMEM, the backward by hand) and ``ssd_chunked`` elsewhere: off the TPU,
+at sizes that fill no lane tile, at another chunk than 128. ``ssd_chunked``
+is also the form that continues from a state (``ssd_step``'s prefix).
 """
 
 from __future__ import annotations
@@ -352,6 +357,23 @@ def grouped_rms_norm(x, w, groups: int, eps: float):
                     zero_centered=False).reshape(x.shape)
 
 
+def gated_group_norm(y, x, z, skip, w, groups: int, eps: float):
+    """What a ``Mamba2Mixer`` makes of its scan's ``y`` (N, T, H, P),
+    float32: ``GroupRMSNorm((y + skip x) * silu(z))`` for ``x`` (N, T, H,
+    P), ``skip`` (H,), ``z`` (N, T, H P) and the norm's weight ``w`` (H
+    P,), in float32; the result (N, T, H P) in ``x``'s type. The skip runs
+    over the channels as the projections have them: a (T, H, P) array with
+    ``P`` under a lane tile is another layout on a TPU, each way there and
+    back a copy of the rows."""
+    n, t, h, p = x.shape
+    f32 = jnp.promote_types(jnp.float32, x.dtype)
+    gated = (y.reshape(n, t, h * p)
+             + jnp.repeat(skip.astype(f32), p)
+             * x.reshape(n, t, h * p).astype(f32)) * jax.nn.silu(
+                 z.astype(f32))
+    return grouped_rms_norm(gated, w, groups, eps).astype(x.dtype)
+
+
 @register_serializable
 @dataclasses.dataclass(frozen=True)
 class Mamba2Mixer(FeedForwardLayer):
@@ -362,9 +384,13 @@ class Mamba2Mixer(FeedForwardLayer):
     ``H``; ``xBC <- silu(conv(xBC) + b_conv)`` (causal, depthwise,
     ``d_conv`` taps, over all its channels); ``[x | B | C] = xBC``, ``x``
     as (H, P), ``B`` and ``C`` as (G, S); ``dt = softplus(dt + dt_bias)``,
-    ``A = -exp(A_log)`` (one a head); the state-space duality scan
-    (``ssd_chunked``, chunks of ``chunk_size``); ``y <- y + D x`` (``D``
-    one a head); ``o = GroupRMSNorm(y * silu(z))``: the gate first, then
+    ``A = -exp(A_log)`` (one a head); the state-space duality scan in
+    chunks of ``chunk_size``, ``y <- y + D x`` (``D`` one a head) and the
+    gated norm below (``ops.pallas_ssd_scan.ssd_scan``: all three in the
+    Pallas kernels on a TPU at chunks of 128, states and a group's
+    channels that fill lane tiles; the plain ``ssd_chunked`` and
+    ``gated_group_norm`` anywhere else; the choice rests on the input's
+    shapes and the backend, no field selects it); ``o = GroupRMSNorm(y * silu(z))``: the gate first, then
     an RMSNorm over each of the ``G`` groups of ``H P / G`` channels times
     a plain weight; the result ``o W_out``. ``dt``, ``A``, the state and
     ``y`` are float32.
@@ -373,8 +399,10 @@ class Mamba2Mixer(FeedForwardLayer):
     +-1/sqrt(taps) with a zero bias, ``A_log`` = log(1..H), ``D`` = 1, the
     norm's weight 1, and ``dt_bias`` the inverse softplus of a log-uniform
     draw in [``dt_min``, ``dt_max``] floored at ``dt_floor``. ``n_out`` is
-    the model width. With a name the layer publishes the chunks a pass
-    walks as the gauge ``dl4j_ssd_chunks`` when the step is traced."""
+    the model width. With a name the layer publishes, when the step is
+    traced, the chunks a pass walks as the gauge ``dl4j_ssd_chunks`` and
+    how many of them go through the kernels as ``dl4j_ssd_kernel_chunks``
+    (0: the plain form)."""
     n_heads: int = 64
     head_dim: int = 64
     n_groups: int = 8
@@ -430,6 +458,7 @@ class Mamba2Mixer(FeedForwardLayer):
         }
 
     def apply(self, params, state, x, ctx: LayerContext):
+        from deeplearning4j_tpu.ops.pallas_ssd_scan import ssd_scan
         n, t, _ = x.shape
         d, bc = self._dims
         h, p, g = self.n_heads, self.head_dim, self.n_groups
@@ -449,16 +478,13 @@ class Mamba2Mixer(FeedForwardLayer):
                                       params["conv_w"].astype(f32))
                 + params["conv_b"].astype(f32)).astype(x.dtype)
         with jax.named_scope("ssd.scan"):
-            u = xbc[..., :d].reshape(n, t, h, p)
-            y, _ = ssd_chunked(
-                u, step, -jnp.exp(params["A_log"].astype(f32)),
+            o = ssd_scan(
+                xbc[..., :d].reshape(n, t, h, p), step,
+                -jnp.exp(params["A_log"].astype(f32)),
                 xbc[..., d:d + bc].reshape(n, t, g, self.d_state),
                 xbc[..., d + bc:].reshape(n, t, g, self.d_state),
-                chunk_size=self.chunk_size)
-            y = y + params["D"].astype(f32)[:, None] * u.astype(f32)
-            gated = y.reshape(n, t, d) * jax.nn.silu(z.astype(f32))
-            o = grouped_rms_norm(gated, params["norm_w"], g,
-                                 self.eps).astype(x.dtype)
+                z, params["D"], params["norm_w"], self.eps,
+                chunk_size=self.chunk_size, layer=self.name or None)
         with jax.named_scope("ssd.out"):
             out = jnp.einsum("ntd,do->nto", o, params["W_out"])
         return out, state

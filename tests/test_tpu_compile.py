@@ -342,6 +342,113 @@ def test_a_mamba_layer_keeps_its_kernels_under_ssm_scan(
         layer="block0") == 128
 
 
+def test_ssd_scan_kernels_compile_at_the_cells_shapes(
+        one_chip, no_compile_cache):
+    """Forward (with and without the border states) and backward of the
+    Mamba-2 scan's kernels, skip, gate and grouped norm inside, at the
+    Nemotron 3 Nano cell's shapes: 8,192 tokens, 64 heads of 64 in 8
+    groups, 128 states, bfloat16 ``x``, ``B``, ``C`` and ``z`` and float32
+    ``dt``, inside the scoped VMEM the module states: two launches and no
+    loop in the differentiated program, everything between the inputs and
+    the gradients traced under the caller's scope, no stacked ``[64, 1,
+    128, 64, 64]`` rows, no decay matrices and no float32 ``y``, and the
+    temporaries (the border states, 134 MB, beside the scalar tiles) far
+    under the 17 GB a (T, H, P, S) float32 tensor would take."""
+    import re
+    from deeplearning4j_tpu.observe.scopes import scopes_in_hlo
+    from deeplearning4j_tpu.ops.pallas_kernels import (
+        SCOPED_VMEM_CAP, scoped_vmem_limit)
+    from deeplearning4j_tpu.ops.pallas_ssd_scan import (
+        _Heads, _vmem_need, ssd_scan_kernels)
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    n, t, h, p, g, s = 1, 8192, 64, 64, 8, 128
+    shapes = [jax.ShapeDtypeStruct(shape, kind, sharding=one_chip)
+              for shape, kind in (
+                  ((n, t, h, p), bf16), ((n, t, h), f32), ((h,), f32),
+                  ((n, t, g, s), bf16), ((n, t, g, s), bf16),
+                  ((n, t, h * p), bf16), ((h,), f32), ((h * p,), f32))]
+
+    def scan(*a):
+        with jax.named_scope("ssd.scan"):
+            return ssd_scan_kernels(*a, 1e-5, interpret=False)
+
+    def both(*a):
+        o, vjp = jax.vjp(scan, *a[:-1])
+        return o, vjp(a[-1])
+
+    plain = jax.jit(scan).lower(*shapes).compile()
+    assert plain.as_text().count("tpu_custom_call") == 1
+    do = jax.ShapeDtypeStruct((n, t, h * p), bf16, sharding=one_chip)
+    compiled = jax.jit(both).lower(*shapes, do).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert " while(" not in text
+    assert not re.search(
+        r"\[64,1,128,64,64\]|\[\d+,128,128\]|f32\[1,8192,4096\]", text)
+    traced = [op for op in scopes_in_hlo(text).values()
+              if op.startswith("jit(both)/")]
+    assert traced and not [op for op in traced if "ssd.scan" not in op]
+    for backward in (False, True):
+        need = _vmem_need(s, _Heads(h // g, p), backward)
+        assert need < SCOPED_VMEM_CAP
+        assert scoped_vmem_limit(need) in (None, need)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.4e9
+
+
+def test_a_mamba2_layer_keeps_its_kernels_under_ssd_scan(
+        one_chip, no_compile_cache, monkeypatch):
+    """A ``SingleMixerBlock`` with the Mamba-2 mixer at the Nemotron 3 Nano
+    cell's widths, with ``recompute`` as the cell runs it, traced for a TPU
+    (the test stands in for ``jax.default_backend``): the forward, the
+    recomputed forward and the backward kernel each carry ``ssd.scan`` in
+    their ``op_name``, by the join ``yardstick/scopes.py`` makes, so the
+    per-scope readers find them; no loop is left in the layer; ``x``,
+    ``z`` and the normed rows go to and from the kernels as the layer has
+    them, with no transpose of a (T, 4096) array and no (T, H, P) copy
+    between; and the gauge says 64 chunks."""
+    import re
+    from deeplearning4j_tpu.nn.inputs import RecurrentType
+    from deeplearning4j_tpu.nn.layers.base import LayerContext
+    from deeplearning4j_tpu.nn.layers.decoder import MAMBA2, SingleMixerBlock
+    from deeplearning4j_tpu.observe.registry import default_registry
+    from deeplearning4j_tpu.observe.scopes import scopes_in_hlo
+    from deeplearning4j_tpu.ops.pallas_ssd_scan import SSD_KERNEL_GAUGE
+    from yardstick.scopes import in_scope
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    t, width = 8192, 2688
+    block = SingleMixerBlock(name="block0", n_out=width, mixer=MAMBA2,
+                             dtype="bfloat16", recompute=True)
+    rt = RecurrentType(width, t)
+    params = jax.eval_shape(lambda key: block.initialize(key, rt),
+                            jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), params)
+    x = jax.ShapeDtypeStruct((1, t, width), jnp.bfloat16, sharding=one_chip)
+
+    def total(p, a):
+        y, _ = block.apply(p, {}, a, LayerContext(train=True))
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(total, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    table = scopes_in_hlo(text)
+    kernels = {name: op for name, op in table.items()
+               if name.startswith("ssd_scan_")}         # the custom calls
+    assert len(kernels) == 3
+    assert all(in_scope(op, ("ssd.scan",)) for op in kernels.values())
+    assert sum("ssd_scan_bwd" in op and "transpose(" in op
+               for op in kernels.values()) == 1
+    assert sum("rematted_computation" in op for op in kernels.values()) == 1
+    assert not [op for op in table.values() if "/while" in op]
+    moved = [line for line in text.splitlines()
+             if re.search(r"= \w+\[[\d,]*8192,4096\]\S* transpose\(", line)
+             or re.search(r"= \w+\[[\d,]*4096,8192\]\S* transpose\(", line)]
+    assert not moved
+    assert not re.search(r"= \w+\[1,8192,64,64\]", text)
+    assert default_registry().gauge(*SSD_KERNEL_GAUGE).get(
+        layer="block0") == 64
+
+
 def _recomputing_blocks():
     """``{case: (block, positions, kernel scope, kernels, recomputed)}``:
     a recomputing decoder block of each kind at its cell's mixer widths
@@ -372,12 +479,15 @@ def _recomputing_blocks():
             d_inner=5120, d_state=16, d_conv=4, dt_rank=160,
             mlp_hidden=10240, dtype="bfloat16", recompute=True),
          8192, "ssm.scan", 3, 1),
+        "nemotron3-nano-mamba2": (d.SingleMixerBlock(
+            name="block0", n_out=2688, mixer=d.MAMBA2, dtype="bfloat16",
+            recompute=True), 8192, "ssd.scan", 3, 1),
     }
 
 
 @pytest.mark.parametrize("case", [
     "sdar", "qwen3-next", "phi4-mini-flash", "qwen3-next-deltanet",
-    "phi4-mini-flash-mamba"])
+    "phi4-mini-flash-mamba", "nemotron3-nano-mamba2"])
 def test_a_recomputing_block_runs_its_flash_forward_once(
         one_chip, no_compile_cache, monkeypatch, case):
     """A decoder block with ``recompute`` through its own
@@ -385,7 +495,7 @@ def test_a_recomputing_block_runs_its_flash_forward_once(
     stands in for ``jax.default_backend``): an attention mixer launches
     three kernels under its scope (forward, dQ, dK/dV; four where the
     forward kernel runs again for its result and logsumexp) and none in the
-    recomputation; the DeltaNet block and the Mamba block, whose residuals
+    recomputation; the DeltaNet, Mamba and Mamba-2 blocks, whose residuals
     no policy names, still three with their forward kernel run again. Counted as the program's
     gauge counts them."""
     from deeplearning4j_tpu.nn.inputs import RecurrentType
