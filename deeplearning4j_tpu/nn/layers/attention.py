@@ -285,19 +285,23 @@ class GatedAttention(FeedForwardLayer):
     without, ``W_q`` holds the queries alone and the layer is
     ``W_o(attn)``.
 
-    ``block_length`` 0 is causal attention at positions ``0 .. T - 1``.
-    ``block_length`` B > 0 is a masked diffusion over blocks of B,
-    trained: the input is ``[noisy | clean]``, two copies of a sequence of
-    ``T / 2`` positions, slot ``s`` at rotary position ``s mod T / 2``,
-    under ``ops.visibility.BlockDiffusion(T / 2, B)``.
+    ``block_length`` 0 is causal attention at positions ``0 .. T - 1``;
+    a ``window`` narrows it to a query's own position and the ``window -
+    1`` before it (``Causal(window)``: the flash kernels visit only the key
+    blocks the window reaches). ``block_length`` B > 0 is a masked
+    diffusion over blocks of B, trained: the input is ``[noisy | clean]``,
+    two copies of a sequence of ``T / 2`` positions, slot ``s`` at rotary
+    position ``s mod T / 2``, under ``ops.visibility.BlockDiffusion(T / 2,
+    B)``.
 
     The head size is its own field, not ``n_out / n_heads``. ``n_out`` is
     the model width. Attention itself goes through
     ``ops.pallas_kernels.attention`` (the flash kernel from 1,024
     positions on a TPU, plain XLA else) with the key/value heads repeated
     for it, under the named scope ``attn.gated``, with blocks
-    ``attn.block_diffusion``, and ``attn.causal`` where it is causal and
-    has neither gate nor q/k norm."""
+    ``attn.block_diffusion``, with a window ``attn.window``, and
+    ``attn.causal`` where it is causal and has neither gate nor q/k
+    norm."""
     n_heads: int = 16
     n_kv_heads: int = 2
     head_dim: int = 256
@@ -308,8 +312,10 @@ class GatedAttention(FeedForwardLayer):
     output_gate: bool = True
     block_length: int = 0
     qk_norm: bool = True
+    window: Optional[int] = None
 
-    named_scopes = ("attn.gated", "attn.block_diffusion", "attn.causal")
+    named_scopes = ("attn.gated", "attn.block_diffusion", "attn.causal",
+                    "attn.window")
 
     def __post_init__(self):
         if self.n_heads % self.n_kv_heads:
@@ -319,11 +325,17 @@ class GatedAttention(FeedForwardLayer):
         if self.block_length < 0:
             raise ValueError(f"block_length={self.block_length}: 0 "
                              "(causal) or a block's length")
+        if self.window is not None and (self.window < 1
+                                        or self.block_length):
+            raise ValueError(f"window={self.window}: a causal window of "
+                             "one position or more, without block_length")
 
     @property
     def scope(self) -> str:
         if self.block_length:
             return "attn.block_diffusion"
+        if self.window is not None:
+            return "attn.window"
         return ("attn.gated" if self.output_gate or self.qk_norm
                 else "attn.causal")
 
@@ -379,7 +391,7 @@ class GatedAttention(FeedForwardLayer):
                 k = rms_norm(k, params["k_norm"], self.eps)
             rot = int(dh * self.partial_rotary_factor)
             pos = jnp.arange(t)
-            vis = Causal()
+            vis = Causal(self.window)
             if self.block_length:
                 pos = pos % (t // 2)
                 vis = BlockDiffusion(t // 2, self.block_length)

@@ -47,6 +47,18 @@ order of the family's pattern string, ``CausalLMOutputLayer``. It shares
 ``_ResidualBlock`` with ``HybridDecoderBlock``: the output type, the
 recomputation and the hand-off of the expert layer's state.
 
+``SandwichDecoderBlock`` is the unit of the AFMoE family (Arcee's
+Trinity): gated grouped-query attention and a feed-forward branch, each
+between two RMSNorms of its own,
+
+    h = x + N2(Attn(N1(x))),   y = h + N4(FFN(N3(h)))
+
+where the attention is windowed with rotary positions or full without
+any (``GatedAttention``'s ``window`` and rotary share), and the
+feed-forward branch a dense gated MLP (``gated_mlp``, the leading layers)
+or ``HeldExpertsMoE`` (gated experts under a sigmoid router, a shared
+expert added ungated). It shares ``_ResidualBlock`` with the other two.
+
 ``recompute`` wraps a block's ``apply`` in ``jax.checkpoint`` while
 training, so the step ``fit()`` builds keeps each block's input for the
 backward pass and recomputes the block's internals there, but for the
@@ -100,6 +112,7 @@ BLOCK_DIFFUSION_ATTENTION = "block_diffusion_attention"
 MAMBA2 = "mamba2"
 CAUSAL_ATTENTION = "causal_attention"
 EXPERTS = "experts"
+DENSE = "dense"
 MAMBA = "mamba"
 ATTENTION = "attention"
 GATED_MEMORY = "gated_memory"
@@ -151,8 +164,47 @@ class TokenEmbedding(Layer):
         idx = x.astype(jnp.int32)
         if idx.ndim == 3 and idx.shape[-1] == 1:
             idx = idx[..., 0]
-        y = jnp.take(params["W"], idx, axis=0)
+        y = self._scaled(jnp.take(params["W"], idx, axis=0))
         return ((y, params["W"]) if self.emit_table else y), state
+
+    def _scaled(self, rows):
+        return rows
+
+
+@register_serializable
+@dataclasses.dataclass(frozen=True)
+class ScaledTokenEmbedding(TokenEmbedding):
+    """``TokenEmbedding`` whose rows are multiplied by ``sqrt(n_out)`` on
+    the way out (the AFMoE family's muP); the table and its initialisation
+    are the same. A class of its own, so that the text a saved
+    ``TokenEmbedding`` writes stays what it was."""
+
+    def _scaled(self, rows):
+        return rows * jnp.asarray(self.n_out ** 0.5, rows.dtype)
+
+
+def gated_mlp_params(k_gate_up, k_down, width, hidden, init_std, dtype):
+    """A dense gated MLP's two matrices, normal(0, ``init_std``): ``W1``
+    (width, 2 hidden) with columns ``[gate | up]``, ``W2`` (hidden,
+    width)."""
+    return {"W1": init_std * jax.random.normal(
+                k_gate_up, (width, 2 * hidden), dtype),
+            "W2": init_std * jax.random.normal(k_down, (hidden, width),
+                                               dtype)}
+
+
+def gated_mlp(params, h):
+    """``W2(silu(g) * u)`` with ``[g | u] = h W1`` (SwiGLU; the gate and
+    the product in float32, the result in ``h``'s type), under the named
+    scope ``mlp.glu``: the dense MLP of ``StateSpaceHybridBlock`` and of
+    ``SandwichDecoderBlock``'s dense layers."""
+    hidden = params["W2"].shape[0]
+    with jax.named_scope("mlp.glu"):
+        f32 = jnp.promote_types(jnp.float32, h.dtype)
+        gu = jnp.einsum("ntf,fe->nte", h, params["W1"])
+        g, u = gu[..., :hidden], gu[..., hidden:]
+        act = (u.astype(f32) * jax.nn.silu(g.astype(f32))).astype(h.dtype)
+        return jnp.einsum("nte,ef->ntf", act, params["W2"])
 
 
 _MOE_SCOPES = ("moe.route", "moe.dispatch", "moe.experts", "moe.shared",
@@ -397,6 +449,109 @@ class SingleMixerBlock(_ResidualBlock, FeedForwardLayer):
 
 @register_serializable
 @dataclasses.dataclass(frozen=True)
+class SandwichDecoderBlock(_ResidualBlock, FeedForwardLayer):
+    """One layer of the AFMoE family (module docstring): gated grouped-query
+    attention and a feed-forward branch, each between two RMSNorms of its
+    own (the sandwich). The attention is ``GatedAttention`` with q/k norm
+    and output gate; with a ``window`` it is a sliding layer's, rotary on
+    the whole head, without one a full layer's, with no positional
+    encoding. ``ffn`` picks the feed-forward branch,
+    ``dense`` (``gated_mlp`` of ``mlp_hidden``) or ``experts``
+    (``HeldExpertsMoE``: gated experts under a sigmoid router whose bias
+    the load moves by ``bias_update_rate`` a step, weights times
+    ``routed_scale``, the shared expert added ungated). The four norms run
+    under the named scope ``block.norm``. ``n_out`` is the model width and
+    equals the input's; an ``experts`` block's state is its expert
+    layer's, a ``dense`` one has none.
+
+    ``recompute``: as ``HybridDecoderBlock``'s."""
+    ffn: str = DENSE
+    # gated attention
+    n_heads: int = 32
+    n_kv_heads: int = 4
+    head_dim: int = 128
+    window: Optional[int] = None
+    rope_theta: float = 1e4
+    # dense gated MLP
+    mlp_hidden: int = 0
+    # experts
+    num_experts: int = 8
+    held_experts: Tuple[int, ...] = ()
+    expert_hidden: int = 0
+    shared_hidden: int = 0
+    top_k: int = 2
+    routed_scale: float = 1.0
+    bias_update_rate: float = 0.0
+    router_aux_loss_coef: float = 0.0
+    eps: float = 1e-5
+    init_std: float = 0.02
+    recompute: bool = False
+
+    named_scopes = (GatedAttention.named_scopes + _MOE_SCOPES
+                    + ("mlp.glu", "block.norm"))
+
+    def __post_init__(self):
+        if self.ffn not in (DENSE, EXPERTS):
+            raise ValueError(f"ffn={self.ffn!r}: {DENSE!r} or {EXPERTS!r}")
+
+    def _parts(self):
+        common = dict(n_in=self.n_out, n_out=self.n_out, dtype=self.dtype,
+                      init_std=self.init_std)
+        mixer = GatedAttention(
+            n_heads=self.n_heads, n_kv_heads=self.n_kv_heads,
+            head_dim=self.head_dim, eps=self.eps,
+            partial_rotary_factor=0.0 if self.window is None else 1.0,
+            rope_theta=self.rope_theta, window=self.window, **common)
+        if self.ffn == DENSE:
+            return mixer, None
+        return mixer, HeldExpertsMoE(
+            num_experts=self.num_experts, held_experts=self.held_experts,
+            hidden=self.expert_hidden, shared_hidden=self.shared_hidden,
+            top_k=self.top_k, aux_loss_coef=self.router_aux_loss_coef,
+            expert_form="gated", router_scoring="sigmoid",
+            routed_scale=self.routed_scale,
+            shared_gate=False, bias_update_rate=self.bias_update_rate,
+            **common)
+
+    def _expert_layer(self):
+        return self._parts()[1]
+
+    def initialize(self, key, input_type):
+        width = self._check_width(input_type)
+        mixer, moe = self._parts()
+        km, k1, k2 = jax.random.split(key, 3)
+        rt = RecurrentType(width, None)
+        norm = RMSNorm(eps=self.eps, dtype=self.dtype)
+        params = {f"norm{i}": norm.initialize(None, rt)
+                  for i in (1, 2, 3, 4)}
+        params["mixer"] = mixer.initialize(km, rt)
+        if moe is None:
+            params["mlp"] = gated_mlp_params(k1, k2, width, self.mlp_hidden,
+                                             self.init_std,
+                                             self.param_dtype())
+        else:
+            params["moe"] = moe.initialize(k1, rt)
+        return params
+
+    def _apply(self, params, state, x, ctx: LayerContext):
+        mixer, moe = self._parts()
+
+        def norm(i, a):
+            with jax.named_scope("block.norm"):
+                return rms_norm(a, params[f"norm{i}"]["w"], self.eps)
+
+        a, _ = mixer.apply(params["mixer"], {}, norm(1, x), ctx)
+        x = x + norm(2, a)
+        u = norm(3, x)
+        if moe is None:
+            f = gated_mlp(params["mlp"], u)
+        else:
+            f, state = moe.apply(params["moe"], state, u, ctx)
+        return x + norm(4, f), state
+
+
+@register_serializable
+@dataclasses.dataclass(frozen=True)
 class StateSpaceHybridBlock(FeedForwardLayer):
     """One block of the decoder-hybrid-decoder family (module docstring).
     ``mixer`` picks the token mixer; the fields after it are the parts'
@@ -490,15 +645,11 @@ class StateSpaceHybridBlock(FeedForwardLayer):
         norm = LayerNormalization(eps=self.eps, dtype=self.dtype)
         km, k1, k2 = jax.random.split(key, 3)
         rt = RecurrentType(width, None)
-        dt = self.param_dtype()
         return {"norm1": norm.initialize(None, rt),
                 "mixer": self._mixer().initialize(km, rt),
                 "norm2": norm.initialize(None, rt),
-                # columns [gate | up]
-                "mlp": {"W1": self.init_std * jax.random.normal(
-                            k1, (width, 2 * self.mlp_hidden), dt),
-                        "W2": self.init_std * jax.random.normal(
-                            k2, (self.mlp_hidden, width), dt)}}
+                "mlp": gated_mlp_params(k1, k2, width, self.mlp_hidden,
+                                        self.init_std, self.param_dtype())}
 
     def _apply(self, params, x, ctx: LayerContext):
         x, *extras = x if isinstance(x, tuple) else (x,)
@@ -508,13 +659,7 @@ class StateSpaceHybridBlock(FeedForwardLayer):
                                        mask=ctx.mask)
         x = x + m
         h, _ = norm.apply(params["norm2"], {}, x, ctx)
-        with jax.named_scope("mlp.glu"):
-            f32 = jnp.promote_types(jnp.float32, h.dtype)
-            gu = jnp.einsum("ntf,fe->nte", h, params["mlp"]["W1"])
-            g, u = gu[..., :self.mlp_hidden], gu[..., self.mlp_hidden:]
-            act = (u.astype(f32) * jax.nn.silu(g.astype(f32))).astype(h.dtype)
-            f = jnp.einsum("nte,ef->ntf", act, params["mlp"]["W2"])
-        y = x + f
+        y = x + gated_mlp(params["mlp"], h)
         return (y,) + tuple(emitted) if self.emit else y
 
     def apply(self, params, state, x, ctx: LayerContext):

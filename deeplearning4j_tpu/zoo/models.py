@@ -746,6 +746,111 @@ class NemotronH(ZooModel):
 
 
 @dataclasses.dataclass
+class AFMoE(ZooModel):
+    """Mixture-of-experts causal language model of Arcee's AFMoE family, as
+    Trinity-Mini (published config: huggingface.co/arcee-ai/Trinity-Mini,
+    ``config.json``, ``model_type: afmoe``; the field names below are its
+    keys). Every layer is ``h = x + N2(Attn(N1(x)))``, ``y = h +
+    N4(FFN(N3(h)))`` (``nn.layers.decoder.SandwichDecoderBlock``): the
+    attention is grouped-query with a per-head q/k RMSNorm and a sigmoid
+    output gate, over a causal window of ``sliding_window`` with rotary
+    positions on the whole head where ``layer_types[l]`` is
+    ``sliding_attention`` and over the whole past with no positional
+    encoding where it is ``full_attention``; the feed-forward branch is a
+    dense SwiGLU MLP of ``intermediate_size`` for the first
+    ``num_dense_layers`` layers and after them gated experts of
+    ``moe_intermediate_size`` under a sigmoid router whose choice adds a
+    bias that the load moves (``load_balance_coeff`` a step), the chosen
+    scores renormalised and times ``route_scale``, with one shared expert
+    of the same width added ungated (the family's ``score_func`` sigmoid,
+    ``route_norm`` and ``num_shared_experts`` 1). With ``mup_enabled`` the
+    embedding's output is multiplied by ``sqrt(hidden_size)``. RMSNorm
+    (zero-centred), an untied head, next-token loss.
+
+    ``held_experts`` (default: all) and a sliced ``vocab_size`` are an
+    expert-parallel deployment's share, as ``Qwen3Next``'s;
+    ``layer_types`` gives the layers built, one each.
+    ``router_aux_loss_coef`` above 0 adds that many times each expert
+    layer's balance loss on the normalised sigmoid scores to the training
+    loss. Features are integer token ids (N, seq_len), labels
+    ``nn.layers.decoder.next_token_labels(ids)``. Not in the model:
+    dropout, generation."""
+    vocab_size: int = 200192
+    hidden_size: int = 2048
+    intermediate_size: int = 6144
+    moe_intermediate_size: int = 1024
+    layer_types: Tuple[str, ...] = (
+        ("sliding_attention",) * 3 + ("full_attention",)) * 8
+    num_dense_layers: int = 2
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 4
+    head_dim: int = 128
+    sliding_window: int = 2048
+    rope_theta: float = 1e4
+    num_experts: int = 128
+    held_experts: Tuple[int, ...] = ()
+    num_experts_per_tok: int = 8
+    route_scale: float = 2.826
+    load_balance_coeff: float = 1e-3
+    router_aux_loss_coef: float = 0.0
+    rms_norm_eps: float = 1e-5
+    mup_enabled: bool = True
+    initializer_range: float = 0.02
+    seq_len: int = 8192
+    recompute: bool = True
+    compute_dtype: str = "bfloat16"
+    updater: Optional[Updater] = None
+    seed: int = 123
+
+    def conf(self):
+        from deeplearning4j_tpu.nn.layers.decoder import (
+            DENSE, EXPERTS, CausalLMOutputLayer, SandwichDecoderBlock,
+            ScaledTokenEmbedding, TokenEmbedding)
+        kinds = {"sliding_attention": True, "full_attention": False}
+        unknown = set(self.layer_types) - set(kinds)
+        if unknown:
+            raise ValueError(
+                f"layer_types holds {sorted(unknown)}; a layer is "
+                "'sliding_attention' or 'full_attention'")
+        std = self.initializer_range
+        embed = ScaledTokenEmbedding if self.mup_enabled else TokenEmbedding
+        b = (NeuralNetConfiguration.Builder()
+             .seed(self.seed)
+             .updater(self.updater or Adam(1e-4))
+             .compute_dtype(self.compute_dtype)
+             .list()
+             .layer(embed(name="embed", vocab_size=self.vocab_size,
+                          n_out=self.hidden_size, init_std=std)))
+        for l, kind in enumerate(self.layer_types):
+            b = b.layer(SandwichDecoderBlock(
+                name=f"block{l}", n_out=self.hidden_size,
+                ffn=DENSE if l < self.num_dense_layers else EXPERTS,
+                n_heads=self.num_attention_heads,
+                n_kv_heads=self.num_key_value_heads, head_dim=self.head_dim,
+                window=self.sliding_window if kinds[kind] else None,
+                rope_theta=self.rope_theta,
+                mlp_hidden=self.intermediate_size,
+                num_experts=self.num_experts,
+                held_experts=tuple(self.held_experts),
+                expert_hidden=self.moe_intermediate_size,
+                shared_hidden=self.moe_intermediate_size,
+                top_k=self.num_experts_per_tok,
+                routed_scale=self.route_scale,
+                bias_update_rate=self.load_balance_coeff,
+                router_aux_loss_coef=self.router_aux_loss_coef,
+                eps=self.rms_norm_eps, init_std=std,
+                recompute=self.recompute))
+        return (b.layer(CausalLMOutputLayer(
+                    name="lm_head", n_out=self.vocab_size,
+                    eps=self.rms_norm_eps, init_std=std))
+                .set_input_type(InputType.recurrent(1, self.seq_len))
+                .build())
+
+    def init(self) -> MultiLayerNetwork:
+        return MultiLayerNetwork(self.conf()).init()
+
+
+@dataclasses.dataclass
 class Phi4MiniFlash(ZooModel):
     """Decoder-hybrid-decoder causal language model of the Phi-4-mini-flash
     family (published config: huggingface.co/microsoft/

@@ -116,6 +116,31 @@ def test_differential_attention_kernels_compile_at_the_published_widths(
     assert compiled.as_text().count("tpu_custom_call") >= 3
 
 
+def test_windowed_gated_attention_kernels_compile_at_the_published_widths(
+        one_chip, no_compile_cache):
+    """Forward and both backward kernels as ``GatedAttention(window=2048)``
+    calls them at Trinity-Mini's widths: 32 heads of 128 (the 4 key/value
+    heads repeated), 8,192 positions, bfloat16; a window wider than the
+    1024 x 1024 tile keeps the tile, and a query block visits 3 key
+    blocks (21 of 64 a head)."""
+    from deeplearning4j_tpu.ops.pallas_kernels import (
+        _default_blocks, flash_attention, flash_kv_blocks)
+    from deeplearning4j_tpu.ops.visibility import Causal
+    vis = Causal(2048)
+    assert _default_blocks(128, vis) == (1024, 1024)
+    assert flash_kv_blocks(8192, 8192, 1024, 1024, vis) == (21, 64)
+    x = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def total(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, visibility=vis,
+                                       interpret=False).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(total, argnums=(0, 1, 2))).lower(
+        x, x, x).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
 def test_block_diffusion_kernels_compile_at_the_published_widths(
         one_chip, no_compile_cache):
     """Forward and both backward kernels under ``BlockDiffusion(8192, 4)``
