@@ -15,7 +15,9 @@ a seed, with no network and no files outside the checkout:
                  1, 3, 32 and 50 rows (the split path) against
                  ``model.output``
   phase=kernels  flash attention and the Pallas LSTM recurrence,
-                 compiled (interpret=False), and the fused block's conv
+                 compiled (interpret=False), the flash backward's one
+                 launch at the SDAR cell's block-diffusion shape against
+                 its two-launch path, and the fused block's conv
                  + BN statistics (Gram and direct), forward and
                  backward, against plain XLA; the held experts' loop over
                  blocks of rows at routings that fill one block and
@@ -263,6 +265,43 @@ def phase_serve(ctx):
             "warmup_seconds": round(eng.warmup_seconds, 1)}
 
 
+def _check_one_kernel_backward(arr, errs):
+    """The flash backward as the SDAR cell takes it, one launch under
+    ``BlockDiffusion(8192, 4)`` at 32 heads of 128 over 16,384 positions,
+    bfloat16, against the two-launch path (dK/dV kernel, then the dQ
+    kernel) on the same residuals: the same sums in the same order."""
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.ops import pallas_kernels as pk
+    from deeplearning4j_tpu.ops.visibility import BlockDiffusion
+    name = "flash_bwd_one_kernel_block_diffusion"
+    try:
+        vis, (n, h, t, dh) = BlockDiffusion(8192, 4), (1, 32, 16384, 128)
+        bq, bk = pk._default_blocks(dh, vis)
+        q, k, v, do = (arr((n, h, t, dh), jnp.bfloat16) for _ in range(4))
+        mask = jnp.ones((n, t), jnp.float32)
+        out, lse = jax.jit(lambda q, k, v: pk._flash_forward(
+            q, k, v, mask, vis, bq, bk, False))(q, k, v)
+        args = (q, k, v, mask, out, lse, do, vis, bq, bk, False)
+        launches = str(jax.make_jaxpr(
+            lambda *a: pk._flash_backward_pallas(*a, *args[7:]))(
+                *args[:7])).count("pallas_call")
+        got = jax.jit(lambda *a: pk._flash_backward_pallas(*a, *args[7:]))(
+            *args[:7])
+        want = jax.jit(lambda *a: pk._flash_backward_kernels(
+            *a, *args[7:], with_dq=False))(*args[:7])
+        errs[name] = max(_rel_err(g, w) for g, w in zip(got, want))
+        print(f"{name}: launches={launches} bitwise="
+              f"{[bool(jnp.array_equal(g, w)) for g, w in zip(got, want)]}")
+        if launches != 1:
+            raise AssertionError(f"{launches} launches, not one")
+        if errs[name] > TOL["bfloat16"]:
+            raise AssertionError(f"max relative error {errs[name]:.3e}")
+    except Exception as e:
+        traceback.print_exc()
+        errs[name] = f"FAIL {type(e).__name__}: {str(e)[:200]}"
+
+
 def phase_kernels(ctx):
     import jax
     import jax.numpy as jnp
@@ -300,6 +339,7 @@ def phase_kernels(ctx):
                                         interpret=False),
         lambda q, k, v: scaled_dot_product_attention(q, k, v, mask=mask),
         (q, k, v), "bfloat16", errs)
+    _check_one_kernel_backward(arr, errs)
 
     # Pallas LSTM recurrence at the 2xLSTM-512 geometry of
     # benchmarks/lstm_crossover.py --quick: batch 256, hidden 512, T=128

@@ -11,7 +11,10 @@ never materializes the (Tq, Tk) score matrix in HBM.
 Layout: q/k/v are (N, H, T, Dh) inside the kernel (the layer-facing
 wrapper accepts the framework-standard (N, T, H, Dh)). The grid is
 (batch, head, q-block); each program streams the full K/V for its head
-through VMEM in ``block_k`` chunks with an online softmax.
+through VMEM in ``block_k`` chunks with an online softmax. The backward
+is one kernel over (batch, head, k-block) that streams the query blocks
+and keeps the head's dQ in VMEM beside the key block's dK/dV; where that
+dQ does not fit, a second kernel computes it.
 
 `attention()` chooses between the kernel and the plain XLA path from
 what it can observe in its inputs (backend, sequence length); a kernel
@@ -152,9 +155,12 @@ def scoped_vmem_limit(need: int) -> Optional[int]:
     return min(need, SCOPED_VMEM_CAP)
 
 
-def _dim_sem(n: int, vmem_limit_bytes: Optional[int] = None):
+def _dim_sem(n: int, vmem_limit_bytes: Optional[int] = None,
+             sequential: int = 1):
+    """``n`` grid axes, the last ``sequential`` of them in order."""
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel",) * (n - 1) + ("arbitrary",),
+        dimension_semantics=(("parallel",) * (n - sequential)
+                             + ("arbitrary",) * sequential),
         vmem_limit_bytes=vmem_limit_bytes)
 
 
@@ -233,15 +239,27 @@ def _flash_fwd_rule(q, k, v, mask, vis, block_q, block_k, interpret,
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
-                          delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
-                          vis: Visibility, scale: float, q_blocks: int = 0):
+                          delta_ref, *refs, vis: Visibility, scale: float,
+                          q_blocks: int, with_dq: bool):
     """dK/dV for one key block: the query-block axis is the innermost
     sequential grid dim, accumulating into VMEM scratch — P is recomputed
     from the saved logsumexp, never materialized in HBM. That axis runs
     over the query blocks that see the key block (``vis.q_tile``, of the
-    ``q_blocks`` there are)."""
+    ``q_blocks`` there are).
+
+    ``with_dq``: the tile's ``ds`` also goes into dQ, kept for the whole
+    head in a float32 scratch of (Tq, dh) and written once, at the head's
+    last step, into an output block the head holds. A query block meets
+    its key blocks in ascending order under every ``Visibility``, the
+    order the dQ kernel walks them in, so dQ's sums are that kernel's to
+    the last bit."""
+    if with_dq:
+        dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr = refs
+    else:
+        dk_ref, dv_ref, dk_scr, dv_scr = refs
     ki = pl.program_id(2)
     qj = pl.program_id(3)
+    nk = pl.num_programs(2)
     nq = pl.num_programs(3)
     bq = q_ref.shape[2]
     bk = k_ref.shape[2]
@@ -250,6 +268,11 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    if with_dq:
+        @pl.when((ki == 0) & (qj == 0))
+        def _init_dq():
+            dq_scr[...] = jnp.zeros_like(dq_scr)
 
     qi, live = vis.q_tile(ki, qj, bq, bk, q_blocks)
 
@@ -274,18 +297,29 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
         dk_scr[...] += lax.dot_general(
             ds, q, dimension_numbers=(((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        if with_dq:
+            rows = pl.ds(pl.multiple_of(qi * bq, bq), bq)
+            dq_scr[rows, :] += jnp.dot(ds, kb,
+                                       preferred_element_type=jnp.float32)
 
     @pl.when(qj == nq - 1)
     def _finalize():
         dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
 
+    if with_dq:
+        @pl.when((ki == nk - 1) & (qj == nq - 1))
+        def _finalize_dq():
+            dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
+
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
                          delta_ref, dq_ref, dq_scr, *, vis: Visibility,
                          scale: float):
     """dQ for one query block: key blocks stream on the sequential grid
-    dim (the forward kernel's axis), accumulating into VMEM scratch."""
+    dim (the forward kernel's axis), accumulating into VMEM scratch. The
+    backward's second launch, only where a head's dQ does not fit in
+    scoped VMEM beside the dK/dV kernel's tile (``_bwd_vmem_need``)."""
     qi = pl.program_id(2)
     kj = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -321,12 +355,57 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
         dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
 
 
+def _lanes(n: int) -> int:
+    """``n`` in whole lanes of 128: the width a VMEM row of ``n`` takes."""
+    return -(-n // 128) * 128
+
+
+def _bwd_vmem_need(tq: int, dh: int, dv: int, block_q: int, block_k: int,
+                   itemsize: int) -> int:
+    """Scoped VMEM the one-kernel backward asks for, every row in whole
+    lanes: its double-buffered blocks (a query block's q, dO, logsumexp
+    and delta; a key block's k, v and key mask; the key block's dK and dV
+    and the head's dQ out), its float32 scratch (dK, dV and the head's
+    whole dQ) and float32 temporaries: the four input blocks' and three
+    score tiles. More than Mosaic takes, by a score tile and more: at the
+    five cells' shapes it compiles under 21.9-30.5 MiB where this reads
+    28.1-36.1 (Phi's window of 512 under the default 16, this 15.0), and
+    under 48.9 where this reads 53.6 for 16 heads of 512."""
+    qrow, vrow = _lanes(dh), _lanes(dv)
+    blocks = ((block_q + block_k) * (qrow + vrow) * itemsize
+              + 2 * block_q * 128 * 4 + 8 * block_k * 4)
+    outputs = (block_k * (qrow + vrow) + tq * qrow) * itemsize
+    scratch = (block_k * (qrow + vrow) + tq * qrow) * 4
+    temps = ((block_q + block_k) * (qrow + vrow) + 3 * block_q * block_k) * 4
+    return 2 * (blocks + outputs) + scratch + temps
+
+
 def _flash_backward_pallas(q, k, v, mask, out, lse, do, vis: Visibility,
                            block_q: int, block_k: int, interpret: bool):
     """Pallas dq/dk/dv (VERDICT r3 #2 — both passes in kernels, like the
-    reference's CudnnLSTMHelper accelerating fwd AND bwd). The tiny
-    delta = rowsum(dO ⊙ O) precompute stays in XLA (one fused elementwise
-    pass); everything matmul-shaped runs on the MXU in Pallas."""
+    reference's CudnnLSTMHelper accelerating fwd AND bwd): one kernel over
+    the dK/dV grid that accumulates dQ beside dK and dV, each tile's
+    scores recomputed and exponentiated once, wherever a head's dQ fits in
+    scoped VMEM with the tile (``_bwd_vmem_need`` under
+    ``SCOPED_VMEM_CAP``: every cell's shape, 36 MiB at 16,384 positions
+    of 128, up to some 80,000 positions of 128 in bfloat16); past that
+    (very long sequences, long ring-attention blocks) two launches, the
+    dK/dV kernel and the dQ kernel, which computes the tile's scores
+    again."""
+    need = _bwd_vmem_need(q.shape[2], q.shape[3], v.shape[3], block_q,
+                          block_k, q.dtype.itemsize)
+    return _flash_backward_kernels(q, k, v, mask, out, lse, do, vis,
+                                   block_q, block_k, interpret,
+                                   with_dq=need <= SCOPED_VMEM_CAP)
+
+
+def _flash_backward_kernels(q, k, v, mask, out, lse, do, vis: Visibility,
+                            block_q: int, block_k: int, interpret: bool,
+                            with_dq: bool):
+    """The backward's launches: the dK/dV kernel, which also writes dQ
+    ``with_dq``, else followed by the dQ kernel. The tiny delta =
+    rowsum(dO ⊙ O) precompute stays in XLA (one fused elementwise pass);
+    everything matmul-shaped runs on the MXU in Pallas."""
     n, h, tq, dh = q.shape
     tk, dv = k.shape[2], v.shape[3]
     scale = 1.0 / float(dh) ** 0.5  # host-sync-ok: static shape
@@ -342,8 +421,30 @@ def _flash_backward_pallas(q, k, v, mask, out, lse, do, vis: Visibility,
     kb = functools.partial(vis.kv_fetch, bq=block_q, bk=block_k)
 
     kernel = functools.partial(_flash_bwd_dkv_kernel, vis=vis, scale=scale,
-                               q_blocks=nq)
-    dk, dv_ = pl.pallas_call(
+                               q_blocks=nq, with_dq=with_dq)
+    out_specs = [
+        pl.BlockSpec((1, 1, block_k, dh),
+                     lambda i, j, ki, qi: (i, j, ki, 0), memory_space=vm),
+        pl.BlockSpec((1, 1, block_k, dv),
+                     lambda i, j, ki, qi: (i, j, ki, 0), memory_space=vm),
+    ]
+    out_shape = [jax.ShapeDtypeStruct((n, h, tk, dh), k.dtype),
+                 jax.ShapeDtypeStruct((n, h, tk, dv), v.dtype)]
+    scratch = [pltpu.VMEM((block_k, dh), jnp.float32),
+               pltpu.VMEM((block_k, dv), jnp.float32)]
+    params = _dim_sem(4)
+    if with_dq:
+        # the head's dQ: one block the head's steps all hold, so it goes
+        # back to HBM once, when the head changes; the key-block axis is
+        # sequential too, since every key block adds to it
+        out_specs.insert(0, pl.BlockSpec(
+            (1, 1, tq, dh), lambda i, j, ki, qi: (i, j, 0, 0),
+            memory_space=vm))
+        out_shape.insert(0, jax.ShapeDtypeStruct((n, h, tq, dh), q.dtype))
+        scratch.insert(0, pltpu.VMEM((tq, dh), jnp.float32))
+        params = _dim_sem(4, scoped_vmem_limit(_bwd_vmem_need(
+            tq, dh, dv, block_q, block_k, q.dtype.itemsize)), sequential=2)
+    grads = pl.pallas_call(
         kernel,
         grid=(n, h, nk, nq_inner),
         in_specs=[
@@ -369,25 +470,15 @@ def _flash_backward_pallas(q, k, v, mask, out, lse, do, vis: Visibility,
                          lambda i, j, ki, qi: (i, j, qb(ki, qi), 0),
                          memory_space=vm),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_k, dh),
-                         lambda i, j, ki, qi: (i, j, ki, 0),
-                         memory_space=vm),
-            pl.BlockSpec((1, 1, block_k, dv),
-                         lambda i, j, ki, qi: (i, j, ki, 0),
-                         memory_space=vm),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, h, tk, dh), k.dtype),
-            jax.ShapeDtypeStruct((n, h, tk, dv), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, dh), jnp.float32),
-            pltpu.VMEM((block_k, dv), jnp.float32),
-        ],
-        compiler_params=_dim_sem(4),
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
+        compiler_params=params,
         interpret=interpret,
     )(q, k, v, mask3, do, lse4, delta)
+    if with_dq:
+        return tuple(grads)
+    dk, dv_ = grads
 
     kernel = functools.partial(_flash_bwd_dq_kernel, vis=vis, scale=scale)
     dq = pl.pallas_call(
@@ -429,8 +520,9 @@ def _flash_backward_pallas(q, k, v, mask, out, lse, do, vis: Visibility,
 
 def _flash_bwd_rule(vis, block_q, block_k, interpret, bwd_impl, res, do):
     """Flash backward from saved (O, logsumexp) — dq/dk/dv Pallas kernels
-    (``_flash_backward_pallas``); P is recomputed from the normalizer
-    instead of being saved. ``bwd_impl`` ("pallas"/"xla", the explicit
+    (``_flash_backward_pallas``: one launch where a head's dQ fits in
+    VMEM, else two); P is recomputed from the normalizer instead of being
+    saved. ``bwd_impl`` ("pallas"/"xla", the explicit
     flash_attention parameter) takes precedence; when None the
     ``DL4J_FLASH_BWD=xla`` env override selects the jnp/scan reference
     implementation (also used by equivalence tests). The env var is read
@@ -512,7 +604,9 @@ def _default_blocks(head_dim: int, vis: Visibility = Visibility()):
     side, whatever the inputs' type (the TPU compiler, 16 heads, T =
     8,192: 18.5 MB at 1024 x 1024 x 256 and 19.8 MB at 512 x 1024 x 512
     are refused; 1024 x 1024 x 128, 512 x 1024 x 256 and 256 x 1024 x 512
-    compile).
+    compile). The tile is the two-launch backward's; the one-kernel
+    backward keeps the same tile beside a head's whole dQ and asks for a
+    scoped limit of its own (``_bwd_vmem_need``).
 
     ``vis`` may narrow the tile (``tile_side``). Under a causal window
     neither side of the tile is wider than the window (in whole lanes of

@@ -6,6 +6,8 @@ SURVEY §4 "accelerated-vs-reference validation"); same idea here, with
 the kernel run in interpreter mode on the CPU mesh.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -15,7 +17,8 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.nn.layers.attention import (
     scaled_dot_product_attention)
 from deeplearning4j_tpu.ops.pallas_kernels import attention, flash_attention
-from deeplearning4j_tpu.ops.visibility import Causal, Visibility
+from deeplearning4j_tpu.ops.visibility import (
+    BlockDiffusion, Causal, Visibility)
 
 
 def _vis(causal):
@@ -308,3 +311,115 @@ def test_default_blocks_shrink_with_the_head(head_dim, blocks):
     TPU compiler accepts and refuses: tests/test_tpu_compile.py)."""
     from deeplearning4j_tpu.ops.pallas_kernels import _default_blocks
     assert _default_blocks(head_dim) == blocks
+
+
+# (visibility, positions, head, value head, key mask, dtype): every kind of
+# visibility, with and without a key mask, lengths that pad (250, 500 and
+# the two halves of 200 under block diffusion) and a value head of its own
+ONE_KERNEL_CASES = {
+    "all": (Visibility(), 256, 64, 64, False, np.float32),
+    "all_masked": (Visibility(), 256, 64, 64, True, np.float32),
+    "causal": (Causal(), 256, 64, 64, False, np.float32),
+    "causal_padded_masked": (Causal(), 250, 64, 64, True, np.float32),
+    "causal_wide_value": (Causal(), 256, 64, 128, False, np.float32),
+    "causal_bfloat16": (Causal(), 256, 64, 64, True, jnp.bfloat16),
+    "window": (Causal(100), 512, 64, 64, False, np.float32),
+    "window_padded_masked": (Causal(100), 500, 64, 64, True, np.float32),
+    "window_wide_value": (Causal(200), 512, 64, 128, False, np.float32),
+    "block_diffusion": (BlockDiffusion(128, 4), 256, 64, 64, False,
+                        np.float32),
+    "block_diffusion_masked": (BlockDiffusion(256, 4), 512, 64, 64, True,
+                               np.float32),
+    "block_diffusion_padded_wide_value": (BlockDiffusion(200, 4), 400, 64,
+                                          128, True, np.float32),
+}
+
+
+def _one_kernel_grads(case, rng):
+    """dQ, dK, dV of ``flash_attention`` in ``case`` at 128 x 128 tiles,
+    and the ``pallas_call``s of its differentiated program."""
+    vis, t, dh, dv, masked, dtype = ONE_KERNEL_CASES[case]
+    n, h = 2, 2
+    q, k = (jnp.asarray(rng.normal(size=(n, t, h, dh)), dtype)
+            for _ in range(2))
+    v, do = (jnp.asarray(rng.normal(size=(n, t, h, dv)), dtype)
+             for _ in range(2))
+    mask = None
+    if masked:
+        mask = np.ones((n, t), np.float32)
+        mask[0, t - 37:] = 0.0
+        mask[1, 3:t // 3] = 0.0
+        mask = jnp.asarray(mask)
+
+    def f(q, k, v, impl=None):
+        o = flash_attention(q, k, v, mask=mask, visibility=vis, block_q=128,
+                            block_k=128, interpret=True, bwd_impl=impl)
+        return jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32))
+
+    grad = jax.grad(f, argnums=(0, 1, 2))
+    launches = str(jax.make_jaxpr(grad)(q, k, v)).count("pallas_call")
+    xla = jax.grad(functools.partial(f, impl="xla"), argnums=(0, 1, 2))
+    return grad(q, k, v), launches, xla(q, k, v)
+
+
+@pytest.mark.parametrize("case", ONE_KERNEL_CASES)
+def test_the_one_kernel_backward_is_the_two_launch_backward_to_the_bit(
+        monkeypatch, case):
+    """The backward as one kernel (dQ kept in VMEM beside dK/dV) against
+    the two launches it stands in for where a head's dQ fits, on the same
+    inputs: the same sums in the same order, so dQ, dK and dV are equal
+    to the last bit; and both within the scan reference's tolerance."""
+    from deeplearning4j_tpu.ops import pallas_kernels as pk
+    one, launches, xla = _one_kernel_grads(case, np.random.default_rng(7))
+    assert launches == 2                    # the forward and one backward
+    monkeypatch.setattr(pk, "SCOPED_VMEM_CAP", 0)   # no dQ fits: two
+    two, launches, _ = _one_kernel_grads(case, np.random.default_rng(7))
+    assert launches == 3
+    for a, b, name in zip(one, two, "qkv"):
+        assert a.dtype == b.dtype
+        assert jnp.array_equal(a, b), f"d{name} differs"
+    if one[0].dtype == jnp.float32:
+        for a, b, name in zip(one, xla, "qkv"):
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-5,
+                err_msg=f"d{name} mismatch vs scan reference")
+
+
+# the five language-model cells' attention calls: (positions, head, value
+# head, visibility) as their layers hand them to the kernels
+CELL_ATTENTION = {
+    "sdar.block_diffusion": (16384, 128, 128, BlockDiffusion(8192, 4)),
+    "qwen3-next.gated": (8192, 256, 256, Causal()),
+    "phi4-mini-flash.full": (8192, 64, 128, Causal()),
+    "phi4-mini-flash.window": (8192, 64, 128, Causal(512)),
+    "nemotron3-nano.causal": (8192, 128, 128, Causal()),
+    "trinity-mini.window": (8192, 128, 128, Causal(2048)),
+    "trinity-mini.full": (8192, 128, 128, Causal()),
+}
+
+
+@pytest.mark.parametrize("cell", CELL_ATTENTION)
+def test_every_cells_backward_is_one_kernel(cell):
+    """A head's dQ fits in scoped VMEM beside the tile at every cell's
+    shape, bfloat16 or float32: the backward is one launch there."""
+    from deeplearning4j_tpu.ops.pallas_kernels import (
+        SCOPED_VMEM_CAP, _bwd_vmem_need, _default_blocks)
+    t, dh, dv, vis = CELL_ATTENTION[cell]
+    bq, bk = _default_blocks(max(dh, dv), vis)
+    for itemsize in (2, 4):
+        assert _bwd_vmem_need(t, dh, dv, bq, bk, itemsize) < SCOPED_VMEM_CAP
+
+
+@pytest.mark.parametrize("t,launches", [(8192, 2), (65536, 2),
+                                        (131072, 3)])
+def test_the_backward_is_two_launches_where_a_heads_dq_passes_the_cap(
+        t, launches):
+    """Traced at one causal head of 128, bfloat16: up to 65,536 positions
+    a head's dQ (float32 scratch and the output block's two buffers) fits
+    under ``SCOPED_VMEM_CAP`` with the tile; at 131,072 it is 128 MiB and
+    the backward is the dK/dV kernel and the dQ kernel."""
+    x = jax.ShapeDtypeStruct((1, t, 1, 128), jnp.bfloat16)
+    grad = jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, visibility=Causal(), interpret=True).astype(jnp.float32)),
+        argnums=(0, 1, 2))
+    assert str(jax.make_jaxpr(grad)(x, x, x)).count("pallas_call") == launches
