@@ -14,7 +14,9 @@ wrapper accepts the framework-standard (N, T, H, Dh)). The grid is
 through VMEM in ``block_k`` chunks with an online softmax. The backward
 is one kernel over (batch, head, k-block) that streams the query blocks
 and keeps the head's dQ in VMEM beside the key block's dK/dV; where that
-dQ does not fit, a second kernel computes it.
+dQ does not fit, a second kernel computes it. The backward computes each
+tile transposed, key rows by query columns (``k q^T``), so that dV and
+dK are plain products and only dQ's contracts a leading axis.
 
 `attention()` chooses between the kernel and the plain XLA path from
 what it can observe in its inputs (backend, sequence length); a kernel
@@ -60,9 +62,12 @@ FLASH_OUT_NAME = "flash_attention.out"
 FLASH_LSE_NAME = "flash_attention.lse"
 
 
-def _masked(s, vis: Visibility, qi, ki, bq: int, bk: int):
-    """The (bq, bk) score tile with the pairs ``vis`` hides at ``_NEG``."""
-    seen = vis.tile_visible(qi, ki, bq, bk)
+def _masked(s, vis: Visibility, qi, ki, bq: int, bk: int,
+            transposed: bool = False):
+    """The (bq, bk) score tile, or ``transposed`` the (bk, bq) one, with
+    the pairs ``vis`` hides at ``_NEG``."""
+    seen = (vis.tile_visible_t if transposed else vis.tile_visible)(
+        qi, ki, bq, bk)
     return s if seen is None else jnp.where(seen, s, _NEG)
 
 
@@ -247,12 +252,27 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
     over the query blocks that see the key block (``vis.q_tile``, of the
     ``q_blocks`` there are).
 
+    The tile is computed transposed, key rows by query columns: ``s^T = k
+    q^T`` (bk, bq), a product of the forward's form, and ``dP^T = v
+    dO^T`` likewise. dV and dK sum over the tile's queries, its columns
+    now, so ``dV += P^T dO`` and ``dK += dS^T q`` are plain products;
+    only dQ sums over the keys, the tile's rows, and takes ``dS^T`` as an
+    operand contracted over its leading axis: one transposed operand a
+    tile, where a query-by-key tile makes dV's and dK's take one each.
+    So the logsumexp and delta come in as rows (1, bq) and the key mask
+    as a column (bk, 1), and the visibility's tile mask is
+    ``tile_visible_t``. One orientation for every shape: no tile side or
+    head size the cells use is faster the other way.
+
     ``with_dq``: the tile's ``ds`` also goes into dQ, kept for the whole
     head in a float32 scratch of (Tq, dh) and written once, at the head's
     last step, into an output block the head holds. A query block meets
     its key blocks in ascending order under every ``Visibility``, the
-    order the dQ kernel walks them in, so dQ's sums are that kernel's to
-    the last bit."""
+    order the dQ kernel walks them in, and each tile's ``(dS^T)^T k``
+    takes that kernel's ``dS k`` operands, transposed exactly: dQ's sums
+    are that kernel's to the last bit (on a v5e at the cells' shapes, and
+    interpreted). dK and dV are this kernel's on both paths (the
+    two-launch path runs it without dQ)."""
     if with_dq:
         dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr = refs
     else:
@@ -282,25 +302,22 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
         vb = v_ref[0, 0].astype(jnp.float32)
         q = q_ref[0, 0].astype(jnp.float32)                # (bq, dh)
         do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0]                                # (bq, 1)
-        delta = delta_ref[0, 0]
-        s = jnp.dot(q, kb.T, preferred_element_type=jnp.float32) * scale
-        s = jnp.where(mask_ref[0, 0][None, :] > 0.0, s, _NEG)
-        s = _masked(s, vis, qi, ki, bq, bk)
-        p = jnp.exp(s - lse)
-        p = jnp.where(lse > (_NEG * 0.5), p, 0.0)          # (bq, bk)
-        dv_scr[...] += lax.dot_general(
-            p, do, dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jnp.dot(do, vb.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        dk_scr[...] += lax.dot_general(
-            ds, q, dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        lse = lse_ref[0, 0, 0]                             # (1, bq)
+        delta = delta_ref[0, 0, 0]
+        st = jnp.dot(kb, q.T, preferred_element_type=jnp.float32) * scale
+        st = jnp.where(mask_ref[0] > 0.0, st, _NEG)        # (bk, 1) keys
+        st = _masked(st, vis, qi, ki, bq, bk, transposed=True)
+        pt = jnp.exp(st - lse)
+        pt = jnp.where(lse > (_NEG * 0.5), pt, 0.0)        # (bk, bq)
+        dv_scr[...] += jnp.dot(pt, do, preferred_element_type=jnp.float32)
+        dpt = jnp.dot(vb, do.T, preferred_element_type=jnp.float32)
+        dst = pt * (dpt - delta) * scale
+        dk_scr[...] += jnp.dot(dst, q, preferred_element_type=jnp.float32)
         if with_dq:
             rows = pl.ds(pl.multiple_of(qi * bq, bq), bq)
-            dq_scr[rows, :] += jnp.dot(ds, kb,
-                                       preferred_element_type=jnp.float32)
+            dq_scr[rows, :] += lax.dot_general(
+                dst, kb, dimension_numbers=(((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
     @pl.when(qj == nq - 1)
     def _finalize():
@@ -363,17 +380,18 @@ def _lanes(n: int) -> int:
 def _bwd_vmem_need(tq: int, dh: int, dv: int, block_q: int, block_k: int,
                    itemsize: int) -> int:
     """Scoped VMEM the one-kernel backward asks for, every row in whole
-    lanes: its double-buffered blocks (a query block's q, dO, logsumexp
-    and delta; a key block's k, v and key mask; the key block's dK and dV
-    and the head's dQ out), its float32 scratch (dK, dV and the head's
-    whole dQ) and float32 temporaries: the four input blocks' and three
-    score tiles. More than Mosaic takes, by a score tile and more: at the
-    five cells' shapes it compiles under 21.9-30.5 MiB where this reads
-    28.1-36.1 (Phi's window of 512 under the default 16, this 15.0), and
-    under 48.9 where this reads 53.6 for 16 heads of 512."""
+    lanes: its double-buffered blocks (a query block's q, dO, and its
+    logsumexp and delta as rows of 8 sublanes; a key block's k, v and key
+    mask, a column; the key block's dK and dV and the head's dQ out), its
+    float32 scratch (dK, dV and the head's whole dQ) and float32
+    temporaries: the four input blocks' and three score tiles (transposed,
+    of the same size). More than Mosaic takes, by a score tile and more:
+    at the five cells' shapes it compiles under 18.1-27.7 MiB where this
+    reads 27.1-35.1 (Phi's window of 512 under 11.5, this 14.6), and
+    under 49.6 where this reads 54.0 for 16 heads of 512."""
     qrow, vrow = _lanes(dh), _lanes(dv)
     blocks = ((block_q + block_k) * (qrow + vrow) * itemsize
-              + 2 * block_q * 128 * 4 + 8 * block_k * 4)
+              + 2 * 8 * _lanes(block_q) * 4 + block_k * 128 * 4)
     outputs = (block_k * (qrow + vrow) + tq * qrow) * itemsize
     scratch = (block_k * (qrow + vrow) + tq * qrow) * 4
     temps = ((block_q + block_k) * (qrow + vrow) + 3 * block_q * block_k) * 4
@@ -387,7 +405,7 @@ def _flash_backward_pallas(q, k, v, mask, out, lse, do, vis: Visibility,
     the dK/dV grid that accumulates dQ beside dK and dV, each tile's
     scores recomputed and exponentiated once, wherever a head's dQ fits in
     scoped VMEM with the tile (``_bwd_vmem_need`` under
-    ``SCOPED_VMEM_CAP``: every cell's shape, 36 MiB at 16,384 positions
+    ``SCOPED_VMEM_CAP``: every cell's shape, 35 MiB at 16,384 positions
     of 128, up to some 80,000 positions of 128 in bfloat16); past that
     (very long sequences, long ring-attention blocks) two launches, the
     dK/dV kernel and the dQ kernel, which computes the tile's scores
@@ -410,11 +428,16 @@ def _flash_backward_kernels(q, k, v, mask, out, lse, do, vis: Visibility,
     tk, dv = k.shape[2], v.shape[3]
     scale = 1.0 / float(dh) ** 0.5  # host-sync-ok: static shape
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1, keepdims=True)                # (n, h, tq, 1)
-    lse4 = lse[..., None]                                  # (n, h, tq, 1)
-    mask3 = mask[:, None, :]                               # (n, 1, tk)
+                    axis=-1)                               # (n, h, tq)
     vm = pl.ANY if interpret else pltpu.VMEM
     nq, nk = tq // block_q, tk // block_k
+    # the dK/dV kernel's transposed tile takes the per-query values as
+    # rows, one a query block (the block's last two dims are the array's
+    # at any tile), and the key mask as a column; the dQ kernel's tile
+    # takes them the other way
+    lse_row, delta_row = (x.reshape(n, h, nq, 1, block_q)
+                          for x in (lse, delta))
+    mask_col = mask[:, :, None]                            # (n, tk, 1)
     nq_inner = vis.q_steps(nq, nk, block_q, block_k)
     nk_inner = vis.kv_steps(nq, nk, block_q, block_k)
     qb = functools.partial(vis.q_fetch, bq=block_q, bk=block_k, nq=nq)
@@ -457,17 +480,17 @@ def _flash_backward_kernels(q, k, v, mask, out, lse, do, vis: Visibility,
             pl.BlockSpec((1, 1, block_k, dv),
                          lambda i, j, ki, qi: (i, j, ki, 0),
                          memory_space=vm),
-            pl.BlockSpec((1, 1, block_k),
-                         lambda i, j, ki, qi: (i, 0, ki),
+            pl.BlockSpec((1, block_k, 1),
+                         lambda i, j, ki, qi: (i, ki, 0),
                          memory_space=vm),
             pl.BlockSpec((1, 1, block_q, dv),
                          lambda i, j, ki, qi: (i, j, qb(ki, qi), 0),
                          memory_space=vm),
-            pl.BlockSpec((1, 1, block_q, 1),
-                         lambda i, j, ki, qi: (i, j, qb(ki, qi), 0),
+            pl.BlockSpec((1, 1, 1, 1, block_q),
+                         lambda i, j, ki, qi: (i, j, qb(ki, qi), 0, 0),
                          memory_space=vm),
-            pl.BlockSpec((1, 1, block_q, 1),
-                         lambda i, j, ki, qi: (i, j, qb(ki, qi), 0),
+            pl.BlockSpec((1, 1, 1, 1, block_q),
+                         lambda i, j, ki, qi: (i, j, qb(ki, qi), 0, 0),
                          memory_space=vm),
         ],
         out_specs=out_specs,
@@ -475,7 +498,7 @@ def _flash_backward_kernels(q, k, v, mask, out, lse, do, vis: Visibility,
         scratch_shapes=scratch,
         compiler_params=params,
         interpret=interpret,
-    )(q, k, v, mask3, do, lse4, delta)
+    )(q, k, v, mask_col, do, lse_row, delta_row)
     if with_dq:
         return tuple(grads)
     dk, dv_ = grads
@@ -514,8 +537,17 @@ def _flash_backward_kernels(q, k, v, mask, out, lse, do, vis: Visibility,
         scratch_shapes=[pltpu.VMEM((block_q, dh), jnp.float32)],
         compiler_params=_dim_sem(4),
         interpret=interpret,
-    )(q, k, v, mask3, do, lse4, delta)
+    )(q, k, v, mask[:, None, :], do, lse[..., None], delta[..., None])
     return dq, dk, dv_
+
+
+def _pallas_backward(bwd_impl: Optional[str]) -> bool:
+    """Whether the backward runs the Pallas kernels: ``bwd_impl``, else
+    the ``DL4J_FLASH_BWD`` override, else yes."""
+    import os
+    if bwd_impl is None:
+        bwd_impl = os.environ.get("DL4J_FLASH_BWD", "pallas")
+    return bwd_impl != "xla"
 
 
 def _flash_bwd_rule(vis, block_q, block_k, interpret, bwd_impl, res, do):
@@ -529,11 +561,8 @@ def _flash_bwd_rule(vis, block_q, block_k, interpret, bwd_impl, res, do):
     at TRACE time — a jitted train step freezes the choice; call
     ``jax.clear_caches()`` after changing it (advisor r4: pass bwd_impl
     for programmatic control instead)."""
-    import os
     q, k, v, mask, out, lse = res
-    if bwd_impl is None:
-        bwd_impl = os.environ.get("DL4J_FLASH_BWD", "pallas")
-    if bwd_impl != "xla":
+    if _pallas_backward(bwd_impl):
         dq, dk, dv = _flash_backward_pallas(
             q, k, v, mask, out, lse, do, vis, block_q, block_k, interpret)
         return dq, dk, dv, jnp.zeros_like(mask)
@@ -661,11 +690,20 @@ FLASH_BLOCK_GAUGES = (
 )
 
 
-def _publish_kv_blocks(scope: str, visited: int, total: int) -> None:
+FLASH_BWD_GAUGE = (
+    "dl4j_flash_bwd_transposed",
+    "1 where the scope's flash-attention backward runs the Pallas kernels, "
+    "whose dK/dV tile is computed transposed (key rows by query columns), "
+    "0 where it runs the scan reference; as the step was last traced")
+
+
+def _publish_kv_blocks(scope: str, visited: int, total: int,
+                       transposed: bool) -> None:
     from deeplearning4j_tpu.observe.registry import default_registry
     reg = default_registry()
-    for (name, help_text), value in zip(FLASH_BLOCK_GAUGES,
-                                        (visited, total)):
+    for (name, help_text), value in zip(
+            FLASH_BLOCK_GAUGES + (FLASH_BWD_GAUGE,),
+            (visited, total, int(transposed))):
         reg.gauge(name, help_text).set(value, scope=scope)
 
 
@@ -710,8 +748,9 @@ def flash_attention(q, k, v, mask=None,
     implementation explicitly ("pallas" kernels or the "xla" jnp/scan
     reference); None defers to the ``DL4J_FLASH_BWD`` env override
     (default pallas). ``scope`` names the caller's ``jax.named_scope``:
-    with it, the visited and total key blocks of the grid are published
-    as gauges when the call is traced.
+    with it, the visited and total key blocks of the grid, and whether
+    the backward's tile is the Pallas kernel's transposed one, are
+    published as gauges when the call is traced.
     """
     if bwd_impl not in (None, "pallas", "xla"):
         raise ValueError(f"bwd_impl must be 'pallas'/'xla'/None, "
@@ -774,7 +813,8 @@ def flash_attention(q, k, v, mask=None,
             mask = jnp.pad(mask, ((0, 0), (0, pk)))
     if scope is not None:
         _publish_kv_blocks(scope, *flash_kv_blocks(
-            qt.shape[2], kt.shape[2], block_q, block_k, vis))
+            qt.shape[2], kt.shape[2], block_q, block_k, vis),
+            transposed=_pallas_backward(bwd_impl))
 
     out = _flash_attention(qt, kt, vt, mask, vis, block_q, block_k,
                            interpret, bwd_impl)

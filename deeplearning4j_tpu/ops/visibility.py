@@ -5,7 +5,8 @@ jnp/scan backward, the plain XLA lowering, the tile chooser and the
 counter of visited tiles in ``ops/pallas_kernels.py``;
 ``nn.layers.attention.scaled_dot_product_attention``) are handed one
 ``Visibility`` and ask it two things: whether a pair is visible
-(``visible``, ``tile_visible``), and which tiles of the (query block, key
+(``visible``, ``tile_visible``; ``tile_visible_t`` for the dK/dV kernel's
+tile, which is transposed), and which tiles of the (query block, key
 block) grid hold a visible pair at all, so that the others are neither
 fetched nor computed (``kv_tile`` / ``kv_fetch`` / ``kv_steps`` for the
 kernels that stream key blocks past a query block, ``q_tile`` / ``q_fetch``
@@ -69,9 +70,12 @@ def _where(cond, a, b):
     return jnp.where(cond, a, b)
 
 
-def _tile_positions(qi, ki, bq: int, bk: int):
-    qpos = qi * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    kpos = ki * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+def _tile_positions(qi, ki, bq: int, bk: int, transposed: bool = False):
+    """Query and key positions of the (bq, bk) tile, or ``transposed`` of
+    its (bk, bq) transpose: key rows by query columns."""
+    shape, q_axis = ((bk, bq), 1) if transposed else ((bq, bk), 0)
+    qpos = qi * bq + lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    kpos = ki * bk + lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
     return qpos, kpos
 
 
@@ -89,6 +93,11 @@ class Visibility:
     def tile_visible(self, qi, ki, bq: int, bk: int):
         """``visible`` for the (bq, bk) tile of query block ``qi`` and key
         block ``ki``, inside a kernel."""
+        return None
+
+    def tile_visible_t(self, qi, ki, bq: int, bk: int):
+        """``tile_visible`` transposed: the (bk, bq) tile, key rows by
+        query columns, as the dK/dV kernel computes it."""
         return None
 
     # ---- kernels whose inner axis streams key blocks past query block qi
@@ -140,6 +149,9 @@ class Causal(Visibility):
 
     def tile_visible(self, qi, ki, bq, bk):
         return self.visible(*_tile_positions(qi, ki, bq, bk))
+
+    def tile_visible_t(self, qi, ki, bq, bk):
+        return self.visible(*_tile_positions(qi, ki, bq, bk, transposed=True))
 
     def kv_steps(self, nq, nk, bq, bk):
         if self.window is None:
@@ -237,19 +249,27 @@ class BlockDiffusion(Visibility):
         return pos // b
 
     def tile_visible(self, qi, ki, bq, bk):
+        return self._tile_mask(qi, ki, bq, bk, transposed=False)
+
+    def tile_visible_t(self, qi, ki, bq, bk):
+        return self._tile_mask(qi, ki, bq, bk, transposed=True)
+
+    def _tile_mask(self, qi, ki, bq, bk, transposed):
         """A tile lies in one half on each side, so which of the three
         rules holds is a scalar; the one tile kind that is never visited
-        (clean queries on noisy keys) is not told apart."""
+        (clean queries on noisy keys) is not told apart. Either
+        orientation: the bounds are scalars, so only the positions'
+        axes differ."""
         half_q, half_k = self.seq_len // bq, self.seq_len // bk
         q_noisy, k_noisy = qi < half_q, ki < half_k
         qpos, kpos = _tile_positions(
             jnp.where(q_noisy, qi, qi - half_q),
-            jnp.where(k_noisy, ki, ki - half_k), bq, bk)
+            jnp.where(k_noisy, ki, ki - half_k), bq, bk, transposed)
         # by how many blocks the query is ahead of the key: 0 for a noisy
         # key; for a clean one at least 1, and 0 too for a clean query
         # (scalar bounds: Mosaic selects no vector of booleans)
         ahead = self._block_of(qpos) - self._block_of(kpos)
-        least = jnp.where(k_noisy | ~q_noisy, 0, 1)
+        least = jnp.where(k_noisy | jnp.logical_not(q_noisy), 0, 1)
         most = jnp.where(k_noisy, 0, self.seq_len)
         return (ahead >= least) & (ahead <= most)
 

@@ -72,8 +72,14 @@ SHAPES = [(16, 1, 4, 4), (16, 1, 1, 1), (32, 4, 4, 4), (32, 4, 8, 16),
 
 
 @pytest.mark.parametrize("t,b,bq,bk", SHAPES)
-@pytest.mark.parametrize("bwd", ["pallas", "xla"])
-def test_the_kernels_compute_the_literal_mask(t, b, bq, bk, bwd):
+@pytest.mark.parametrize("bwd", ["pallas", "pallas_two_launches", "xla"])
+def test_the_kernels_compute_the_literal_mask(t, b, bq, bk, bwd,
+                                              monkeypatch):
+    """The forward and the backward as one kernel, as two launches (the
+    transposed dK/dV kernel, then the dQ kernel) and as the scan."""
+    if bwd == "pallas_two_launches":
+        monkeypatch.setattr(pk, "SCOPED_VMEM_CAP", 0)   # no dQ fits
+        bwd = "pallas"
     q, k, v = qkv(t, seed=t + b)
     want = values_and_gradients(
         lambda *a: by_the_mask(*a, literal_mask(t, b)), q, k, v)

@@ -100,7 +100,9 @@ def test_attention_dispatch_falls_back(rng):
 
 class TestFlashBlockLayout:
     """Regression for the TPU lowering constraint: the mask rides as
-    (n, 1, tk) and lse as (n, h, tq, 1) so block trailing dims are legal.
+    (n, 1, tk) and lse as (n, h, tq, 1) so block trailing dims are legal
+    (the forward and dQ kernels; the dK/dV kernel takes the mask as (n,
+    tk, 1) and lse as (n, h, nq, 1, block_q)).
     On CPU this runs the same kernel in interpret mode; on TPU it must
     compile WITHOUT falling back (the silent-fallback path once hid a
     never-ran kernel)."""
@@ -313,12 +315,59 @@ def test_default_blocks_shrink_with_the_head(head_dim, blocks):
     assert _default_blocks(head_dim) == blocks
 
 
+# (visibility, positions, query tile, key tile): the backward's tile is
+# the forward's transposed; windows under, at and over the tile, blocks of
+# a power of two and not, tiles wider on either side
+TRANSPOSED_MASK_CASES = {
+    "all": (Visibility(), 32, 8, 16),
+    "causal": (Causal(), 32, 8, 8),
+    "causal_wide_query": (Causal(), 32, 16, 8),
+    "causal_wide_key": (Causal(), 32, 8, 16),
+    "window_under_the_tile": (Causal(5), 32, 8, 8),
+    "window_of_the_tile": (Causal(8), 32, 8, 8),
+    "window_over_the_tile": (Causal(20), 48, 16, 8),
+    "block_diffusion": (BlockDiffusion(32, 4), 64, 8, 8),
+    "block_diffusion_wide_query": (BlockDiffusion(32, 4), 64, 16, 8),
+    "block_diffusion_wide_key": (BlockDiffusion(32, 4), 64, 8, 32),
+    "block_diffusion_block_of_3": (BlockDiffusion(36, 3), 72, 6, 12),
+    "block_diffusion_block_of_3_wide_query": (BlockDiffusion(36, 3), 72,
+                                              12, 6),
+}
+
+
+@pytest.mark.parametrize("case", TRANSPOSED_MASK_CASES)
+def test_the_transposed_tile_mask_is_the_tile_mask_transposed(case):
+    """``tile_visible_t`` (the dK/dV kernel's key-by-query tile) is
+    ``tile_visible`` transposed at every (query block, key block) of the
+    grid, the tiles the kernels skip too; and both are ``visible``."""
+    vis, t, bq, bk = TRANSPOSED_MASK_CASES[case]
+    pos = np.arange(t)
+    full = vis.visible(pos[:, None], pos[None, :])
+    for qi in range(t // bq):
+        for ki in range(t // bk):
+            seen = vis.tile_visible(qi, ki, bq, bk)
+            seen_t = vis.tile_visible_t(qi, ki, bq, bk)
+            if full is None:
+                assert seen is None and seen_t is None
+                continue
+            seen, seen_t = np.asarray(seen), np.asarray(seen_t)
+            assert seen_t.shape == (bk, bq)
+            np.testing.assert_array_equal(seen_t, seen.T)
+            want = np.asarray(full)[qi * bq:(qi + 1) * bq,
+                                    ki * bk:(ki + 1) * bk]
+            if not isinstance(vis, BlockDiffusion) or want.any():
+                # block diffusion tells apart the tiles it visits alone
+                np.testing.assert_array_equal(seen, want)
+
+
 # (visibility, positions, head, value head, key mask, dtype): every kind of
 # visibility, with and without a key mask, lengths that pad (250, 500 and
-# the two halves of 200 under block diffusion) and a value head of its own
+# the two halves of 200 under block diffusion), a value head of its own,
+# and bfloat16 inputs
 ONE_KERNEL_CASES = {
     "all": (Visibility(), 256, 64, 64, False, np.float32),
     "all_masked": (Visibility(), 256, 64, 64, True, np.float32),
+    "all_bfloat16": (Visibility(), 256, 64, 64, True, jnp.bfloat16),
     "causal": (Causal(), 256, 64, 64, False, np.float32),
     "causal_padded_masked": (Causal(), 250, 64, 64, True, np.float32),
     "causal_wide_value": (Causal(), 256, 64, 128, False, np.float32),
@@ -326,12 +375,15 @@ ONE_KERNEL_CASES = {
     "window": (Causal(100), 512, 64, 64, False, np.float32),
     "window_padded_masked": (Causal(100), 500, 64, 64, True, np.float32),
     "window_wide_value": (Causal(200), 512, 64, 128, False, np.float32),
+    "window_bfloat16": (Causal(100), 500, 64, 64, True, jnp.bfloat16),
     "block_diffusion": (BlockDiffusion(128, 4), 256, 64, 64, False,
                         np.float32),
     "block_diffusion_masked": (BlockDiffusion(256, 4), 512, 64, 64, True,
                                np.float32),
     "block_diffusion_padded_wide_value": (BlockDiffusion(200, 4), 400, 64,
                                           128, True, np.float32),
+    "block_diffusion_bfloat16": (BlockDiffusion(256, 4), 512, 64, 64, True,
+                                 jnp.bfloat16),
 }
 
 
@@ -346,9 +398,11 @@ def _one_kernel_grads(case, rng):
              for _ in range(2))
     mask = None
     if masked:
+        # row 0 padded at its end; row 1's leading keys masked, so that
+        # its first queries see no key under every visibility but all
         mask = np.ones((n, t), np.float32)
         mask[0, t - 37:] = 0.0
-        mask[1, 3:t // 3] = 0.0
+        mask[1, :t // 3] = 0.0
         mask = jnp.asarray(mask)
 
     def f(q, k, v, impl=None):
@@ -367,8 +421,12 @@ def test_the_one_kernel_backward_is_the_two_launch_backward_to_the_bit(
         monkeypatch, case):
     """The backward as one kernel (dQ kept in VMEM beside dK/dV) against
     the two launches it stands in for where a head's dQ fits, on the same
-    inputs: the same sums in the same order, so dQ, dK and dV are equal
-    to the last bit; and both within the scan reference's tolerance."""
+    inputs. dK and dV come from the one transposed tile on both paths, so
+    they are equal to the last bit; dQ sums the same tiles in the same
+    order, the one kernel's ``(dS^T)^T k`` of the dQ kernel's ``dS k``
+    operands transposed exactly, so it is equal to the last bit too.
+    Both paths are within the scan reference's tolerance, float32, and
+    within a rounding of bfloat16's."""
     from deeplearning4j_tpu.ops import pallas_kernels as pk
     one, launches, xla = _one_kernel_grads(case, np.random.default_rng(7))
     assert launches == 2                    # the forward and one backward
@@ -378,11 +436,18 @@ def test_the_one_kernel_backward_is_the_two_launch_backward_to_the_bit(
     for a, b, name in zip(one, two, "qkv"):
         assert a.dtype == b.dtype
         assert jnp.array_equal(a, b), f"d{name} differs"
-    if one[0].dtype == jnp.float32:
-        for a, b, name in zip(one, xla, "qkv"):
-            np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-5,
-                err_msg=f"d{name} mismatch vs scan reference")
+    bfloat16 = one[0].dtype == jnp.bfloat16
+    for got in (one, two):
+        for a, b, name in zip(got, xla, "qkv"):
+            a, b = (np.asarray(x, np.float32) for x in (a, b))
+            if bfloat16:        # a sum's last float32 bit may round over
+                np.testing.assert_allclose(
+                    a, b, rtol=2 ** -7, atol=2 ** -7 * np.abs(b).max(),
+                    err_msg=f"d{name} mismatch vs scan reference")
+            else:
+                np.testing.assert_allclose(
+                    a, b, rtol=2e-4, atol=2e-5,
+                    err_msg=f"d{name} mismatch vs scan reference")
 
 
 # the five language-model cells' attention calls: (positions, head, value
@@ -408,6 +473,29 @@ def test_every_cells_backward_is_one_kernel(cell):
     bq, bk = _default_blocks(max(dh, dv), vis)
     for itemsize in (2, 4):
         assert _bwd_vmem_need(t, dh, dv, bq, bk, itemsize) < SCOPED_VMEM_CAP
+
+
+@pytest.mark.parametrize("bwd_impl,env,transposed", [
+    (None, None, 1), ("pallas", "xla", 1), (None, "xla", 0),
+    ("xla", None, 0)])
+def test_the_backward_layout_gauge_is_set_under_the_callers_scope(
+        monkeypatch, bwd_impl, env, transposed):
+    """``dl4j_flash_bwd_transposed{scope}``: 1 where the backward runs the
+    Pallas kernels (their dK/dV tile is the transposed one), 0 where it
+    runs the scan reference; set when the call is traced."""
+    from deeplearning4j_tpu.observe.registry import default_registry
+    from deeplearning4j_tpu.ops import pallas_kernels as pk
+    if env is None:
+        monkeypatch.delenv("DL4J_FLASH_BWD", raising=False)
+    else:
+        monkeypatch.setenv("DL4J_FLASH_BWD", env)
+    q, k, v = _qkv(np.random.default_rng(0), t=32)
+    jax.jit(lambda *a: flash_attention(
+        *a, visibility=Causal(), block_q=16, block_k=16, interpret=True,
+        bwd_impl=bwd_impl, scope="attn.layout_probe")).lower(q, k, v)
+    series = default_registry().get_metric(pk.FLASH_BWD_GAUGE[0]).series()
+    assert [value for key, value in series.items()
+            if "attn.layout_probe" in str(key)] == [float(transposed)]
 
 
 @pytest.mark.parametrize("t,launches", [(8192, 2), (65536, 2),

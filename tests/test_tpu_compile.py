@@ -39,25 +39,49 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
-@pytest.mark.parametrize("heads,head_dim", [(12, 64), (16, 256), (16, 512)])
+def _flash_cases():
+    """(heads, positions, head, value head, visibility): causal heads of
+    64, 256 and 512 at 8,192 positions, the cells' attention calls
+    (``test_pallas_kernels.CELL_ATTENTION``), and block diffusion over
+    halves of 600, whose query tile (600) is not whole lanes."""
+    from deeplearning4j_tpu.ops.visibility import BlockDiffusion, Causal
+    from test_pallas_kernels import CELL_ATTENTION
+    heads = {"sdar.block_diffusion": 32, "qwen3-next.gated": 16,
+             "phi4-mini-flash.full": 40, "phi4-mini-flash.window": 40}
+    cases = {f"{h}-{dh}": (h, 8192, dh, dh, Causal())
+             for h, dh in ((12, 64), (16, 256), (16, 512))}
+    for cell, (t, dh, dv, vis) in CELL_ATTENTION.items():
+        cases[cell] = (heads.get(cell, 32), t, dh, dv, vis)
+    cases["block_diffusion_tile_of_600"] = (2, 1200, 128, 128,
+                                            BlockDiffusion(600, 4))
+    return cases
+
+
+FLASH_CASES = _flash_cases()
+
+
+@pytest.mark.parametrize("case", FLASH_CASES)
 def test_flash_attention_compiles_with_its_default_tiles(
-        one_chip, no_compile_cache, heads, head_dim):
-    """The forward kernel and the one backward kernel, causal, 8,192
-    positions, bfloat16: BERT-like heads of 64, the gated attention's 16
+        one_chip, no_compile_cache, case):
+    """The forward kernel and the one backward kernel, bfloat16, at their
+    default tiles: BERT-like causal heads of 64, the gated attention's 16
     heads of 256 (Qwen3-Next's; 1024 x 1024 tiles are refused there: 18.5
-    MB of scoped VMEM), and 512; the backward holds a head's whole dQ in
-    VMEM under the scoped limit it asks for."""
+    MB of scoped VMEM), 512, and every cell's call with its visibility;
+    the backward holds a head's whole dQ in VMEM under the scoped limit
+    it asks for, its per-query rows and key column in legal blocks."""
     from deeplearning4j_tpu.ops.pallas_kernels import flash_attention
-    from deeplearning4j_tpu.ops.visibility import Causal
-    x = jax.ShapeDtypeStruct((1, 8192, heads, head_dim), jnp.bfloat16,
+    heads, t, dh, dv, vis = FLASH_CASES[case]
+    qk = jax.ShapeDtypeStruct((1, t, heads, dh), jnp.bfloat16,
+                              sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, t, heads, dv), jnp.bfloat16,
                              sharding=one_chip)
 
     def total(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, visibility=Causal(),
+        return jnp.sum(flash_attention(q, k, v, visibility=vis,
                                        interpret=False).astype(jnp.float32))
 
     compiled = jax.jit(jax.grad(total, argnums=(0, 1, 2))).lower(
-        x, x, x).compile()
+        qk, qk, v).compile()
     assert compiled.as_text().count("tpu_custom_call") == 2
 
 

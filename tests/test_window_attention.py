@@ -62,9 +62,16 @@ def test_window_values_in_flash_and_xla_equal_the_explicit_mask(window):
 
 
 @pytest.mark.parametrize("window", [1, 5, 32, 40])
-@pytest.mark.parametrize("bwd_impl", ["pallas", "xla"])
+@pytest.mark.parametrize("bwd_impl", ["pallas", "pallas_two_launches",
+                                      "xla"])
 def test_window_gradients_in_both_backward_passes_equal_xlas(window,
-                                                             bwd_impl):
+                                                             bwd_impl,
+                                                             monkeypatch):
+    """The Pallas backward as one kernel and as two launches (the dK/dV
+    kernel, then the dQ kernel), and the scan, against XLA's autodiff."""
+    if bwd_impl == "pallas_two_launches":
+        monkeypatch.setattr(pk, "SCOPED_VMEM_CAP", 0)   # no dQ fits
+        bwd_impl = "pallas"
     q, k, v = qkv()
 
     def through(attn):
