@@ -24,7 +24,12 @@ from deeplearning4j_tpu.datasets.dataset import DataSet, DataSetIterator
 from deeplearning4j_tpu.evaluation.evaluation import Evaluation, RegressionEvaluation
 from deeplearning4j_tpu.observe.tracer import get_tracer
 from deeplearning4j_tpu.optimize.listeners import TrainingListener
-from deeplearning4j_tpu.optimize.solver import TrainState
+from deeplearning4j_tpu.optimize.solver import (
+    TrainState,
+    make_constrain_fn,
+    make_scan_train_step,
+    make_train_step,
+)
 
 
 def compute_cast(x, dt: str):
@@ -51,6 +56,7 @@ class BaseModel:
         self.listeners: List[TrainingListener] = []
         self._train_step = None
         self._scan_step = None
+        self._tbptt_step = None
         self._rng = None
         self.epoch_count = 0
         self._last_loss = None
@@ -86,12 +92,13 @@ class BaseModel:
     def init(self, seed: Optional[int] = None):
         raise NotImplementedError
 
-    def _build_train_step(self):
+    def _loss(self, params, model_state, features, labels, fmask, lmask,
+              rng, iteration, carries: Optional[dict] = None):
+        """(loss, new model state): the model's whole training loss."""
         raise NotImplementedError
 
-    def _build_scan_train_step(self):
-        """K-step fused variant (optimize/solver.make_scan_train_step);
-        built lazily by the fit loop when ``k_steps > 1``."""
+    def _constraint_layers(self):
+        """The model's layers, in the order of ``layer_names``."""
         raise NotImplementedError
 
     def output(self, features, train: bool = False):
@@ -152,8 +159,7 @@ class BaseModel:
         # the spec is baked into the jitted steps — force rebuilds
         self._train_step = None
         self._scan_step = None
-        if hasattr(self, "_tbptt_step"):
-            self._tbptt_step = None
+        self._tbptt_step = None
         return self
 
     def set_tracer(self, tracer):
@@ -324,6 +330,173 @@ class BaseModel:
                     rec.poll(self)
         return it
 
+    # ---- the dispatch protocol of every fit path -------------------------
+    def _send_step(self, step, name, batch, *, lead=(), after=(), seq=None,
+                   k=None, unnoted=0):
+        """Dispatch one training step: split the step key off ``_rng``,
+        make sure the telemetry ring rides in the train state, show the
+        recompile watchdog the ``batch`` arrays under the step's ``name``,
+        hand an enabled tracer the step's named scopes, and call
+        ``step(train_state, *lead, *batch, key, *after)`` inside the
+        ``dispatch`` span (cat ``step``) with the steps ``in_flight``
+        (``unnoted`` more since the last ``_record_step``) and, for a fed
+        item, its ``seq`` and ``k``. Returns what the step returns.
+        ``fit()``'s bodies and ``ParallelWrapper``'s all dispatch here."""
+        self._rng, key = jax.random.split(self._rng)
+        if self._telemetry is not None:
+            self.train_state = self._telemetry.ensure_buffer(self.train_state)
+        if self.recompile_watchdog is not None:
+            self.recompile_watchdog.observe(name, *batch)
+        args = (self.train_state, *lead, *batch, key, *after)
+        tracer = get_tracer(self)
+        if tracer.enabled:
+            self._trace_step_scopes(tracer, step, *args)
+        span = {"in_flight": self._in_flight(unnoted)}
+        if seq is not None:
+            span["seq"] = seq
+        if k is not None:
+            span["k"] = k
+        with tracer.span("dispatch", cat="step", **span):
+            return step(*args)
+
+    def _record_step(self, steps: int, loss, wait_ms: float,
+                     n_examples: int):
+        """After ``_send_step``: ``_post_step`` for the ``steps`` the
+        dispatch ran, ``iteration_done`` on every listener with the wait
+        for the batch and its real example count, and the last loss. A
+        scanned step returns its K inner losses; listeners see the last."""
+        it = self._post_step(steps, loss)
+        if np.ndim(loss):
+            loss = loss[-1]
+        for lst in self.listeners:
+            lst.iteration_done(self, it, self.epoch_count, loss, wait_ms,
+                               n_examples)
+        self._last_loss = loss
+
+    def _epochs(self, epochs: int, source):
+        """The epoch loop of every fit: yields once a pass, between the
+        listeners' epoch start and end; after each pass the source is
+        reset (a ``DataSetIterator`` reshuffles) and the epoch counted."""
+        for _ in range(epochs):
+            for lst in self.listeners:
+                lst.on_epoch_start(self, self.epoch_count)
+            yield
+            if isinstance(source, DataSetIterator):
+                source.reset()
+            for lst in self.listeners:
+                lst.on_epoch_end(self, self.epoch_count)
+            self.epoch_count += 1
+
+    def _end_fit_call(self):
+        """End of a ``fit()`` / ``ParallelWrapper.fit()`` call: publish
+        the routing gauges, flush the telemetry rows still on the device
+        (fewer than a flush interval) and give the recorder a last look."""
+        self._publish_routing_gauges()
+        if self._telemetry is not None:
+            with get_tracer(self).span("telemetry_flush", cat="telemetry"):
+                self._telemetry.flush(self.train_state)
+            rec = self._recorder()
+            if rec is not None:
+                rec.poll(self)
+
+    # ---- train steps ----------------------------------------------------
+    def _loss_fn(self):
+        def loss_fn(params, model_state, features, labels, fmask, lmask, rng,
+                    iteration):
+            return self._loss(params, model_state, features, labels, fmask,
+                              lmask, rng, iteration)
+        return loss_fn
+
+    def _build_train_step(self):
+        return make_train_step(
+            self._loss_fn(), self._tx,
+            constrain_fn=make_constrain_fn(
+                [l for l in self._constraint_layers()]),
+            telemetry=self._telemetry_spec())
+
+    def _build_scan_train_step(self):
+        """K fused optimizer steps per dispatch (fit(k_steps=K)); same
+        loss/constraint/telemetry spec as the per-batch step, scanned
+        over a leading K dim. No bf16 shadow here: the regularization
+        term reads master params, and the fed path promises a bitwise
+        match with the per-batch trajectory."""
+        return make_scan_train_step(
+            self._loss_fn(), self._tx,
+            constrain_fn=make_constrain_fn(
+                [l for l in self._constraint_layers()]),
+            telemetry=self._telemetry_spec())
+
+    # ---- truncated BPTT (reference: doTruncatedBPTT, SURVEY §5.7) --------
+    def _recurrent_carry_nodes(self):
+        """(layer name, stateful core layer, is_lstm) for every layer whose
+        hidden state crosses TBPTT chunks / rnn_time_step calls —
+        including cores wrapped in LastTimeStep / MaskZeroLayer (the
+        wrappers delegate state + initial_state)."""
+        from deeplearning4j_tpu.nn.layers.recurrent import (
+            LSTM, SimpleRnn, unwrap_recurrent)
+        out = []
+        for name, layer in zip(self.layer_names, self._constraint_layers()):
+            core = unwrap_recurrent(layer)
+            if isinstance(core, (LSTM, SimpleRnn)):
+                out.append((name, core, isinstance(core, LSTM)))
+        return out
+
+    def _zero_carries(self, batch_size: int):
+        dt = (jnp.bfloat16 if self.conf_global.compute_dtype == "bfloat16"
+              else jnp.float32)
+        out = {}
+        for name, core, is_lstm in self._recurrent_carry_nodes():
+            h = jnp.zeros((batch_size, core.n_out), dt)
+            out[name] = (h, h) if is_lstm else h
+        return out
+
+    def _tbptt_ready(self):
+        """Before a TBPTT batch: warn about a bidirectional layer (its
+        backward half sees one chunk at a time), build the chunk step."""
+        from deeplearning4j_tpu.nn.layers.recurrent import (
+            first_bidirectional_name, warn_tbptt_bidirectional)
+        bidi = first_bidirectional_name(
+            zip(self.layer_names, self._constraint_layers()))
+        if bidi is not None:
+            warn_tbptt_bidirectional(bidi)
+        if self._tbptt_step is None:
+            self._tbptt_step = self._build_tbptt_step()
+
+    def _build_tbptt_step(self):
+        import optax
+        constrain_fn = make_constrain_fn(list(self._constraint_layers()))
+        carry_nodes = self._recurrent_carry_nodes()
+        telemetry = self._telemetry_spec()
+
+        def step(ts, features, labels, fmask, lmask, rng, carries):
+            def lf(params):
+                return self._loss(params, ts.model_state, features, labels,
+                                  fmask, lmask, rng, ts.iteration,
+                                  carries=carries)
+            (loss, new_ms), grads = jax.value_and_grad(
+                lf, has_aux=True)(ts.params)
+            updates, new_opt = self._tx.update(grads, ts.opt_state, ts.params)
+            new_params = optax.apply_updates(ts.params, updates)
+            if constrain_fn is not None:
+                new_params = constrain_fn(new_params)
+            buf = ts.telemetry
+            if telemetry is not None:
+                buf = telemetry.record(buf, loss=loss, grads=grads,
+                                       params=new_params,
+                                       prev_params=ts.params,
+                                       iteration=ts.iteration)
+            # carries cross the chunk boundary with gradients cut — this IS
+            # the truncation (reference: tbpttBackLength; here back==fwd)
+            new_carries = {}
+            for name, _core, is_lstm in carry_nodes:
+                s = new_ms[name]
+                c = ((s["last_h"], s["last_c"]) if is_lstm else s["last_h"])
+                new_carries[name] = jax.lax.stop_gradient(c)
+            return (TrainState(new_params, new_ms, new_opt,
+                               ts.iteration + 1, buf), loss, new_carries)
+
+        return jax.jit(step, donate_argnums=(0,))
+
     # ---- fit loop -------------------------------------------------------
     def fit(self, data, epochs: int = 1, k_steps: Optional[int] = None,
             prefetch: Optional[int] = None,
@@ -454,11 +627,16 @@ class BaseModel:
                                k_steps=k, tracer=tracer)
                   if feed else None)
         try:
-            for epoch in range(epochs):
-                for lst in self.listeners:
-                    lst.on_epoch_start(self, self.epoch_count)
+            for _ in self._epochs(epochs, source):
                 if feeder is not None:
-                    self._fit_epoch_fed(feeder, tracer)
+                    for item in feeder:
+                        if item.k == 0:
+                            # a foreign object (e.g. MultiDataSet) the
+                            # feeder passed through: the unfed path
+                            self._fit_batch(item.raw,
+                                            etl_ms=item.queue_wait_ms)
+                        else:
+                            self._fit_item(item)
                 else:
                     it_start = time.perf_counter()
                     for batch in iterator:
@@ -467,122 +645,48 @@ class BaseModel:
                         tracer.add_span("etl", it_start, now, cat="data")
                         self._fit_batch(batch, etl_ms=etl_ms)
                         it_start = time.perf_counter()
-                if isinstance(source, DataSetIterator):
-                    source.reset()
-                for lst in self.listeners:
-                    lst.on_epoch_end(self, self.epoch_count)
-                self.epoch_count += 1
         finally:
             if feeder is not None:
                 # a traced fit's last ``resident`` spans; no-op untraced
                 feeder.close()
-        self._publish_routing_gauges()
-        # tail flush so the last (< flush_interval) rows aren't stranded
-        # on device when training ends
-        if self._telemetry is not None:
-            with tracer.span("telemetry_flush", cat="telemetry"):
-                self._telemetry.flush(self.train_state)
-            rec = self._recorder()
-            if rec is not None:
-                rec.poll(self)
+        self._end_fit_call()
         return self
 
+    def _host_step_args(self, batch):
+        """A host batch moved to the device in this model's step
+        signature."""
+        return self._staged_step_args(
+            jnp.asarray(batch.features), jnp.asarray(batch.labels),
+            None if batch.features_mask is None
+            else jnp.asarray(batch.features_mask),
+            None if batch.labels_mask is None
+            else jnp.asarray(batch.labels_mask))
+
     def _fit_batch(self, batch: DataSet, etl_ms: float = 0.0):
-        tracer = get_tracer(self)
-        self._rng, step_key = jax.random.split(self._rng)
-        with tracer.span("host_to_device", cat="data"):
-            features = jnp.asarray(batch.features)
-            labels = jnp.asarray(batch.labels)
-            fmask = None if batch.features_mask is None else jnp.asarray(
-                batch.features_mask)
-            lmask = None if batch.labels_mask is None else jnp.asarray(
-                batch.labels_mask)
-        if self._telemetry is not None:
-            self.train_state = self._telemetry.ensure_buffer(
-                self.train_state)
-        if self.recompile_watchdog is not None:
-            self.recompile_watchdog.observe(
-                "train_step", features, labels, fmask, lmask)
-        if tracer.enabled:
-            self._trace_step_scopes(tracer, self._train_step,
-                                    self.train_state, features, labels,
-                                    fmask, lmask, step_key)
-        with tracer.span("dispatch", cat="step",
-                         in_flight=self._in_flight()):
-            self.train_state, loss = self._train_step(
-                self.train_state, features, labels, fmask, lmask, step_key)
-        it = self._post_step(1, loss)
-        for lst in self.listeners:
-            lst.iteration_done(self, it, self.epoch_count, loss, etl_ms,
-                               batch.num_examples())
-        self._last_loss = loss
+        with get_tracer(self).span("host_to_device", cat="data"):
+            args = self._host_step_args(batch)
+        self.train_state, loss = self._send_step(self._train_step,
+                                                 "train_step", args)
+        self._record_step(1, loss, etl_ms, batch.num_examples())
 
-    # ---- fed fit path (datasets/feeder.DeviceFeeder) --------------------
-    def _fit_epoch_fed(self, feeder, tracer):
-        """One epoch off the device feeder: arrays arrive pre-staged, so
-        the only host work per dispatch is handing them to the jitted
-        step. ``k == 0`` items are foreign objects (e.g. MultiDataSet)
-        the feeder passed through — they take the classic unfed path."""
-        for item in feeder:
-            if item.k == 0:
-                self._fit_batch(item.raw, etl_ms=item.queue_wait_ms)
-            elif item.k == 1:
-                self._fit_staged(item, tracer)
-            else:
-                self._fit_group(item, tracer)
-
-    def _fit_staged(self, item, tracer):
-        """Single pre-staged batch → one step dispatch. Mirrors
-        _fit_batch exactly (same rng split, same step, same watchdog
-        key), minus the host→device transfer that already happened in
-        the feeder — the K=1 fed trajectory is bitwise-equal to unfed."""
-        self._rng, step_key = jax.random.split(self._rng)
+    def _fit_item(self, item):
+        """A batch the DeviceFeeder staged (``k == 1``) is one step, the
+        step ``_fit_batch`` takes (the K=1 fed trajectory is bitwise the
+        unfed one); K stacked batches are ONE scanned dispatch of K
+        optimizer steps, whose telemetry records a row per inner step.
+        Listeners fire once with the item's real example count."""
         args = self._staged_step_args(item.features, item.labels,
                                       item.features_mask, item.labels_mask)
-        if self._telemetry is not None:
-            self.train_state = self._telemetry.ensure_buffer(
-                self.train_state)
-        if self.recompile_watchdog is not None:
-            self.recompile_watchdog.observe("train_step", *args)
-        if tracer.enabled:
-            self._trace_step_scopes(tracer, self._train_step,
-                                    self.train_state, *args, step_key)
-        with tracer.span("dispatch", cat="step", seq=item.seq,
-                         in_flight=self._in_flight()):
-            self.train_state, loss = self._train_step(
-                self.train_state, *args, step_key)
-        it = self._post_step(1, loss)
-        for lst in self.listeners:
-            lst.iteration_done(self, it, self.epoch_count, loss,
-                               item.queue_wait_ms, item.n_examples)
-        self._last_loss = loss
-
-    def _fit_group(self, item, tracer):
-        """K stacked pre-staged batches → ONE scanned dispatch running K
-        optimizer steps (bench.py's amortization, promoted to fit).
-        Iteration advances by K, telemetry records a row per inner step
-        on-device, listeners fire once with the last inner loss and the
-        group's REAL (pre-padding) example count."""
-        if self._scan_step is None:
-            self._scan_step = self._build_scan_train_step()
-        self._rng, group_key = jax.random.split(self._rng)
-        args = self._staged_step_args(item.features, item.labels,
-                                      item.features_mask, item.labels_mask)
-        if self._telemetry is not None:
-            self.train_state = self._telemetry.ensure_buffer(
-                self.train_state)
-        if self.recompile_watchdog is not None:
-            self.recompile_watchdog.observe("scan_train_step", *args)
-        with tracer.span("dispatch", cat="step", k=item.k, seq=item.seq,
-                         in_flight=self._in_flight()):
-            self.train_state, losses = self._scan_step(
-                self.train_state, *args, group_key)
-        it = self._post_step(item.k, losses)
-        loss = losses[-1]
-        for lst in self.listeners:
-            lst.iteration_done(self, it, self.epoch_count, loss,
-                               item.queue_wait_ms, item.n_examples)
-        self._last_loss = loss
+        if item.k == 1:
+            self.train_state, loss = self._send_step(
+                self._train_step, "train_step", args, seq=item.seq)
+        else:
+            if self._scan_step is None:
+                self._scan_step = self._build_scan_train_step()
+            self.train_state, loss = self._send_step(
+                self._scan_step, "scan_train_step", args, seq=item.seq,
+                k=item.k)
+        self._record_step(item.k, loss, item.queue_wait_ms, item.n_examples)
 
     def score(self, dataset: Optional[DataSet] = None) -> float:
         """Loss on a dataset (reference: MultiLayerNetwork.score(DataSet)),

@@ -22,13 +22,7 @@ from deeplearning4j_tpu.models.base import BaseModel, cast_params, compute_cast
 from deeplearning4j_tpu.nn.graph.config import ComputationGraphConfiguration
 from deeplearning4j_tpu.nn.inputs import RecurrentType
 from deeplearning4j_tpu.nn.layers.base import LayerContext
-from deeplearning4j_tpu.optimize.solver import (
-    TrainState,
-    make_constrain_fn,
-    build_optimizer,
-    make_scan_train_step,
-    make_train_step,
-)
+from deeplearning4j_tpu.optimize.solver import TrainState, build_optimizer
 
 
 class ComputationGraph(BaseModel):
@@ -42,7 +36,6 @@ class ComputationGraph(BaseModel):
         self.layer_names = tuple(n.name for n in self._layer_nodes)
         self._output_fn = None
         self._loss_eval_fn = None
-        self._tbptt_step = None
         self._rnn_step_fn = None
         self._rnn_carries = None   # stored state for rnn_time_step
         # tensor-parallel activation specs (parallel/tensor_parallel.py);
@@ -190,152 +183,32 @@ class ComputationGraph(BaseModel):
     def _constraint_layers(self):
         return [n.layer for n in self._layer_nodes]
 
-    def _build_train_step(self):
-        def loss_fn(params, model_state, features, labels, fmask, lmask, rng,
-                    iteration):
-            # features/labels arrive as tuples (multi-input safe)
-            return self._loss(params, model_state, features, labels, fmask,
-                              lmask, rng, iteration)
-        return make_train_step(
-            loss_fn, self._tx,
-            constrain_fn=make_constrain_fn(
-                [l for l in self._constraint_layers()]),
-            telemetry=self._telemetry_spec())
-
-    def _build_scan_train_step(self):
-        """K fused steps per dispatch; the scan carries the input/output
-        tuples so each inner step sees per-batch (B, ...) elements."""
-        def loss_fn(params, model_state, features, labels, fmask, lmask,
-                    rng, iteration):
-            return self._loss(params, model_state, features, labels, fmask,
-                              lmask, rng, iteration)
-        return make_scan_train_step(
-            loss_fn, self._tx,
-            constrain_fn=make_constrain_fn(
-                [l for l in self._constraint_layers()]),
-            telemetry=self._telemetry_spec())
-
     def _staged_step_args(self, features, labels, fmask, lmask):
         # the DeviceFeeder stages plain DataSets; this graph's step takes
-        # input/output tuples (multi-input safe) like _fit_batch_standard
+        # input/output tuples (multi-input safe) like _host_step_args
         return ((features,), (labels,),
                 None if fmask is None else (fmask,),
                 None if lmask is None else (lmask,))
 
-    # ---- fit ------------------------------------------------------------
-    def _fit_batch_standard(self, batch: Union[DataSet, MultiDataSet],
-                            etl_ms: float = 0.0):
-        from deeplearning4j_tpu.observe.tracer import get_tracer
-        tracer = get_tracer(self)
-        self._rng, step_key = jax.random.split(self._rng)
-        with tracer.span("host_to_device", cat="data"):
-            if isinstance(batch, MultiDataSet):
-                feats = tuple(jnp.asarray(f) for f in batch.features)
-                labels = tuple(jnp.asarray(l) for l in batch.labels)
-                fmasks = tuple(None if m is None else jnp.asarray(m)
-                               for m in (batch.features_masks or [])) or None
-                lmasks = tuple(None if m is None else jnp.asarray(m)
-                               for m in (batch.labels_masks or [])) or None
-                n_examples = batch.num_examples()
-            else:
-                feats = (jnp.asarray(batch.features),)
-                labels = (jnp.asarray(batch.labels),)
-                fmasks = (None if batch.features_mask is None
-                          else (jnp.asarray(batch.features_mask),))
-                lmasks = (None if batch.labels_mask is None
-                          else (jnp.asarray(batch.labels_mask),))
-                n_examples = batch.num_examples()
-        if self._telemetry is not None:
-            self.train_state = self._telemetry.ensure_buffer(
-                self.train_state)
-        if self.recompile_watchdog is not None:
-            self.recompile_watchdog.observe(
-                "train_step", feats, labels, fmasks, lmasks)
-        with tracer.span("dispatch", cat="step",
-                         in_flight=self._in_flight()):
-            self.train_state, loss = self._train_step(
-                self.train_state, feats, labels, fmasks, lmasks, step_key)
-        it = self._post_step(1, loss)
-        for lst in self.listeners:
-            lst.iteration_done(self, it, self.epoch_count, loss, etl_ms,
-                               n_examples)
-        self._last_loss = loss
+    def _host_step_args(self, batch):
+        if not isinstance(batch, MultiDataSet):
+            return super()._host_step_args(batch)
+
+        def masks(ms):
+            return tuple(None if m is None else jnp.asarray(m)
+                         for m in (ms or [])) or None
+        return (tuple(jnp.asarray(f) for f in batch.features),
+                tuple(jnp.asarray(l) for l in batch.labels),
+                masks(batch.features_masks), masks(batch.labels_masks))
 
     # ---- truncated BPTT (reference: ComputationGraph.java:955,1184) -----
-    def _recurrent_carry_nodes(self):
-        """(node name, stateful core layer, is_lstm) for every node whose
-        hidden state crosses TBPTT chunks / rnn_time_step calls —
-        including LSTM/SimpleRnn wrapped in LastTimeStep/MaskZeroLayer
-        (the wrappers delegate state to the core)."""
-        from deeplearning4j_tpu.nn.layers.recurrent import (
-            LSTM, SimpleRnn, unwrap_recurrent)
-        out = []
-        for n in self._layer_nodes:
-            core = unwrap_recurrent(n.layer)
-            if isinstance(core, (LSTM, SimpleRnn)):
-                out.append((n.name, core, isinstance(core, LSTM)))
-        return out
-
-    def _zero_carries(self, batch_size: int):
-        dt = (jnp.bfloat16 if self.conf.global_config.compute_dtype ==
-              "bfloat16" else jnp.float32)
-        out = {}
-        for name, core, is_lstm in self._recurrent_carry_nodes():
-            h = jnp.zeros((batch_size, core.n_out), dt)
-            out[name] = (h, h) if is_lstm else h
-        return out
-
-    def _build_tbptt_step(self):
-        import optax
-        constrain_fn = make_constrain_fn(list(self._constraint_layers()))
-        carry_nodes = self._recurrent_carry_nodes()
-        telemetry = self._telemetry_spec()
-
-        def step(ts, features, labels, fmasks, lmasks, rng, carries):
-            def lf(params):
-                return self._loss(params, ts.model_state, features, labels,
-                                  fmasks, lmasks, rng, ts.iteration,
-                                  carries=carries)
-            (loss, new_ms), grads = jax.value_and_grad(
-                lf, has_aux=True)(ts.params)
-            updates, new_opt = self._tx.update(grads, ts.opt_state,
-                                               ts.params)
-            new_params = optax.apply_updates(ts.params, updates)
-            if constrain_fn is not None:
-                new_params = constrain_fn(new_params)
-            buf = ts.telemetry
-            if telemetry is not None:
-                buf = telemetry.record(buf, loss=loss, grads=grads,
-                                       params=new_params,
-                                       prev_params=ts.params,
-                                       iteration=ts.iteration)
-            # carries cross the chunk boundary with gradients cut — this
-            # IS the truncation (same contract as the MLN TBPTT step)
-            new_carries = {}
-            for name, _, is_lstm in carry_nodes:
-                s = new_ms[name]
-                c = ((s["last_h"], s["last_c"]) if is_lstm
-                     else s["last_h"])
-                new_carries[name] = jax.lax.stop_gradient(c)
-            return (TrainState(new_params, new_ms, new_opt,
-                               ts.iteration + 1, buf), loss, new_carries)
-
-        return jax.jit(step, donate_argnums=(0,))
-
     def _fit_batch_tbptt(self, batch, etl_ms: float = 0.0):
         """Chunked-time fit over a DAG (reference: doTruncatedBPTT path of
         ComputationGraph.fit, ComputationGraph.java:955). 3-D features and
         sequence labels are sliced along time; 2-D (static) inputs repeat
         whole into every chunk, exactly like the reference's handling of
         non-sequence graph inputs."""
-        from deeplearning4j_tpu.nn.layers.recurrent import (
-            first_bidirectional_name, warn_tbptt_bidirectional)
-        bidi = first_bidirectional_name(
-            (n.name, n.layer) for n in self._layer_nodes)
-        if bidi is not None:
-            warn_tbptt_bidirectional(bidi)
-        if self._tbptt_step is None:
-            self._tbptt_step = self._build_tbptt_step()
+        self._tbptt_ready()
         if isinstance(batch, MultiDataSet):
             feats = [np.asarray(f) for f in batch.features]  # host-sync-ok: eval host staging
             labels = [np.asarray(l) for l in batch.labels]  # host-sync-ok: eval host staging
@@ -362,11 +235,6 @@ class ComputationGraph(BaseModel):
                 "streams (with a features mask) to a common length.")
         T = seq_lens.pop()
         n = feats[0].shape[0]
-        from deeplearning4j_tpu.observe.tracer import get_tracer
-        tracer = get_tracer(self)
-        if self._telemetry is not None:
-            self.train_state = self._telemetry.ensure_buffer(
-                self.train_state)
         carries = self._zero_carries(n)
         loss = None
         n_chunks = 0
@@ -424,23 +292,14 @@ class ComputationGraph(BaseModel):
                     else:
                         clm[i] = padt(clm[i])
                     cl[i] = padt(cl[i])
-            self._rng, step_key = jax.random.split(self._rng)
             tj = lambda seq: tuple(None if a is None else jnp.asarray(a)
                                    for a in seq)
-            cf, cl, cfm, clm = tj(cf), tj(cl), tj(cfm), tj(clm)
-            if self.recompile_watchdog is not None:
-                self.recompile_watchdog.observe("tbptt_step", cf, cl,
-                                                cfm, clm)
-            with tracer.span("dispatch", cat="step",
-                             in_flight=self._in_flight(n_chunks)):
-                self.train_state, loss, carries = self._tbptt_step(
-                    self.train_state, cf, cl, cfm, clm, step_key, carries)
+            self.train_state, loss, carries = self._send_step(
+                self._tbptt_step, "tbptt_step",
+                (tj(cf), tj(cl), tj(cfm), tj(clm)), after=(carries,),
+                unnoted=n_chunks)
             n_chunks += 1
-        it = self._post_step(n_chunks, loss)
-        for lst in self.listeners:
-            lst.iteration_done(self, it, self.epoch_count, loss, etl_ms,
-                               n)
-        self._last_loss = loss
+        self._record_step(n_chunks, loss, etl_ms, n)
 
     def _fit_batch(self, batch: Union[DataSet, MultiDataSet],
                    etl_ms: float = 0.0):
@@ -450,7 +309,7 @@ class ComputationGraph(BaseModel):
                         (batch.features if isinstance(batch, MultiDataSet)
                          else [batch.features]))):
             return self._fit_batch_tbptt(batch, etl_ms=etl_ms)
-        return self._fit_batch_standard(batch, etl_ms=etl_ms)
+        return super()._fit_batch(batch, etl_ms=etl_ms)
 
     # ---- stateful rnn inference (reference: CG.rnnTimeStep:2720) --------
     def rnn_time_step(self, *features, mask=None):

@@ -28,13 +28,7 @@ from deeplearning4j_tpu.models.base import BaseModel, cast_params, compute_cast
 from deeplearning4j_tpu.nn.config import MultiLayerConfiguration
 from deeplearning4j_tpu.nn.inputs import RecurrentType
 from deeplearning4j_tpu.nn.layers.base import LayerContext
-from deeplearning4j_tpu.optimize.solver import (
-    TrainState,
-    make_constrain_fn,
-    build_optimizer,
-    make_scan_train_step,
-    make_train_step,
-)
+from deeplearning4j_tpu.optimize.solver import TrainState, build_optimizer
 
 
 
@@ -78,7 +72,6 @@ class MultiLayerNetwork(BaseModel):
         self._input_types = conf.layer_input_types()
         self._output_fn = None
         self._loss_eval_fn = None
-        self._tbptt_step = None
         # tensor-parallel activation specs (parallel/tensor_parallel.py);
         # set by ParallelWrapper when TP is enabled
         self._tp_plan = None
@@ -197,119 +190,21 @@ class MultiLayerNetwork(BaseModel):
     def _constraint_layers(self):
         return self.layers
 
-    def _build_train_step(self):
-        def loss_fn(params, model_state, features, labels, fmask, lmask, rng,
-                    iteration):
-            return self._loss(params, model_state, features, labels, fmask,
-                              lmask, rng, iteration)
-        return make_train_step(
-            loss_fn, self._tx,
-            constrain_fn=make_constrain_fn(
-                [l for l in self._constraint_layers()]),
-            telemetry=self._telemetry_spec())
-
-    def _build_scan_train_step(self):
-        """K fused optimizer steps per dispatch (fit(k_steps=K)); same
-        loss/constraint/telemetry spec as the per-batch step, scanned
-        over a leading K dim. No bf16 shadow here: the regularization
-        term reads master params, and the fed path promises a bitwise
-        match with the per-batch trajectory."""
-        def loss_fn(params, model_state, features, labels, fmask, lmask,
-                    rng, iteration):
-            return self._loss(params, model_state, features, labels, fmask,
-                              lmask, rng, iteration)
-        return make_scan_train_step(
-            loss_fn, self._tx,
-            constrain_fn=make_constrain_fn(
-                [l for l in self._constraint_layers()]),
-            telemetry=self._telemetry_spec())
-
-    # ---- truncated BPTT (reference: doTruncatedBPTT:1521, SURVEY §5.7) --
-    def _recurrent_carry_layers(self):
-        """(layer, is_lstm) for every layer whose hidden state crosses
-        TBPTT chunks — including cores wrapped in LastTimeStep /
-        MaskZeroLayer (the wrappers delegate state + initial_state)."""
-        from deeplearning4j_tpu.nn.layers.recurrent import (
-            LSTM, SimpleRnn, unwrap_recurrent)
-        out = []
-        for l in self.layers:
-            core = unwrap_recurrent(l)
-            if isinstance(core, (LSTM, SimpleRnn)):
-                out.append((l, core, isinstance(core, LSTM)))
-        return out
-
-    def _zero_carries(self, batch_size: int):
-        dt = (jnp.bfloat16 if self.conf.global_config.compute_dtype ==
-              "bfloat16" else jnp.float32)
-        out = {}
-        for layer, core, is_lstm in self._recurrent_carry_layers():
-            h = jnp.zeros((batch_size, core.n_out), dt)
-            out[layer.name] = (h, h) if is_lstm else h
-        return out
-
-    def _build_tbptt_step(self):
-        import optax
-        from deeplearning4j_tpu.optimize.solver import TrainState
-        constrain_fn = make_constrain_fn(list(self._constraint_layers()))
-        carry_layers = self._recurrent_carry_layers()
-        telemetry = self._telemetry_spec()
-
-        def step(ts, features, labels, fmask, lmask, rng, carries):
-            def lf(params):
-                return self._loss(params, ts.model_state, features, labels,
-                                  fmask, lmask, rng, ts.iteration,
-                                  carries=carries)
-            (loss, new_ms), grads = jax.value_and_grad(
-                lf, has_aux=True)(ts.params)
-            updates, new_opt = self._tx.update(grads, ts.opt_state, ts.params)
-            new_params = optax.apply_updates(ts.params, updates)
-            if constrain_fn is not None:
-                new_params = constrain_fn(new_params)
-            buf = ts.telemetry
-            if telemetry is not None:
-                buf = telemetry.record(buf, loss=loss, grads=grads,
-                                       params=new_params,
-                                       prev_params=ts.params,
-                                       iteration=ts.iteration)
-            # carries cross the chunk boundary with gradients cut — this IS
-            # the truncation (reference: tbpttBackLength; here back==fwd)
-            new_carries = {}
-            for layer, _core, is_lstm in carry_layers:
-                s = new_ms[layer.name]
-                c = ((s["last_h"], s["last_c"]) if is_lstm else s["last_h"])
-                new_carries[layer.name] = jax.lax.stop_gradient(c)
-            return (TrainState(new_params, new_ms, new_opt,
-                               ts.iteration + 1, buf), loss, new_carries)
-
-        return jax.jit(step, donate_argnums=(0,))
-
     def _fit_batch(self, batch, etl_ms: float = 0.0):
         conf = self.conf
-        feats = np.asarray(batch.features)  # host-sync-ok: eval host staging
-        if (conf.backprop_type != "tbptt" or feats.ndim != 3
-                or not self._recurrent_carry_layers()):
+        if (conf.backprop_type != "tbptt" or np.ndim(batch.features) != 3
+                or not self._recurrent_carry_nodes()):
             return super()._fit_batch(batch, etl_ms=etl_ms)
-        from deeplearning4j_tpu.nn.layers.recurrent import (
-            first_bidirectional_name, warn_tbptt_bidirectional)
-        bidi = first_bidirectional_name(
-            (l.name, l) for l in self.layers)
-        if bidi is not None:
-            warn_tbptt_bidirectional(bidi)
-        if self._tbptt_step is None:
-            self._tbptt_step = self._build_tbptt_step()
+        self._tbptt_ready()
         k = conf.tbptt_fwd_length
+        feats = np.asarray(batch.features)  # host-sync-ok: TBPTT slices the batch along time on the host
         T = feats.shape[1]
-        labels = np.asarray(batch.labels)  # host-sync-ok: eval host staging
+        labels = np.asarray(batch.labels)  # host-sync-ok: TBPTT slices the batch along time on the host
         seq_labels = labels.ndim == 3
         fmask = (None if batch.features_mask is None
-                 else np.asarray(batch.features_mask))  # host-sync-ok: eval host staging
+                 else np.asarray(batch.features_mask))  # host-sync-ok: TBPTT slices the batch along time on the host
         lmask = (None if batch.labels_mask is None
-                 else np.asarray(batch.labels_mask))  # host-sync-ok: eval host staging
-        from deeplearning4j_tpu.observe.tracer import get_tracer
-        tracer = get_tracer(self)
-        if self._telemetry is not None:
-            self.train_state = self._telemetry.ensure_buffer(
-                self.train_state)
+                 else np.asarray(batch.labels_mask))  # host-sync-ok: TBPTT slices the batch along time on the host
         carries = self._zero_carries(feats.shape[0])
         loss = None
         n_chunks = 0
@@ -328,22 +223,14 @@ class MultiLayerNetwork(BaseModel):
                 # doTruncatedBPTT processes it; costs one extra compiled
                 # shape because fm/lm go from None to arrays).
                 f, l, fm, lm = _pad_tbptt_tail(f, l, fm, lm, k, seq_labels)
-            self._rng, step_key = jax.random.split(self._rng)
             fm = None if fm is None else jnp.asarray(fm)
             lm = None if lm is None else jnp.asarray(lm)
             f, l = jnp.asarray(f), jnp.asarray(l)
-            if self.recompile_watchdog is not None:
-                self.recompile_watchdog.observe("tbptt_step", f, l, fm, lm)
-            with tracer.span("dispatch", cat="step",
-                             in_flight=self._in_flight(n_chunks)):
-                self.train_state, loss, carries = self._tbptt_step(
-                    self.train_state, f, l, fm, lm, step_key, carries)
+            self.train_state, loss, carries = self._send_step(
+                self._tbptt_step, "tbptt_step", (f, l, fm, lm),
+                after=(carries,), unnoted=n_chunks)
             n_chunks += 1
-        it = self._post_step(n_chunks, loss)
-        for lst in self.listeners:
-            lst.iteration_done(self, it, self.epoch_count, loss, etl_ms,
-                               batch.num_examples())
-        self._last_loss = loss
+        self._record_step(n_chunks, loss, etl_ms, batch.num_examples())
 
     # ---- inference ------------------------------------------------------
     def build_inference_fn(self):

@@ -552,10 +552,13 @@ class ParallelWrapper:
         self._arm_telemetry()
         try:
             if self.mode is TrainingMode.SHARED_GRADIENTS:
-                return self._fit_sync(iterator, epochs)
-            if self.mode is TrainingMode.ASYNC_ELASTIC:
-                return self._fit_async(iterator, epochs)
-            return self._fit_averaging(iterator, epochs)
+                self._fit_sync(iterator, epochs)
+            elif self.mode is TrainingMode.ASYNC_ELASTIC:
+                self._fit_async(iterator, epochs)
+            else:
+                self._fit_averaging(iterator, epochs)
+            m._end_fit_call()
+            return m
         except Exception as e:
             # a collective that RAISES on peer death (fail-fast
             # transports like gloo) goes through the watchdog's
@@ -785,9 +788,7 @@ class ParallelWrapper:
             self._step, self._batch_sh = self._build_sync_step()
         m = self.model
         feeder, source = self._make_feeder(iterator)
-        for epoch in range(epochs):
-            for lst in m.listeners:
-                lst.on_epoch_start(m, m.epoch_count)
+        for _ in m._epochs(epochs, source):
             if feeder is not None:
                 for item in feeder:
                     if item.k == 0:
@@ -795,102 +796,60 @@ class ParallelWrapper:
                         # legacy staging (raises where it always did)
                         self._fit_sync_one(item.raw, item.queue_wait_ms)
                     else:
-                        self._dispatch_sync(item)
+                        # the feeder already padded and placed the
+                        # per-replica shards
+                        self._send(item[:4], item.queue_wait_ms,
+                                   item.n_examples, seq=item.seq)
             else:
                 t0 = time.perf_counter()
                 for batch in iterator:
                     etl_ms = (time.perf_counter() - t0) * 1000
                     self._fit_sync_one(batch, etl_ms)
                     t0 = time.perf_counter()
-            source.reset()
             # an epoch's final batch is "final" — a legal uneven tail
             # must not trip the drift monitor on the next epoch
             self._pending_uneven_per = None
-            for lst in m.listeners:
-                lst.on_epoch_end(m, m.epoch_count)
-            m.epoch_count += 1
-        self._tail_flush()
-        return m
 
     def _fit_sync_one(self, batch, etl_ms: float):
         """Legacy (unfed) sync-mode body: stage this batch now, then
         dispatch — used when the feeder is shielded off, and for foreign
         passthrough objects."""
-        m = self.model
         n_real = batch.num_examples()
-        m._rng, key = jax.random.split(m._rng)
-        feats, labels, fmask, lmask = self._stage_batch(batch)
-        if m._telemetry is not None:
-            m.train_state = m._telemetry.ensure_buffer(m.train_state)
-        with get_tracer(m).span("dispatch", cat="step",
-                                in_flight=m._in_flight()):
-            m.train_state, loss = self._step(m.train_state, feats, labels,
-                                             fmask, lmask, key)
-        self._guarded_wait(loss)
-        it = m._post_step(1, loss)
-        for lst in m.listeners:
-            lst.iteration_done(m, it, m.epoch_count, loss, etl_ms, n_real)
-        m._last_loss = loss
+        self._send(self._stage_batch(batch), etl_ms, n_real)
 
-    def _dispatch_sync(self, item):
-        """Fed sync-mode body: the feeder already padded and placed the
-        per-replica shards; only the dispatch remains on this thread."""
+    def _send(self, arrays, wait_ms, n_real, seq=None, k=None):
+        """One SHARED_GRADIENTS step, or an AVERAGING round of ``k`` local
+        steps, through the model's dispatch protocol."""
         m = self.model
-        m._rng, key = jax.random.split(m._rng)
-        if m._telemetry is not None:
-            m.train_state = m._telemetry.ensure_buffer(m.train_state)
-        with get_tracer(m).span("dispatch", cat="step", seq=item.seq,
-                                in_flight=m._in_flight()):
-            m.train_state, loss = self._step(
-                m.train_state, item.features, item.labels,
-                item.features_mask, item.labels_mask, key)
-        self._guarded_wait(loss)
-        it = m._post_step(1, loss)
-        for lst in m.listeners:
-            lst.iteration_done(m, it, m.epoch_count, loss,
-                               item.queue_wait_ms, item.n_examples)
-        m._last_loss = loss
-
-    def _tail_flush(self):
-        """Drain rows still on device when the fit ends (mirrors
-        BaseModel's tail flush), then give the recorder a final look."""
-        m = self.model
-        if m._telemetry is not None:
-            with get_tracer(m).span("telemetry_flush", cat="telemetry"):
-                m._telemetry.flush(m.train_state)
-            rec = m._recorder()
-            if rec is not None:
-                rec.poll(m)
+        steps = 1 if k is None else k
+        m.train_state, loss = m._send_step(self._step, "parallel_step",
+                                           arrays, seq=seq, k=k)
+        self._guarded_wait(loss, steps)
+        m._record_step(steps, loss, wait_ms, n_real)
 
     def _fit_averaging(self, iterator, epochs):
         if self._step is None:
             self._step, _ = self._build_averaging_step()
-        return self._fit_rounds(iterator, epochs,
-                                self._dispatch_averaging,
-                                self._run_averaging_round)
+        self._fit_rounds(iterator, epochs, self._send)
 
     def _fit_async(self, iterator, epochs):
         if self._step is None:
             self._step, _ = self._build_async_step()
         if self._elastic is None:
             self._init_elastic_state()
-        return self._fit_rounds(iterator, epochs,
-                                self._dispatch_async,
-                                self._run_async_round)
+        self._fit_rounds(iterator, epochs, self._send_async)
 
-    def _fit_rounds(self, iterator, epochs, dispatch, run_round):
+    def _fit_rounds(self, iterator, epochs, send):
         """Shared round loop for the k-local-steps modes (AVERAGING and
-        ASYNC_ELASTIC): group k batches per round, fed or legacy."""
+        ASYNC_ELASTIC): group k batches per round, fed or legacy, and
+        ``send`` each round."""
         # (k, B, ...) rounds shard the batch dim over data; multi-host
         # staging assembles each process's slice (see _put_batch)
         self._avg_batch_sh = NamedSharding(self.mesh,
                                            P(None, DATA_AXIS))
-        m = self.model
         k = self.averaging_frequency
         feeder, source = self._make_feeder(iterator)
-        for epoch in range(epochs):
-            for lst in m.listeners:
-                lst.on_epoch_start(m, m.epoch_count)
+        for _ in self.model._epochs(epochs, source):
             if feeder is not None:
                 # the feeder groups k batches per round (short tails
                 # repeat the last batch — the old pending loop's
@@ -903,26 +862,32 @@ class ParallelWrapper:
                             f"ParallelWrapper {self.mode.name} consumes "
                             "DataSet batches, got "
                             f"{type(item.raw).__name__}")
-                    dispatch(item)
+                    send(item[:4], item.queue_wait_ms, item.n_examples,
+                         seq=item.seq, k=item.k)
             else:
                 pending = []
                 for batch in iterator:
                     pending.append(batch)
                     if len(pending) == k:
-                        run_round(pending)
+                        self._send_round(send, pending)
                         pending = []
                 if pending:
                     # pad the round reusing batches (keeps shapes static)
                     while len(pending) < k:
                         pending.append(pending[-1])
-                    run_round(pending)
-            source.reset()
+                    self._send_round(send, pending)
             self._pending_uneven_per = None     # legal uneven tail round
-            for lst in m.listeners:
-                lst.on_epoch_end(m, m.epoch_count)
-            m.epoch_count += 1
-        self._tail_flush()
-        return m
+
+    def _send_round(self, send, batches):
+        """Legacy (unfed) round: stack the batches and place them now."""
+        n_real = sum(b.num_examples() for b in batches)
+        # multi-host: each process holds its slice of the (k, B) global
+        # batch along the batch dim (dim 1)
+        arrays = tuple(
+            None if a is None else self._put_batch(
+                a, sharding=self._avg_batch_sh, batch_dim=1)
+            for a in self._avg_group_prepare(batches))
+        send(arrays, 0.0, n_real, k=len(batches))
 
     def _avg_group_prepare(self, batches):
         """Host-side staging of one averaging round: equalize example
@@ -955,48 +920,6 @@ class ParallelWrapper:
                 stack(lambda b: b.features_mask),
                 stack(lambda b: b.labels_mask))
 
-    def _dispatch_averaging(self, item):
-        """Fed averaging-round body: arrays arrive stacked and placed;
-        dispatch, then advance the host mirrors by the k local steps the
-        round ran."""
-        m = self.model
-        m._rng, key = jax.random.split(m._rng)
-        if m._telemetry is not None:
-            m.train_state = m._telemetry.ensure_buffer(m.train_state)
-        with get_tracer(m).span("dispatch", cat="step", k=item.k,
-                                seq=item.seq, in_flight=m._in_flight()):
-            m.train_state, loss = self._step(
-                m.train_state, item.features, item.labels,
-                item.features_mask, item.labels_mask, key)
-        self._guarded_wait(loss, item.k)
-        it = m._post_step(item.k, loss)
-        for lst in m.listeners:
-            lst.iteration_done(m, it, m.epoch_count, loss,
-                               item.queue_wait_ms, item.n_examples)
-        m._last_loss = loss
-
-    def _run_averaging_round(self, batches):
-        m = self.model
-        m._rng, key = jax.random.split(m._rng)
-        n_real = sum(b.num_examples() for b in batches)
-        arrays = self._avg_group_prepare(batches)
-        # multi-host: each process holds its slice of the (k, B) global
-        # batch along the batch dim (dim 1)
-        feats, labels, fmask, lmask = (
-            None if a is None else self._put_batch(
-                a, sharding=self._avg_batch_sh, batch_dim=1)
-            for a in arrays)
-        if m._telemetry is not None:
-            m.train_state = m._telemetry.ensure_buffer(m.train_state)
-        m.train_state, loss = self._step(m.train_state, feats, labels,
-                                         fmask, lmask, key)
-        self._guarded_wait(loss, len(batches))
-        # the round advanced the device iteration by k local steps
-        it = m._post_step(len(batches), loss)
-        for lst in m.listeners:
-            lst.iteration_done(m, it, m.epoch_count, loss, 0.0, n_real)
-        m._last_loss = loss
-
     # ---- ASYNC_ELASTIC --------------------------------------------------
     def _init_elastic_state(self):
         """Stack n copies of the server params/updater-state with a
@@ -1021,28 +944,11 @@ class ParallelWrapper:
             "hard_next": False,
         }
 
-    def _dispatch_async(self, item):
-        self._async_round_core(item.features, item.labels,
-                               item.features_mask, item.labels_mask,
-                               item.k, item.queue_wait_ms,
-                               item.n_examples)
-
-    def _run_async_round(self, batches):
-        n_real = sum(b.num_examples() for b in batches)
-        arrays = self._avg_group_prepare(batches)
-        feats, labels, fmask, lmask = (
-            None if a is None else self._put_batch(
-                a, sharding=self._avg_batch_sh, batch_dim=1)
-            for a in arrays)
-        self._async_round_core(feats, labels, fmask, lmask,
-                               len(batches), 0.0, n_real)
-
-    def _async_round_core(self, feats, labels, fmask, lmask,
-                          k_real, wait_ms, n_real):
-        """One bounded-staleness round: host computes this round's
-        membership (deterministic straggler policy) and staleness ages,
-        the device step does the weighted merge, then the divergence
-        guard decides whether the NEXT round is a hard sync."""
+    def _send_async(self, arrays, wait_ms, n_real, seq=None, k=1):
+        """One bounded-staleness round of ``k`` local steps: host computes
+        this round's membership (deterministic straggler policy) and
+        staleness ages, the device step does the weighted merge, then the
+        divergence guard decides whether the NEXT round is a hard sync."""
         m = self.model
         el = self._elastic
         opts = self.elastic_options
@@ -1062,16 +968,14 @@ class ParallelWrapper:
             present = np.ones(n, dtype=np.float32)
         ages = el["ages"]
 
-        m._rng, key = jax.random.split(m._rng)
-        if m._telemetry is not None:
-            m.train_state = m._telemetry.ensure_buffer(m.train_state)
         (m.train_state, el["local_params"], el["local_opt"],
-         el["base_params"], stats, loss) = self._step(
-            m.train_state, el["local_params"], el["local_opt"],
-            el["base_params"], feats, labels, fmask, lmask, key,
-            jnp.asarray(present), jnp.asarray(ages),
-            jnp.float32(1.0 if hard else 0.0))
-        self._guarded_wait(loss, k_real)
+         el["base_params"], stats, loss) = m._send_step(
+            self._step, "parallel_step", arrays,
+            lead=(el["local_params"], el["local_opt"], el["base_params"]),
+            after=(jnp.asarray(present), jnp.asarray(ages),
+                   jnp.float32(1.0 if hard else 0.0)),
+            seq=seq, k=k)
+        self._guarded_wait(loss, k)
 
         # ---- host bookkeeping: ages, counters, divergence guard -------
         age1 = ages + 1.0
@@ -1086,7 +990,7 @@ class ParallelWrapper:
 
         # ONE small fetch per round (k steps amortize it) — the
         # divergence guard needs the per-worker grad norms on host
-        with m._blocked("elastic_stats", k_real):
+        with m._blocked("elastic_stats", k):
             arr = np.asarray(stats)  # host-sync-ok: per-round (k steps) fetch of the (n,2) stats row for the divergence guard
         gnorms = arr[:, 1]
         finite = gnorms[np.isfinite(gnorms)]
@@ -1102,11 +1006,7 @@ class ParallelWrapper:
                               discarded_stale, float(el["ages"].max()),  # host-sync-ok: host np bookkeeping
                               div, hard)
 
-        it = m._post_step(k_real, loss)
-        for lst in m.listeners:
-            lst.iteration_done(m, it, m.epoch_count, loss, wait_ms,
-                               n_real)
-        m._last_loss = loss
+        m._record_step(k, loss, wait_ms, n_real)
 
     def _publish_elastic(self, members, dropped, merged_stale,
                          discarded_stale, max_age, div, was_hard):
