@@ -220,19 +220,22 @@ class BaseModel:
 
     def _publish_routing_gauges(self):
         """Expert layers leave their step's routing counters in the model
-        state (``moe_routing``); publish the last step's as gauges. One
-        small fetch, at a telemetry flush and at the end of a ``fit()``
+        state (``moe_routing``), and a head with more than one loss term
+        its terms (``lm_loss_terms``); publish the last step's as gauges.
+        One small fetch, at a telemetry flush and at the end of a ``fit()``
         call, never between steps; it waits for every step in flight
         (span ``blocked``, ``on="routing"``)."""
         state = self.train_state.model_state
-        rows = {name: s["moe_routing"] for name, s in state.items()
-                if isinstance(s, dict) and "moe_routing" in s}
-        if rows:
+        rows = {key: {name: s[key] for name, s in state.items()
+                      if isinstance(s, dict) and key in s}
+                for key in ("moe_routing", "lm_loss_terms")}
+        if any(rows.values()):
             from deeplearning4j_tpu.observe.telemetry import (
-                publish_routing)
+                publish_loss_terms, publish_routing)
             with self._blocked("routing"):
                 rows = jax.device_get(rows)  # host-sync-ok: once per fit() call / telemetry flush
-            publish_routing(rows)
+            publish_routing(rows["moe_routing"])
+            publish_loss_terms(rows["lm_loss_terms"])
 
     def _enter_fit_call(self):
         """Start of a ``fit()`` / ``ParallelWrapper.fit()`` call: the

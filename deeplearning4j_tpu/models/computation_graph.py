@@ -168,6 +168,8 @@ class ComputationGraph(BaseModel):
                     ctx = dataclasses.replace(ctx, mask=lmask)
                 loss = node.layer.compute_loss(
                     lp, model_state.get(out_name, {}), x, label, ctx)
+                if isinstance(loss, tuple):     # a head that keeps state
+                    loss, new_state[out_name] = loss
             else:
                 raise TypeError(f"output node '{out_name}' is not a loss-"
                                 "bearing layer")

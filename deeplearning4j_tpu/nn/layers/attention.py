@@ -412,6 +412,113 @@ class GatedAttention(FeedForwardLayer):
         return y, state
 
 
+@register_serializable
+@dataclasses.dataclass(frozen=True)
+class LatentAttention(FeedForwardLayer):
+    """Causal multi-head latent attention (MLA; DeepSeek-V2,
+    arXiv:2405.04434, section 2.1), bias-free, rotary on a part of the
+    head. With ``dn`` = ``qk_nope_head_dim``, ``dr`` = ``qk_rope_head_dim``
+    and ``dv`` = ``v_head_dim``, at positions ``0 .. T - 1``:
+
+        c_q = RMSNorm_q(x W_qa)                        (q_lora_rank)
+        [q_nope | q_rope] = c_q W_qb                   (each head dn + dr)
+        [c_kv | k_r] = x W_kva                         (kv_lora_rank + dr)
+        c_kv <- RMSNorm_kv(c_kv)
+        [k_nope | v] = c_kv W_kvb                      (each head dn + dv)
+        q = [q_nope | RoPE(q_rope)],  k = [k_nope | RoPE(k_r)]
+        o = softmax(q k^T / sqrt(dn + dr), causal) v
+        y = concat_h(o) W_o
+
+    The one rotary key ``k_r`` of ``dr`` is computed once a token and
+    shared by every head. Rotary is rotate-half (``rotary_embedding``) on
+    the ``dr`` dimensions at ``rope_theta``; both latent norms are
+    zero-centred RMSNorms at ``eps``. ``W_qb`` holds each head's ``[nope |
+    rope]`` side by side and ``W_kvb`` each head's ``[k_nope | v]``.
+
+    Attention itself goes through ``ops.pallas_kernels.attention`` (the
+    flash kernel from 1,024 positions on a TPU, plain XLA else) with
+    queries and keys of ``dn + dr`` and values of ``dv``, and everything
+    the layer computes runs under the named scope ``attn.latent``.
+    Training only: no cache of the latents. ``n_out`` is the model
+    width."""
+    n_heads: int = 20
+    q_lora_rank: int = 768
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 192
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 256
+    rope_theta: float = 1e6
+    eps: float = 1e-5
+    init_std: float = 0.02
+
+    named_scopes = ("attn.latent",)
+
+    def __post_init__(self):
+        if min(self.q_lora_rank, self.kv_lora_rank, self.v_head_dim,
+               self.qk_nope_head_dim + self.qk_rope_head_dim) < 1 \
+                or self.qk_rope_head_dim % 2:
+            raise ValueError(
+                "LatentAttention needs latents and heads of one dimension "
+                f"or more and an even rotary part; got q_lora_rank="
+                f"{self.q_lora_rank}, kv_lora_rank={self.kv_lora_rank}, "
+                f"qk_rope_head_dim={self.qk_rope_head_dim}")
+
+    def output_type(self, input_type: InputType) -> InputType:
+        t = (input_type.timesteps
+             if isinstance(input_type, RecurrentType) else None)
+        return RecurrentType(self.n_out, t)
+
+    def initialize(self, key, input_type):
+        n_in = self.resolved_n_in(input_type)
+        h, dn, dr, dv = (self.n_heads, self.qk_nope_head_dim,
+                         self.qk_rope_head_dim, self.v_head_dim)
+        dt = self.param_dtype()
+        ks = jax.random.split(key, 5)
+
+        def normal(k, shape):
+            return self.init_std * jax.random.normal(k, shape, dt)
+
+        return {
+            "W_qa": normal(ks[0], (n_in, self.q_lora_rank)),
+            "q_norm": jnp.zeros((self.q_lora_rank,), dt),
+            "W_qb": normal(ks[1], (self.q_lora_rank, h * (dn + dr))),
+            # columns [c_kv | k_r]
+            "W_kva": normal(ks[2], (n_in, self.kv_lora_rank + dr)),
+            "kv_norm": jnp.zeros((self.kv_lora_rank,), dt),
+            "W_kvb": normal(ks[3], (self.kv_lora_rank, h * (dn + dv))),
+            "W_o": normal(ks[4], (h * dv, self.n_out)),
+        }
+
+    def apply(self, params, state, x, ctx: LayerContext):
+        from deeplearning4j_tpu.nn.layers.normalization import rms_norm
+        from deeplearning4j_tpu.ops.pallas_kernels import attention as _attn
+        n, t, _ = x.shape
+        h, dn, dr, dv = (self.n_heads, self.qk_nope_head_dim,
+                         self.qk_rope_head_dim, self.v_head_dim)
+        r = self.kv_lora_rank
+        pos = jnp.arange(t)
+        with jax.named_scope("attn.latent"):
+            c_q = rms_norm(jnp.einsum("ntf,fe->nte", x, params["W_qa"]),
+                           params["q_norm"], self.eps)
+            q = jnp.einsum("ntc,ce->nte", c_q, params["W_qb"]).reshape(
+                n, t, h, dn + dr)
+            kv = jnp.einsum("ntf,fe->nte", x, params["W_kva"])
+            c_kv = rms_norm(kv[..., :r], params["kv_norm"], self.eps)
+            k_r = rotary_embedding(kv[..., r:].reshape(n, t, 1, dr), pos, dr,
+                                   self.rope_theta)
+            kvb = jnp.einsum("ntc,ce->nte", c_kv, params["W_kvb"]).reshape(
+                n, t, h, dn + dv)
+            q = jnp.concatenate([q[..., :dn], rotary_embedding(
+                q[..., dn:], pos, dr, self.rope_theta)], -1)
+            k = jnp.concatenate(
+                [kvb[..., :dn], jnp.broadcast_to(k_r, (n, t, h, dr))], -1)
+            o = _attn(q, k, kvb[..., dn:], mask=ctx.mask, visibility=Causal(),
+                      scope="attn.latent")
+            y = jnp.einsum("nte,eo->nto", o.reshape(n, t, h * dv),
+                           params["W_o"])
+        return y, state
+
+
 def differential_lambda_init(layer_index: int) -> float:
     """``0.8 - 0.6 exp(-0.3 l)`` for the layer of depth ``l`` in the whole
     model (arXiv:2410.05258, section 3.1)."""

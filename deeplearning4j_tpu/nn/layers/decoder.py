@@ -59,6 +59,20 @@ feed-forward branch a dense gated MLP (``gated_mlp``, the leading layers)
 or ``HeldExpertsMoE`` (gated experts under a sigmoid router, a shared
 expert added ungated). It shares ``_ResidualBlock`` with the other two.
 
+``LatentDecoderBlock`` is the unit of the GLM-4.7-Flash family
+(``glm4_moe_lite``, DeepSeek-V3's layout): pre-norm residuals round
+multi-head latent attention and a feed-forward branch,
+
+    h = x + MLA(RMSNorm(x)),   y = h + FFN(RMSNorm(h))
+
+with the same two feed-forward branches as ``SandwichDecoderBlock``. Its
+subclass ``MultiTokenPredictionBlock`` is the family's
+multi-token-prediction module: one more such block on the main model's
+normed final state and the next token's embedding, whose output the
+main model's own head (``MultiTokenLMOutputLayer``) scores against the
+token after that. A model is a ``ComputationGraph``: the embedding's
+output reaches the module, and the module's output the head, as edges.
+
 ``recompute`` wraps a block's ``apply`` in ``jax.checkpoint`` while
 training, so the step ``fit()`` builds keeps each block's input for the
 backward pass and recomputes the block's internals there, but for the
@@ -85,6 +99,7 @@ from deeplearning4j_tpu.nn.inputs import InputType, RecurrentType
 from deeplearning4j_tpu.nn.layers.attention import (
     DifferentialAttention,
     GatedAttention,
+    LatentAttention,
 )
 from deeplearning4j_tpu.nn.layers.base import (
     FeedForwardLayer,
@@ -209,6 +224,21 @@ def gated_mlp(params, h):
 
 _MOE_SCOPES = ("moe.route", "moe.dispatch", "moe.experts", "moe.shared",
                "moe.combine")
+
+
+def _sigmoid_experts(block, **common) -> HeldExpertsMoE:
+    """The expert layer of ``SandwichDecoderBlock`` and
+    ``LatentDecoderBlock``, from the block's fields: gated experts under a
+    sigmoid router whose bias the load moves by ``bias_update_rate`` a
+    step, the chosen scores renormalised and times ``routed_scale``, the
+    shared expert added ungated."""
+    return HeldExpertsMoE(
+        num_experts=block.num_experts, held_experts=block.held_experts,
+        hidden=block.expert_hidden, shared_hidden=block.shared_hidden,
+        top_k=block.top_k, aux_loss_coef=block.router_aux_loss_coef,
+        expert_form="gated", router_scoring="sigmoid",
+        routed_scale=block.routed_scale, shared_gate=False,
+        bias_update_rate=block.bias_update_rate, **common)
 
 
 class _ResidualBlock:
@@ -504,14 +534,7 @@ class SandwichDecoderBlock(_ResidualBlock, FeedForwardLayer):
             rope_theta=self.rope_theta, window=self.window, **common)
         if self.ffn == DENSE:
             return mixer, None
-        return mixer, HeldExpertsMoE(
-            num_experts=self.num_experts, held_experts=self.held_experts,
-            hidden=self.expert_hidden, shared_hidden=self.shared_hidden,
-            top_k=self.top_k, aux_loss_coef=self.router_aux_loss_coef,
-            expert_form="gated", router_scoring="sigmoid",
-            routed_scale=self.routed_scale,
-            shared_gate=False, bias_update_rate=self.bias_update_rate,
-            **common)
+        return mixer, _sigmoid_experts(self, **common)
 
     def _expert_layer(self):
         return self._parts()[1]
@@ -548,6 +571,158 @@ class SandwichDecoderBlock(_ResidualBlock, FeedForwardLayer):
         else:
             f, state = moe.apply(params["moe"], state, u, ctx)
         return x + norm(4, f), state
+
+
+@register_serializable
+@dataclasses.dataclass(frozen=True)
+class LatentDecoderBlock(_ResidualBlock, FeedForwardLayer):
+    """One layer of the GLM-4.7-Flash family (module docstring): ``h = x +
+    MLA(N1(x))``, ``y = h + FFN(N2(h))``. The attention is
+    ``LatentAttention`` (its fields, kept flat here); ``ffn`` picks the
+    feed-forward branch as ``SandwichDecoderBlock``'s does, ``dense``
+    (``gated_mlp`` of ``mlp_hidden``) or ``experts`` (gated experts under a
+    sigmoid router, the shared expert added ungated). The two norms run
+    under the named scope ``block.norm``. ``n_out`` is the model width and
+    equals the input's; an ``experts`` block's state is its expert
+    layer's, a ``dense`` one has none.
+
+    ``recompute``: as ``HybridDecoderBlock``'s; what the block keeps a row
+    beside its input is the flash kernel's ``T x heads x v_head_dim`` at
+    the compute type and ``T x heads`` float32."""
+    ffn: str = DENSE
+    # latent attention
+    n_heads: int = 20
+    q_lora_rank: int = 768
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 192
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 256
+    rope_theta: float = 1e6
+    # dense gated MLP
+    mlp_hidden: int = 0
+    # experts
+    num_experts: int = 8
+    held_experts: Tuple[int, ...] = ()
+    expert_hidden: int = 0
+    shared_hidden: int = 0
+    top_k: int = 2
+    routed_scale: float = 1.0
+    bias_update_rate: float = 0.0
+    router_aux_loss_coef: float = 0.0
+    eps: float = 1e-5
+    init_std: float = 0.02
+    recompute: bool = False
+
+    named_scopes = (LatentAttention.named_scopes + _MOE_SCOPES
+                    + ("mlp.glu", "block.norm"))
+
+    def __post_init__(self):
+        if self.ffn not in (DENSE, EXPERTS):
+            raise ValueError(f"ffn={self.ffn!r}: {DENSE!r} or {EXPERTS!r}")
+
+    def _parts(self):
+        common = dict(n_in=self.n_out, n_out=self.n_out, dtype=self.dtype,
+                      init_std=self.init_std)
+        mixer = LatentAttention(
+            n_heads=self.n_heads, q_lora_rank=self.q_lora_rank,
+            kv_lora_rank=self.kv_lora_rank,
+            qk_nope_head_dim=self.qk_nope_head_dim,
+            qk_rope_head_dim=self.qk_rope_head_dim,
+            v_head_dim=self.v_head_dim, rope_theta=self.rope_theta,
+            eps=self.eps, **common)
+        if self.ffn == DENSE:
+            return mixer, None
+        return mixer, _sigmoid_experts(self, **common)
+
+    def _expert_layer(self):
+        return self._parts()[1]
+
+    def _norm(self, params, name, a):
+        with jax.named_scope("block.norm"):
+            return rms_norm(a, params[name]["w"], self.eps)
+
+    def _block_params(self, key, width):
+        mixer, moe = self._parts()
+        km, k1, k2 = jax.random.split(key, 3)
+        rt = RecurrentType(width, None)
+        norm = RMSNorm(eps=self.eps, dtype=self.dtype)
+        params = {"norm1": norm.initialize(None, rt),
+                  "mixer": mixer.initialize(km, rt),
+                  "norm2": norm.initialize(None, rt)}
+        if moe is None:
+            params["mlp"] = gated_mlp_params(k1, k2, width, self.mlp_hidden,
+                                             self.init_std,
+                                             self.param_dtype())
+        else:
+            params["moe"] = moe.initialize(k1, rt)
+        return params
+
+    def initialize(self, key, input_type):
+        return self._block_params(key, self._check_width(input_type))
+
+    def _block(self, params, state, x, ctx: LayerContext):
+        mixer, moe = self._parts()
+        a, _ = mixer.apply(params["mixer"], {},
+                           self._norm(params, "norm1", x), ctx)
+        x = x + a
+        u = self._norm(params, "norm2", x)
+        if moe is None:
+            f = gated_mlp(params["mlp"], u)
+        else:
+            f, state = moe.apply(params["moe"], state, u, ctx)
+        return x + f, state
+
+    def _apply(self, params, state, x, ctx: LayerContext):
+        return self._block(params, state, x, ctx)
+
+
+@register_serializable
+@dataclasses.dataclass(frozen=True)
+class MultiTokenPredictionBlock(LatentDecoderBlock):
+    """The multi-token-prediction module of the GLM-4.7-Flash family
+    (``num_nextn_predict_layers`` 1; DeepSeek-V3, arXiv:2412.19437 section
+    2.2). From the main model's final state after its final norm, ``h``
+    (N, T, n_out), and the extra input ``embedded``, the embedding layer's
+    output for the same tokens:
+
+        e'_i = Emb(t_{i+1})        (``embedded`` shifted left by one,
+                                    zeros in the last slot)
+        u = [RMSNorm_e(e') ; RMSNorm_h(h)] W_eh        (W_eh: 2 n_out x n_out)
+        out = RMSNorm(Block(u))
+
+    ``Block`` is the ``LatentDecoderBlock`` of this class's fields; ``out``
+    goes to the main model's head (``MultiTokenLMOutputLayer``'s input
+    ``mtp``), which scores position i against ``t_{i+2}``. Everything runs
+    under the named scope ``mtp``. State and ``recompute``: the block's."""
+
+    named_scopes = LatentDecoderBlock.named_scopes + ("mtp",)
+
+    @property
+    def extra_inputs(self):
+        return ("embedded",)
+
+    def initialize(self, key, input_type):
+        width = self._check_width(input_type)
+        kb, ke = jax.random.split(key)
+        rt = RecurrentType(width, None)
+        norm = RMSNorm(eps=self.eps, dtype=self.dtype)
+        return {"enorm": norm.initialize(None, rt),
+                "hnorm": norm.initialize(None, rt),
+                "W_eh": self.init_std * jax.random.normal(
+                    ke, (2 * width, width), self.param_dtype()),
+                **self._block_params(kb, width),
+                "head_norm": norm.initialize(None, rt)}
+
+    def _apply(self, params, state, x, ctx: LayerContext):
+        h, embedded = x
+        with jax.named_scope("mtp"):
+            later = jnp.concatenate(
+                [embedded[:, 1:], jnp.zeros_like(embedded[:, :1])], 1)
+            u = jnp.concatenate([self._norm(params, "enorm", later),
+                                 self._norm(params, "hnorm", h)], -1)
+            u = jnp.einsum("ntf,fe->nte", u, params["W_eh"])
+            g, state = self._block(params, state, u, ctx)
+            return self._norm(params, "head_norm", g), state
 
 
 @register_serializable
@@ -757,16 +932,8 @@ class CausalLMOutputLayer(FeedForwardLayer):
         if labels.ndim == 3 and labels.shape[-1] == 2:
             return self._diffusion_loss(params, x, labels, ctx)
         with jax.named_scope("lm.head_loss"):
-            logits = self._logits(params, x)
-            labels = labels.astype(jnp.int32)
-            if labels.ndim == 3 and labels.shape[-1] == 1:
-                labels = labels[..., 0]
-            weight = (labels >= 0).astype(logits.dtype)
-            if ctx.mask is not None:
-                m = ctx.mask.astype(logits.dtype)
-                weight = weight * (m[:, None] if m.ndim == 1 else m)
-            per = _token_losses(logits, labels)
-            return jnp.sum(per * weight) / jnp.maximum(jnp.sum(weight), 1.0)
+            return _next_token_loss(self._logits(params, x),
+                                    _id_labels(labels), ctx.mask)
 
     def _diffusion_loss(self, params, x, labels, ctx):
         """The masked-diffusion loss of the class docstring."""
@@ -788,6 +955,98 @@ class CausalLMOutputLayer(FeedForwardLayer):
             per = _token_losses(logits, ids)
             return jnp.sum(per * weight * kept) / jnp.maximum(
                 jnp.sum(kept), 1.0)
+
+
+@register_serializable
+@dataclasses.dataclass(frozen=True)
+class MultiTokenLMOutputLayer(FeedForwardLayer):
+    """The head shared by the next-token loss and a multi-token-prediction
+    module's (``MultiTokenPredictionBlock``), as a ``ComputationGraph``'s
+    output: the input ``h`` and the further input ``mtp`` (both (N, T,
+    n_in), normed already by the layers that give them) go through the
+    one matrix ``W``,
+
+        L = CE(h W, labels) + mtp_weight CE(g W, labels')
+
+    with next-token ``labels`` (``CausalLMOutputLayer``'s, mask and all)
+    and ``labels'[:, t] = labels[:, t + 1]``: the token after the next,
+    the last two positions counting for nothing. The embedding and ``W``
+    take gradients from both terms. ``apply`` returns the next-token
+    logits alone (N, T, n_out), float32.
+
+    ``compute_loss`` returns the loss and the layer's new state, whose
+    ``lm_loss_terms`` (float32[2], no gradient) holds the step's two
+    means, next-token then multi-token, before the weight: what the fit
+    loop publishes beside the routing counters. The second term's head and
+    loss run under the named scope ``mtp``."""
+    init_std: float = 0.02
+    has_bias: bool = False
+    mtp_weight: float = 0.3
+
+    named_scopes = ("lm.head_loss", "mtp")
+
+    def output_type(self, input_type: InputType) -> InputType:
+        t = (input_type.timesteps
+             if isinstance(input_type, RecurrentType) else None)
+        return RecurrentType(self.n_out, t)
+
+    @property
+    def extra_inputs(self):
+        return ("mtp",)
+
+    def initialize(self, key, input_type):
+        return {"W": self.init_std * jax.random.normal(
+            key, (self.resolved_n_in(input_type), self.n_out),
+            self.param_dtype())}
+
+    def init_state(self, input_type):
+        return {"lm_loss_terms": jnp.zeros((2,), jnp.float32)}
+
+    def _logits(self, params, h):
+        return jnp.einsum("nth,hv->ntv", h, params["W"].astype(h.dtype),
+                          preferred_element_type=jnp.promote_types(
+                              jnp.float32, h.dtype))
+
+    def _loss(self, params, h, labels, mask):
+        with jax.named_scope("lm.head_loss"):
+            return _next_token_loss(self._logits(params, h), labels, mask)
+
+    def apply(self, params, state, x, ctx):
+        return self._logits(params, x[0]), state
+
+    def compute_loss(self, params, state, x, labels, ctx):
+        h, g = x
+        labels = _id_labels(labels)
+        if labels.ndim != 2:
+            raise ValueError("a multi-token head takes next-token labels "
+                             f"(N, T); got {labels.shape}")
+        later = jnp.concatenate(
+            [labels[:, 1:], jnp.full_like(labels[:, :1], IGNORE_LABEL)], 1)
+        main = self._loss(params, h, labels, ctx.mask)
+        with jax.named_scope("mtp"):
+            mtp = self._loss(params, g, later, ctx.mask)
+        terms = jax.lax.stop_gradient(
+            jnp.stack([main, mtp]).astype(jnp.float32))
+        return main + self.mtp_weight * mtp, {"lm_loss_terms": terms}
+
+
+def _id_labels(labels):
+    """Next-token labels (N, T) or (N, T, 1) as int32 (N, T)."""
+    labels = labels.astype(jnp.int32)
+    return labels[..., 0] if labels.ndim == 3 and labels.shape[-1] == 1 \
+        else labels
+
+
+def _next_token_loss(logits, labels, mask):
+    """The mean next-token cross-entropy of ``CausalLMOutputLayer``'s
+    docstring: ``labels`` int32 (N, T), those below zero left out, a mask
+    (N,) or (N, T) weighting rows or positions."""
+    weight = (labels >= 0).astype(logits.dtype)
+    if mask is not None:
+        m = mask.astype(logits.dtype)
+        weight = weight * (m[:, None] if m.ndim == 1 else m)
+    per = _token_losses(logits, labels)
+    return jnp.sum(per * weight) / jnp.maximum(jnp.sum(weight), 1.0)
 
 
 def _token_losses(logits, ids):

@@ -648,6 +648,26 @@ ROUTING_GAUGES = (
 )
 
 
+LOSS_TERM_GAUGE = (
+    "dl4j_loss_term",
+    "one term of the last step's training loss, before its weight, as the "
+    "head that computes it leaves it in its state (labels: layer, term)")
+LOSS_TERMS = ("next_token", "mtp")
+
+
+def publish_loss_terms(rows: Dict[str, Any],
+                       registry: Optional[MetricsRegistry] = None) -> None:
+    """``rows``: layer name -> the ``lm_loss_terms`` a head with a
+    multi-token-prediction term leaves in its state (next-token mean, then
+    the multi-token mean), on the host; a gauge each, labelled by layer
+    and ``term``."""
+    reg = registry if registry is not None else default_registry()
+    gauge = reg.gauge(*LOSS_TERM_GAUGE)
+    for layer, row in rows.items():
+        for term, value in zip(LOSS_TERMS, np.asarray(row)):
+            gauge.set(float(value), layer=layer, term=term)
+
+
 def publish_routing(rows: Dict[str, Any],
                     registry: Optional[MetricsRegistry] = None) -> None:
     """``rows``: layer name -> the row a ``HeldExpertsMoE`` leaves in its

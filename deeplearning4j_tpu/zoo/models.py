@@ -851,6 +851,131 @@ class AFMoE(ZooModel):
 
 
 @dataclasses.dataclass
+class GLM4MoeLite(ZooModel):
+    """Mixture-of-experts causal language model of the GLM-4.7-Flash family
+    (published config: huggingface.co/zai-org/GLM-4.7-Flash,
+    ``config.json``, ``model_type: glm4_moe_lite``; the field names below
+    are its keys). Every layer is ``h = x + MLA(RMSNorm(x))``, ``y = h +
+    FFN(RMSNorm(h))`` (``nn.layers.decoder.LatentDecoderBlock``): multi-head
+    latent attention with a normed query latent of ``q_lora_rank``, a
+    normed key/value latent of ``kv_lora_rank`` and one rotary key of
+    ``qk_rope_head_dim`` shared by the ``num_attention_heads`` heads (query
+    and key ``qk_nope_head_dim + qk_rope_head_dim``, value ``v_head_dim``);
+    the feed-forward branch is a dense SwiGLU MLP of ``intermediate_size``
+    for the first ``first_k_dense_replace`` layers and after them gated
+    experts of ``moe_intermediate_size`` under a sigmoid router whose
+    choice adds a bias that the load moves (``bias_update_rate`` a step;
+    ``topk_method`` noaux_tc, one group), the ``num_experts_per_tok``
+    chosen scores renormalised (``norm_topk_prob``) and times
+    ``routed_scaling_factor``, with ``n_shared_experts`` shared experts of
+    the same width added ungated. RMSNorm (zero-centred), an untied head.
+
+    The model also trains the family's multi-token-prediction module
+    (``num_nextn_predict_layers`` 1, the release's and the one value
+    built; ``MultiTokenPredictionBlock``: one more expert layer on the
+    normed final state and the next token's embedding) through the same
+    head on the token after the next, its cross-entropy weighted
+    ``mtp_loss_weight`` in the loss. The model is a
+    ``ComputationGraph``: the final norm is a layer of its own (``norm``)
+    that the head and the module both read, the embedding's output
+    reaches the module (``embed``) and the module's the head (``mtp``).
+
+    ``held_experts`` (default: all) and a sliced ``vocab_size`` are an
+    expert-parallel deployment's share, as ``Qwen3Next``'s.
+    ``router_aux_loss_coef`` above 0 adds that many times each expert
+    layer's balance loss on the normalised sigmoid scores to the training
+    loss. Features are integer token ids (N, seq_len), labels
+    ``nn.layers.decoder.next_token_labels(ids)``. Not in the model:
+    dropout, generation (the latent cache, the module as a draft)."""
+    vocab_size: int = 154880
+    hidden_size: int = 2048
+    intermediate_size: int = 10240
+    moe_intermediate_size: int = 1536
+    num_hidden_layers: int = 47
+    num_attention_heads: int = 20
+    q_lora_rank: int = 768
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 192
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 256
+    rope_theta: float = 1e6
+    first_k_dense_replace: int = 1
+    n_routed_experts: int = 64
+    held_experts: Tuple[int, ...] = ()
+    num_experts_per_tok: int = 4
+    routed_scaling_factor: float = 1.8
+    n_shared_experts: int = 1
+    num_nextn_predict_layers: int = 1
+    mtp_loss_weight: float = 0.3
+    bias_update_rate: float = 1e-3
+    router_aux_loss_coef: float = 0.0
+    rms_norm_eps: float = 1e-5
+    initializer_range: float = 0.02
+    seq_len: int = 8192
+    recompute: bool = True
+    compute_dtype: str = "bfloat16"
+    updater: Optional[Updater] = None
+    seed: int = 123
+
+    def _block(self, kind, ffn: str):
+        return kind(
+            n_out=self.hidden_size, ffn=ffn,
+            n_heads=self.num_attention_heads, q_lora_rank=self.q_lora_rank,
+            kv_lora_rank=self.kv_lora_rank,
+            qk_nope_head_dim=self.qk_nope_head_dim,
+            qk_rope_head_dim=self.qk_rope_head_dim,
+            v_head_dim=self.v_head_dim, rope_theta=self.rope_theta,
+            mlp_hidden=self.intermediate_size,
+            num_experts=self.n_routed_experts,
+            held_experts=tuple(self.held_experts),
+            expert_hidden=self.moe_intermediate_size,
+            shared_hidden=self.n_shared_experts * self.moe_intermediate_size,
+            top_k=self.num_experts_per_tok,
+            routed_scale=self.routed_scaling_factor,
+            bias_update_rate=self.bias_update_rate,
+            router_aux_loss_coef=self.router_aux_loss_coef,
+            eps=self.rms_norm_eps, init_std=self.initializer_range,
+            recompute=self.recompute)
+
+    def conf(self):
+        from deeplearning4j_tpu.nn.layers.decoder import (
+            DENSE, EXPERTS, LatentDecoderBlock, MultiTokenLMOutputLayer, MultiTokenPredictionBlock,
+            TokenEmbedding)
+        from deeplearning4j_tpu.nn.layers.normalization import RMSNorm
+        if self.num_nextn_predict_layers != 1:
+            raise ValueError(
+                f"num_nextn_predict_layers={self.num_nextn_predict_layers}: "
+                "the release's 1 is the one built")
+        std = self.initializer_range
+        g = (NeuralNetConfiguration.Builder()
+             .seed(self.seed)
+             .updater(self.updater or Adam(1e-4))
+             .compute_dtype(self.compute_dtype)
+             .graph_builder()
+             .add_inputs("ids")
+             .set_input_types(InputType.recurrent(1, self.seq_len)))
+        g.add_layer("embed", TokenEmbedding(
+            vocab_size=self.vocab_size, n_out=self.hidden_size,
+            init_std=std), "ids")
+        last = "embed"
+        for l in range(self.num_hidden_layers):
+            ffn = DENSE if l < self.first_k_dense_replace else EXPERTS
+            g.add_layer(f"layer{l}", self._block(LatentDecoderBlock, ffn),
+                        last)
+            last = f"layer{l}"
+        g.add_layer("norm", RMSNorm(eps=self.rms_norm_eps), last)
+        g.add_layer("mtp", self._block(MultiTokenPredictionBlock, EXPERTS),
+                    "norm", "embed")
+        g.add_layer("lm_head", MultiTokenLMOutputLayer(
+            n_out=self.vocab_size, init_std=std,
+            mtp_weight=self.mtp_loss_weight), "norm", "mtp")
+        return g.set_outputs("lm_head").build()
+
+    def init(self) -> ComputationGraph:
+        return ComputationGraph(self.conf()).init()
+
+
+@dataclasses.dataclass
 class Phi4MiniFlash(ZooModel):
     """Decoder-hybrid-decoder causal language model of the Phi-4-mini-flash
     family (published config: huggingface.co/microsoft/

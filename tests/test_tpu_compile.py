@@ -190,6 +190,43 @@ def test_windowed_gated_attention_kernels_compile_at_the_published_widths(
     assert compiled.as_text().count("tpu_custom_call") == 2
 
 
+def test_latent_attention_compiles_at_the_published_widths(
+        one_chip, no_compile_cache, monkeypatch):
+    """``LatentAttention`` as GLM-4.7-Flash's layers call it, forward and
+    backward, traced for a TPU (the test stands in for
+    ``jax.default_backend``): 20 heads whose queries and keys are 192 + 64
+    (the one rotary key broadcast to every head) and values 256, latents
+    of 768 and 512, 8,192 positions, bfloat16. The flash kernels take the
+    head of 256 on 512 x 1024 tiles and launch twice under the layer's
+    scope (the forward kernel, and one backward kernel for dQ, dK and
+    dV)."""
+    from deeplearning4j_tpu.nn.inputs import RecurrentType
+    from deeplearning4j_tpu.nn.layers.attention import LatentAttention
+    from deeplearning4j_tpu.nn.layers.base import LayerContext
+    from deeplearning4j_tpu.observe.scopes import kernel_calls, kernels_in_hlo
+    from deeplearning4j_tpu.ops.pallas_kernels import _default_blocks
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert _default_blocks(256) == (512, 1024)
+    layer = LatentAttention(n_in=2048, n_out=2048, dtype="bfloat16")
+    rt = RecurrentType(2048, 8192)
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, jnp.bfloat16, sharding=one_chip), jax.eval_shape(
+            lambda key: layer.initialize(key, rt), jax.random.PRNGKey(0)))
+    assert params["W_kva"].shape == (2048, 512 + 64)
+    assert params["W_kvb"].shape == (512, 20 * (192 + 256))
+    x = jax.ShapeDtypeStruct((1, 8192, 2048), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def total(p, a):
+        y, _ = layer.apply(p, {}, a, LayerContext(train=True))
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(total, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    assert kernel_calls(kernels_in_hlo(text), ("attn.latent",)) == {
+        "attn.latent": 2}
+
+
 def test_block_diffusion_kernels_compile_at_the_published_widths(
         one_chip, no_compile_cache):
     """The forward and the one backward kernel under ``BlockDiffusion(8192,
