@@ -75,16 +75,33 @@ output reaches the module, and the module's output the head, as edges.
 
 ``recompute`` wraps a block's ``apply`` in ``jax.checkpoint`` while
 training, so the step ``fit()`` builds keeps each block's input for the
-backward pass and recomputes the block's internals there, but for the
-flash-attention kernel's two results where the mixer took that kernel
-(``_recomputed``): the attention's output and its logsumexp are kept too,
-since only a second launch of the forward kernel could give them back.
-That costs a layer ``T x H x Dv`` at the compute type plus ``T x H``
-float32 a row (at 16,384 positions and 32 heads of 128 in bfloat16: 134.2
-+ 2.1 MB, for 18.6 ms of kernel), two to four times the block's input.
-The XLA attention path (short sequences, the CPU) names nothing and keeps
-nothing more. A model at its memory limit has ``recompute`` and the batch
-to give, as before.
+backward pass and recomputes the block's internals there, but for what a
+Pallas kernel's forward writes where the mixer took that kernel
+(``_recomputed``, by the names the kernels tag it with): only a second
+launch of the forward kernel could give it back. A row and layer keeps,
+at the cells' shapes:
+
+- the flash-attention kernel: the attention's output, ``T x H x Dv`` at
+  the compute type, and its logsumexp, ``T x H`` float32 (16,384
+  positions and 32 heads of 128 in bfloat16: 134.2 + 2.1 MB, for 18.6 ms
+  of kernel);
+- the gated delta rule: its result ``o``, ``T x Hv x Dv`` float32, the
+  state at every chunk border, ``T / C x Hv x Dk x Dv`` float32, and the
+  inverses ``Tm``, ``T x Hv x 128`` at the compute type (8,192 positions,
+  32 value heads of 128, chunks of 64, bfloat16: 134.2 + 268.4 + 67.1 =
+  469.8 MB);
+- the selective scan: its ``y``, ``T x D`` float32, and the border
+  states, ``T / 64 x S x D`` float32 (8,192 positions, 5,120 channels, 16
+  states: 167.8 + 41.9 = 209.7 MB);
+- the Mamba-2 scan: its normed rows ``o``, ``T x H P`` at the compute
+  type, and the border states, ``T / 128 x H P x S`` float32 (8,192
+  positions, 64 heads of 64, 128 states, bfloat16: 67.1 + 134.2 = 201.3
+  MB).
+
+That is two to fourteen times the block's input. The plain forms (the XLA
+attention path at short sequences, the plain scans at heads or states the
+kernels do not take, both on the CPU) name nothing and keep nothing more. A model at its memory limit has
+``recompute`` and the batch to give, as before.
 """
 
 from __future__ import annotations
@@ -137,16 +154,28 @@ CROSS_ATTENTION = "cross_attention"
 IGNORE_LABEL = -1
 
 
+def _kept_names() -> Tuple[str, ...]:
+    """The ``checkpoint_name`` tags a recomputing block keeps beside its
+    arguments: what the flash-attention kernel and the three scan kernels
+    write in their forward pass (module docstring). A name matches only
+    where a mixer traced that kernel."""
+    from deeplearning4j_tpu.ops import (
+        pallas_delta_rule as gdn, pallas_kernels as flash,
+        pallas_selective_scan as ssm, pallas_ssd_scan as ssd)
+    return (flash.FLASH_OUT_NAME, flash.FLASH_LSE_NAME,
+            gdn.DELTA_RULE_OUT_NAME, gdn.DELTA_RULE_STATES_NAME,
+            gdn.DELTA_RULE_TM_NAME, ssm.SCAN_Y_NAME, ssm.SCAN_STATES_NAME,
+            ssd.SSD_OUT_NAME, ssd.SSD_BORDERS_NAME)
+
+
 def _recomputed(apply):
     """``apply`` under the blocks' ``jax.checkpoint``: its arguments are
-    kept for the backward pass and, where a mixer ran the flash-attention
-    kernel, that kernel's output and logsumexp (module docstring);
-    everything else is computed again there."""
-    from deeplearning4j_tpu.ops.pallas_kernels import (
-        FLASH_LSE_NAME, FLASH_OUT_NAME)
+    kept for the backward pass and, where a mixer ran a Pallas kernel, what
+    that kernel's forward wrote (``_kept_names``); everything else is
+    computed again there."""
     return jax.checkpoint(
         apply, policy=jax.checkpoint_policies.save_only_these_names(
-            FLASH_OUT_NAME, FLASH_LSE_NAME))
+            *_kept_names()))
 
 
 @register_serializable
@@ -290,8 +319,10 @@ class HybridDecoderBlock(_ResidualBlock, FeedForwardLayer):
     ``recompute``: while training, keep the block's input for the backward
     pass and compute its internals again there; an attention mixer on the
     flash kernel keeps that kernel's output and logsumexp besides (``T x H
-    x head_dim`` at the compute type and ``T x H`` float32 a row), so its
-    forward kernel runs once. Off: no ``jax.checkpoint``."""
+    x head_dim`` at the compute type and ``T x H`` float32 a row), and a
+    DeltaNet mixer on the delta rule's kernels that rule's result, border
+    states and inverses (module docstring), so either forward kernel runs
+    once. Off: no ``jax.checkpoint``."""
     mixer: str = GATED_DELTANET
     # gated attention, and block-diffusion attention (no output gate;
     # ``block_length`` is its alone)

@@ -51,6 +51,7 @@ from typing import Any, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -59,6 +60,16 @@ from deeplearning4j_tpu.ops.pallas_kernels import (
 
 _F32 = jnp.float32
 _ROWS = 8        # rows of a scalar tile: gc, gc_last, beta, then zeros
+
+# ``checkpoint_name`` tags of what the forward kernel writes when
+# differentiated: the result and the backward's two residuals (the states at
+# the chunk borders, float32, and the inverses ``Tm``, compute type). A
+# ``jax.checkpoint`` whose policy saves these names (the decoder blocks')
+# keeps all three and does not launch the forward kernel again; anywhere
+# else the tags are the identity. All three come out of one ``pallas_call``.
+DELTA_RULE_OUT_NAME = "gdn_delta_rule.o"
+DELTA_RULE_STATES_NAME = "gdn_delta_rule.states"
+DELTA_RULE_TM_NAME = "gdn_delta_rule.tm"
 
 GDN_KERNEL_GAUGE = (
     "dl4j_gdn_kernel_chunks",
@@ -476,6 +487,10 @@ def _delta_rule(q, k, v, sc, s0, chunk, precision, interpret):
 def _delta_rule_fwd(q, k, v, sc, s0, chunk, precision, interpret):
     o, s_final, states, tm = _forward(q, k, v, sc, s0, chunk, precision,
                                       True, interpret)
+    # the tagged result is the primal too (``_flash_fwd_rule``'s reason)
+    o = checkpoint_name(o, DELTA_RULE_OUT_NAME)
+    states = checkpoint_name(states, DELTA_RULE_STATES_NAME)
+    tm = checkpoint_name(tm, DELTA_RULE_TM_NAME)
     return (o, s_final), (q, k, v, sc, states, tm)
 
 

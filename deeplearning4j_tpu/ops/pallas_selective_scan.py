@@ -49,6 +49,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -60,6 +61,14 @@ from deeplearning4j_tpu.ops.pallas_kernels import (
 _F32 = jnp.float32
 _GROUP = 8       # tokens a loop iteration unrolls: the rows of a sublane tile
 _TILE = 128      # tokens a lane tile of B^T and C^T holds
+
+# ``checkpoint_name`` tags of what the forward kernel writes when
+# differentiated: ``y`` and the float32 states at the chunk borders. A
+# ``jax.checkpoint`` whose policy saves these names (the decoder blocks')
+# keeps both and does not launch the forward kernel again; anywhere else the
+# tags are the identity. Both come out of one ``pallas_call``.
+SCAN_Y_NAME = "ssm_selective_scan.y"
+SCAN_STATES_NAME = "ssm_selective_scan.states"
 
 SSM_KERNEL_GAUGE = (
     "dl4j_ssm_kernel_chunks",
@@ -332,6 +341,9 @@ def _scan(x, dt, a_t, b_t, c_t, chunk, interpret):
 
 def _scan_fwd(x, dt, a_t, b_t, c_t, chunk, interpret):
     y, states = _forward(x, dt, a_t, b_t, c_t, chunk, True, interpret)
+    # the tagged result is the primal too (``_flash_fwd_rule``'s reason)
+    y = checkpoint_name(y, SCAN_Y_NAME)
+    states = checkpoint_name(states, SCAN_STATES_NAME)
     return y, (x, dt, a_t, b_t, c_t, states)
 
 

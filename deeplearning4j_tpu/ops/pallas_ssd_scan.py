@@ -84,6 +84,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -97,6 +98,15 @@ from deeplearning4j_tpu.ops.pallas_kernels import (
 _F32 = jnp.float32
 _Q = 128         # tokens a chunk: a decay matrix is one (128, 128) tile
 _TILE = 128      # lanes
+
+# ``checkpoint_name`` tags of what the forward kernel writes when
+# differentiated: the normed rows ``o`` (compute type) and the float32
+# states at the chunk borders. A ``jax.checkpoint`` whose policy saves these
+# names (the decoder blocks') keeps both and does not launch the forward
+# kernel again; anywhere else the tags are the identity. Both come out of
+# one ``pallas_call``.
+SSD_OUT_NAME = "ssd_scan.o"
+SSD_BORDERS_NAME = "ssd_scan.borders"
 
 SSD_KERNEL_GAUGE = (
     "dl4j_ssd_kernel_chunks",
@@ -537,6 +547,9 @@ def _scan(x, z, b, c, sc, skip, w, heads, eps, precision, interpret):
 def _scan_fwd(x, z, b, c, sc, skip, w, heads, eps, precision, interpret):
     o, borders = _forward(x, z, b, c, sc, skip, w, heads, eps, precision,
                           True, interpret)
+    # the tagged result is the primal too (``_flash_fwd_rule``'s reason)
+    o = checkpoint_name(o, SSD_OUT_NAME)
+    borders = checkpoint_name(borders, SSD_BORDERS_NAME)
     return o, (x, z, b, c, sc, skip, w, borders)
 
 

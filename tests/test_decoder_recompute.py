@@ -1,8 +1,9 @@
 """What a recomputing decoder block keeps for its backward pass: its
-arguments and, where the mixer ran the flash kernel (interpreted here), that
-kernel's output and logsumexp by name, so the forward kernel is not launched
-again; the numbers are those of the block without ``jax.checkpoint``. And
-the counter that says so of a compiled step."""
+arguments and, where the mixer ran the flash kernel or a scan kernel
+(interpreted here), what that kernel's forward wrote, by name, so the
+forward kernel is not launched again; the numbers are those of the block
+without ``jax.checkpoint``. And the counter that says so of a compiled
+step."""
 
 import contextlib
 import functools
@@ -18,7 +19,10 @@ from deeplearning4j_tpu.nn.layers import decoder
 from deeplearning4j_tpu.nn.layers.base import LayerContext
 from deeplearning4j_tpu.observe import scopes
 from deeplearning4j_tpu.observe.registry import default_registry
+from deeplearning4j_tpu.ops import pallas_delta_rule as gdn
 from deeplearning4j_tpu.ops import pallas_kernels as pk
+from deeplearning4j_tpu.ops import pallas_selective_scan as ssm
+from deeplearning4j_tpu.ops import pallas_ssd_scan as ssd
 
 T, WIDTH = 64, 32
 VISIBILITIES = {"causal": pk.Causal(), "window": pk.Causal(16),
@@ -147,6 +151,97 @@ def test_outside_a_checkpoint_the_tags_change_nothing_that_is_lowered(
     jax.clear_caches()
     assert "name[" not in str(jax.make_jaxpr(grad)(q, k, v))
     assert lowered() == tagged
+
+
+# a scan mixer's kernel module and the names its forward rule tags, the
+# result's first
+SCANS = {
+    decoder.GATED_DELTANET: (gdn, (gdn.DELTA_RULE_OUT_NAME,
+                                   gdn.DELTA_RULE_STATES_NAME,
+                                   gdn.DELTA_RULE_TM_NAME)),
+    decoder.MAMBA2: (ssd, (ssd.SSD_OUT_NAME, ssd.SSD_BORDERS_NAME)),
+    decoder.MAMBA: (ssm, (ssm.SCAN_Y_NAME, ssm.SCAN_STATES_NAME)),
+}
+
+
+def scan_block_under(mixer, recompute):
+    """A small block on ``mixer`` at head and state sizes its kernels take
+    (lane tiles of 128)."""
+    if mixer == decoder.GATED_DELTANET:
+        return decoder.HybridDecoderBlock(
+            name="b", n_in=WIDTH, n_out=WIDTH, mixer=mixer, n_key_heads=1,
+            n_value_heads=2, key_head_dim=128, value_head_dim=128,
+            num_experts=4, held_experts=(0, 1, 2, 3), expert_hidden=16,
+            top_k=2, recompute=recompute)
+    if mixer == decoder.MAMBA2:
+        return decoder.SingleMixerBlock(
+            name="b", n_in=WIDTH, n_out=WIDTH, mixer=mixer, mamba_heads=2,
+            mamba_head_dim=64, n_groups=1, d_state=128, recompute=recompute)
+    return decoder.StateSpaceHybridBlock(
+        name="b", n_in=WIDTH, n_out=WIDTH, mixer=mixer, d_inner=128,
+        d_state=8, dt_rank=3, mlp_hidden=48, recompute=recompute)
+
+
+@pytest.fixture()
+def on_the_scan_kernels(monkeypatch):
+    """The scan layers take their Pallas kernels, as on a TPU at these
+    sizes (the kernels interpret on the CPU)."""
+    for module, _ in SCANS.values():
+        monkeypatch.setattr(module, "kernels_take", lambda *a: True)
+
+
+@pytest.mark.parametrize("mixer", SCANS)
+def test_a_recomputing_scan_block_gives_the_plain_blocks_loss_and_gradients(
+        on_the_scan_kernels, mixer):
+    results = []
+    for recompute in (False, True):
+        loss, params, x = loss_of(scan_block_under(mixer, recompute),
+                                  squared=True)
+        results.append(jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(
+            params, x))
+    (plain, plain_grads), (kept, kept_grads) = results
+    assert plain == kept
+    same = jax.tree.map(lambda a, b: bool(jnp.array_equal(a, b)),
+                        plain_grads, kept_grads)
+    assert all(jax.tree.leaves(same)), same
+
+
+@pytest.mark.parametrize("mixer", SCANS)
+def test_a_recomputing_scan_block_keeps_its_arguments_and_the_named_results(
+        on_the_scan_kernels, mixer):
+    """Beside its arguments the block keeps exactly what the scan kernel's
+    forward wrote, and its gradient launches that kernel once: one forward
+    and one backward ``pallas_call``."""
+    _, names = SCANS[mixer]
+    loss, params, x = loss_of(scan_block_under(mixer, recompute=True))
+    lines = saved_residuals(loss, params, x)
+    kept = [line for line in lines if " from the argument " not in line
+            and not line.endswith("from a constant")]
+    assert len(kept) == len(names) and len(lines) > len(names), lines
+    for name in names[1:]:
+        assert sum(f"named '{name}'" in line for line in kept) == 1, kept
+    # the result feeds the forward pass too: the printer may name the
+    # ``reduce_precision`` jax puts after the tag (the flash case's reason)
+    out, = (line for line in kept
+            if not any(f"named '{name}'" in line for name in names[1:]))
+    assert (f"named '{names[0]}'" in out
+            or ("output of reduce_precision" in out
+                and "_kernels)" in out)), out
+    saved = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(params, x))
+    for name in names:
+        assert saved.count(f"name[name={name}]") == 1, name
+    assert saved.count("pallas_call") == 2
+
+
+@pytest.mark.parametrize("mixer", SCANS)
+def test_on_the_plain_scan_forms_a_recomputing_block_keeps_no_more(mixer):
+    """Narrow heads and the CPU: the plain scans name nothing, and the
+    block keeps nothing but its arguments."""
+    loss, params, x = loss_of(scan_block_under(mixer, recompute=True))
+    lines = saved_residuals(loss, params, x)
+    assert not [line for line in lines if "named '" in line]
+    assert all(" from the argument " in line
+               or line.endswith("from a constant") for line in lines), lines
 
 
 # a compiled step's text as the TPU's compiler writes it, cut to what is
